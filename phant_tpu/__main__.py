@@ -285,10 +285,12 @@ def make_genesis_parent_header() -> BlockHeader:
     )
 
 
-def main(argv=None) -> int:
-    logging.basicConfig(level=logging.INFO, format="%(levelname)s %(name)s: %(message)s")
-    args = build_parser().parse_args(argv)
-
+def build_server(args) -> EngineAPIServer:
+    """Everything `python -m phant_tpu` does before it serves, from parsed
+    args: select the backends (a `tpu` backend without a TPU raises HERE,
+    before the port is bound), resolve the chain config, build the chain,
+    the scheduler config and the bound Engine API server. `main` serves
+    what this returns; chip_smoke.py posts to it over loopback."""
     set_crypto_backend(args.crypto_backend)
     set_evm_backend(args.evm_backend)
     if args.commitment is not None:
@@ -383,6 +385,13 @@ def main(argv=None) -> int:
         sched_config=sched_config,
     )
     log.info("Engine API listening on %s:%d", args.host, server.port)
+    return server
+
+
+def main(argv=None) -> int:
+    logging.basicConfig(level=logging.INFO, format="%(levelname)s %(name)s: %(message)s")
+    args = build_parser().parse_args(argv)
+    server = build_server(args)
     metrics_server = None
     if args.metrics:
         from phant_tpu.engine_api.server import serve_metrics

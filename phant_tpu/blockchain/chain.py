@@ -199,7 +199,7 @@ class Blockchain:
 
         def resolve(span, handle):
             """Materialize a window's senders; a device failure mid-replay
-            (tunnel drop, OOM, preemption) degrades to the CPU batch for
+            (device lost, OOM, preemption) degrades to the CPU batch for
             the window instead of sinking the import — the reference has
             no device to lose (its crypto is always in-process,
             src/crypto/ecdsa.zig); fault tolerance here is the cost of the
@@ -211,6 +211,9 @@ class Blockchain:
             except Exception:
                 import logging
 
+                from phant_tpu.backend import device_fallback
+
+                device_fallback("chain_senders")
                 logging.getLogger("phant.chain").warning(
                     "device sender-recovery failed for blocks %s-%s; "
                     "recovering on CPU",

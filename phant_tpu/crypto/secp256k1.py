@@ -32,7 +32,10 @@ Point = Optional[Tuple[int, int]]  # None = point at infinity (affine)
 
 
 def _inv(a: int, m: int) -> int:
-    return pow(a, m - 2, m)
+    # extended Euclid in C (~10x the Fermat exponentiation it replaces);
+    # 0 maps to 0 exactly as pow(0, m - 2, m) did
+    a %= m
+    return pow(a, -1, m) if a else 0
 
 
 def _point_add(p1: Point, p2: Point) -> Point:

@@ -149,10 +149,10 @@ def keccak256_chunked(words: jax.Array, nchunks: jax.Array, *, max_chunks: int) 
 def keccak256_chunked_auto(
     words: jax.Array, nchunks: jax.Array, *, max_chunks: int
 ) -> jax.Array:
-    """Device keccak dispatch: the Pallas kernel where Mosaic runs (real
-    TPU — slope-timed 44.4M hashes/s on a v5e-1, ~34x the host AVX-512
-    batch and 1.25x this file's jnp program), the jnp program otherwise
-    (CPU-mesh tests, interpret-less backends).  Same contract and
+    """Device keccak dispatch: the Pallas kernel on the `tpu` platform
+    (where it IS the keccak — a Mosaic failure propagates, see
+    keccak_pallas.pallas_available), the jnp program on the CPU platform
+    (CPU-mesh tests without interpret mode).  Same contract and
     bit-identical output on both paths; composes inside jit (the fused
     witness/ecrecover programs call this mid-graph)."""
     from phant_tpu.ops.keccak_pallas import keccak256_chunked_pallas, pallas_available

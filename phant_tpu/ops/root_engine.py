@@ -244,6 +244,9 @@ class RootEngine:
                 except Exception:
                     import logging
 
+                    from phant_tpu.backend import device_fallback
+
+                    device_fallback("root_dispatch")
                     logging.getLogger("phant.root").warning(
                         "device root dispatch failed for %d plans; "
                         "host fallback at resolve",

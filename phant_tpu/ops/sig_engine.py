@@ -269,6 +269,9 @@ class SigEngine:
                 except Exception:
                     import logging
 
+                    from phant_tpu.backend import device_fallback
+
+                    device_fallback("sig_dispatch")
                     logging.getLogger("phant.sig").warning(
                         "device sig dispatch failed for %d rows; "
                         "native fallback at resolve",

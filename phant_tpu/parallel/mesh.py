@@ -35,16 +35,12 @@ from phant_tpu.ops.witness_jax import (
     witness_digests,
 )
 
-if hasattr(jax, "shard_map"):  # jax >= 0.8 moved shard_map out of experimental
-    shard_map = jax.shard_map
-else:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map  # type: ignore
-
+shard_map = jax.shard_map
 
 import contextlib
 import threading
 
-# serializes the cache-suspension window below: the config flip is
+# serializes the cache-off window below: the config flip is
 # process-global, so concurrent sharded compiles must take turns. A
 # single-device compile racing the window at worst skips one persistent-
 # cache write (benign; its in-memory executable is unaffected) — there is
@@ -53,18 +49,16 @@ import threading
 _CACHE_TOGGLE_LOCK = threading.RLock()
 
 # AOT-compiled sharded executables, keyed by (kernel, mesh devices, static
-# params, input shapes/dtypes). Every sharded entry point below used to
-# build a FRESH closure and jax.jit it per call, which meant (a) a full
-# re-trace on every call and (b) the process-global cache-suspension
-# window toggling around every one of them — under mesh-sharded SERVING
-# that toggle would fire per dispatched batch forever, and any concurrent
-# single-device compile would lose its persistent-cache write each time.
-# The memo compiles once per key (inside the suspension window) via the
-# AOT path (jit().lower().compile()); steady-state calls hit the compiled
+# params, input shapes/dtypes). Every sharded entry point below builds a
+# FRESH closure; jitting it per call would mean (a) a full re-trace on
+# every dispatched batch under mesh-sharded SERVING and (b) the
+# process-global cache-off window toggling around every one of them. The
+# memo compiles once per key (inside the window) via the AOT path
+# (jit().lower().compile()); steady-state calls hit the compiled
 # executable directly and never touch the cache config again.
 # MeshExecutorPool pre-warms the serving kernels at start
-# (prewarm_sharded), so a serving process pays its suspension windows at
-# boot, not mid-traffic.
+# (prewarm_sharded), so a serving process pays its windows at boot, not
+# mid-traffic.
 _EXEC_CACHE: dict = {}
 _EXEC_LOCK = threading.Lock()
 
@@ -84,10 +78,10 @@ def _compiled_call(key: tuple, build, args):
     expects — the lowered executable bakes them in, and the memo key
     carries the mesh device ids + input shapes/dtypes so a shape or mesh
     change compiles a fresh executable. The whole miss path (including
-    the compile) runs under _EXEC_LOCK: first-compiles were already
-    serialized by the cache-toggle lock, and a lock-free read of the
-    shared dict would be exactly the unlocked-shared-state hazard
-    phantlint's LOCK rule exists to catch."""
+    the compile) runs under _EXEC_LOCK: first-compiles are serialized by
+    the cache-toggle lock anyway, and a lock-free read of the shared dict
+    would be exactly the unlocked-shared-state hazard phantlint's LOCK
+    rule exists to catch."""
     with _EXEC_LOCK:
         fn = _EXEC_CACHE.get(key)
         if fn is None:
@@ -99,25 +93,26 @@ def _compiled_call(key: tuple, build, args):
 
 @contextlib.contextmanager
 def _no_compile_cache():
-    """Serializing multi-device (shard_map) executables SEGFAULTS this
-    image's jaxlib in the persistent compilation cache's write path
-    (reproduced deterministically with a fresh single-writer cache dir), so
-    every sharded compile below runs with the cache suspended. Single-device
-    kernels keep the cache — their serialization is fine."""
+    """Serializing SOME multi-device (shard_map) executables still
+    SEGFAULTS jax 0.9.0 in the persistent compilation cache's write path
+    (`compilation_cache.put_executable_and_time`; the sharded GLV
+    ecrecover does, in PR 24's whole-suite run, while the sharded witness
+    programs were written and re-read fine), so every sharded compile
+    below runs with the cache SWITCHED OFF — `jax_enable_compilation_cache`,
+    the directory is left alone. Single-device kernels keep the cache."""
+    from jax.experimental.compilation_cache import compilation_cache
+
     with _CACHE_TOGGLE_LOCK:
-        try:
-            prev = jax.config.jax_compilation_cache_dir
-        except AttributeError:  # pragma: no cover - much older jax
+        if not jax.config.jax_enable_compilation_cache:
             yield
             return
-        if prev is None:
-            yield
-            return
-        jax.config.update("jax_compilation_cache_dir", None)
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()  # the on/off decision is memoized
         try:
             yield
         finally:
-            jax.config.update("jax_compilation_cache_dir", prev)
+            jax.config.update("jax_enable_compilation_cache", True)
+            compilation_cache.reset_cache()
 
 
 def make_mesh(n_devices: Optional[int] = None, axis: str = "dp") -> Mesh:
@@ -402,12 +397,12 @@ def prewarm_sharded(
 
     MeshExecutorPool calls this when the mesh serving path comes up so the
     first served batch doesn't pay a multi-second cold shard_map compile
-    mid-traffic, and so the compile-cache suspension windows
-    (_no_compile_cache — a process-global config toggle) fire at BOOT,
-    where no single-device compile is racing them. Production shapes that
-    differ from the prewarm shapes still compile once each on first hit
-    (bucketing keeps that set small); what the executable memo guarantees
-    is that STEADY-STATE sharded dispatches never toggle the cache at all.
+    mid-traffic, and so the compile-cache-off windows (_no_compile_cache —
+    a process-global config toggle) fire at BOOT, where no single-device
+    compile is racing them. Production shapes that differ from the prewarm
+    shapes still compile once each on first hit (bucketing keeps that set
+    small); what the executable memo guarantees is that STEADY-STATE
+    sharded dispatches never toggle the cache at all.
     Returns the number of executables compiled (0 when both were already
     warm)."""
     n = int(mesh.devices.size)

@@ -21,6 +21,7 @@ from phant_tpu.replay.engine import (
 from phant_tpu.replay.fixture import (
     ReplayFixture,
     attach_witnesses,
+    build_synthetic_chain,
     from_bench_tuple,
     load_fixture,
     save_fixture,
@@ -33,6 +34,7 @@ __all__ = [
     "ReplayReport",
     "ReplayFixture",
     "attach_witnesses",
+    "build_synthetic_chain",
     "from_bench_tuple",
     "load_fixture",
     "replay_fixture",

@@ -68,6 +68,7 @@ import numpy as np
 
 from phant_tpu.obs import critpath
 from phant_tpu.obs.busy import BusyAccountant
+from phant_tpu.serving import deadline as deadline_clock
 from phant_tpu.utils.trace import metrics
 
 log = logging.getLogger("phant_tpu.serving.mesh")
@@ -267,10 +268,14 @@ class MeshExecutorPool:
         for t in self._threads:
             t.start()
         metrics.gauge_set("sched.mesh_devices", self._n)
+        # sharded executables the boot prewarm compiled (None: not run, or
+        # failed — then counted in backend.device_fallbacks too)
+        self.prewarm_compiled: Optional[int] = None
+        self._prewarm_thread = threading.Thread(
+            target=self._prewarm, name="phant-mesh-prewarm", daemon=True
+        )
         if prewarm:
-            threading.Thread(
-                target=self._prewarm, name="phant-mesh-prewarm", daemon=True
-            ).start()
+            self._prewarm_thread.start()
 
     # -- routing -------------------------------------------------------------
 
@@ -445,10 +450,11 @@ class MeshExecutorPool:
         shed (its waiter is gone) rather than spend engine work — the same
         contract as the scheduler's post-slot-wait re-check."""
         now = time.monotonic()
-        live = [j for j in item["jobs"] if j.deadline is None or now <= j.deadline]
+        live = [j for j in item["jobs"] if not deadline_clock.passed(j.deadline, now)]
         if len(live) != len(item["jobs"]):
+            kept = set(map(id, live))
             for j in item["jobs"]:
-                if j.deadline is not None and now > j.deadline:
+                if id(j) not in kept:
                     self._on_expired(j)
         return live or None
 
@@ -469,6 +475,7 @@ class MeshExecutorPool:
         return eng
 
     def _run_executor(self, i: int) -> None:
+        deadline_clock.serving_thread()
         engine = self._engines[i]
         # immutable pipeline depth, read lock-free (write-once in __init__)
         depth_cap = self._depth
@@ -752,17 +759,23 @@ class MeshExecutorPool:
         once (parallel/mesh.py prewarm_sharded) when the device backend is
         live, so no serving batch pays a cold shard_map compile — and the
         compile-cache suspension windows all fire before traffic."""
-        try:
-            from phant_tpu.backend import crypto_backend, jax_device_ok
+        from phant_tpu.backend import crypto_backend, device_fallback, jax_device_ok
 
-            if crypto_backend() != "tpu" or not jax_device_ok():
-                return
+        if crypto_backend() != "tpu" or not jax_device_ok():
+            return
+        try:
             from phant_tpu.parallel.mesh import make_mesh, prewarm_sharded
 
-            compiled = prewarm_sharded(make_mesh(self._n))
-            log.info("mesh prewarm: %d sharded executables compiled", compiled)
+            self.prewarm_compiled = prewarm_sharded(make_mesh(self._n))
+            log.info(
+                "mesh prewarm: %d sharded executables compiled", self.prewarm_compiled
+            )
         except Exception:
-            # prewarm is an optimization, never a liveness dependency
+            # prewarm is an optimization, never a liveness dependency — but
+            # a failure is counted where an operator (and chip_smoke.py,
+            # which requires zero) sees it: the first sharded batch will
+            # meet the same compiler
+            device_fallback("mesh_prewarm")
             log.warning("mesh prewarm failed", exc_info=True)
 
     def drain(self) -> None:

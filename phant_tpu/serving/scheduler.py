@@ -31,7 +31,9 @@ shape instead:
   touches `wait_for_space` (verify_many) jobs, whose contract is
   completion. Every request still carries a deadline; expiry while
   queued fails with `DeadlineExpired` (`-32051`) without touching the
-  engine.
+  engine. The deadline's clock excludes the seconds this process spends
+  compiling device programs (serving/deadline.py): a compile holding
+  the executor is not load, so a cold server answers late, not 503.
 * **Dequeue: priority + weighted fairness** — the serial mutation lane
   preempts all queued witness work (head-of-chain `newPayload` must not
   sit behind a backfill burst); among witness lanes, lanes whose head is
@@ -151,6 +153,7 @@ from phant_tpu.obs import critpath, timeline
 from phant_tpu.obs.busy import BusyAccountant
 from phant_tpu.obs.flight import flight
 from phant_tpu.obs.watchdog import Watchdog
+from phant_tpu.serving import deadline as deadline_clock
 from phant_tpu.serving.qos import (
     DEFAULT_TENANT,
     OVERFLOW_TENANT,
@@ -501,7 +504,7 @@ class _Job:
     kind: str
     future: Future
     admitted: float  # monotonic admission time
-    deadline: Optional[float]  # monotonic expiry, None = no deadline
+    deadline: Optional[float]  # expiry on serving/deadline.py's clock, None = none
     # QoS: the tenant lane this job queues in (folded through the
     # max_tenants cap at admission) and its priority class. `sheddable`
     # is False for wait_for_space admissions (verify_many): their
@@ -551,6 +554,12 @@ class VerificationScheduler:
         self._pipe_depth = max(1, self.config.pipeline_depth)
         self._quota = max(0, self.config.tenant_quota)
         self._max_tenants = max(1, self.config.max_tenants)
+        # deadlines do not count compile seconds (serving/deadline.py);
+        # jax is in the process iff the tpu backend resolved a device
+        from phant_tpu.backend import jax_device_ok
+
+        if jax_device_ok():
+            deadline_clock.start_compile_clock()
         # QoS policy objects (serving/qos.py): both are only ever touched
         # under _lock, so they need no locking of their own
         self._picker = WeightedFairPicker(self.config.tenant_weights)
@@ -1085,7 +1094,7 @@ class VerificationScheduler:
             d = deadline_s
         if d <= 0 or d == float("inf"):
             return None
-        return time.monotonic() + d
+        return deadline_clock.expiry(d)
 
     # -- QoS locked helpers --------------------------------------------------
 
@@ -1475,6 +1484,7 @@ class VerificationScheduler:
             self._exec_stage = stage
 
     def _run(self) -> None:
+        deadline_clock.serving_thread()
         batch: List[_Job] = []
         try:
             while True:
@@ -2043,13 +2053,10 @@ class VerificationScheduler:
         now = time.monotonic()
         expired: List[_Job] = []
         for q in (self._serial_q, *self._lanes.values()):
-            live = [
-                j for j in q if j.deadline is None or now <= j.deadline
-            ]
+            live = [j for j in q if not deadline_clock.passed(j.deadline, now)]
             if len(live) != len(q):
-                expired.extend(
-                    j for j in q if j.deadline is not None and now > j.deadline
-                )
+                kept = set(map(id, live))
+                expired.extend(j for j in q if id(j) not in kept)
                 q[:] = live
         if not expired:
             return
@@ -2189,7 +2196,7 @@ class VerificationScheduler:
         with self._lock:
             self.stats["serial_jobs"] += 1
             self._tenant_locked(job.tenant)["served"] += 1
-        if job.deadline is not None and time.monotonic() > job.deadline:
+        if deadline_clock.passed(job.deadline):
             self._shed_expired(job)
             return
         t0 = time.monotonic()
@@ -2234,7 +2241,7 @@ class VerificationScheduler:
     def _shed_or_keep(self, batch: List[_Job], now: float) -> List[_Job]:
         jobs = []
         for j in batch:
-            if j.deadline is not None and now > j.deadline:
+            if deadline_clock.passed(j.deadline, now):
                 self._shed_expired(j)
             else:
                 jobs.append(j)
@@ -2639,6 +2646,7 @@ class VerificationScheduler:
     # -- resolve worker (pipeline_depth > 1) ---------------------------------
 
     def _resolve_run(self) -> None:
+        deadline_clock.serving_thread()
         item: Optional[dict] = None
         try:
             while True:

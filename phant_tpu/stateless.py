@@ -946,6 +946,9 @@ def dispatch_sender_recovery(chain_id: int, txs, rows=None):
         # shed/crashed lane: recover from the rows ALREADY built (no
         # second signing-hash pass) on the fused native batch —
         # force_cpu because a -32052 may mean the device itself died
+        from phant_tpu.backend import device_fallback
+
+        device_fallback("sig_lane")
         return signer.recover_rows_async(rows, force_cpu=True)()
 
     try:

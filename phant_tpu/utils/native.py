@@ -1,14 +1,21 @@
-"""Loader/builder for the native C++ runtime (native/*.cc -> libphant_native.so).
+"""Loader/builder for the native C++ runtime (native/*.cc -> libphant_native-<key>.so).
 
 The reference builds its native components (ethash keccak, evmone, secp256k1)
 as static libs inside build.zig (reference: build.zig:79-135). Here the native
 runtime is a single shared library compiled on demand with g++ and loaded via
 ctypes; if the toolchain is unavailable the pure-Python fallbacks take over.
+
+Built libraries are keyed (in their file name under build/) on a hash of
+their sources, the compiler flags and the host's CPU flags: the flags
+include -march=native, so a build/ carried over from another machine —
+the chip tool copies the checkout as it stands on disk — is never loaded,
+and everything the program loads comes from files git would commit.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -18,7 +25,6 @@ from typing import List, Optional, Sequence
 _REPO_ROOT = Path(__file__).resolve().parent.parent.parent
 _NATIVE_DIR = _REPO_ROOT / "native"
 _BUILD_DIR = _REPO_ROOT / "build"
-_LIB_PATH = _BUILD_DIR / "libphant_native.so"
 
 _lock = threading.Lock()
 _loaded: Optional["NativeLib"] = None
@@ -36,11 +42,42 @@ def _sources() -> List[Path]:
     )
 
 
-def _needs_rebuild() -> bool:
-    if not _LIB_PATH.exists():
-        return True
-    lib_mtime = _LIB_PATH.stat().st_mtime
-    return any(src.stat().st_mtime > lib_mtime for src in _sources())
+def _host_cpu_flags() -> str:
+    """What -march=native resolves against on this host."""
+    import platform
+
+    try:
+        with open("/proc/cpuinfo") as f:
+            for line in f:
+                if line.startswith(("flags", "Features")):
+                    return line
+    except OSError:
+        pass
+    return platform.machine() + platform.processor()
+
+
+def _keyed_build(stem: str, srcs: Sequence[Path], flags: Sequence[str], verbose: bool = False) -> Path:
+    """Compile `srcs` with `g++ flags` into build/<stem>-<key>.so unless
+    that exact file exists; the key covers source bytes, flags and the
+    host's CPU flags. The compiler writes a private temp file that is
+    renamed into place, so concurrent first builds (pytest workers) never
+    load a half-written library."""
+    h = hashlib.sha256()
+    for src in srcs:
+        h.update(src.name.encode())
+        h.update(src.read_bytes())
+    h.update(" ".join(flags).encode())
+    h.update(_host_cpu_flags().encode())
+    path = _BUILD_DIR / f"{stem}-{h.hexdigest()[:16]}.so"
+    if not path.exists():
+        _BUILD_DIR.mkdir(exist_ok=True)
+        tmp = path.with_suffix(f".{os.getpid()}.tmp")
+        cmd = ["g++", *flags, *(str(s) for s in srcs), "-o", str(tmp)]
+        if verbose:
+            print("[phant_tpu.native]", " ".join(cmd))
+        subprocess.run(cmd, check=True, capture_output=not verbose)
+        os.replace(tmp, path)
+    return path
 
 
 def _arch_flags() -> list:
@@ -62,19 +99,12 @@ def _arch_flags() -> list:
 
 
 def build_native(verbose: bool = False) -> Path:
-    """Compile native/*.cc into build/libphant_native.so (idempotent)."""
-    _BUILD_DIR.mkdir(exist_ok=True)
-    if _needs_rebuild():
-        cmd = [
-            "g++", "-O3", *_arch_flags(), "-std=c++20", "-shared", "-fPIC",
-            "-fno-exceptions", "-fno-rtti", "-Wall",
-            *(str(s) for s in _sources()),
-            "-o", str(_LIB_PATH),
-        ]
-        if verbose:
-            print("[phant_tpu.native]", " ".join(cmd))
-        subprocess.run(cmd, check=True, capture_output=not verbose)
-    return _LIB_PATH
+    """Compile native/*.cc into build/libphant_native-<key>.so (idempotent)."""
+    flags = [
+        "-O3", *_arch_flags(), "-std=c++20", "-shared", "-fPIC",
+        "-fno-exceptions", "-fno-rtti", "-Wall",
+    ]
+    return _keyed_build("libphant_native", _sources(), flags, verbose)
 
 
 class NativeLib:
@@ -371,7 +401,6 @@ class EngineCore:
         return ok.astype(bool)
 
 
-_EXT_PATH = _BUILD_DIR / "phant_engine_ext.so"
 _ext_lock = threading.Lock()
 _ext_mod = None
 _ext_failed = False
@@ -404,25 +433,19 @@ def load_engine_ext():
                 _NATIVE_DIR / "engine.cc",
                 _NATIVE_DIR / "keccak.cc",
             ]
-            _BUILD_DIR.mkdir(exist_ok=True)
-            if not _EXT_PATH.exists() or any(
-                s.stat().st_mtime > _EXT_PATH.stat().st_mtime for s in srcs
-            ):
-                cmd = [
-                    "g++", "-O3", *_arch_flags(), "-std=c++20", "-shared",
-                    "-fPIC", "-fno-rtti",
-                    f"-I{sysconfig.get_paths()['include']}",
-                    *(str(s) for s in srcs),
-                    "-o", str(_EXT_PATH),
-                ]
-                # the one-time g++ compile runs UNDER _ext_lock on purpose:
-                # concurrent first callers must wait for one build, not
-                # race two compilers over the same .so path
-                subprocess.run(cmd, check=True, capture_output=True)  # phantlint: disable=LOCKBLOCK — serialized one-time build
+            flags = [
+                "-O3", *_arch_flags(), "-std=c++20", "-shared",
+                "-fPIC", "-fno-rtti",
+                f"-I{sysconfig.get_paths()['include']}",
+            ]
+            # the one-time g++ compile runs UNDER _ext_lock on purpose:
+            # concurrent first callers must wait for one build, not
+            # run two compilers
+            ext_path = _keyed_build("phant_engine_ext", srcs, flags)  # phantlint: disable=LOCKBLOCK — serialized one-time build
             import importlib.util
             from importlib.machinery import ExtensionFileLoader
 
-            loader = ExtensionFileLoader("phant_engine_ext", str(_EXT_PATH))
+            loader = ExtensionFileLoader("phant_engine_ext", str(ext_path))
             spec = importlib.util.spec_from_loader("phant_engine_ext", loader)
             mod = importlib.util.module_from_spec(spec)
             spec.loader.exec_module(mod)

@@ -18,9 +18,9 @@
 # Usage: scripts/check.sh [extra pytest args]
 set -uo pipefail
 cd "$(dirname "$0")/.."
-export PHANT_JAX_CACHE="${PHANT_JAX_CACHE:-$PWD/build/jax_cache_tests}"
+export JAX_COMPILATION_CACHE_DIR="${JAX_COMPILATION_CACHE_DIR:-$PWD/build/jax_cache_tests}"
 export PYTHONFAULTHANDLER=1
-mkdir -p "$PHANT_JAX_CACHE" build/logs
+mkdir -p "$JAX_COMPILATION_CACHE_DIR" build/logs
 
 # device-kernel / compile-heavy files get a process each; everything else
 # shares the "core" group. Keep this list in sync with tests/.
@@ -137,10 +137,10 @@ rc=$?
 echo "[check] group soak: rc=$rc in $(( $(date +%s) - t0 ))s"
 if [ "$rc" -ne 0 ]; then cat build/logs/soak.log; fail=1; fi
 
-# Bench-trend sentinel, STRICT: the committed BENCH_ACK file carries the
-# root-caused dead artifacts (BENCH_r05), so the sentinel can finally be
-# a real gate — a new dead round or a beyond-noise-bar section regression
-# goes red here instead of hiding in a report nobody reads.
+# Bench-trend sentinel, STRICT: a BENCH_ACK file next to the artifacts
+# carries root-caused dead rounds, so the sentinel is a real gate — a new
+# dead round or a beyond-noise-bar section regression goes red here
+# instead of hiding in a report nobody reads.
 t0=$(date +%s)
 python scripts/benchtrend.py > build/logs/trend.log 2>&1
 rc=$?
@@ -150,7 +150,7 @@ if [ "$rc" -ne 0 ]; then cat build/logs/trend.log; fail=1; fi
 
 total=$(( $(date +%s) - start ))
 if [ "$fail" -ne 0 ]; then
-  echo "[check] RED in ${total}s (cache: $PHANT_JAX_CACHE)"
+  echo "[check] RED in ${total}s (cache: $JAX_COMPILATION_CACHE_DIR)"
   exit 1
 fi
-echo "[check] green in ${total}s (cache: $PHANT_JAX_CACHE)"
+echo "[check] green in ${total}s (cache: $JAX_COMPILATION_CACHE_DIR)"

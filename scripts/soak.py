@@ -637,11 +637,11 @@ def _post_root_phase() -> int:
         SchedulerDown,
         VerificationScheduler,
     )
-    from phant_tpu.utils.jaxcache import enable_compile_cache
+    from phant_tpu.ops._cache import enable_compilation_cache
 
     from test_post_root import _request_set
 
-    enable_compile_cache()  # warm from the pytest groups' persistent cache
+    enable_compilation_cache()  # warm from the pytest groups' persistent cache
     failures: list = []
     os.environ["PHANT_ALLOW_JAX_CPU"] = "1"
     set_crypto_backend("tpu")
@@ -760,11 +760,11 @@ def _sender_lane_phase() -> int:
         SchedulerDown,
         VerificationScheduler,
     )
-    from phant_tpu.utils.jaxcache import enable_compile_cache
+    from phant_tpu.ops._cache import enable_compilation_cache
 
     from test_sender_lane import _request_set
 
-    enable_compile_cache()  # warm from the pytest groups' persistent cache
+    enable_compilation_cache()  # warm from the pytest groups' persistent cache
     failures: list = []
     os.environ["PHANT_ALLOW_JAX_CPU"] = "1"
     set_crypto_backend("tpu")

@@ -138,9 +138,10 @@ def test_acked_dead_artifact_reports_but_does_not_flag(tmp_path):
 
 
 def test_committed_tree_is_strict_green(tmp_path):
-    """check.sh now runs benchtrend WITHOUT --report-only: the committed
-    artifacts + BENCH_ACK must be strict-green or the gate is red on
-    arrival (r05 is acked in the committed BENCH_ACK)."""
+    """check.sh runs benchtrend WITHOUT --report-only: the committed
+    tree must be strict-green or the gate is red on arrival. The tree
+    carries no bench history today (PR 24 removed the pre-PR-5 records),
+    and an empty history is green."""
     real = subprocess.run(
         [sys.executable, str(REPO / "scripts" / "benchtrend.py")],
         capture_output=True,
@@ -217,8 +218,7 @@ def test_cli_exit_codes(tmp_path):
         text=True,
     )
     assert report.returncode == 0, report.stdout
-    # and the committed repo artifacts parse end to end (r05's dead
-    # artifact is a known flag: report-only must still exit 0 over them)
+    # and the committed tree (no artifacts today) is handled end to end
     real = subprocess.run(
         [sys.executable, str(REPO / "scripts" / "benchtrend.py"), "--report-only"],
         capture_output=True,

@@ -30,10 +30,8 @@ def interpret_mode():
         return
     old = kp._INTERPRET
     kp._INTERPRET = True
-    kp._PALLAS_OK = None
     yield
     kp._INTERPRET = old
-    kp._PALLAS_OK = None
 
 
 def _run(payloads, max_chunks=None):

@@ -88,8 +88,8 @@ def test_run_blocks_cpu_path(small_chain):
 
 
 def test_run_blocks_survives_device_loss(small_chain, monkeypatch):
-    """Fault injection (SURVEY §5): the device dying mid-replay (tunnel
-    drop / preemption) must degrade to CPU recovery, not sink the import."""
+    """Fault injection (SURVEY §5): the device dying mid-replay (device
+    lost / preemption) must degrade to CPU recovery, not sink the import."""
     import phant_tpu.ops.secp256k1_jax as secp_jax
 
     genesis, blocks, fresh_state, _total, _calls = small_chain

@@ -241,6 +241,63 @@ def test_deadline_expires_while_queued():
         s.shutdown()
 
 
+def test_deadline_does_not_count_compile_seconds():
+    """The same queue wait as above, but the executor spent it COMPILING
+    (jax's compile-duration event, as the listener receives it): the job
+    behind it is served, not shed — a cold server answers late, not 503."""
+    from phant_tpu.serving import deadline
+
+    wits = _witness_set(1)
+    s = _sched(max_batch=4, max_wait_ms=1.0, queue_depth=16, deadline_ms=40.0)
+    try:
+
+        def compile_for_100ms():  # runs on the executor, a serving thread
+            t0 = time.monotonic()
+            time.sleep(0.1)
+            deadline._on_duration(
+                "/jax/core/compile/backend_compile_duration", time.monotonic() - t0
+            )
+
+        s.submit_serial(compile_for_100ms)
+        time.sleep(0.02)
+        assert s.submit_witness(*wits[0]).result(timeout=30)
+    finally:
+        s.shutdown()
+
+
+def test_compile_clock_counts_the_union_of_intervals(monkeypatch):
+    """Lanes compile concurrently and traces nest: overlapping intervals
+    are counted once, other events not at all, so the credit never
+    exceeds the wall clock."""
+    import types
+
+    from phant_tpu.serving import deadline
+
+    clock = [1000.0]
+    monkeypatch.setattr(
+        deadline, "time", types.SimpleNamespace(monotonic=lambda: clock[0])
+    )
+    event = "/jax/core/compile/backend_compile_duration"
+    d = deadline.expiry(30.0)  # admitted at 1000, 30s to live
+
+    def ends(at, secs, name=event):
+        clock[0] = at
+        deadline._on_duration(name, secs)
+
+    ends(1125.0, 5.0)  # not a serving thread yet: holds no queue, no credit
+    assert deadline.passed(d, at=1031.0)
+    monkeypatch.setattr(deadline._tls, "serving", True, raising=False)
+    ends(1125.0, 5.0)  # a short compile on one lane, [1120, 1125]
+    ends(1130.0, 130.0)  # a long one on another, [1000, 1130], ends later
+    ends(1130.0, 40.0, "/jax/core/compile/jaxpr_trace_duration")  # nested
+    ends(1130.0, 999.0, "/jax/some/other_duration")
+    assert not deadline.passed(d, at=1159.0)  # 130s credited: 29s waited
+    assert deadline.passed(d, at=1161.0)  # and no more than 130s
+    ends(1200.0, 30.0)  # apart from the others: [1170, 1200]
+    assert not deadline.passed(d, at=1189.0) and deadline.passed(d, at=1191.0)
+    assert not deadline.passed(None)
+
+
 # ---------------------------------------------------------------------------
 # lifecycle: crash fail-fast + drain
 # ---------------------------------------------------------------------------

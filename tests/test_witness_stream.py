@@ -695,7 +695,10 @@ def test_sigint_mesh_e2e_exits_clean():
     drain and exit rc 0 within the deadline after one SIGINT."""
     port = 18651 + (os.getpid() % 500)
     env = dict(os.environ)
-    env.setdefault("PHANT_JAX_CACHE", os.path.join("build", "jax_cache_pytest"))
+    env.setdefault(
+        "JAX_COMPILATION_CACHE_DIR",
+        os.path.abspath(os.path.join("build", "jax_cache_pytest")),
+    )
     proc = subprocess.Popen(
         [
             sys.executable, "-m", "phant_tpu",
