@@ -76,13 +76,12 @@ DEFAULT_ENTRIES: Tuple[str, ...] = (
     # stage's honest sender readback is annotated)
     "phant_tpu.ops.sig_engine.SigEngine.prefetch_batch",
     "phant_tpu.ops.sig_engine.SigEngine.sig_many",
-    # critical-path attribution (PR 15): the busy-time integration points
-    # in the lane loops — begin_batch's handoff (busy begin) and the
-    # resolve worker (busy end) — are pure host arithmetic by design; a
+    # the lanes' measured stages (PR 26, utils/trace.lane_stage): the
+    # brackets around begin_batch in the pipeline handoff and around
+    # resolve_batch in the resolve worker are clock readings by design; a
     # reintroduced `.item()`/readback there would put a device sync on
     # EVERY pipelined batch under the banner of observability (the mesh
-    # lane loop, _run_executor above, already covers its own busy
-    # brackets)
+    # lane loop, _run_executor above, already covers its own brackets)
     "phant_tpu.serving.scheduler.VerificationScheduler._pipeline_handoff",
     "phant_tpu.serving.scheduler.VerificationScheduler._resolve_run",
     # pluggable commitment schemes (PR 12): the binary backend's witness

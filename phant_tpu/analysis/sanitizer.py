@@ -417,12 +417,11 @@ def register_default_shared_classes() -> List[type]:
     objects every Engine API handler thread, scheduler worker, and obs
     poller touches concurrently.  Imports lazily: callers enable the
     sanitizer first, so the classes' locks are built as proxies."""
-    from phant_tpu.obs.busy import BusyAccountant
     from phant_tpu.obs.flight import FlightRecorder
     from phant_tpu.serving.scheduler import VerificationScheduler
     from phant_tpu.utils.trace import Metrics
 
-    targets = [VerificationScheduler, FlightRecorder, BusyAccountant, Metrics]
+    targets = [VerificationScheduler, FlightRecorder, Metrics]
     for cls in targets:
         register_shared_class(cls)
     return targets

@@ -36,16 +36,18 @@ Observability surface: `GET /metrics` serves the process metrics registry
 as Prometheus text exposition (histogram families additionally carry
 derived bucket-interpolated p50/p99 gauges), `GET /healthz` a JSON
 liveness probe that includes the scheduler state (queue depth, executor
-liveness, per-lane `device_busy_pct`) and turns 503 when the executor has
+liveness) and turns 503 when the executor has
 died; `GET /debug/flight` serves the obs flight recorder's ring (recent
 spans / errors / scheduler transitions) live, `GET /debug/slow` the
 SLO-exemplar ring (obs/critpath.py — full span trees of requests that
 blew `--slo-budget-ms`), `GET /debug/timeline?window=S` the unified
 tail-sampled timeline as Perfetto-loadable Chrome-trace JSON
-(obs/timeline.py — requests, lane batches, device busy windows on one
-time axis), `POST /debug/profile?seconds=T` grabs an
+(obs/timeline.py — requests with their phases at measured offsets and
+lane batches on one time axis), `POST /debug/profile?seconds=T` grabs an
 on-demand, single-flight-guarded `jax_profile` capture into
-`--profile-dir` (obs/profiler.py), and the first `/healthz` flip to 503
+`--profile-dir` (obs/profiler.py; the profiler's Python tracer off, its
+host tracer at level 1, so the capture holds the program's own `phant/`
+events and does not slow the server it looks at), and the first `/healthz` flip to 503
 auto-dumps the flight ring to `build/flight/` (phant_tpu/obs/). Every POST runs inside its own trace
 context — the `trace_id` rides the scheduler jobs and span records the
 request creates, and is echoed back in the `X-Phant-Trace` response
@@ -83,14 +85,25 @@ from phant_tpu.serving import (
 )
 from phant_tpu.utils.trace import (
     REQUEST_SECONDS_BUCKETS,
+    current_span,
     current_trace_id,
     metrics,
+    span,
     trace_context,
+    unwatch_gc,
+    watch_gc,
 )
 
 log = logging.getLogger("phant_tpu.engine_api")
 
 _START_MONOTONIC = time.monotonic()
+
+#: the front end's own phases of a POST (`engine_api.phase_seconds{phase=}`):
+#: the marks `do_POST`/`_handle_post` set on the request's frame span, which
+#: with verify_block's wall clock tile `engine_api.request_seconds`. `gate`
+#: is the wait for a slot of the stateless gate: microseconds unless the
+#: node is saturated, and then not to be read as decode time
+FRONTEND_PHASES = ("read", "json", "gate", "decode", "reply")
 
 #: methods that mutate Blockchain state and therefore run as serial jobs
 #: on the scheduler's executor (everything else is read-only or stateless
@@ -241,13 +254,6 @@ class _ObservableHandler(BaseHTTPRequestHandler):
     def do_GET(self) -> None:  # noqa: N802 (stdlib API)
         path = self.path.split("?", 1)[0]
         if path == "/metrics":
-            # re-integrate the device-busy windows to NOW before
-            # rendering: the gauges otherwise move only on batch
-            # transitions, and a metrics-only scraper would read an idle
-            # lane frozen at its last mid-traffic value forever
-            sched = active_scheduler()
-            if sched is not None:
-                sched.refresh_busy_gauges()
             self._reply_raw(
                 200,
                 metrics.prometheus_text().encode(),
@@ -267,8 +273,8 @@ class _ObservableHandler(BaseHTTPRequestHandler):
             )
         elif path == "/debug/timeline":
             # the unified timeline (obs/timeline.py): the last `window`
-            # seconds of kept requests, lane batches, device busy
-            # windows, and profiler captures as Perfetto-loadable
+            # seconds of kept requests, lane batches and profiler
+            # captures as Perfetto-loadable
             # Chrome-trace JSON — curl it straight into ui.perfetto.dev
             query = self.path.partition("?")[2]
             params = dict(
@@ -430,7 +436,6 @@ class EngineAPIServer:
                     # request — skip the Engine API accounting so the
                     # front-door latency histogram measures only traffic
                     return self._do_debug_post()
-                t0 = time.perf_counter()
                 # Lock-discipline audit (phantlint LOCK, PR 2): the
                 # counter / in-flight gauge / latency-histogram updates
                 # here deliberately run on the handler thread with no
@@ -441,43 +446,64 @@ class EngineAPIServer:
                 # to the lock-owning object's own attributes, so it
                 # (correctly) reports nothing here — this comment, not a
                 # disable annotation, is the audit record.
-                metrics.gauge_add("engine_api.inflight", 1)
+                #
+                # one trace context per request: the trace_id rides
+                # every span this thread opens and every scheduler job
+                # it submits, and comes back in X-Phant-Trace. The
+                # `request` frame span is the measured interval of the
+                # whole POST (headers parsed -> reply written): the
+                # front end's phases are its marks, verify_block its
+                # child by parent_id, and its duration IS
+                # engine_api.request_seconds.
+                req = None
                 try:
-                    # one trace context per request: the trace_id rides
-                    # every span this thread opens and every scheduler job
-                    # it submits, and comes back in X-Phant-Trace. The
-                    # tenant context (QoS lane + priority class,
-                    # serving/qos.py) rides the same thread-local channel:
-                    # X-Phant-Tenant names the admission lane (sanitized —
-                    # the header is attacker-controlled) and
-                    # X-Phant-Priority: head marks head-of-chain work
-                    # (state-mutating methods are always head class via
-                    # the serial lane, so the header only matters for
-                    # executeStateless).
-                    tenant = sanitize_tenant(
-                        self.headers.get("X-Phant-Tenant")
-                    )
-                    priority = (
-                        PRIORITY_HEAD
-                        if self.headers.get("X-Phant-Priority", "").lower()
-                        == "head"
-                        else PRIORITY_BACKFILL
-                    )
-                    with trace_context(), tenant_context(tenant, priority):
-                        self._handle_post()
+                    with trace_context(), span("request", frame=True) as req:
+                        req.resume = "reply"  # what follows a verify_block
+                        req.mark("read")
+                        metrics.gauge_add("engine_api.inflight", 1)
+                        try:
+                            # The tenant context (QoS lane + priority class,
+                            # serving/qos.py) rides the same thread-local
+                            # channel: X-Phant-Tenant names the admission lane
+                            # (sanitized — the header is attacker-controlled)
+                            # and X-Phant-Priority: head marks head-of-chain
+                            # work (state-mutating methods are always head
+                            # class via the serial lane, so the header only
+                            # matters for executeStateless).
+                            tenant = sanitize_tenant(
+                                self.headers.get("X-Phant-Tenant")
+                            )
+                            priority = (
+                                PRIORITY_HEAD
+                                if self.headers.get("X-Phant-Priority", "").lower()
+                                == "head"
+                                else PRIORITY_BACKFILL
+                            )
+                            with tenant_context(tenant, priority):
+                                self._handle_post()
+                        finally:
+                            metrics.gauge_add("engine_api.inflight", -1)
                 finally:
-                    metrics.gauge_add("engine_api.inflight", -1)
-                    # the front-door latency histogram rides THE shared
-                    # bucket table (trace.REQUEST_SECONDS_BUCKETS): buckets
-                    # freeze at first observation, so a second call site
-                    # with its own tuple would silently split the family —
-                    # and the derived p50/p99 gauges (prometheus_text)
-                    # need the overload tail the shared table carries
-                    metrics.observe_hist(
-                        "engine_api.request_seconds",
-                        time.perf_counter() - t0,
-                        buckets=REQUEST_SECONDS_BUCKETS,
-                    )
+                    if req is not None:
+                        for name, t_a, t_b in req.intervals:
+                            if name in FRONTEND_PHASES:
+                                metrics.observe_hist(
+                                    "engine_api.phase_seconds",
+                                    (t_b - t_a) / 1e9,
+                                    buckets=REQUEST_SECONDS_BUCKETS,
+                                    phase=name,
+                                )
+                        # the front-door latency histogram rides THE shared
+                        # bucket table (trace.REQUEST_SECONDS_BUCKETS): buckets
+                        # freeze at first observation, so a second call site
+                        # with its own tuple would silently split the family —
+                        # and the derived p50/p99 gauges (prometheus_text)
+                        # need the overload tail the shared table carries
+                        metrics.observe_hist(
+                            "engine_api.request_seconds",
+                            req.duration_s,
+                            buckets=REQUEST_SECONDS_BUCKETS,
+                        )
 
             def _handle_post(self) -> None:
                 length = int(self.headers.get("Content-Length", 0))
@@ -492,6 +518,8 @@ class EngineAPIServer:
                     log.debug("client stalled mid-body; connection dropped")
                     self.close_connection = True
                     return
+                req = current_span()
+                req.mark("json")
                 try:
                     request = json.loads(body)
                 except json.JSONDecodeError:
@@ -511,10 +539,16 @@ class EngineAPIServer:
                     )
                     return
                 method = request.get("method", "")
+                # `decode` ends where verify_block opens (utils/trace.span,
+                # under a frame): the handler's own time is not the
+                # front end's
                 try:
                     if isinstance(method, str) and method.startswith(
                         _SERIAL_METHOD_PREFIXES
                     ):
+                        # nothing is marked while this thread waits on the
+                        # serial lane: the executor does the work
+                        req.mark(None)
                         # state-mutating: exclusive execution on the
                         # scheduler's single executor thread (the global
                         # lock's replacement — admission-ordered, drained
@@ -530,6 +564,7 @@ class EngineAPIServer:
                         # box must shed backfill fast (head-of-chain gets
                         # 8x the patience) instead of thrashing hundreds
                         # of half-done EVM re-executions
+                        req.mark("gate")
                         tenant = current_tenant()
                         if not outer._gate.acquire(
                             current_priority() == PRIORITY_HEAD
@@ -559,6 +594,7 @@ class EngineAPIServer:
                                 },
                             )
                             return
+                        req.mark("decode")
                         try:
                             status, response = handle_request(
                                 outer.blockchain, request
@@ -569,10 +605,12 @@ class EngineAPIServer:
                         # read-only: run concurrently on THIS handler
                         # thread; any witness verification inside
                         # coalesces via the scheduler's batch assembler
+                        req.mark("decode")
                         status, response = handle_request(
                             outer.blockchain, request
                         )
                 except SchedulerError as e:
+                    req.mark("reply")
                     # overload / deadline / executor-down: distinct
                     # JSON-RPC codes (-32050/-32051/-32052) over HTTP 503
                     metrics.count("engine_api.request_errors")
@@ -585,6 +623,7 @@ class EngineAPIServer:
                         },
                     )
                     return
+                req.mark("reply")
                 if status >= 400 or "error" in response:
                     metrics.count("engine_api.request_errors")
                 self._reply(status, response)
@@ -600,6 +639,9 @@ class EngineAPIServer:
         # install only after the socket bound: a bind failure must not
         # leak a process-globally installed scheduler
         install(scheduler)
+        # the collector's pauses, as the program sees them: one callback
+        # for the process (runtime.gc_pause_seconds, `gc` intervals)
+        watch_gc()
 
     @property
     def port(self) -> int:
@@ -627,6 +669,7 @@ class EngineAPIServer:
         finally:
             uninstall(self.scheduler)
             self._server.server_close()
+            unwatch_gc()
 
 
 class MetricsServer:

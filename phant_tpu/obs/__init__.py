@@ -28,17 +28,20 @@ died. This package is that layer:
   resolve / sig_wait / EVM / post-root ...), gauges the unattributed
   residual (the honesty check), and captures SLO-busting requests as
   full span trees into a dedicated ring (`GET /debug/slow`).
-* **Device-busy accounting** (`busy.py`, PR 15): per-lane
-  union-of-intervals busy integration over the two-phase begin/resolve
-  brackets — `sched.device_busy_pct{device=}` in /metrics and /healthz.
 * **On-demand profiler** (`profiler.py`, PR 15): `POST /debug/profile`
   grabs a single-flight-guarded, hard-capped `jax_profile` window from a
   live server.
 * **Timeline export** (`timeline.py`, PR 16): a third span sink plus
-  batch/busy/profiler taps tail-sample the serving path into a bounded
+  batch/profiler taps tail-sample the serving path into a bounded
   recorder, rendered as Perfetto-loadable Chrome-trace JSON at
-  `GET /debug/timeline?window=S` — requests, lane batches, and device
-  busy windows on one time axis, stitched by flow events.
+  `GET /debug/timeline?window=S` — requests (phases at their measured
+  offsets) and lane batches on one time axis, stitched by flow events.
+* **Measured intervals** (`utils/trace.py`, PR 26): spans carry ids, a
+  start and an end on one clock, phases are child intervals, and all of
+  it is written into the profiler's trace as `phant/` events; what the
+  device is busy or idle under is read from that trace
+  (`scripts/trace_gaps.py`), and the host's own time at the device from
+  `device.host_seconds{lane=,op=}`.
 
 Importing this package registers the flight recorder, the critpath
 rollup, and the timeline recorder as span sinks, so any module that
@@ -49,13 +52,11 @@ free; the registrations are idempotent.
 from __future__ import annotations
 
 from phant_tpu.obs import critpath, timeline
-from phant_tpu.obs.busy import BusyAccountant
 from phant_tpu.obs.flight import FlightRecorder, flight
 from phant_tpu.obs.watchdog import Watchdog
 from phant_tpu.utils.trace import add_span_sink
 
 __all__ = [
-    "BusyAccountant",
     "FlightRecorder",
     "Watchdog",
     "critpath",

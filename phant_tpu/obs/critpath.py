@@ -14,7 +14,11 @@ wall clock into an exclusive phase breakdown:
   pack           begin_batch's lock-held scan (the batch's pack_ms)
   dispatch       dispatched-and-in-flight: begin_batch return -> resolve
                  start — the window the device (or the pipeline ahead of
-                 this batch) owns the request
+                 this batch) owns the request. A REMAINDER (witness_verify
+                 less the four batch-record numbers), not a measured
+                 wait: the seconds a host thread stood blocked on the
+                 chip are `device.host_seconds{op=sync}` (utils/trace.py
+                 device_host)
   resolve        readback + commit + linkage join (the batch's resolve_ms)
   witness_decode witness -> WitnessStateDB materialization
   sig_wait       the sig-lane join block before EVM execution
@@ -210,8 +214,7 @@ def configure(
 
 
 def enabled() -> bool:
-    """Is the attribution layer on? Read at scheduler/pool construction to
-    gate the busy accountants (obs/busy.py) with the same switch."""
+    """Is the attribution layer on?"""
     return _cfg.enabled
 
 
@@ -279,7 +282,9 @@ def attribute(record: dict) -> Tuple[Dict[str, float], float, float]:
     # witness_verify sub-tiling: queue_wait/prefetch/pack/resolve come
     # from the witness batch record (bare keys — the sig/root lanes
     # prefix theirs), each clipped to what is left of the phase; the
-    # remainder is `dispatch`, the dispatched-and-in-flight window
+    # remainder is `dispatch`, the dispatched-and-in-flight window (a
+    # remainder by definition: the measured device wait is
+    # device.host_seconds{op=sync})
     wv = ph("stateless.witness_verify")
     rem = wv
     for label, key in (

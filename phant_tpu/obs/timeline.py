@@ -1,24 +1,22 @@
 """Unified timeline export (PR 16): tail-sampled Perfetto traces.
 
-PR 15 built the instruments — critpath phase tiling, per-lane busy
-gauges, `/debug/slow`, the on-demand profiler — but each is an island:
-span records are ring entries, batch intervals are flight records, busy
-windows are gauges, and the XLA profiler writes its own directory.
-Nothing lines them up on ONE time axis. This module is that axis: an
-always-on, bounded-memory timeline recorder — a third span sink plus
-taps on the scheduler/mesh batch finishers and the BusyAccountant —
+PR 15 built the instruments — critpath phase tiling, `/debug/slow`, the
+on-demand profiler — but each is an island: span records are ring
+entries, batch intervals are flight records, and the XLA profiler
+writes its own directory. Nothing lines them up on ONE time axis. This
+module is that axis: an always-on, bounded-memory timeline recorder — a
+third span sink plus taps on the scheduler/mesh batch finishers —
 whose `export(window_s)` renders the recent past as Chrome-trace JSON
 (the `traceEvents` object format) that Perfetto loads directly:
 
 * pid 1 "requests"   — one track per HTTP handler thread; each kept
-  request is a `verify_block` slice tiled with its critpath phase
-  sub-slices (laid SEQUENTIALLY in pipeline order from the span's
-  phase totals — a reconstruction, not measured start offsets);
+  request is a `verify_block` slice with its critpath phases as
+  sub-slices at their MEASURED offsets: the span's own child intervals
+  (utils/trace.py) on the handler thread, and inside the witness wait
+  the lane stages' start and end from the batch record (`stages`);
 * pid 2 "lanes"      — one track per (lane, device): witness/root/sig
   batch slices with prefetch/pack/dispatch/resolve sub-stages, keyed
   by batch_id;
-* pid 3 "devices"    — per-device busy slices from the BusyAccountant's
-  union-of-intervals open/close transitions;
 * pid 4 "profiler"   — one slice + start/end instants per
   `POST /debug/profile` capture inside the window, so the XLA device
   trace can be laid alongside the host timeline (clock-sync metadata
@@ -59,7 +57,7 @@ the env), `configure()` overrides directly (tests, the bench A/B).
 Thread-safety: one module lock guards the rings, the tail-sample
 counters, and the p99 state; every tap is O(1) dict work under it.
 The sink must never fail the traced work — span() swallows sink
-exceptions, and the batch/busy taps are called outside scheduler locks.
+exceptions, and the batch taps are called outside scheduler locks.
 """
 
 from __future__ import annotations
@@ -137,11 +135,10 @@ _lock = threading.Lock()
 _rng = random.Random()
 
 # the rings (all bounded by cfg.ring except profiles, which are rare):
-# requests/batches carry the flow-joinable entries, busy the device
+# requests/batches carry the flow-joinable entries,
 # occupancy slices, profiles the clock-sync markers
 _requests: deque = deque(maxlen=_cfg.ring)
 _batches: deque = deque(maxlen=_cfg.ring)
-_busy: deque = deque(maxlen=_cfg.ring)
 _profiles: deque = deque(maxlen=16)
 
 # tail-sample accounting (mirrored to obs.timeline_{kept,dropped})
@@ -196,15 +193,14 @@ def configure(
 
 
 def _resize_locked(n: int) -> None:
-    global _requests, _batches, _busy
+    global _requests, _batches
     if _requests.maxlen != n:
         _requests = deque(_requests, maxlen=n)
         _batches = deque(_batches, maxlen=n)
-        _busy = deque(_busy, maxlen=n)
 
 
 def enabled() -> bool:
-    """Is the timeline recorder on? Read by the batch/busy taps before
+    """Is the timeline recorder on? Read by the batch taps before
     building their entry dicts."""
     return _cfg.enabled
 
@@ -230,7 +226,6 @@ def reset() -> None:
     with _lock:
         _requests.clear()
         _batches.clear()
-        _busy.clear()
         _profiles.clear()
         _kept.clear()
         _dropped.clear()
@@ -278,6 +273,61 @@ def _keep_reason_locked(
     if n == 1 or (n > 1 and _rng.randrange(n) == 0):
         return "sample"
     return None
+
+
+#: the span's child intervals that are critpath phases as they stand
+_HANDLER_SLICES = {
+    "stateless.sig_rows": "sig_rows",
+    "stateless.witness_decode": "witness_decode",
+    "sched.sig_wait": "sig_wait",
+    "stateless.execute": "evm",
+    "stateless.post_root_plan": "root_plan",
+    "stateless.post_root": "post_root",
+}
+
+
+def measured_slices(record: dict) -> List[Tuple[str, int, int]]:
+    """(critpath phase, offset_us from the span's start, dur_us) of one
+    `verify_block` record, from its measured intervals: the handler
+    thread's phases as the span recorded them (`sig_wait` lies inside
+    `evm`, `root_plan` inside `post_root`: nested slices), and the
+    witness wait cut at the lane stages' own clock readings, which are on
+    the span's clock: `queue_wait` up to the first stage, `prefetch`,
+    `pack`, `dispatch` from pack's end to resolve's start, `resolve`."""
+    t_span = record.get("start_ns")
+    if not isinstance(t_span, int):
+        return []
+    out: List[Tuple[str, int, int]] = []
+
+    def put(name: str, t0: int, t1: int) -> None:
+        if t1 > t0:
+            out.append((name, (t0 - t_span) // 1000, max((t1 - t0) // 1000, 1)))
+
+    for name, t0, t1 in record.get("intervals") or ():
+        label = _HANDLER_SLICES.get(name)
+        if label is not None:
+            put(label, t0, t1)
+        elif name == "stateless.witness_verify":
+            stages = record.get("stages") or {}
+            cuts = []
+            for stage in ("prefetch", "pack", "resolve"):
+                se = stages.get(stage)
+                if se:
+                    # clipped: a stage can claim no more than the wait
+                    a, b = max(se[0], t0), min(se[1], t1)
+                    if b > a:
+                        cuts.append((stage, a, b))
+            if not cuts:
+                put("dispatch", t0, t1)  # no stage record: one wait
+                continue
+            put("queue_wait", t0, cuts[0][1])
+            for i, (stage, a, b) in enumerate(cuts):
+                put(stage, a, b)
+                nxt = cuts[i + 1][1] if i + 1 < len(cuts) else t1
+                # between stages (and after the last) the pipeline or
+                # the device owns the request
+                put("dispatch", b, nxt)
+    return out
 
 
 def on_span(record: dict) -> None:
@@ -331,6 +381,7 @@ def on_span(record: dict) -> None:
                     "block": record.get("block"),
                     "error": record.get("error"),
                     "phases": {k: round(v, 3) for k, v in breakdown.items()},
+                    "slices": measured_slices(record),
                     "flows": flows,
                 }
             )
@@ -343,7 +394,7 @@ def on_span(record: dict) -> None:
             metrics.count("obs.timeline_dropped", reason="ring_full")
 
 
-# -- batch / busy / profiler taps --------------------------------------------
+# -- batch / profiler taps ---------------------------------------------------
 
 
 def record_batch(
@@ -377,17 +428,6 @@ def record_batch(
         _batches.append(entry)
 
 
-def record_busy(device: str, start_wall: float, end_wall: float) -> None:
-    """One closed device-busy interval (the BusyAccountant's open-count
-    1->0 transition): a slice on the pid-3 device track."""
-    if not _cfg.enabled or end_wall <= start_wall:
-        return
-    with _lock:
-        _busy.append(
-            {"device": str(device), "start": start_wall, "end": end_wall}
-        )
-
-
 def record_profile(path: str, start_wall: float, end_wall: float) -> None:
     """One on-demand profiler capture window (POST /debug/profile):
     start/end markers on the profiler track + `metadata.clock_sync`, so
@@ -406,7 +446,6 @@ def record_profile(path: str, start_wall: float, end_wall: float) -> None:
 #: Chrome-trace process ids (one per track family); M metadata names them
 _PID_REQUESTS = 1
 _PID_LANES = 2
-_PID_DEVICES = 3
 _PID_PROFILER = 4
 
 
@@ -423,7 +462,6 @@ def export(window_s: float) -> dict:
     with _lock:
         reqs = [r for r in _requests if r["end"] >= cutoff]
         bats = [b for b in _batches if b["end"] >= cutoff]
-        busy = [b for b in _busy if b["end"] >= cutoff]
         profs = [p for p in _profiles if p["end"] >= cutoff]
         kept = dict(_kept)
         dropped = dict(_dropped)
@@ -474,28 +512,20 @@ def export(window_s: float) -> dict:
                 },
             }
         )
-        # phase sub-slices: SEQUENTIAL layout in pipeline order from the
-        # span's phase totals — a reconstruction (the span measures
-        # totals, not offsets), honest about being one
-        off = start_us
-        for phase in critpath.PHASES:
-            v = r["phases"].get(phase)
-            if not v:
-                continue
-            pdur = int(v * 1e3)
+        # phase sub-slices at their measured offsets (measured_slices)
+        for phase, off_us, pdur in r["slices"]:
             events.append(
                 {
                     "ph": "X",
                     "pid": _PID_REQUESTS,
                     "tid": tid,
-                    "ts": off,
-                    "dur": max(pdur, 1),
+                    "ts": start_us + off_us,
+                    "dur": pdur,
                     "name": phase,
                     "cat": "phase",
-                    "args": {"ms": v},
+                    "args": {"ms": r["phases"].get(phase)},
                 }
             )
-            off += max(pdur, 1)
         for lane, bid in r["flows"]:
             if (lane, bid) not in batch_keys:
                 continue  # the serving batch fell outside the window
@@ -616,27 +646,6 @@ def export(window_s: float) -> dict:
                     "id": fid,
                 }
             )
-
-    # -- devices (pid 3): busy slices ----------------------------------------
-    if busy:
-        meta(_PID_DEVICES, "devices")
-    dev_tids: Dict[str, int] = {}
-    for dev in sorted({b["device"] for b in busy}):
-        dev_tids[dev] = len(dev_tids) + 1
-        meta(_PID_DEVICES, f"device {dev}", tid=dev_tids[dev])
-    for b in busy:
-        events.append(
-            {
-                "ph": "X",
-                "pid": _PID_DEVICES,
-                "tid": dev_tids[b["device"]],
-                "ts": _us(b["start"]),
-                "dur": max(_us(b["end"]) - _us(b["start"]), 1),
-                "name": "busy",
-                "cat": "busy",
-                "args": {},
-            }
-        )
 
     # -- profiler (pid 4): capture windows + clock-sync instants -------------
     clock_sync = []

@@ -244,11 +244,13 @@ class DeviceDigests:
         self.on_resolve = on_resolve
 
     def resolve(self) -> List[bytes]:
-        from phant_tpu.utils.trace import metrics
+        from phant_tpu.utils.trace import device_host, metrics
 
         with metrics.phase("keccak.host_readback"):
             # the timed readback IS the honest sync (see phase name)
-            digests = digests_to_bytes(np.asarray(self.out))[: self.n]  # phantlint: disable=HOSTSYNC — timed digest readback
+            with device_host("witness", "sync"):
+                words = np.asarray(self.out)  # phantlint: disable=HOSTSYNC — timed digest readback
+            digests = digests_to_bytes(words)[: self.n]
         if self.on_resolve is not None:
             # fire ONCE: a second resolve() returning the same staging
             # lease to the pool twice would alias buffers across batches
@@ -263,13 +265,13 @@ def keccak256_batch_jax_async(
     """Enqueue a batched keccak on the device WITHOUT any host sync:
     returns a DeviceDigests handle whose `resolve()` pays the readback.
     `keccak256_batch_jax` is this plus an immediate resolve."""
-    from phant_tpu.utils.trace import metrics
+    from phant_tpu.utils.trace import device_host, metrics
 
     platform = jax.default_backend()
     metrics.count("keccak.batches", backend=platform)
     metrics.count("keccak.bytes", sum(map(len, payloads)), backend=platform)
     words, nchunks, C = pack_payloads(payloads, max_chunks)
-    with metrics.phase("keccak.device_dispatch"):
+    with metrics.phase("keccak.device_dispatch"), device_host("witness", "enqueue"):
         out = keccak256_chunked_auto(
             jnp.asarray(words), jnp.asarray(nchunks), max_chunks=C
         )

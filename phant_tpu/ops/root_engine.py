@@ -48,7 +48,7 @@ from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from phant_tpu.utils.trace import metrics
+from phant_tpu.utils.trace import device_host, metrics
 
 
 class RootPrefetch:
@@ -239,7 +239,8 @@ class RootEngine:
         if route:
             with metrics.phase("witness_engine.root_dispatch"):
                 try:
-                    h.device_out = self._dispatch(h.merged)
+                    with device_host("root", "enqueue"):
+                        h.device_out = self._dispatch(h.merged)
                     h.backend = "device"
                 except Exception:
                     import logging
@@ -303,7 +304,8 @@ class RootEngine:
         try:
             with metrics.phase("witness_engine.root_resolve"):
                 if handle.backend == "device":
-                    arr = np.asarray(handle.device_out, dtype="<u4")  # phantlint: disable=HOSTSYNC — timed root readback is the product
+                    with device_host("root", "sync"):
+                        arr = np.asarray(handle.device_out, dtype="<u4")  # phantlint: disable=HOSTSYNC — timed root readback is the product
                     flat = [arr[k].tobytes() for k in range(arr.shape[0])]
                     out: List[List[bytes]] = []
                     pos = 0

@@ -61,7 +61,7 @@ from typing import List, Optional, Sequence
 
 import numpy as np
 
-from phant_tpu.utils.trace import metrics
+from phant_tpu.utils.trace import device_host, metrics
 
 #: padding row for the device kernel: (e=1, r=1, s=1, parity=0) — the
 #: same filler `ecrecover_batch_async` pads its pow2 buckets with (a
@@ -264,7 +264,8 @@ class SigEngine:
         if route:
             with metrics.phase("witness_engine.sig_dispatch"):
                 try:
-                    h.device_out = self._dispatch(packed)
+                    with device_host("sig", "enqueue"):
+                        h.device_out = self._dispatch(packed)
                     h.backend = "device"
                 except Exception:
                     import logging
@@ -351,8 +352,10 @@ class SigEngine:
         from phant_tpu.ops.secp256k1_jax import digest_words_to_addresses
 
         digest, valid = handle.device_out
-        addrs = digest_words_to_addresses(np.asarray(digest))  # phantlint: disable=HOSTSYNC — timed sender readback is the product
-        valid_np = np.asarray(valid)  # phantlint: disable=HOSTSYNC — timed sender readback is the product
+        with device_host("sig", "sync"):
+            digest_np = np.asarray(digest)  # phantlint: disable=HOSTSYNC — timed sender readback is the product
+            valid_np = np.asarray(valid)  # phantlint: disable=HOSTSYNC — timed sender readback is the product
+        addrs = digest_words_to_addresses(digest_np)
         return [
             addrs[k] if bool(valid_np[k]) else None
             for k in range(handle.n_rows)

@@ -42,7 +42,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from phant_tpu.utils.trace import metrics
+from phant_tpu.utils.trace import device_host, metrics
 from phant_tpu.ops.witness_jax import (
     WITNESS_MAX_CHUNKS,
     _account_storage_root_off,
@@ -955,7 +955,9 @@ class WitnessEngine:
         # timed separately: the split localizes whether
         # the link or the kernel is eating the batch budget
         try:
-            with metrics.phase("keccak.device_dispatch"):
+            with metrics.phase("keccak.device_dispatch"), device_host(
+                "witness", "enqueue"
+            ):
                 if use_sharded and len(jax.devices()) > 1 and B % len(jax.devices()) == 0:
                     # multi-chip novelty hashing: shard the node axis over
                     # the mesh (default-safe: the sharded compile's cache-

@@ -60,7 +60,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from phant_tpu.utils.trace import metrics
+from phant_tpu.utils.trace import device_host, metrics
 from phant_tpu.ops.witness_jax import WITNESS_MAX_CHUNKS, _pow2ceil
 
 __all__ = [
@@ -225,16 +225,20 @@ class ResidentBatch:
         from phant_tpu.ops.keccak_jax import digests_to_bytes
 
         with metrics.phase("witness_resident.resolve"):
-            # the timed verdict readback IS the honest sync (1 B/block)
-            verdicts = np.asarray(self.verdict_out)[: self.n_blocks]  # phantlint: disable=HOSTSYNC — timed resident verdict readback
+            # the timed verdict readback IS the honest sync (1 B/block):
+            # device.host_seconds{lane=witness,op=sync} is the time this
+            # thread stands blocked on the chip, and nothing else
+            with device_host("witness", "sync"):
+                verdicts = np.asarray(self.verdict_out)[: self.n_blocks]  # phantlint: disable=HOSTSYNC — timed resident verdict readback
+                digest_words = None
+                if self.digest_out is not None:
+                    digest_words = np.asarray(self.digest_out)  # phantlint: disable=HOSTSYNC — timed core-commit digest readback
+                dropped = 0
+                for out in self.dropped_outs:
+                    dropped += int(np.asarray(out))  # phantlint: disable=HOSTSYNC — rides the resolve sync above
             digests: List[bytes] = []
-            if self.digest_out is not None:
-                digests = digests_to_bytes(np.asarray(self.digest_out))[  # phantlint: disable=HOSTSYNC — timed core-commit digest readback
-                    : self.n_core_novel
-                ]
-        dropped = 0
-        for out in self.dropped_outs:
-            dropped += int(np.asarray(out))  # phantlint: disable=HOSTSYNC — rides the resolve sync above
+            if digest_words is not None:
+                digests = digests_to_bytes(digest_words)[: self.n_core_novel]
         if dropped and self._table is not None:
             self._table.note_index_dropped(dropped)
         self.resolved = True
@@ -554,14 +558,18 @@ class ResidentTable:
             np.cumsum(lens[:-1], out=offsets[1:])
             slots = np.full(np_b, -1, np.int32)
             slots[: len(cand)] = np.arange(base, base + len(cand), dtype=np.int32)
-            out = self._update_fn(
-                *self._arrays,
-                self._put(blob),
-                self._put(offsets),
-                self._put(lens),
-                self._put(slots),
-                max_chunks=WITNESS_MAX_CHUNKS,
-            )
+            # device.host_seconds{lane=witness,op=enqueue}: the uploads
+            # and launches of this batch (update, verdict, gather), and
+            # not the host's numpy work between them
+            with device_host("witness", "enqueue"):
+                out = self._update_fn(
+                    *self._arrays,
+                    self._put(blob),
+                    self._put(offsets),
+                    self._put(lens),
+                    self._put(slots),
+                    max_chunks=WITNESS_MAX_CHUNKS,
+                )
             self._arrays = out[:5]
             h.dropped_outs.append(out[5])
         h.dropped_outs.extend(self._deferred_dropped)
@@ -583,16 +591,17 @@ class ResidentTable:
         for b, (root, _nodes) in enumerate(witnesses):
             roots_w[b] = np.frombuffer(root, dtype="<u4")
         digests, refs, ref_live = self._arrays[:3]
-        rows_d = self._put(rows)
-        h.verdict_out = self._verdict_fn(
-            digests,
-            refs,
-            ref_live,
-            rows_d,
-            rows_d >= 0,
-            self._put(block_id),
-            self._put(roots_w),
-        )
+        with device_host("witness", "enqueue"):
+            rows_d = self._put(rows)
+            h.verdict_out = self._verdict_fn(
+                digests,
+                refs,
+                ref_live,
+                rows_d,
+                rows_d >= 0,
+                self._put(block_id),
+                self._put(roots_w),
+            )
 
         # core-commit digests: the engine's host tables intern from the
         # DEVICE digests, so the host never hashes on this route
@@ -602,7 +611,8 @@ class ResidentTable:
             cslots[: len(core_novel)] = np.fromiter(
                 (sob[nb] for nb in core_novel), np.int32, len(core_novel)
             )
-            h.digest_out = self._gather_fn(digests, self._put(cslots))
+            with device_host("witness", "enqueue"):
+                h.digest_out = self._gather_fn(digests, self._put(cslots))
 
         h.uploaded_nodes = len(cand)
         h.uploaded_bytes = sum(map(len, cand))

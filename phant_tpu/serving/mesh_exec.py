@@ -66,10 +66,8 @@ from typing import Callable, List, Optional, Sequence
 
 import numpy as np
 
-from phant_tpu.obs import critpath
-from phant_tpu.obs.busy import BusyAccountant
 from phant_tpu.serving import deadline as deadline_clock
-from phant_tpu.utils.trace import metrics
+from phant_tpu.utils.trace import fold_stages, lane_stage, metrics
 
 log = logging.getLogger("phant_tpu.serving.mesh")
 
@@ -247,15 +245,6 @@ class MeshExecutorPool:
         self._closed = False
         self._dead: Optional[BaseException] = None
         self._mega_mesh = None  # memoized (mesh, ok) probe for megabatch
-        # per-lane device-busy accounting (obs/busy.py): each lane
-        # integrates its own [begin, resolve] union; megabatches occupy
-        # every chip at once and ride a dedicated device="mesh" series.
-        # Same switch as the critpath rollup (PHANT_OBS_ATTRIBUTION).
-        busy_on = critpath.enabled()
-        self._busy = [
-            BusyAccountant(str(i), enabled=busy_on) for i in range(self._n)
-        ]
-        self._mega_busy = BusyAccountant("mesh", enabled=busy_on)
         self._threads = [
             threading.Thread(
                 target=self._run_executor,
@@ -406,24 +395,18 @@ class MeshExecutorPool:
         padded[: len(blob)] = blob
         roots = roots_to_words([j.root for j in jobs])
         t0 = time.monotonic()
-        # device-busy: the fused dispatch occupies the WHOLE mesh —
-        # integrated on the device="mesh" series, not any one lane's
-        self._mega_busy.begin()
-        try:
-            out = witness_verify_fused_sharded(
-                mesh,
-                padded,
-                meta16,
-                roots,
-                max_chunks=WITNESS_MAX_CHUNKS,
-                n_blocks=len(jobs),
-            )
-            # the verdict readback is this batch's resolve — an honest sync
-            # (HOSTSYNC's cross-module taint does not reach here; comment,
-            # not a dead disable annotation)
-            verdicts = np.asarray(out)
-        finally:
-            self._mega_busy.end()
+        out = witness_verify_fused_sharded(
+            mesh,
+            padded,
+            meta16,
+            roots,
+            max_chunks=WITNESS_MAX_CHUNKS,
+            n_blocks=len(jobs),
+        )
+        # the verdict readback is this batch's resolve — an honest sync
+        # (HOSTSYNC's cross-module taint does not reach here; comment,
+        # not a dead disable annotation)
+        verdicts = np.asarray(out)
         with self._lock:
             self._megabatches += 1
             n_mega = self._megabatches
@@ -547,7 +530,13 @@ class MeshExecutorPool:
                             stage = "prefetch"
                             self._on_stage(item["batch_id"], "prefetch", i)
                             t0 = time.perf_counter()
-                            plan = pf(wits)
+                            with lane_stage(
+                                item.setdefault("stages", {}),
+                                "prefetch",
+                                [j.trace_id for j in jobs],
+                                item["batch_id"],
+                            ):
+                                plan = pf(wits)
                             item["prefetch_ms"] = round(
                                 (time.perf_counter() - t0) * 1e3, 3
                             )
@@ -558,12 +547,18 @@ class MeshExecutorPool:
                         self._on_stage(item["batch_id"], "pack", i)
                         t0 = time.perf_counter()
                         try:
-                            if plan is not None:
-                                handle = eng.begin_batch(
-                                    wits, prefetch=plan
-                                )
-                            else:
-                                handle = eng.begin_batch(wits)
+                            with lane_stage(
+                                item.setdefault("stages", {}),
+                                "pack",
+                                [j.trace_id for j in jobs],
+                                item["batch_id"],
+                            ):
+                                if plan is not None:
+                                    handle = eng.begin_batch(
+                                        wits, prefetch=plan
+                                    )
+                                else:
+                                    handle = eng.begin_batch(wits)
                         except BaseException:
                             # a lane death here reaches _die, which never
                             # sees lane-local plans: return the staging
@@ -575,10 +570,6 @@ class MeshExecutorPool:
                         item["pack_ms"] = round(
                             (time.perf_counter() - t0) * 1e3, 3
                         )
-                        # device-busy: dispatch enqueued on this lane's
-                        # chip; the resolve below (or a crash-path
-                        # cleanup) closes the interval
-                        self._busy[i].begin()
                         inflight.append((item, handle, eng))
                         stage = "dispatch"
                         self._on_stage(item["batch_id"], "dispatch", i)
@@ -592,16 +583,12 @@ class MeshExecutorPool:
                     else:
                         stage = "dispatch"
                         self._on_stage(item["batch_id"], "dispatch", i)
-                        self._busy[i].begin()
-                        try:
-                            if is_root:
-                                verdicts, record = self._roots_inline(eng, item)
-                            elif is_sig:
-                                verdicts, record = self._sigs_inline(eng, item)
-                            else:
-                                verdicts, record = self._verify_inline(eng, item)
-                        finally:
-                            self._busy[i].end()
+                        if is_root:
+                            verdicts, record = self._roots_inline(eng, item)
+                        elif is_sig:
+                            verdicts, record = self._sigs_inline(eng, item)
+                        else:
+                            verdicts, record = self._verify_inline(eng, item)
                         cur = None
                         self._finish(i, item, verdicts, record)
                         continue
@@ -610,12 +597,13 @@ class MeshExecutorPool:
                     cur, stage = item2, "resolve"
                     self._on_stage(item2["batch_id"], "resolve", i)
                     t0 = time.monotonic()
-                    try:
+                    with lane_stage(
+                        item2.setdefault("stages", {}),
+                        "resolve",
+                        [j.trace_id for j in item2["jobs"]],
+                        item2["batch_id"],
+                    ):
                         verdicts = eng2.resolve_batch(handle)
-                    finally:
-                        # the [begin, resolve] interval closes on the
-                        # crash path too (the handle is abandoned there)
-                        self._busy[i].end()
                     record = self._record_from_handle(handle, item2)
                     record["resolve_ms"] = round(
                         (time.monotonic() - t0) * 1e3, 3
@@ -624,26 +612,22 @@ class MeshExecutorPool:
                     self._finish(i, item2, verdicts, record)
         except _PoolDead as dead:
             # another lane crashed: abandon this lane's handles (the
-            # engines outlive the pool — leases must not leak; each open
-            # busy interval closes with its handle) and fail the
+            # engines outlive the pool — leases must not leak) and fail the
             # begun-but-unresolved jobs nobody else knows about
-            self._cleanup_inflight(inflight, dead.args[0], self._busy[i])
+            self._cleanup_inflight(inflight, dead.args[0])
             return
         except BaseException as e:  # systemic: this lane crashed
             for it, h, hg in inflight:
                 _abandon(hg, h)
-                self._busy[i].end()
                 if it is not cur:
                     self._fail_jobs(it["jobs"], e)
             # the crashing batch's jobs ride to scheduler._die via
             # on_crash (it fails their futures with the crash record)
             self._on_crash(e, cur["jobs"] if cur else [], stage, i)
 
-    def _cleanup_inflight(self, inflight, exc, busy=None) -> None:
+    def _cleanup_inflight(self, inflight, exc) -> None:
         for it, h, hg in inflight:
             _abandon(hg, h)
-            if busy is not None:
-                busy.end()
             self._fail_jobs(it["jobs"], exc)
 
     def _fail_jobs(self, jobs, exc) -> None:
@@ -671,6 +655,8 @@ class MeshExecutorPool:
         for key in ("pack_ms", "prefetch_ms"):
             if key in item:
                 record.setdefault(key, item[key])
+        if item.get("stages"):
+            fold_stages(record, item["stages"])
         with self._lock:
             self._inflight_n[i] -= 1
             self._served[i] += 1
@@ -833,9 +819,6 @@ class MeshExecutorPool:
         # list is write-once; is_alive is the interpreter's own state)
         alive_list = [t.is_alive() for t in self._threads]
         n = self._n
-        # busy pct reads integrate to now (their own per-accountant locks;
-        # taken OUTSIDE _lock, same discipline as every metric publish)
-        busy = [self._busy[d].pct() for d in range(n)]
         with self._lock:
             per_device = {
                 str(d): {
@@ -843,7 +826,6 @@ class MeshExecutorPool:
                     "queued": len(self._queues[d]),
                     "inflight": self._inflight_n[d],
                     "dispatches": self._dispatches[d],
-                    "busy_pct": busy[d],
                 }
                 for d in range(n)
             }
@@ -870,14 +852,6 @@ class MeshExecutorPool:
                 "megabatches": self._megabatches,
                 "prefetched_batches": self._prefetched,
             }
-
-    def refresh_busy(self) -> None:
-        """Re-integrate + republish every lane's (and the megabatch
-        series') busy gauge — the pool half of the scheduler's
-        refresh_busy_gauges."""
-        for acct in self._busy:
-            acct.pct()
-        self._mega_busy.pct()
 
     def engines(self) -> list:
         """The per-lane engines (tests assert lease accounting on them)."""

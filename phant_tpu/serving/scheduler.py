@@ -150,7 +150,6 @@ from typing import Callable, List, Optional, Sequence, Tuple
 import numpy as np
 
 from phant_tpu.obs import critpath, timeline
-from phant_tpu.obs.busy import BusyAccountant
 from phant_tpu.obs.flight import flight
 from phant_tpu.obs.watchdog import Watchdog
 from phant_tpu.serving import deadline as deadline_clock
@@ -165,7 +164,12 @@ from phant_tpu.serving.qos import (
     current_tenant,
     parse_weights,
 )
-from phant_tpu.utils.trace import current_trace_id, metrics
+from phant_tpu.utils.trace import (
+    current_trace_id,
+    fold_stages,
+    lane_stage,
+    metrics,
+)
 
 log = logging.getLogger("phant_tpu.serving")
 
@@ -635,15 +639,6 @@ class VerificationScheduler:
         # worker computes raises instead — the fire drill for the
         # 4th-stage crash path (stage-named record, -32052 fail-fast)
         self._chaos_prefetch = chaos == "prefetch"
-        # per-lane device-busy accounting (obs/busy.py): the single
-        # executor drives ONE device ("0" — lane 0's chip in mesh terms);
-        # with a mesh pool the LANES bracket their own devices instead.
-        # Gated by the same switch as the critpath rollup
-        # (PHANT_OBS_ATTRIBUTION, read once here) so the obs_overhead
-        # bench A/B flips the whole attribution layer together.
-        self._busy_acct = BusyAccountant(
-            "0", enabled=critpath.enabled() and self._pool is None
-        )
         self._lock = threading.Lock()
         self._cond = threading.Condition(self._lock)
         # admission state (guarded by _lock): the serial mutation lane is
@@ -1383,18 +1378,6 @@ class VerificationScheduler:
             "prefetch": self._prefetch_on
             or bool(mesh is not None and mesh.get("prefetch")),
             "prefetch_pending": prefetch_pending,
-            # per-lane device-busy (obs/busy.py): "the chip idles 60% at
-            # depth 1" read straight off the probe. Reads integrate to
-            # now, so idle lanes decay without traffic; mesh mode reports
-            # every lane's own accountant instead of the executor's.
-            "device_busy_pct": (
-                {
-                    d: st["busy_pct"]
-                    for d, st in mesh["per_device"].items()
-                }
-                if mesh is not None
-                else {self._busy_acct.device: self._busy_acct.pct()}
-            ),
         }
         if mesh is not None:
             out["mesh"] = mesh
@@ -1419,17 +1402,6 @@ class VerificationScheduler:
             # stage run" the same way in every deployment shape
             st["prefetched_batches"] += st["mesh"]["prefetched_batches"]
         return st
-
-    def refresh_busy_gauges(self) -> None:
-        """Re-integrate every lane's busy window to NOW and republish the
-        `sched.device_busy_pct{device=}` gauges. Called by the /metrics
-        scrape path (engine_api/server.py): the gauges otherwise update
-        only on batch transitions, and an idle lane's last published
-        value would read frozen-busy forever on a metrics-only scraper."""
-        if self._pool is not None:
-            self._pool.refresh_busy()
-        else:
-            self._busy_acct.pct()
 
     def inflight_state(self) -> Optional[dict]:
         """The OLDEST batch currently in flight — `batch_id`, `lane`,
@@ -1598,6 +1570,9 @@ class VerificationScheduler:
             "picked": now,
             "plan": None,
             "ready": False,
+            # each stage's measured [start_ns, end_ns], written by the
+            # thread that runs it (utils/trace.lane_stage)
+            "stages": {},
         }
         with self._lock:
             self._batch_seq += 1
@@ -1704,6 +1679,7 @@ class VerificationScheduler:
                 item["picked"],
                 plan=plan,
                 prefetch_ms=item.get("prefetch_ms"),
+                stages=item["stages"],
                 plan_payload=item["payload"],
                 plan_njobs=len(item["jobs"]),
                 kind=kind,
@@ -1736,6 +1712,7 @@ class VerificationScheduler:
             item["picked"],
             plan=plan,
             prefetch_ms=item.get("prefetch_ms"),
+            stages=item["stages"],
             plan_payload=item["payload"],
             plan_njobs=len(item["jobs"]),
         )
@@ -1751,6 +1728,7 @@ class VerificationScheduler:
         plan_payload=None,
         plan_njobs: int = 0,
         kind: str = _WITNESS,
+        stages: Optional[dict] = None,
     ) -> None:
         """Shared tail of the pipelined witness paths (3- and 4-stage):
         wait for a pipeline slot, re-shed expired jobs, begin_batch —
@@ -1792,14 +1770,12 @@ class VerificationScheduler:
         else:
             payload = self._payload_of(jobs, kind)
         t_pack = time.perf_counter()
-        if plan is not None:
-            handle = engine.begin_batch(payload, prefetch=plan)
-        else:
-            handle = engine.begin_batch(payload)
-        # device-busy: the dispatch is enqueued — the lane's device owns
-        # this batch until the resolve worker finishes it (obs/busy.py;
-        # every exit path below pairs this with an end())
-        self._busy_acct.begin()
+        stages = {} if stages is None else stages
+        with lane_stage(stages, "pack", [j.trace_id for j in jobs], batch_id):
+            if plan is not None:
+                handle = engine.begin_batch(payload, prefetch=plan)
+            else:
+                handle = engine.begin_batch(payload)
         pipe_item = {
             "jobs": jobs,
             "handle": handle,
@@ -1808,6 +1784,7 @@ class VerificationScheduler:
             "kind": kind,
             "engine": engine,
             "pack_ms": round((time.perf_counter() - t_pack) * 1e3, 3),
+            "stages": stages,
         }
         if prefetch_ms is not None:
             pipe_item["prefetch_ms"] = prefetch_ms
@@ -1819,7 +1796,6 @@ class VerificationScheduler:
             # the worker died while we packed: the just-begun handle will
             # never be resolved — release its engine lease before failing
             _abandon_handle(engine, handle)
-            self._busy_acct.end()
             raise SchedulerDown(f"resolve worker is down: {dead!r}")
         with self._lock:
             self.stats["pipelined_batches"] += 1
@@ -1872,7 +1848,13 @@ class VerificationScheduler:
                 plan = None
                 if pf is not None:
                     t0 = time.perf_counter()
-                    plan = pf(item["payload"])
+                    with lane_stage(
+                        item["stages"],
+                        "prefetch",
+                        [j.trace_id for j in item["jobs"]],
+                        item["batch_id"],
+                    ):
+                        plan = pf(item["payload"])
                     pf_ms = round((time.perf_counter() - t0) * 1e3, 3)
                 with self._lock:
                     orphaned = self._dead is not None
@@ -2268,15 +2250,16 @@ class VerificationScheduler:
         # engine falls back device->native internally), so it propagates
         # to _run and takes the executor down — requests fail fast rather
         # than silently retrying into a broken engine.
-        self._busy_acct.begin()
-        try:
+        stages: dict = {}
+        with lane_stage(
+            stages, "dispatch", [j.trace_id for j in jobs], batch_id
+        ):
             verdicts = engine.verify_batch([(j.root, j.nodes) for j in jobs])
-        finally:
-            self._busy_acct.end()
         s1 = self._engine_cache_stats(engine)
         record = batch_record_from_stats(
             batch_id, len(jobs), jobs[0].bucket, s0, s1
         )
+        fold_stages(record, stages)
         self._finish_witness_jobs(jobs, verdicts, record, picked)
 
     def _execute_witness_pipelined(
@@ -2315,16 +2298,17 @@ class VerificationScheduler:
         if not jobs:
             return
         self._set_exec_stage("dispatch")
-        self._busy_acct.begin()
-        try:
+        stages: dict = {}
+        ids = [j.trace_id for j in jobs]
+        with lane_stage(stages, "pack", ids, batch_id):
             handle = engine.begin_batch([j.plan for j in jobs])
+        with lane_stage(stages, "resolve", ids, batch_id):
             results = engine.resolve_batch(handle)
-        finally:
-            self._busy_acct.end()
         record = root_record_from_handle(
             handle, batch_id, len(jobs), jobs[0].bucket
         )
         record["stage"] = "dispatch"  # fused begin+resolve, like depth-1
+        fold_stages(record, stages)
         self._finish_root_jobs(jobs, results, record, picked)
 
     def _finish_plan_jobs(
@@ -2423,16 +2407,17 @@ class VerificationScheduler:
         if not jobs:
             return
         self._set_exec_stage("dispatch")
-        self._busy_acct.begin()
-        try:
+        stages: dict = {}
+        ids = [j.trace_id for j in jobs]
+        with lane_stage(stages, "pack", ids, batch_id):
             handle = engine.begin_batch([j.rows for j in jobs])
+        with lane_stage(stages, "resolve", ids, batch_id):
             results = engine.resolve_batch(handle)
-        finally:
-            self._busy_acct.end()
         record = sig_record_from_handle(
             handle, batch_id, len(jobs), jobs[0].bucket
         )
         record["stage"] = "dispatch"  # fused begin+resolve, like depth-1
+        fold_stages(record, stages)
         self._finish_sig_jobs(jobs, results, record, picked)
 
     def _finish_sig_jobs(
@@ -2687,32 +2672,26 @@ class VerificationScheduler:
             self._die(e, item["jobs"] if item else [], stage="resolve")
 
     def _resolve_one(self, item: dict) -> None:
-        try:
-            self._resolve_one_inner(item)
-        finally:
-            # device-busy: the [begin, resolve] interval closes whether
-            # the readback succeeded or the crash path takes over
-            self._busy_acct.end()
-
-    def _resolve_one_inner(self, item: dict) -> None:
         jobs = item["jobs"]
         handle = item["handle"]
         engine = item.get("engine") or self._resolve_engine()
         t0 = time.monotonic()
-        if item.get("kind") == _ROOT:
+        stages = item["stages"]
+        with lane_stage(
+            stages, "resolve", [j.trace_id for j in jobs], item["batch_id"]
+        ):
             results = engine.resolve_batch(handle)
+        if item.get("kind") == _ROOT:
             record = root_record_from_handle(
                 handle, item["batch_id"], len(jobs), jobs[0].bucket
             )
             finish = self._finish_root_jobs
         elif item.get("kind") == _SIG:
-            results = engine.resolve_batch(handle)
             record = sig_record_from_handle(
                 handle, item["batch_id"], len(jobs), jobs[0].bucket
             )
             finish = self._finish_sig_jobs
         else:
-            results = engine.resolve_batch(handle)
             record = batch_record_from_handle(
                 handle, item["batch_id"], len(jobs), jobs[0].bucket
             )
@@ -2721,6 +2700,7 @@ class VerificationScheduler:
         if "prefetch_ms" in item:
             record["prefetch_ms"] = item["prefetch_ms"]
         record["resolve_ms"] = round((time.monotonic() - t0) * 1e3, 3)
+        fold_stages(record, stages)
         finish(jobs, results, record, item["picked"])
 
     def _resolve_engine(self):
@@ -2777,10 +2757,8 @@ class VerificationScheduler:
         for item in dropped_items:
             # never resolved, never will be: release the engine leases so
             # a shared engine keeps evicting after this scheduler's death
-            # (each pipe item carries ITS engine — witness or root), and
-            # close each one's device-busy interval (begun at handoff)
+            # (each pipe item carries ITS engine — witness or root)
             _abandon_handle(item.get("engine") or self._resolve_engine(), item["handle"])
-            self._busy_acct.end()
         for item in dropped_plans:
             plan = item.get("plan")
             if plan is not None:

@@ -933,8 +933,6 @@ def dispatch_sender_recovery(chain_id: int, txs, rows=None):
     sched = active_scheduler()
     if sched is None or not sched.accepts_sig():
         return None
-    import time as _time
-
     from phant_tpu.utils.trace import metrics
 
     signer = _request_signer(chain_id)
@@ -957,13 +955,11 @@ def dispatch_sender_recovery(chain_id: int, txs, rows=None):
         return degrade  # shed at admission
 
     def resolve():
-        t0 = _time.perf_counter()
         try:
-            senders, meta = inner()
+            with metrics.phase("sched.sig_wait"):
+                senders, meta = inner()
         except SchedulerError:
             return degrade()
-        finally:
-            metrics.observe("sched.sig_wait", _time.perf_counter() - t0)
         if meta is not None:
             from phant_tpu.utils.trace import current_span
 

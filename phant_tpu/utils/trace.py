@@ -34,11 +34,15 @@ family must have an entry in METRIC_HELP — `make metrics-lint` enforces it.
 from __future__ import annotations
 
 import contextlib
+import gc
+import itertools
 import json
 import logging
 import re
+import sys
 import threading
 import time
+from collections import deque
 from dataclasses import dataclass, field
 from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 
@@ -180,6 +184,7 @@ METRIC_HELP: Dict[str, str] = {
     "engine_api.client_disconnects": "Engine API responses aborted by client disconnect (BrokenPipe/ConnectionReset)",
     "engine_api.inflight": "Engine API requests currently being handled",
     "engine_api.request_seconds": "Engine API request latency (decode + handle + reply)",
+    "engine_api.phase_seconds": "The front end's own share of a POST, by phase, timed where the work happens (engine_api/server.py): read = headers parsed -> body read; json = json.loads; gate = the wait for a slot of the stateless gate; decode = payload and witness hex/RLP decode and the block-hash check, up to where verify_block opens; reply = result -> bytes -> written. With verify_block's wall clock they tile engine_api.request_seconds",
     "engine_api.decode_payload": "JSON -> ExecutionPayload decode phase",
     "engine_api.new_payload": "engine_newPayloadV2/V3/V4 handler phase",
     "engine_api.execute_stateless": "engine_executeStatelessPayloadV1 handler phase",
@@ -275,8 +280,12 @@ METRIC_HELP: Dict[str, str] = {
     "sched.device_stall": "Scheduler waits for a free mesh lane slot (every device at its bound)",
     "sched.mesh_megabatches": "Full single-bucket batches dispatched as one whole-mesh sharded fused kernel call",
     "sched.megabatch_backlog_triggers": "Megabatches fired by the backlog-depth trigger (queued same-bucket work >= mesh width x k) rather than a full batch",
-    # per-lane device-busy accounting (phant_tpu/obs/busy.py)
-    "sched.device_busy_pct": "Rolling-window device-busy percentage per lane (device='mesh' = whole-mesh megabatch dispatches): the two-phase begin/resolve protocol brackets device occupancy, integrated as a union of in-flight intervals — 'the chip idles 60% at depth 1' read directly off /metrics or /healthz",
+    # measured host time at the device, the collector, compiles (utils/trace.py,
+    # serving/deadline.py)
+    "device.host_seconds": "Seconds a host thread spent at the device, by lane (witness/sig/root) and op: enqueue = upload + program launch with no wait (begin); sync = the readback, i.e. the thread stood BLOCKED on the chip (resolve). The measurement critpath's `dispatch` remainder is not",
+    "jit.compiles": "Programs jax first built in this process, by thread (serving = a scheduler thread whose compile holds a job queue; other): one per backend compile and one per load from the persistent cache",
+    "jit.serving_compile_seconds": "Wall-clock seconds the serving threads have spent compiling (union of jax's trace/lower/compile intervals): the credit the request deadline clock runs on (serving/deadline.py)",
+    "runtime.gc_pause_seconds": "Pauses of CPython's collector in this process, by generation, from the one gc.callbacks entry the server installs (every collection; a full one, generation 2, is also a `gc` interval of every request span open then)",
     # observability layer (phant_tpu/obs/)
     "sched.watchdog_stalls": "Executor stalls detected by the obs watchdog (in-flight batch past its deadline)",
     "flight.dumps": "Flight-recorder postmortem dumps written, by trigger reason",
@@ -331,6 +340,7 @@ METRIC_HELP: Dict[str, str] = {
 #: documented, and free of dead catalog entries.
 SPAN_HELP: Dict[str, str] = {
     # spans (top-level records carry trace_id + the scheduler batch fields)
+    "request": "One Engine API POST on its handler thread, headers parsed -> reply written: the FRAME around verify_block (its parent_id), tiled by the front end's intervals read/json/gate/decode/reply; reported to the sinks when a verify_block ran under it",
     "verify_block": "One stateless payload execution: witness_verify/witness_decode/execute/post_root phases plus the serving batch fields (batch_id, queue_wait_ms, ...)",
     # flight-event kinds (phant_tpu/obs/flight.py ring records)
     "span": "A completed top-level span record (mirrored from the span sink)",
@@ -378,12 +388,19 @@ class Metrics:
         with self._lock:
             self._gauges[key] = self._gauges.get(key, 0) + delta
 
-    def observe(self, name: str, seconds: float) -> None:
+    def observe(
+        self, name: str, seconds: float, end_ns: Optional[int] = None
+    ) -> None:
+        """Add `seconds` to the phase timer `name` and, inside an open
+        span, record the child interval that ends at `end_ns` (default:
+        now) on the span clock."""
         with self._lock:
             self._timers.setdefault(name, TimerStat()).add(seconds)
         sp = current_span()
         if sp is not None:
-            sp.add_phase(name, seconds)
+            if end_ns is None:
+                end_ns = clock_ns()
+            sp.add_interval(name, end_ns - int(seconds * 1e9), end_ns)
 
     def observe_hist(
         self,
@@ -401,17 +418,23 @@ class Metrics:
 
     @contextlib.contextmanager
     def phase(self, name: str) -> Iterator[None]:
-        """Time a phase: `with metrics.phase("engine_api.new_payload"): ...`"""
-        t0 = time.perf_counter()
+        """Time a phase: `with metrics.phase("engine_api.new_payload"): ...`.
+        Inside an open span the phase is a measured child interval of it,
+        and where ANNOTATIONS names it, an event of the profiler's trace."""
+        ann = annotate(ANNOTATIONS.get(name))
+        t0 = clock_ns()
         try:
             yield
         finally:
-            self.observe(name, time.perf_counter() - t0)
+            t1 = clock_ns()
+            end_annotation(ann)
+            self.observe(name, (t1 - t0) / 1e9, end_ns=t1)
 
     def snapshot(self) -> dict:
         """Deep copy of every table under the lock: TimerStat/Histogram
         objects keep mutating concurrently, and exposition must never read
         a torn (count updated, sum not yet) pair."""
+        flush_gc()
         with self._lock:
             return {
                 "counters": dict(self._counters),
@@ -583,10 +606,23 @@ def phase(name: str):
 _span_log = logging.getLogger("phant_tpu.span")
 _span_tls = threading.local()
 
+#: THE clock of every span, interval and lane stage: the one the deadline
+#: clock (serving/deadline.py) and the benchmark harness already read, so a
+#: span's `start_ns`/`end_ns` compare with both without conversion
+clock_ns = time.monotonic_ns
+
+#: span ids: process-unique, ascending (`next()` on a count is atomic)
+_span_ids = itertools.count(1)
+
 #: top-level span records (dicts) fan out here in addition to the log line;
 #: the obs flight recorder registers a sink (phant_tpu/obs/__init__.py).
 #: Mutated only via add/remove below; iteration reads a snapshot reference.
 _span_sinks: List = []
+
+#: the spans that are open and top-level (a frame, or a frame's child) right
+#: now, by id: the collector's callback writes each full collection into
+#: every one of them. Single dict operations only, so no lock
+_open_spans: Dict[int, "Span"] = {}
 
 
 def add_span_sink(fn) -> None:
@@ -631,40 +667,258 @@ def trace_context(trace_id: Optional[str] = None) -> Iterator[str]:
         stack.pop()
 
 
+@contextlib.contextmanager
+def lane_stage(
+    stages: dict, stage: str, trace_ids: Sequence[Optional[str]], batch_id
+) -> Iterator[None]:
+    """One stage of a lane batch, on the (lane) thread that runs it. The
+    batch is bound to the thread while the stage runs: annotations opened
+    inside carry the batch's `trace_id`s (joined by "|": the profiler's
+    event names keep their attributes comma-separated) and `batch_id`. The
+    stage's measured `[start_ns, end_ns]` is written into `stages` (the
+    dict the batch record carries beside its `*_ms`), with `compile_ns`
+    where jax reported a compile on this thread meanwhile: the batch
+    stood behind it."""
+    ctx = {"batch_id": batch_id, "compile_ns": 0}
+    prev = getattr(_span_tls, "lane", None)
+    _span_tls.lane = ctx
+    ids = "|".join(t for t in trace_ids if t)
+    bound = trace_context(ids) if ids else contextlib.nullcontext()
+    t0 = clock_ns()
+    try:
+        with bound:
+            yield
+    finally:
+        stages[stage] = [t0, clock_ns()]
+        if ctx["compile_ns"]:
+            stages["compile_ns"] = stages.get("compile_ns", 0) + ctx["compile_ns"]
+        _span_tls.lane = prev
+
+
+def fold_stages(record: dict, stages: dict) -> None:
+    """`lane_stage`'s measurements into the batch record: `stages`, each
+    stage's [start_ns, end_ns] beside the `*_ms` it is the ends of, and
+    `compile_ms` where the batch stood behind a compile."""
+    stages = dict(stages)
+    compile_ns = stages.pop("compile_ns", 0)
+    if compile_ns:
+        record["compile_ms"] = round(compile_ns / 1e6, 3)
+    if stages:
+        record["stages"] = stages
+
+
+# -- the device trace's clock ------------------------------------------------
+
+#: phase timer -> the name it carries in the profiler's trace. With the
+#: names that `span`, `Span.mark`, `device_host`, `note_compile` and the
+#: collector's callback open themselves (`phant/<span name>`, the front
+#: end's five, `phant/device_enqueue`, `phant/device_sync`, `phant/compile`,
+#: `phant/gc`) this is the whole vocabulary: PERF.md section 3 lists each
+#: name with the metric it serves, and scripts/trace_gaps.py reads them
+ANNOTATIONS: Dict[str, str] = {
+    # the handler thread's phases of a request (critpath's names)
+    "stateless.sig_rows": "phant/sig_rows",
+    "stateless.witness_verify": "phant/witness_verify",
+    "stateless.witness_decode": "phant/witness_decode",
+    "stateless.execute": "phant/evm",
+    "sched.sig_wait": "phant/sig_wait",
+    "stateless.post_root_plan": "phant/root_plan",
+    "stateless.post_root": "phant/post_root",
+    # the lanes' stages, on the thread that runs each
+    "witness_engine.prefetch": "phant/witness.prefetch",
+    "witness_engine.pack": "phant/witness.pack",
+    "witness_engine.dispatch": "phant/witness.dispatch",
+    "witness_engine.resolve": "phant/witness.resolve",
+    "witness_engine.sig_prefetch": "phant/sig.prefetch",
+    "witness_engine.sig_pack": "phant/sig.pack",
+    "witness_engine.sig_dispatch": "phant/sig.dispatch",
+    "witness_engine.sig_resolve": "phant/sig.resolve",
+    "witness_engine.root_prefetch": "phant/root.prefetch",
+    "witness_engine.root_pack": "phant/root.pack",
+    "witness_engine.root_dispatch": "phant/root.dispatch",
+    "witness_engine.root_resolve": "phant/root.resolve",
+}
+
+_trace_me = None  # jax's TraceMe class, once jax is in the process
+
+
+def annotate(name: Optional[str], **attrs):
+    """An ENTERED `jax.profiler.TraceAnnotation(name, ...)` carrying this
+    thread's `trace_id` and `batch_id`, or None: where `name` is None,
+    jax is not in the process (the cpu backend must not import it) or no
+    profiler is running. The caller ends it with `end_annotation`. With
+    no profiler running this is one flag test."""
+    global _trace_me
+    if name is None:
+        return None
+    tm = _trace_me
+    if tm is None:
+        if "jax" not in sys.modules:
+            return None
+        try:
+            from jax.profiler import TraceAnnotation as tm
+        except ImportError:  # jax is mid-import on another thread
+            return None
+        _trace_me = tm
+    if not tm.is_enabled():
+        return None
+    tid = current_trace_id()
+    if tid is not None:
+        attrs.setdefault("trace_id", tid)
+    lane = getattr(_span_tls, "lane", None)
+    if lane is not None and lane["batch_id"] is not None:
+        attrs.setdefault("batch_id", lane["batch_id"])
+    ann = tm(name, **attrs)
+    ann.__enter__()
+    return ann
+
+
+def end_annotation(ann) -> None:
+    """End what `annotate` returned (None: there was nothing to end)."""
+    if ann is not None:
+        ann.__exit__(None, None, None)
+
+
+@contextlib.contextmanager
+def device_host(lane: str, op: str) -> Iterator[None]:
+    """Time a host thread at the device: `op` "enqueue" is an upload and a
+    program launch that does not wait, "sync" is a readback, i.e. the
+    seconds the thread stood blocked on the chip. Observed into
+    `device.host_seconds{lane=,op=}` and shown in the profiler's trace as
+    `phant/device_enqueue` / `phant/device_sync`."""
+    ann = annotate("phant/device_" + op, lane=lane)
+    t0 = clock_ns()
+    try:
+        yield
+    finally:
+        dt = (clock_ns() - t0) / 1e9
+        end_annotation(ann)
+        metrics.observe_hist("device.host_seconds", dt, lane=lane, op=op)
+
+
+def note_compile(seconds: float) -> None:
+    """A program was built on this thread and the build ended now (jax
+    reports a compile only at its end; serving/deadline.py hears it): a
+    `compile` interval of the span open here, `compile_ns` of the lane
+    batch bound here, and a `phant/compile` marker that carries the
+    seconds, since an annotation cannot be opened in the past."""
+    end = clock_ns()
+    ns = int(seconds * 1e9)
+    sp = current_span()
+    if sp is not None:
+        sp.add_interval("compile", end - ns, end)
+    lane = getattr(_span_tls, "lane", None)
+    if lane is not None:
+        lane["compile_ns"] += ns
+    end_annotation(annotate("phant/compile", seconds=seconds))
+
+
 class Span:
-    """One traced operation: wall-clock duration + the phase timings that
-    ran inside it (fed by Metrics.observe) + any child spans. Spans stack
-    per-thread (thread-local), which is the thread-safety mechanism —
-    concurrent request threads each trace their own block without locking."""
+    """One traced operation: a measured interval (`start_ns`, `end_ns` on
+    `clock_ns`) with a process-unique `span_id` and its parent's, the
+    child intervals `(name, start_ns, end_ns)` of the phases that ran
+    inside it (fed by Metrics.observe / Metrics.phase), and any child
+    spans. `phases`, the per-name count and total the sinks read, is
+    derived from the intervals, so the two cannot disagree. Spans stack
+    per-thread (thread-local), which is the thread-safety mechanism:
+    concurrent request threads each trace their own block without
+    locking; the one writer from outside, the collector's callback, only
+    appends to `intervals`.
 
-    __slots__ = ("name", "attrs", "duration_s", "phases", "children")
+    A FRAME span (`span(name, frame=True)`: the Engine API server's
+    `request`) stands around top-level spans without making them children:
+    a span opened directly under it is still reported to the sinks as a
+    top-level record, with the frame's id as its `parent_id`. `mark(name)`
+    tiles a frame with contiguous intervals (the front end's phases)."""
 
-    def __init__(self, name: str, attrs: dict):
+    __slots__ = (
+        "name",
+        "attrs",
+        "duration_s",
+        "intervals",
+        "children",
+        "span_id",
+        "parent_id",
+        "start_ns",
+        "end_ns",
+        "frame",
+        "resume",
+        "reported",
+        "_mark",
+    )
+
+    def __init__(self, name: str, attrs: dict, frame: bool = False):
         self.name = name
         self.attrs = attrs
         self.duration_s = 0.0
-        self.phases: Dict[str, List[float]] = {}  # name -> [count, total_s]
+        self.intervals: List[Tuple[str, int, int]] = []
         self.children: List[dict] = []
+        self.span_id = next(_span_ids)
+        self.parent_id: Optional[int] = None
+        self.start_ns = 0
+        self.end_ns = 0
+        self.frame = frame
+        self.resume: Optional[str] = None  # the mark a closing child leaves
+        self.reported = 0  # spans reported to the sinks from under a frame
+        self._mark = None  # (name, start_ns, annotation) of the open mark
 
-    def add_phase(self, name: str, seconds: float) -> None:
-        st = self.phases.get(name)
-        if st is None:
-            self.phases[name] = [1, seconds]
-        else:
-            st[0] += 1
-            st[1] += seconds
+    def add_interval(self, name: str, start_ns: int, end_ns: int) -> None:
+        self.intervals.append((name, start_ns, end_ns))
+
+    def mark(self, name: Optional[str], at: Optional[int] = None) -> None:
+        """End the interval the last `mark` began and begin `name` (None:
+        nothing, the span's time belongs to a child or to nobody), at
+        clock reading `at` (default: now). Marks are contiguous, and
+        `span` hands a frame the child's own start and end readings, so
+        marks and children tile the frame exactly, less the stretches
+        marked None."""
+        if self._mark is not None and self._mark[0] == name:
+            return
+        now = clock_ns() if at is None else at
+        if self._mark is not None:
+            prev, t0, ann = self._mark
+            end_annotation(ann)
+            self.intervals.append((prev, t0, now))
+        self._mark = (
+            None if name is None else (name, now, annotate("phant/" + name))
+        )
+
+    @property
+    def phases(self) -> Dict[str, List[float]]:
+        """name -> [count, total_s] over the intervals."""
+        return _phases_of(tuple(self.intervals))
 
     def to_dict(self) -> dict:
         d: dict = {"span": self.name, **self.attrs}
         d["duration_ms"] = round(self.duration_s * 1e3, 3)
-        if self.phases:
+        d["span_id"] = self.span_id
+        if self.parent_id is not None:
+            d["parent_id"] = self.parent_id
+        d["start_ns"] = self.start_ns
+        d["end_ns"] = self.end_ns
+        intervals = tuple(self.intervals)
+        if intervals:
             d["phases"] = {
                 k: {"count": c, "total_ms": round(t * 1e3, 3)}
-                for k, (c, t) in self.phases.items()
+                for k, (c, t) in _phases_of(intervals).items()
             }
+            # [name, start_ns, end_ns]: of this span, so of its trace_id
+            d["intervals"] = [list(iv) for iv in intervals]
         if self.children:
             d["children"] = self.children
         return d
+
+
+def _phases_of(intervals) -> Dict[str, List[float]]:
+    out: Dict[str, List[float]] = {}
+    for name, t0, t1 in intervals:
+        st = out.get(name)
+        if st is None:
+            out[name] = [1, (t1 - t0) / 1e9]
+        else:
+            st[0] += 1
+            st[1] += (t1 - t0) / 1e9
+    return out
 
 
 def current_span() -> Optional[Span]:
@@ -672,60 +926,166 @@ def current_span() -> Optional[Span]:
     return stack[-1] if stack else None
 
 
+def _report(sp: Span) -> None:
+    """One top-level record: to the sinks and the span log."""
+    sinks = tuple(_span_sinks)  # snapshot: a concurrent
+    # remove_span_sink must not shift the list mid-iteration
+    if sinks or _span_log.isEnabledFor(logging.INFO):
+        # serialization is per-block work on the serving hot path —
+        # skip it entirely when nobody listens
+        record = sp.to_dict()
+        for sink in sinks:
+            try:
+                sink(record)
+            except Exception:  # tracing must never fail the work
+                pass
+        if _span_log.isEnabledFor(logging.INFO):
+            _span_log.info(json.dumps(record, default=str))
+
+
 @contextlib.contextmanager
-def span(name: str, **attrs) -> Iterator[Span]:
+def span(name: str, frame: bool = False, **attrs) -> Iterator[Span]:
     """Trace one operation: `with span("verify_block", block=n): ...`.
 
     Phase timings recorded inside (via `metrics.phase` / `observe`) attach
-    to the innermost open span of the current thread. A nested span folds
-    its summary into its parent; each TOP-LEVEL span emits one
-    structured-JSON log line (logger `phant_tpu.span`, INFO) with the
-    nested phase timings — the per-block trace record — and fans the same
-    record out to registered span sinks (the obs flight recorder). A span
-    opened inside a `trace_context` carries its `trace_id`."""
+    to the innermost open span of the current thread as measured child
+    intervals. A nested span folds its summary into its parent; each
+    TOP-LEVEL span emits one structured-JSON log line (logger
+    `phant_tpu.span`, INFO) with the nested phase timings — the per-block
+    trace record — and fans the same record out to registered span sinks
+    (the obs flight recorder). A span opened inside a `trace_context`
+    carries its `trace_id`. A span directly under a FRAME span (see Span)
+    counts as top-level; the frame itself is reported when it closes, if a
+    span was reported from under it. While a profiler runs, the span is
+    also an event `phant/<name>` of its trace."""
     if "trace_id" not in attrs:
         tid = current_trace_id()
         if tid is not None:
             attrs["trace_id"] = tid
-    sp = Span(name, attrs)
+    sp = Span(name, attrs, frame)
     stack = getattr(_span_tls, "stack", None)
     if stack is None:
         stack = _span_tls.stack = []
+    parent = stack[-1] if stack else None
+    top = parent is None or parent.frame
+    t0 = sp.start_ns = clock_ns()
+    if parent is not None:
+        sp.parent_id = parent.span_id
+        if parent.frame:
+            parent.mark(None, at=t0)  # the frame's own time stops here
     stack.append(sp)
-    t0 = time.perf_counter()
+    if top:
+        _open_spans[sp.span_id] = sp
+    ann = annotate("phant/" + name)
     try:
         yield sp
     finally:
-        sp.duration_s = time.perf_counter() - t0
+        t1 = sp.end_ns = clock_ns()
+        sp.mark(None, at=t1)
+        sp.duration_s = (t1 - t0) / 1e9
+        end_annotation(ann)
         stack.pop()
-        if stack:
-            stack[-1].children.append(sp.to_dict())
+        if top:
+            _open_spans.pop(sp.span_id, None)
+            if parent is not None:
+                parent.reported += 1
+                parent.mark(parent.resume, at=t1)
+            flush_gc()
+            if not frame or sp.reported:
+                _report(sp)
         else:
-            sinks = tuple(_span_sinks)  # snapshot: a concurrent
-            # remove_span_sink must not shift the list mid-iteration
-            if sinks or _span_log.isEnabledFor(logging.INFO):
-                # serialization is per-block work on the serving hot path —
-                # skip it entirely when nobody listens
-                record = sp.to_dict()
-                for sink in sinks:
-                    try:
-                        sink(record)
-                    except Exception:  # tracing must never fail the work
-                        pass
-                if _span_log.isEnabledFor(logging.INFO):
-                    _span_log.info(json.dumps(record, default=str))
+            parent.children.append(sp.to_dict())
+
+
+# -- the collector -----------------------------------------------------------
+
+#: (generation, start_ns, end_ns) of collections not yet in the registry.
+#: The callback only appends here: a collection can start under any
+#: allocation, also one made while this thread holds the registry's lock,
+#: so the callback may take no lock. `flush_gc` moves them into
+#: `runtime.gc_pause_seconds` at every top-level span's close and at every
+#: snapshot; beyond `maxlen` the oldest are dropped
+_gc_log: deque = deque(maxlen=1 << 16)
+_gc_open: List = [0, None]  # start_ns and annotation of the collection running
+_gc_watchers = 0
+_gc_lock = threading.Lock()
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    if phase == "start":
+        _gc_open[0] = clock_ns()
+        if info["generation"] == 2:
+            _gc_open[1] = annotate("phant/gc", generation=2)
+        return
+    t1 = clock_ns()
+    t0, ann = _gc_open
+    _gc_open[1] = None
+    end_annotation(ann)
+    if not t0:
+        return  # installed while this collection ran: no start was seen
+    _gc_open[0] = 0
+    gen = info["generation"]
+    _gc_log.append((gen, t0, t1))
+    if gen == 2:
+        # a full collection stops every thread: an interval of every
+        # request that is open now
+        for sp in list(_open_spans.values()):
+            sp.intervals.append(("gc", t0, t1))
+
+
+def flush_gc() -> None:
+    """Move the collections logged so far into the registry."""
+    while _gc_log:
+        try:
+            gen, t0, t1 = _gc_log.popleft()
+        except IndexError:  # another thread flushed it
+            return
+        metrics.observe_hist(
+            "runtime.gc_pause_seconds", (t1 - t0) / 1e9, generation=str(gen)
+        )
+
+
+def watch_gc() -> None:
+    """Install the collector's callback (counted: the Engine API server
+    calls this at start and `unwatch_gc` at shutdown)."""
+    global _gc_watchers
+    with _gc_lock:
+        _gc_watchers += 1
+        if _gc_watchers == 1:
+            gc.callbacks.append(_on_gc)
+
+
+def unwatch_gc() -> None:
+    global _gc_watchers
+    with _gc_lock:
+        if _gc_watchers == 0:
+            return
+        _gc_watchers -= 1
+        if _gc_watchers == 0:
+            try:
+                gc.callbacks.remove(_on_gc)
+            except ValueError:
+                pass
+    flush_gc()
 
 
 @contextlib.contextmanager
 def jax_profile(logdir: Optional[str] = None) -> Iterator[None]:
     """Capture a JAX/XLA device trace (view with TensorBoard or Perfetto);
-    no-op when logdir is None so call sites can be left in production code."""
+    no-op when logdir is None so call sites can be left in production code.
+    The profiler's Python tracer is OFF and its host tracer at level 1:
+    with jax's default (the Python tracer on) a capture slowed this server
+    fourteenfold (PERF.md section 3) and measured the profiler; at level 1
+    the host plane holds the program's own `phant/` annotations."""
     if logdir is None:
         yield
         return
     import jax
 
-    jax.profiler.start_trace(logdir)
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 1
+    jax.profiler.start_trace(logdir, profiler_options=options)
     try:
         yield
     finally:

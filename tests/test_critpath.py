@@ -3,8 +3,7 @@
 Covers the tentpole surfaces end to end: the rollup's tiling math (pure
 unit), the >= 95% wall-clock coverage assert on the REAL serving path —
 depths 1 AND 2, all three engine lanes (witness + root + sig) engaged
-through a live EngineAPIServer — per-lane device-busy gauges present per
-mesh lane over real HTTP, the derived p50/p99 quantile gauges in the
+through a live EngineAPIServer — the derived p50/p99 quantile gauges in the
 exposition (front-door histogram included), `POST /debug/profile`'s
 single-flight guard + artifact-on-disk contract, and `/debug/slow`
 exemplar capture under an induced slow request.
@@ -26,7 +25,6 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 from phant_tpu.engine_api.server import EngineAPIServer, MetricsServer
 from phant_tpu.obs import critpath, profiler
-from phant_tpu.obs.busy import BusyAccountant
 from phant_tpu.ops.witness_engine import WitnessEngine
 from phant_tpu.serving import SchedulerConfig, VerificationScheduler
 from phant_tpu.utils.trace import (
@@ -201,68 +199,6 @@ def test_front_door_histogram_rides_the_shared_bucket_table():
 
 
 # ---------------------------------------------------------------------------
-# busy accounting (unit)
-# ---------------------------------------------------------------------------
-
-
-def test_busy_accountant_union_and_window():
-    t = [0.0]
-    acct = BusyAccountant("9", window_s=10.0, publish=False, clock=lambda: t[0])
-    # two OVERLAPPING intervals over [0, 4]: union is 4s busy of 5s wall
-    acct.begin()
-    t[0] = 2.0
-    acct.begin()
-    t[0] = 3.0
-    acct.end()
-    t[0] = 4.0
-    acct.end()
-    t[0] = 5.0
-    assert acct.pct() == pytest.approx(80.0)
-    # idle decay: 15s later (window rotated) the busy share shrinks
-    t[0] = 20.0
-    assert acct.pct() < 30.0
-    # a long EVENTLESS idle gap must not pin the gauge near zero once
-    # traffic returns: the carried bucket is capped at one window, so
-    # ~half a window into renewed saturation the gauge reads the real
-    # recent-past share, not elapsed/(idle_gap + elapsed)
-    t2 = [0.0]
-    a2 = BusyAccountant("7", window_s=10.0, publish=False, clock=lambda: t2[0])
-    t2[0] = 600.0  # 10 minutes of silence
-    a2.begin()  # rotation happens here; the stale span is clamped
-    t2[0] = 605.0  # 5s of saturation
-    assert a2.pct() >= 30.0  # 5 busy / (10 carried + 5 current)
-    # a disabled accountant is a no-op
-    off = BusyAccountant("8", enabled=False, publish=False, clock=lambda: t[0])
-    off.begin()
-    t[0] = 30.0
-    assert off.pct() == 0.0
-
-
-def test_busy_gauge_published_by_single_executor():
-    metrics.reset()
-    wits = _witness_set(8)
-    with VerificationScheduler(
-        engine=WitnessEngine(),
-        config=SchedulerConfig(max_batch=8, max_wait_ms=5.0, pipeline_depth=2),
-    ) as s:
-        assert s.verify_many(wits).all()
-        state = s.state()
-    gauges = metrics.snapshot()["gauges"]
-    assert 'sched.device_busy_pct{device="0"}' in gauges
-    assert "0" in state["device_busy_pct"]
-    # real work just ran inside the rolling window: the lane was busy
-    assert state["device_busy_pct"]["0"] > 0.0
-    # the /metrics scrape path republishes over the last transition
-    # value (a metrics-only scraper must see the window keep moving)
-    metrics.gauge_set("sched.device_busy_pct", 77.77, device="0")
-    s.refresh_busy_gauges()  # shutdown already ran; the accountant lives
-    assert (
-        metrics.snapshot()["gauges"]['sched.device_busy_pct{device="0"}']
-        != 77.77
-    )
-
-
-# ---------------------------------------------------------------------------
 # coverage >= 95% on the REAL serving path: depths 1 and 2, three lanes
 # ---------------------------------------------------------------------------
 
@@ -330,39 +266,6 @@ def test_coverage_on_serving_path_all_three_lanes(depth, monkeypatch):
         assert f'critpath.phase_seconds{{phase="{ph}"}}' in hists, ph
 
 
-def test_busy_gauges_per_mesh_lane_over_http():
-    """Every mesh lane reports its own device_busy_pct — present in
-    /metrics from boot (idle lanes read 0, not absent) and in /healthz
-    under scheduler.device_busy_pct."""
-    metrics.reset()
-    chain, rpc, _root = _stateless_request()
-    server = EngineAPIServer(
-        chain,
-        host="127.0.0.1",
-        port=0,
-        sched_config=SchedulerConfig(
-            max_batch=8, max_wait_ms=5.0, mesh_devices=2, pipeline_depth=2
-        ),
-    )
-    server.serve_in_background()
-    try:
-        base = f"http://127.0.0.1:{server.port}"
-        wits = _witness_set(8)
-        assert server.scheduler.verify_many(wits).all()
-        with urllib.request.urlopen(base + "/metrics", timeout=30) as resp:
-            text = resp.read().decode()
-        assert 'phant_sched_device_busy_pct{device="0"}' in text
-        assert 'phant_sched_device_busy_pct{device="1"}' in text
-        status, health = _get_json(base, "/healthz")
-        assert status == 200
-        busy = health["scheduler"]["device_busy_pct"]
-        assert set(busy) == {"0", "1"}
-        # at least the lane(s) that served the batches integrated busy time
-        assert max(busy.values()) > 0.0
-    finally:
-        server.shutdown()
-
-
 # ---------------------------------------------------------------------------
 # /debug/profile: single-flight + artifact on disk
 # ---------------------------------------------------------------------------
@@ -388,6 +291,9 @@ def test_profile_endpoint_single_flight_and_artifact(tmp_path, monkeypatch):
         code2, body2 = _post_raw(base, "/debug/profile?seconds=1")
         assert code2 == 503, body2  # single-flight: overlap sheds
         assert "in flight" in body2["error"]
+        # a request served inside the capture's window (PR 26)
+        code3, body3 = _post(base, _rpc)
+        assert code3 == 200 and body3["result"]["status"] == "VALID", body3
         # stop_trace serializes the whole process's XLA metadata — in a
         # long-lived warm process that takes tens of seconds on this box
         # (the capture WINDOW stays the clamped seconds; the tail is
@@ -406,6 +312,31 @@ def test_profile_endpoint_single_flight_and_artifact(tmp_path, monkeypatch):
         assert found, "no profiler artifacts written"
     finally:
         server.shutdown()
+    # the capture runs with the profiler's Python tracer OFF and its host
+    # tracer at level 1 (utils/trace.jax_profile): it holds the program's
+    # own `phant/` events with the request's trace_id, and none of the
+    # Python tracer's (which names Python frames `$file:line function`)
+    import glob
+
+    from jax.profiler import ProfileData
+
+    (xplane,) = glob.glob(
+        os.path.join(body1["path"], "plugins/profile/*/*.xplane.pb")
+    )
+    events = [
+        (ev.name, dict(ev.stats))
+        for plane in ProfileData.from_file(xplane).planes
+        if plane.name.startswith("/host:")
+        for line in plane.lines
+        for ev in line.events
+    ]
+    ours = {name: stats for name, stats in events if name.startswith("phant/")}
+    assert {
+        "phant/request", "phant/decode", "phant/verify_block", "phant/evm",
+        "phant/reply",
+    } <= set(ours), sorted(ours)
+    assert ours["phant/verify_block"]["trace_id"] == ours["phant/request"]["trace_id"]
+    assert not [name for name, _stats in events if name.startswith("$")]
 
 
 def test_profile_cap_and_validation(tmp_path, monkeypatch):

@@ -376,7 +376,19 @@ def test_metrics_and_healthz_endpoints():
         out = json.loads(urllib.request.urlopen(req, timeout=10).read())
         assert out["result"]["status"] == "VALID"
 
+        # the latency histogram is observed when the handler has WRITTEN
+        # the reply, which the client may read first: wait for it, do not
+        # race it (PERF.md section 7 (f), PR 25)
+        import time
+
+        give_up = time.monotonic() + 10
         after = scrape()
+        while (
+            "phant_engine_api_request_seconds_count 1" not in after
+            and time.monotonic() < give_up
+        ):
+            time.sleep(0.01)
+            after = scrape()
         assert (
             'phant_engine_api_requests_total{method="engine_newPayloadV2"} 1'
             in after

@@ -1066,13 +1066,12 @@ def test_sig_engine_is_in_hostsync_scope(mutated_tree, monkeypatch):
     assert any("sig_engine" in f.path for f in hits)
 
 
-def test_busy_integration_is_in_hostsync_scope(mutated_tree, monkeypatch):
-    """The busy-time integration points (PR 15) are HOSTSYNC-scoped: the
-    pipeline handoff (busy begin, right after the no-sync begin_batch)
-    and the resolve worker (busy end) are in DEFAULT_ENTRIES, and a
-    stray `.item()` reintroduced next to the busy bracket turns the
-    gate red — observability must never put a device sync on the
-    serving hot path."""
+def test_lane_stage_brackets_are_in_hostsync_scope(mutated_tree, monkeypatch):
+    """The lanes' measured stages (PR 26) are HOSTSYNC-scoped: the
+    pipeline handoff (the bracket around the no-sync begin_batch) and the
+    resolve worker are in DEFAULT_ENTRIES, and a stray `.item()`
+    reintroduced next to the bracket turns the gate red — observability
+    must never put a device sync on the serving hot path."""
     from phant_tpu.analysis.rules.hostsync import DEFAULT_ENTRIES
 
     assert (
@@ -1086,8 +1085,8 @@ def test_busy_integration_is_in_hostsync_scope(mutated_tree, monkeypatch):
     p = mutated_tree / "phant_tpu" / "serving" / "scheduler.py"
     src = p.read_text()
     mutated = src.replace(
-        "        self._busy_acct.begin()\n        pipe_item = {\n",
-        "        self._busy_acct.begin()\n"
+        "                handle = engine.begin_batch(payload)\n        pipe_item = {\n",
+        "                handle = engine.begin_batch(payload)\n"
         "        _n = handle.total.item()\n"
         "        pipe_item = {\n",
         1,

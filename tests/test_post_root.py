@@ -661,3 +661,21 @@ def test_execute_stateless_routes_post_root_through_scheduler(monkeypatch):
         assert st["root_batches"] >= 1, st
     finally:
         server.shutdown()
+
+
+@pytest.mark.parametrize("op", ["enqueue", "sync"])
+def test_device_host_seconds_grows_on_the_root_lane(forced_device, op):
+    """The host's time at the device, measured where it is spent (PR 26):
+    `enqueue` around the merged plan's upload and launch, `sync` around
+    the out-row readback."""
+    from phant_tpu.ops.root_engine import RootEngine
+    from phant_tpu.utils.trace import metrics
+
+    key = f'device.host_seconds{{lane="root",op="{op}"}}'
+    before = metrics.snapshot()["histograms"].get(key, {"count": 0, "sum": 0.0})
+    hosts, prps, dbs = _request_set(seeds=range(1))
+    (out,) = RootEngine(device_floor=0).root_many([prps[0].plan])
+    assert dbs[0].apply_post_root(prps[0], out) == hosts[0]
+    after = metrics.snapshot()["histograms"][key]
+    assert after["count"] == before["count"] + 1
+    assert after["sum"] > before["sum"]

@@ -172,7 +172,6 @@ def test_default_shared_classes_register(san):
     assert {
         "VerificationScheduler",
         "FlightRecorder",
-        "BusyAccountant",
         "Metrics",
     } <= names
 

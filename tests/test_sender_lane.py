@@ -550,3 +550,20 @@ def test_execute_stateless_routes_senders_through_scheduler(monkeypatch):
         assert st["sig_batches"] >= 1, st
     finally:
         server.shutdown()
+
+
+@pytest.mark.parametrize("op", ["enqueue", "sync"])
+def test_device_host_seconds_grows_on_the_sig_lane(forced_device, op):
+    """The host's time at the device, measured where it is spent (PR 26):
+    `enqueue` around the merged ecrecover's upload and launch, `sync`
+    around the sender readback."""
+    from phant_tpu.ops.sig_engine import SigEngine
+    from phant_tpu.utils.trace import metrics
+
+    key = f'device.host_seconds{{lane="sig",op="{op}"}}'
+    before = metrics.snapshot()["histograms"].get(key, {"count": 0, "sum": 0.0})
+    oracles, rows_list = _request_set()
+    assert SigEngine(device_floor=0).sig_many(rows_list[:1]) == oracles[:1]
+    after = metrics.snapshot()["histograms"][key]
+    assert after["count"] == before["count"] + 1
+    assert after["sum"] > before["sum"]

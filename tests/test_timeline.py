@@ -149,8 +149,6 @@ def test_export_schema_valid_and_flows_pair():
         {"batch_id": 9, "device": "0", "batch_size": 1},
         lane="root", duration_ms=1.0, trace_ids=["req-a"],
     )
-    now = time.time()
-    timeline.record_busy("0", now - 0.01, now)
     payload = timeline.export(60.0)
     events = _validate_chrome_trace(payload)
     flows = {e["id"] for e in events if e["ph"] == "s"}
@@ -165,8 +163,9 @@ def test_export_schema_valid_and_flows_pair():
             continue
         assert st["ts"] >= batch["ts"]
         assert st["ts"] + st["dur"] <= batch["ts"] + batch["dur"]
-    # device busy track present
-    assert any(
+    # no host-clock bracket is drawn under a device's name any more (PR
+    # 26): what the chip is busy under is read from the profiler's trace
+    assert not any(
         e["ph"] == "M" and e["args"]["name"] == "devices" for e in events
     )
     assert payload["metadata"]["kept"] == {"sample": 2}
@@ -282,7 +281,6 @@ def test_disabled_recorder_is_a_no_op():
     timeline.on_span(_span_record("off"))
     timeline.record_batch({"batch_id": 1}, lane="witness", duration_ms=1.0,
                           trace_ids=["off"])
-    timeline.record_busy("0", 1.0, 2.0)
     assert not timeline.enabled()
     assert timeline.stats() == {"kept": {}, "dropped": {}}
     timeline.configure(enabled=True)
@@ -375,7 +373,7 @@ def test_request_links_to_all_three_lane_batches_over_http(monkeypatch):
         for e in events
         if e["ph"] == "M" and e["name"] == "process_name"
     }
-    assert {"requests", "lanes", "devices"} <= proc_names, proc_names
+    assert {"requests", "lanes"} <= proc_names, proc_names
     # at least one request flows to a batch on EVERY lane
     f_ids = {e["id"] for e in events if e["ph"] == "f"}
     linked = {}
