@@ -76,6 +76,7 @@ from phant_tpu.serving import (
     SchedulerError,
     VerificationScheduler,
     active_scheduler,
+    collector,
     current_priority,
     current_tenant,
     install,
@@ -90,8 +91,6 @@ from phant_tpu.utils.trace import (
     metrics,
     span,
     trace_context,
-    unwatch_gc,
-    watch_gc,
 )
 
 log = logging.getLogger("phant_tpu.engine_api")
@@ -233,6 +232,11 @@ class _HTTPServer(ThreadingHTTPServer):
     scheduler shed it with explicit -32050s within their patience window."""
 
     request_queue_size = 256
+
+    def service_actions(self) -> None:
+        """Every poll interval of `serve_forever` and after every accepted
+        connection: the one place that runs when nobody is waiting."""
+        collector.idle_tick()
 
 
 class _ObservableHandler(BaseHTTPRequestHandler):
@@ -639,9 +643,10 @@ class EngineAPIServer:
         # install only after the socket bound: a bind failure must not
         # leak a process-globally installed scheduler
         install(scheduler)
-        # the collector's pauses, as the program sees them: one callback
-        # for the process (runtime.gc_pause_seconds, `gc` intervals)
-        watch_gc()
+        # the collector while a server is up: one callback for the process
+        # times its pauses (runtime.gc_pause_seconds, `gc` intervals) and
+        # tenures what survives a full collection (serving/collector.py)
+        collector.install()
 
     @property
     def port(self) -> int:
@@ -669,7 +674,7 @@ class EngineAPIServer:
         finally:
             uninstall(self.scheduler)
             self._server.server_close()
-            unwatch_gc()
+            collector.uninstall()
 
 
 class MetricsServer:

@@ -151,13 +151,22 @@ class Evm:
     # ------------------------------------------------------------------
 
     def execute_message(self, msg: Message) -> ExecResult:
-        if msg.target is None:
-            nonce = self.state.get_nonce(msg.caller)
-            # top-level create: sender nonce was already bumped by tx
-            # processing, so the address derives from nonce-1
-            addr = create_address(msg.caller, nonce - 1)
-            return self._create(msg, addr)
-        return self._call_inner(msg)
+        try:
+            if msg.target is None:
+                nonce = self.state.get_nonce(msg.caller)
+                # top-level create: sender nonce was already bumped by tx
+                # processing, so the address derives from nonce-1
+                addr = create_address(msg.caller, nonce - 1)
+                return self._create(msg, addr)
+            return self._call_inner(msg)
+        finally:
+            # the native session and this Evm refer to each other, and its
+            # ctypes trampolines to it: taken apart here, the transaction's
+            # objects (and the block's state they hold) die by reference
+            # count and wait for no collector
+            session = self.__dict__.pop("_native_session", None)
+            if session is not None:
+                session.close()
 
     # ------------------------------------------------------------------
     # call path (reference: EVMOneHost.call vm.zig:382-522)

@@ -257,6 +257,12 @@ class NativeSession:
             self._cbs[name] = cb
             setattr(self.host, name, cb)
 
+    def close(self) -> None:
+        """Break the cycles this session closes (Evm <-> session, session ->
+        trampoline -> bound callback -> session) once its transaction is
+        over: nothing calls through the vtable any more."""
+        self._cbs = self.host = self.evm = self.state = None
+
     def _guard(self, fn, default):
         """No exception may unwind through the C frame: ctypes would swallow
         it and C++ would keep running on garbage. Stash the first error and

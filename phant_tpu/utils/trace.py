@@ -285,7 +285,10 @@ METRIC_HELP: Dict[str, str] = {
     "device.host_seconds": "Seconds a host thread spent at the device, by lane (witness/sig/root) and op: enqueue = upload + program launch with no wait (begin); sync = the readback, i.e. the thread stood BLOCKED on the chip (resolve). The measurement critpath's `dispatch` remainder is not",
     "jit.compiles": "Programs jax first built in this process, by thread (serving = a scheduler thread whose compile holds a job queue; other): one per backend compile and one per load from the persistent cache",
     "jit.serving_compile_seconds": "Wall-clock seconds the serving threads have spent compiling (union of jax's trace/lower/compile intervals): the credit the request deadline clock runs on (serving/deadline.py)",
-    "runtime.gc_pause_seconds": "Pauses of CPython's collector in this process, by generation, from the one gc.callbacks entry the server installs (every collection; a full one, generation 2, is also a `gc` interval of every request span open then)",
+    "runtime.gc_pause_seconds": "Pauses of CPython's collector in this process, by generation, from the one gc.callbacks entry the server installs (every collection; a full one, generation 2, is also a `gc` interval of every request span open then; generation=deep is the tenure policy's own full collection of everything, run while no request is in flight)",
+    "runtime.gc_tenures": "Full collections after which the survivors were moved to CPython's permanent generation (gc.freeze), so that no later collection walks them again: the tenure policy of serving/collector.py, in force while an Engine API server is up",
+    "runtime.gc_tenured_objects": "Objects in the permanent generation (gc.get_freeze_count) as of the last count: counted where the collector's log is flushed after a tenure, at most once a minute while requests are in flight (a walk of that generation, 15 ms a million objects), at once when none has been for some seconds; objects that died by reference count since are still in it",
+    "runtime.gc_deep_collections": "Deep collections: everything tenured handed back, collected and tenured again, when no request has been in flight for some seconds, the tenured count has grown by a twentieth and the last one is five minutes ago: frees cyclic garbage that formed among tenured objects",
     # observability layer (phant_tpu/obs/)
     "sched.watchdog_stalls": "Executor stalls detected by the obs watchdog (in-flight batch past its deadline)",
     "flight.dumps": "Flight-recorder postmortem dumps written, by trigger reason",
@@ -623,6 +626,15 @@ _span_sinks: List = []
 #: now, by id: the collector's callback writes each full collection into
 #: every one of them. Single dict operations only, so no lock
 _open_spans: Dict[int, "Span"] = {}
+_last_close_ns: List[int] = [clock_ns()]  # when the last of them closed, on the span clock
+
+
+def idle_seconds() -> Optional[float]:
+    """Seconds since the last top-level span closed (since import where none
+    has), or None while one is open: a request is in flight."""
+    if _open_spans:
+        return None
+    return (clock_ns() - _last_close_ns[0]) / 1e9
 
 
 def add_span_sink(fn) -> None:
@@ -987,6 +999,7 @@ def span(name: str, frame: bool = False, **attrs) -> Iterator[Span]:
         stack.pop()
         if top:
             _open_spans.pop(sp.span_id, None)
+            _last_close_ns[0] = t1
             if parent is not None:
                 parent.reported += 1
                 parent.mark(parent.resume, at=t1)
@@ -1009,6 +1022,10 @@ _gc_log: deque = deque(maxlen=1 << 16)
 _gc_open: List = [0, None]  # start_ns and annotation of the collection running
 _gc_watchers = 0
 _gc_lock = threading.Lock()
+#: the collector's policy while a server is up (serving/collector.py sets
+#: and clears it): `_on_gc` tells it of every full collection's end, still
+#: inside the collector, and `flush_gc` lets it publish what it counted there
+gc_policy = None
 
 
 def _on_gc(phase: str, info: dict) -> None:
@@ -1025,8 +1042,12 @@ def _on_gc(phase: str, info: dict) -> None:
         return  # installed while this collection ran: no start was seen
     _gc_open[0] = 0
     gen = info["generation"]
+    full = gen == 2
+    policy = gc_policy
+    if full and policy is not None and policy.full_collection_ended():
+        gen = "deep"  # the policy's own collection, run while nobody waits
     _gc_log.append((gen, t0, t1))
-    if gen == 2:
+    if full:
         # a full collection stops every thread: an interval of every
         # request that is open now
         for sp in list(_open_spans.values()):
@@ -1043,6 +1064,9 @@ def flush_gc() -> None:
         metrics.observe_hist(
             "runtime.gc_pause_seconds", (t1 - t0) / 1e9, generation=str(gen)
         )
+    policy = gc_policy
+    if policy is not None:
+        policy.flush()
 
 
 def watch_gc() -> None:

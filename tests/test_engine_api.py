@@ -11,6 +11,7 @@ parity checks (reference: src/config/config.zig).
 from __future__ import annotations
 
 import json
+import threading
 import urllib.request
 
 import pytest
@@ -101,6 +102,14 @@ def _fresh_chain() -> Blockchain:
         parent_header=make_genesis_parent_header(),
         verify_state_root=False,
     )
+
+
+def _serve(server) -> None:
+    """`serve_in_background` with the accept loop's poll cut from 0.5 s to
+    10 ms: `shutdown` waits out one poll, which tests nothing."""
+    threading.Thread(
+        target=server._server.serve_forever, args=(0.01,), daemon=True
+    ).start()
 
 
 def _valid_payload_json() -> dict:
@@ -300,7 +309,7 @@ def test_http_server_roundtrip():
     """Full HTTP POST round-trip (reference: main.zig:143-149 via httpz)."""
     chain = _fresh_chain()
     server = EngineAPIServer(chain, host="127.0.0.1", port=0)
-    server.serve_in_background()
+    _serve(server)
     try:
         body = json.dumps(
             {
@@ -344,7 +353,7 @@ def test_metrics_and_healthz_endpoints():
     metrics.reset()
     chain = _fresh_chain()
     server = EngineAPIServer(chain, host="127.0.0.1", port=0)
-    server.serve_in_background()
+    _serve(server)
     try:
         base = f"http://127.0.0.1:{server.port}"
         health = json.loads(urllib.request.urlopen(base + "/healthz", timeout=10).read())

@@ -21,7 +21,7 @@ import pytest
 from phant_tpu.backend import set_crypto_backend
 from phant_tpu.engine_api.server import FRONTEND_PHASES, EngineAPIServer
 from phant_tpu.obs import critpath, timeline
-from phant_tpu.serving import SchedulerConfig
+from phant_tpu.serving import SchedulerConfig, collector
 from phant_tpu.utils import trace
 from phant_tpu.utils.trace import (
     ANNOTATIONS,
@@ -32,6 +32,7 @@ from phant_tpu.utils.trace import (
     trace_context,
 )
 
+from test_engine_api import _serve
 from test_serving import _post, _stateless_request
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -232,7 +233,7 @@ def served(monkeypatch):
         chain, host="127.0.0.1", port=0,
         sched_config=SchedulerConfig(max_batch=4, max_wait_ms=1.0),
     )
-    server.serve_in_background()
+    _serve(server)
     try:
         yield f"http://127.0.0.1:{server.port}", rpc, want_root, records
     finally:
@@ -431,15 +432,30 @@ def test_gc_pause_histogram_and_gc_interval_of_an_open_span():
     assert trace._on_gc not in gc.callbacks
 
 
-def test_gc_callback_takes_no_lock_the_collecting_thread_may_hold():
+@pytest.mark.parametrize("with_policy", [False, True])
+def test_gc_callback_takes_no_lock_the_collecting_thread_may_hold(with_policy):
     """A collection can start under an allocation made while the thread
-    holds the registry's lock: the callback must not want that lock."""
-    trace.watch_gc()
+    holds the registry's lock: the callback must not want that lock, nor,
+    where the tenure policy is installed (PR 27), the policy's or the
+    install count's."""
+    if not with_policy:
+        trace.watch_gc()
+        try:
+            with metrics._lock:
+                gc.collect()
+        finally:
+            trace.unwatch_gc()
+        return
+    collector.install()
     try:
-        with metrics._lock:
+        policy = trace.gc_policy
+        tenures = policy.tenures
+        with metrics._lock, collector._lock, policy._lock:
             gc.collect()
+        assert policy.tenures == tenures + 1
     finally:
-        trace.unwatch_gc()
+        collector.uninstall()
+    assert gc.get_freeze_count() == 0
 
 
 # ---------------------------------------------------------------------------
