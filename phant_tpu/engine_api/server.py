@@ -647,6 +647,7 @@ class EngineAPIServer:
         # times its pauses (runtime.gc_pause_seconds, `gc` intervals) and
         # tenures what survives a full collection (serving/collector.py)
         collector.install()
+        _boot_root_lane()
 
     @property
     def port(self) -> int:
@@ -675,6 +676,26 @@ class EngineAPIServer:
             uninstall(self.scheduler)
             self._server.server_close()
             collector.uninstall()
+
+
+def _boot_root_lane() -> None:
+    """`root.plan_shapes` is on /metrics from the start; and where this
+    server sends post roots to an accelerator (the device root lane on,
+    stateless._batched_root_wanted), every rung of the root program's
+    ladder is built now, before a request can wait for one
+    (ops/root_engine.prewarm_ladder). On the CPU, where tests and dry runs
+    force the lane, a rung is built in a second or two when first met."""
+    from phant_tpu.ops.root_engine import note_plan_shape, prewarm_ladder
+    from phant_tpu.stateless import _batched_root_wanted
+
+    note_plan_shape()
+    if not _batched_root_wanted():
+        return
+    import jax
+
+    if jax.default_backend() == "cpu":
+        return
+    log.info("root lane: %d rungs built in %.1fs", *prewarm_ladder())
 
 
 class MetricsServer:

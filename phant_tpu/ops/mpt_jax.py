@@ -16,6 +16,13 @@ src/blockchain/blockchain.zig:83-85).
 Scope: tries whose nodes all RLP-encode to >= 32 bytes (true for the secure
 state trie — account leaves are ~70B — and for receipt/tx tries of real
 blocks). Tries with embedded (<32B) nodes fall back to the CPU walk.
+
+Two executors share the plans. Replay and `trie_root_device` run one plan
+(or K of one structure) level by level, the levels unrolled and the plan's
+own sizes in the jit key (`_hash_plan_fused`, `_hash_plans_batched`). The
+serving root lane merges a batch's plans into strips on a rung of
+PLAN_LADDER and walks them in one loop (`merge_plans`,
+`_hash_plan_outputs`): its jit key is the rung, never a block's sizes.
 """
 
 from __future__ import annotations
@@ -124,6 +131,7 @@ class HashPlan:
     root_pos: int  # row of the root digest in the global digest buffer
     device_args: Optional[tuple] = None  # (blob_d, levels_d) jax arrays
     out_rows: Optional[np.ndarray] = None  # (R,) int32 padded-space rows
+    used: int = 0  # template bytes at the blob's head (0 = not recorded)
 
 
 class PlanBuilder:
@@ -327,6 +335,7 @@ class PlanBuilder:
             n_nodes=n,
             root_pos=int(remap[root_gi]),
             out_rows=out_rows,
+            used=pos,
         )
 
 
@@ -350,95 +359,153 @@ def build_hash_plan(trie: Trie) -> Optional[HashPlan]:
     return builder.finish(res[0])
 
 
+# ---------------------------------------------------------------------------
+# the served layout: strips on a ladder
+# ---------------------------------------------------------------------------
+
+#: rows hashed per step of the served program, and the child digests one
+#: step may scatter. A branch has at most 17 holes, so any row fits a strip.
+STRIP_ROWS = 128
+STRIP_HOLES = 256
+
+
+@dataclass(frozen=True)
+class Rung:
+    """One compiled shape of the served root program: strips the index
+    arrays hold, blob bytes, digest rows read back. The three grow
+    together, so the program's shapes are the rungs and nothing else."""
+
+    steps: int
+    blob: int
+    outs: int
+
+
+#: THE shape set of `_hash_plan_outputs` (PERF.md, fault 0a): a lone
+#: 225-tx block under a 2^20 genesis is 20 strips, 0.5 MB and 17 digests
+#: and sits well inside rung 0; 128 such requests coalesced fill rung 7.
+#: A batch over the top rung is hashed on the host.
+PLAN_LADDER: Tuple[Rung, ...] = tuple(
+    Rung(32 << k, (1 << 20) << k, 64 << k) for k in range(8)
+)
+
+
+@dataclass
+class StripPlan:
+    """K HashPlans merged into the served program's layout: the rows of
+    every level cut into strips of STRIP_ROWS (a strip never spans two
+    levels, so a strip's children all lie in earlier strips), the strips
+    stacked into arrays of the rung's shape. The program walks the first
+    `n_steps` strips and no more: the rest of the rung is never hashed."""
+
+    blob: np.ndarray  # (rung.blob,) uint8
+    off: np.ndarray  # (steps, STRIP_ROWS) int32
+    ln: np.ndarray  # (steps, STRIP_ROWS) int32, 0 = pad row
+    hole_pos: np.ndarray  # (steps, STRIP_HOLES) int32
+    hole_child: np.ndarray  # (steps, STRIP_HOLES) int32 digest rows
+    out_rows: np.ndarray  # (rung.outs,) int32, the last repeated as padding
+    n_steps: int
+    n_nodes: int
+    used: int  # template bytes, packed from the blob's head
+    rung: int  # index into PLAN_LADDER: with the device, the jit key
+
+
+def _cut_strips(holes_of_row: np.ndarray) -> np.ndarray:
+    """Strip number (from 0) of each row of one level: STRIP_ROWS rows a
+    strip, fewer where their holes would pass STRIP_HOLES."""
+    n = len(holes_of_row)
+    strip = np.arange(n) // STRIP_ROWS
+    if np.bincount(strip, weights=holes_of_row).max() <= STRIP_HOLES:
+        return strip
+    k = rows = holes = 0
+    for i, h in enumerate(holes_of_row.tolist()):
+        if rows == STRIP_ROWS or holes + h > STRIP_HOLES:
+            k, rows, holes = k + 1, 0, 0
+        strip[i] = k
+        rows += 1
+        holes += h
+    return strip
+
+
 def merge_plans(
-    plans: Sequence[HashPlan], blob_out: Optional[np.ndarray] = None
-) -> Tuple[HashPlan, List[np.ndarray]]:
-    """K independent HashPlans fused into ONE level-aligned device plan —
-    the cross-request coalescing behind the serving post-root path
-    (ops/root_engine.py): level l of the merged plan is the concatenation
-    of every input plan's level l, so one dispatch hashes all K requests'
-    dirty subtrees with max(depth) sequential keccak rounds instead of K
-    round trips. Row/hole indices are remapped into the merged padded row
-    space; per-plan blob regions keep their own scatter slack, so pad
-    holes stay harmless.
+    plans: Sequence[HashPlan], lease=None
+) -> Tuple[Optional[StripPlan], List[np.ndarray]]:
+    """K independent HashPlans fused into ONE device plan on a rung of
+    PLAN_LADDER — the cross-request coalescing behind the serving
+    post-root path (ops/root_engine.py): level l of the merged plan is the
+    concatenation of every input plan's level l, cut into strips, so one
+    dispatch hashes all K requests' dirty subtrees. Row and hole indices
+    are remapped into the strips' row space; the templates are packed
+    head to tail and the blob's last 32 bytes take the pad holes.
 
-    Returns (merged plan, per-input-plan merged out_rows — same order as
-    each plan's own out_rows, defaulting to [root]). `blob_out` hands in
-    a pre-zeroed pooled buffer at least the merged pow2 size (the serving
-    staging lease); omitted, a fresh buffer is allocated."""
-    shifts: List[int] = []
-    pos = 0
-    for p in plans:
-        shifts.append(pos)
-        pos += len(p.blob)
-    need = _pow2(pos + MPT_MAX_CHUNKS * RATE)
-    if blob_out is not None:
-        if len(blob_out) < need:
-            raise ValueError("merge blob lease too small")
-        blob = blob_out
-    else:
-        blob = np.zeros(need, np.uint8)
-    for p, sp in zip(plans, shifts):
-        blob[sp : sp + len(p.blob)] = p.blob
+    Returns (merged plan, per-input-plan merged out rows — same order as
+    each plan's own out_rows, defaulting to [root]); the plan is None
+    where the batch is over the ladder's top rung. `lease(n)` hands in a
+    pooled buffer of n bytes, zero wherever no earlier merge wrote (the
+    serving staging lease); omitted, a fresh buffer is allocated."""
+    useds = [p.used or len(p.blob) for p in plans]
+    shifts = np.cumsum([0] + useds)
+    pos = int(shifts[-1])
 
-    n_levels = max(len(p.levels) for p in plans)
-    # local padded-row -> merged padded-row maps (pad rows map to 0; only
-    # pad holes reference them and those are dropped below)
+    # local padded-row -> merged row maps (pad rows map to 0; only pad
+    # holes reference them and those are dropped below)
     local_maps = [
         np.zeros(sum(len(off) for off, _l, _p, _c in p.levels), np.int64)
         for p in plans
     ]
-    local_starts: List[List[int]] = []
-    for p in plans:
-        starts: List[int] = []
-        s = 0
-        for off, _l, _p2, _c in p.levels:
-            starts.append(s)
-            s += len(off)
-        local_starts.append(starts)
-
-    merged_levels = []
-    merged_start = 0
-    scratch = len(blob) - 32
-    for lvl in range(n_levels):
-        offs: List[np.ndarray] = []
-        lns: List[np.ndarray] = []
-        hps: List[np.ndarray] = []
-        hcs: List[np.ndarray] = []
-        n_real_tot = 0
+    local_starts = [
+        np.cumsum([0] + [len(off) for off, _l, _p, _c in p.levels]) for p in plans
+    ]
+    rows_at: List[np.ndarray] = []  # per level: merged row of each real row
+    offs: List[np.ndarray] = []
+    lns: List[np.ndarray] = []
+    holes_at: List[np.ndarray] = []  # per level: flat slot of each real hole
+    hps: List[np.ndarray] = []
+    hcs: List[np.ndarray] = []
+    step = 0
+    for lvl in range(max(len(p.levels) for p in plans)):
+        l_off, l_ln, l_hp, l_hc, l_hrow, l_plan = [], [], [], [], [], []
+        n = 0
         for pi, p in enumerate(plans):
             if lvl >= len(p.levels):
                 continue
             off, ln, hp, hc = p.levels[lvl]
             n_real = int(np.count_nonzero(ln))
-            if n_real:
-                local_maps[pi][
-                    local_starts[pi][lvl] : local_starts[pi][lvl] + n_real
-                ] = merged_start + n_real_tot + np.arange(n_real)
-                offs.append(off[:n_real] + shifts[pi])
-                lns.append(ln[:n_real])
-            n_real_tot += n_real
+            l_plan.append((pi, n, n_real))
+            l_off.append(off[:n_real] + shifts[pi])
+            l_ln.append(ln[:n_real])
             # real holes only: pad holes point at the plan's own scratch
             real_h = hp != (len(p.blob) - 32)
             if real_h.any():
-                hps.append(hp[real_h] + shifts[pi])
+                l_hp.append(hp[real_h] + shifts[pi])
                 # children live at strictly lower levels, already mapped
-                hcs.append(local_maps[pi][hc[real_h]])
-        npad = _pow2(max(n_real_tot, 1))
-        moff = np.zeros(npad, np.int32)
-        mln = np.zeros(npad, np.int32)
-        if offs:
-            moff[:n_real_tot] = np.concatenate(offs)
-            mln[:n_real_tot] = np.concatenate(lns)
-        nh = sum(len(h) for h in hps)
-        hpad = _pow2(nh) if nh else 1
-        mhp = np.full(hpad, scratch, np.int32)
-        mhc = np.zeros(hpad, np.int32)
-        if nh:
-            mhp[:nh] = np.concatenate(hps)
-            mhc[:nh] = np.concatenate(hcs)
-        merged_levels.append((moff, mln, mhp, mhc))
-        merged_start += npad
+                l_hc.append(local_maps[pi][hc[real_h]])
+                # holes are listed row by row, and a level's rows lie in
+                # the blob in their own order
+                l_hrow.append(
+                    n + np.searchsorted(off[:n_real], hp[real_h], side="right") - 1
+                )
+            n += n_real
+        if not n:
+            continue
+        hrow = np.concatenate(l_hrow) if l_hrow else np.zeros(0, np.int64)
+        strip = _cut_strips(np.bincount(hrow, minlength=n))
+        first = np.searchsorted(strip, np.arange(strip[-1] + 1))
+        rows = (step + strip) * STRIP_ROWS + np.arange(n) - first[strip]
+        for pi, at, n_real in l_plan:
+            s0 = local_starts[pi][lvl]
+            local_maps[pi][s0 : s0 + n_real] = rows[at : at + n_real]
+        rows_at.append(rows)
+        offs += l_off
+        lns += l_ln
+        if len(hrow):
+            hstrip = strip[hrow]
+            hfirst = np.searchsorted(hstrip, np.arange(strip[-1] + 1))
+            holes_at.append(
+                (step + hstrip) * STRIP_HOLES + np.arange(len(hrow)) - hfirst[hstrip]
+            )
+            hps += l_hp
+            hcs += l_hc
+        step += int(strip[-1]) + 1
 
     outs: List[np.ndarray] = []
     for pi, p in enumerate(plans):
@@ -448,12 +515,46 @@ def merge_plans(
             else np.asarray([p.root_pos], np.int32)
         )
         outs.append(local_maps[pi][rows].astype(np.int32))
-    merged = HashPlan(
+    out_rows = np.concatenate(outs)
+    need = pos + MPT_MAX_CHUNKS * RATE + 32
+    rung = next(
+        (
+            k
+            for k, r in enumerate(PLAN_LADDER)
+            if r.steps >= step and r.blob >= need and r.outs >= len(out_rows)
+        ),
+        None,
+    )
+    if rung is None:
+        return None, outs
+    r = PLAN_LADDER[rung]
+    blob = lease(r.blob) if lease is not None else np.zeros(r.blob, np.uint8)
+    for p, sp, used in zip(plans, shifts, useds):
+        blob[sp : sp + used] = p.blob[:used]
+    off = np.zeros((r.steps, STRIP_ROWS), np.int32)
+    ln = np.zeros((r.steps, STRIP_ROWS), np.int32)
+    at = np.concatenate(rows_at)
+    off.reshape(-1)[at] = np.concatenate(offs)
+    ln.reshape(-1)[at] = np.concatenate(lns)
+    hole_pos = np.full((r.steps, STRIP_HOLES), r.blob - 32, np.int32)
+    hole_child = np.zeros((r.steps, STRIP_HOLES), np.int32)
+    if holes_at:
+        at = np.concatenate(holes_at)
+        hole_pos.reshape(-1)[at] = np.concatenate(hps)
+        hole_child.reshape(-1)[at] = np.concatenate(hcs)
+    padded = np.full(r.outs, out_rows[-1], np.int32)
+    padded[: len(out_rows)] = out_rows
+    merged = StripPlan(
         blob=blob,
-        levels=merged_levels,
+        off=off,
+        ln=ln,
+        hole_pos=hole_pos,
+        hole_child=hole_child,
+        out_rows=padded,
+        n_steps=step,
         n_nodes=sum(p.n_nodes for p in plans),
-        root_pos=int(local_maps[-1][plans[-1].root_pos]),
-        out_rows=np.concatenate(outs).astype(np.int32),
+        used=pos,
+        rung=rung,
     )
     return merged, outs
 
@@ -550,12 +651,33 @@ def _hash_plan_body(blob, levels, *, max_chunks: int):
 
 
 @functools.partial(jax.jit, static_argnames=("max_chunks",))
-def _hash_plan_outputs(blob, levels, out_rows, *, max_chunks: int):
-    """Full-plan execution returning only the requested digest rows —
-    the serving post-root executor (ops/root_engine.py): one dispatch
-    hashes a MERGED multi-request plan and reads back each request's
-    storage roots + account root ((R, 8) u32), nothing else."""
-    return _plan_digests_body(blob, levels, max_chunks=max_chunks)[out_rows]
+def _hash_plan_outputs(
+    blob, off, ln, hole_pos, hole_child, n_steps, out_rows, *, max_chunks: int
+):
+    """A StripPlan executed in ONE device program, returning only the
+    requested digest rows — the serving post-root executor
+    (ops/root_engine.py): one dispatch hashes a MERGED multi-request plan
+    and reads back each request's storage roots + account root ((R, 8)
+    u32), nothing else. One loop over the first `n_steps` strips (a run
+    time number: the rung's empty strips cost nothing): scatter the
+    strip's child digests into its templates' holes, hash its rows, keep
+    the digests. The jit cache key is the rung's shapes (PLAN_LADDER)."""
+    steps, rows = off.shape
+    shifts = jnp.arange(4, dtype=jnp.uint32) * 8
+    pos32 = jnp.arange(32, dtype=jnp.int32)
+
+    def strip(t, carry):
+        blob, digests = carry
+        d = digests[hole_child[t]]  # (H, 8)
+        dbytes = ((d[:, :, None] >> shifts[None, None, :]) & 0xFF).astype(jnp.uint8)
+        flat = hole_pos[t][:, None] + pos32[None, :]
+        blob = blob.at[flat.reshape(-1)].set(dbytes.reshape(-1))
+        hashed = witness_digests(blob, off[t], ln[t], max_chunks=max_chunks)
+        return blob, jax.lax.dynamic_update_slice(digests, hashed, (t * rows, 0))
+
+    digests = jnp.zeros((steps * rows, 8), jnp.uint32)
+    _blob, digests = jax.lax.fori_loop(0, n_steps, strip, (blob, digests))
+    return digests[out_rows]
 
 
 _hash_plan_fused = functools.partial(jax.jit, static_argnames=("max_chunks",))(

@@ -36,8 +36,18 @@ crash paths (handle abandonment), prefetch worker, and mesh lanes drive
 either engine through one code path. Dispatch enqueues with ZERO host
 sync (HOSTSYNC-scoped); resolve pays the readback. Merged staging blobs
 lease from the same process-global pool as witness staging
-(witness_engine._staging), keyed by pow2 size, returned at resolve (or
-abandon) exactly like witness pack leases.
+(witness_engine._staging), keyed by the rung's size, returned at resolve
+(or abandon) exactly like witness pack leases.
+
+THE PROGRAM'S SHAPES: a merged plan is laid out on a rung of
+`mpt_jax.PLAN_LADDER` (strips of 128 rows; steps, blob and read-back rows
+grow together), so `_hash_plan_outputs` is built once a rung and never
+for a block's own sizes; `prewarm_ladder` builds every rung when a
+server starts on an accelerator. `root.plan_shapes` counts the shapes
+this process has dispatched, `root.plan_rung{rung=}` the dispatches by
+rung (`over` = a batch above the top rung, hashed on the host), and
+`root.plan_rows{kind=real|pad}` what the strips' padding costs in keccak
+rows.
 """
 
 from __future__ import annotations
@@ -49,6 +59,72 @@ from typing import List, Optional, Sequence, Tuple
 import numpy as np
 
 from phant_tpu.utils.trace import device_host, metrics
+
+#: the (device, rung) pairs `_hash_plan_outputs` has run on in this process:
+#: its shapes, since a rung fixes every one of them (`root.plan_shapes`)
+_plan_shapes: set = set()
+_plan_shapes_lock = threading.Lock()
+
+
+def note_plan_shape(key: Optional[tuple] = None) -> None:
+    """Count `key` among the shapes run and set `root.plan_shapes`; with
+    no key, only set the gauge: a server calls that at its start, so the
+    family is on /metrics before the first root program (0 then)."""
+    with _plan_shapes_lock:
+        if key is not None:
+            _plan_shapes.add(key)
+        n = len(_plan_shapes)
+    metrics.gauge_set("root.plan_shapes", n)
+
+
+def _run_empty(rung):
+    """The served program once on `rung`, over zeros and with no strip to
+    walk: what builds it. Returns the unresolved output. The zeros are
+    the host's, uploaded: made on the device each shape would be a small
+    program of its own (33 more built at start, my chip runs, PR 28)."""
+    import jax.numpy as jnp
+
+    from phant_tpu.ops.mpt_jax import (
+        MPT_MAX_CHUNKS,
+        STRIP_HOLES,
+        STRIP_ROWS,
+        _hash_plan_outputs,
+    )
+
+    rows = jnp.asarray(np.zeros((rung.steps, STRIP_ROWS), np.int32))
+    holes = jnp.asarray(np.zeros((rung.steps, STRIP_HOLES), np.int32))
+    return _hash_plan_outputs(
+        jnp.asarray(np.zeros(rung.blob, np.uint8)),
+        rows,
+        rows,
+        holes,
+        holes,
+        jnp.asarray(np.int32(0)),
+        jnp.asarray(np.zeros(rung.outs, np.int32)),
+        max_chunks=MPT_MAX_CHUNKS,
+    )
+
+
+def prewarm_ladder(device=None) -> Tuple[int, float]:
+    """Build the served root program on every rung of the ladder, so that
+    no request ever waits for one: called when a server starts with the
+    root lane on and an accelerator under it (engine_api/server.py).
+    Returns the rungs built and the seconds it took
+    (`root.prewarm_seconds`)."""
+    import time
+
+    import jax
+
+    from phant_tpu.ops.mpt_jax import PLAN_LADDER
+
+    t0 = time.monotonic()
+    with jax.default_device(device):
+        for k, r in enumerate(PLAN_LADDER):
+            _run_empty(r).block_until_ready()  # phantlint: disable=HOSTSYNC — boot prewarm: the build is the point
+            note_plan_shape((device, k))
+    dt = time.monotonic() - t0
+    metrics.gauge_set("root.prewarm_seconds", dt)
+    return len(PLAN_LADDER), dt
 
 
 class RootPrefetch:
@@ -168,28 +244,35 @@ class RootEngine:
 
     # -- merge (the plan-lowering stage) --------------------------------------
 
-    def _merge(self, plans: Sequence) -> Tuple[object, list, tuple, int]:
+    def _merge(self, plans: Sequence) -> Tuple[object, list, Optional[tuple], int]:
         """(merged plan, per-plan out rows, staging lease, payload):
-        concatenate the batch's plans into one level-aligned program over
-        a pooled blob (ops/mpt_jax.merge_plans)."""
-        from phant_tpu.crypto.keccak import RATE
-        from phant_tpu.ops.mpt_jax import MPT_MAX_CHUNKS, _pow2, merge_plans
+        concatenate the batch's plans into one program on a rung of the
+        ladder, over a pooled blob (ops/mpt_jax.merge_plans). The plan
+        and the lease are None where the batch is over the top rung."""
+        from phant_tpu.ops.mpt_jax import merge_plans
         from phant_tpu.ops.witness_engine import _staging
 
+        taken: list = []
+
+        def lease(size: int) -> np.ndarray:
+            key = ("root_blob", size)
+            entry = _staging.take(key)
+            if entry is None:
+                entry = {"blob": np.zeros(size, np.uint8), "dirty": 0}
+            taken.append((key, entry))
+            return entry["blob"]
+
         payload = self._payload_bytes(plans)
-        raw = sum(len(p.blob) for p in plans)
-        # the SAME pow2 merge_plans sizes its blob with — the pooled
-        # lease can never come up short
-        need = _pow2(raw + MPT_MAX_CHUNKS * RATE)
-        key = ("root_blob", need)
-        entry = _staging.take(key)
-        if entry is None:
-            entry = {"blob": np.zeros(need, np.uint8), "dirty": 0}
-        blob = entry["blob"]
-        if entry["dirty"] > raw:
-            blob[raw : entry["dirty"]] = 0
-        entry["dirty"] = raw
-        merged, outs = merge_plans(plans, blob_out=blob)
+        merged, outs = merge_plans(plans, lease=lease)
+        if merged is None:
+            metrics.count("root.plan_rung", rung="over")
+            return None, outs, None, payload
+        key, entry = taken[0]
+        # templates lie head to tail from 0: clear what an earlier, longer
+        # merge left beyond them
+        if entry["dirty"] > merged.used:
+            merged.blob[merged.used : entry["dirty"]] = 0
+        entry["dirty"] = merged.used
         return merged, outs, (key, entry), payload
 
     # -- two-phase protocol (scheduler pipeline shape) ------------------------
@@ -232,7 +315,8 @@ class RootEngine:
                     metrics.count("witness_engine.root_plan_hits")
                 else:
                     h.merged, h.outs, h.lease, _ = self._merge(plans)
-            else:
+                route = h.merged is not None  # over the ladder: host
+            if not route:
                 h.backend = "host"
                 if pf is not None:
                     pf.release()  # host route: the merge goes unused
@@ -267,32 +351,33 @@ class RootEngine:
 
         from phant_tpu.ops.mpt_jax import (
             MPT_MAX_CHUNKS,
+            STRIP_ROWS,
             _hash_plan_outputs,
-            _pow2,
         )
 
-        out_rows = merged.out_rows
-        rp = _pow2(len(out_rows))
-        padded = np.full(rp, out_rows[-1], np.int32)
-        padded[: len(out_rows)] = out_rows
         device = self._pinned_device()
-        if device is not None:
-            # committed inputs pin the compute with them (mesh lanes)
-            blob_d = jax.device_put(merged.blob, device)
-            rows_d = jax.device_put(padded, device)
-            levels_d = tuple(
-                tuple(jax.device_put(a, device) for a in lvl)  # phantlint: disable=JNPHOSTLOOP — bounded per-level metadata upload
-                for lvl in merged.levels
+        # committed inputs pin the compute with them (mesh lanes)
+        put = jnp.asarray if device is None else (lambda a: jax.device_put(a, device))
+        args = [
+            put(a)
+            for a in (
+                merged.blob,
+                merged.off,
+                merged.ln,
+                merged.hole_pos,
+                merged.hole_child,
+                np.int32(merged.n_steps),
+                merged.out_rows,
             )
-        else:
-            blob_d = jnp.asarray(merged.blob)
-            rows_d = jnp.asarray(padded)
-            levels_d = tuple(
-                tuple(jnp.asarray(a) for a in lvl) for lvl in merged.levels  # phantlint: disable=JNPHOSTLOOP — bounded per-level metadata upload
-            )
-        return _hash_plan_outputs(
-            blob_d, levels_d, rows_d, max_chunks=MPT_MAX_CHUNKS
+        ]
+        out = _hash_plan_outputs(*args, max_chunks=MPT_MAX_CHUNKS)
+        note_plan_shape((device, merged.rung))
+        metrics.count("root.plan_rung", rung=str(merged.rung))
+        metrics.count("root.plan_rows", merged.n_nodes, kind="real")
+        metrics.count(
+            "root.plan_rows", merged.n_steps * STRIP_ROWS - merged.n_nodes, kind="pad"
         )
+        return out
 
     def resolve_batch(self, handle: RootHandle) -> List[List[bytes]]:
         """Per-plan out-row digests (each plan's storage roots in patch

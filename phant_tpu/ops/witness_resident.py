@@ -260,12 +260,22 @@ class ResidentTable:
         device=None,
     ):
         self._max_cap = _pow2ceil(max_cap or resident_default_cap())
+        import jax
+
+        on_device = jax.default_backend() != "cpu"
         if start_cap is None:
-            # PHANT_RESIDENT_START_CAP: pre-size the row space when the
-            # working set is known (the bench does — growth recompiles
-            # the update program per pow2 step, which must not land in a
-            # timed pass)
-            start_cap = int(os.environ.get("PHANT_RESIDENT_START_CAP", 1 << 10))
+            # the five programs are keyed on the row space, and every
+            # doubling rebuilds them inside a request (PERF.md, fault 0b):
+            # on an accelerator the table is born at its cap, 0.6 GB of
+            # 16 at 2^20 rows. On the CPU (tests, dry runs) a program is
+            # built in seconds and memory is the host's: start small and
+            # grow. PHANT_RESIDENT_START_CAP says otherwise on either.
+            start_cap = int(
+                os.environ.get(
+                    "PHANT_RESIDENT_START_CAP",
+                    self._max_cap if on_device else 1 << 10,
+                )
+            )
         self._start_cap = min(_pow2ceil(max(start_cap, 64)), self._max_cap)
         self._device = device  # jax device handle or None (default placement)
         self._lock = threading.Lock()
@@ -294,9 +304,7 @@ class ResidentTable:
         # resident arrays in place instead of copying ~cap*613B per
         # novel batch; the CPU backend does not support donation and
         # would warn per call.
-        import jax
-
-        fns = _jit_programs(jax.default_backend() != "cpu")
+        fns = _jit_programs(on_device)
         self._update_fn = fns["update"]
         self._verdict_fn = fns["verdict"]
         self._reindex_fn = fns["reindex"]

@@ -838,8 +838,9 @@ class VerificationScheduler:
         priority: Optional[int],
     ) -> _Job:
         # level-shape bucket: plans with the same depth coalesce into one
-        # merged dispatch (pow2 padding absorbs the per-level widths);
-        # NEGATIVE so it never collides with the witness pow2 buckets
+        # merged dispatch (its rung of mpt_jax.PLAN_LADDER is chosen at
+        # merge time, whatever the levels' widths); NEGATIVE so it never
+        # collides with the witness pow2 buckets
         from phant_tpu.ops.mpt_jax import plan_payload_bytes
 
         return _Job(

@@ -661,6 +661,7 @@ class WitnessStateDB(StateDB):
         state_root() is always correct (and cheap, via the memos)."""
         builder = self._scheme.plan_builder()
         patches: List[_RootPatch] = []
+        pending: list = []  # accounts whose leaf takes a storage-root hole
         changed_any = False
         for addr in sorted(self._seen | set(self.accounts)):
             acct = self.accounts.get(addr)
@@ -703,22 +704,22 @@ class WitnessStateDB(StateDB):
                 prefix, suffix = self._account_leaf_segments(fields)
                 self._post_root_memo = None
                 self._trie.put(key, prefix + b"\x00" * 32 + suffix)
-                leaf = _find_leaf(self._trie, key)
-                if leaf is None:  # cannot happen for 32-byte keccak keys
-                    self._repair_pending(patches)
-                    return None
-                builder.value_holes[id(leaf)] = (
-                    prefix,
-                    suffix,
-                    hole[0],
-                    hole[1],
-                )
-                patches.append(
-                    _RootPatch(addr, leaf, prefix, suffix, hole[0], fields)
-                )
+                pending.append((addr, key, prefix, suffix, hole, fields))
             changed_any = True
         if not changed_any:
             return None  # state_root() answers from the memo / pre root
+        # the leaves are looked up only now: a later account's put or
+        # delete may split or collapse the path of an earlier one, and
+        # the trie then holds ANOTHER leaf object for it
+        for addr, key, prefix, suffix, hole, fields in pending:
+            leaf = _find_leaf(self._trie, key)
+            if leaf is None:  # cannot happen for 32-byte keccak keys
+                self._repair_pending(patches)
+                return None
+            builder.value_holes[id(leaf)] = (prefix, suffix, hole[0], hole[1])
+            patches.append(
+                _RootPatch(addr, leaf, prefix, suffix, hole[0], fields)
+            )
         root = self._trie.root
         res = builder.try_subtree(root) if root is not None else None
         if res is None:

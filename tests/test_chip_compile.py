@@ -163,6 +163,48 @@ def test_root_plan_compiles_for_v5e(shape, pallas_is_the_keccak):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("rung", [0, 7])
+def test_served_root_program_compiles_on_its_ladder_for_v5e(
+    shape, pallas_is_the_keccak, rung
+):
+    """The serving root lane's program (PR 28) on the ladder's first rung,
+    where a lone block's plan lies, and on its last (128 MiB of templates):
+    one loop over the strips, the keccak kernel inside it."""
+    import jax.numpy as jnp
+
+    from phant_tpu.ops.mpt_jax import (
+        MPT_MAX_CHUNKS,
+        PLAN_LADDER,
+        STRIP_HOLES,
+        STRIP_ROWS,
+        _hash_plan_outputs,
+    )
+
+    # a trace of these shapes made earlier in this process, on the CPU and
+    # without the kernel (tests/test_root_ladder.py runs rung 0), would be
+    # reused by `lower`
+    from phant_tpu.ops.witness_jax import witness_digests
+
+    witness_digests.clear_cache()
+    _hash_plan_outputs.clear_cache()
+    r = PLAN_LADDER[rung]
+    rows = shape((r.steps, STRIP_ROWS), jnp.int32)
+    holes = shape((r.steps, STRIP_HOLES), jnp.int32)
+    compiled = _hash_plan_outputs.lower(
+        shape((r.blob,), jnp.uint8),
+        rows,
+        rows,
+        holes,
+        holes,
+        shape((), jnp.int32),
+        shape((r.outs,), jnp.int32),
+        max_chunks=MPT_MAX_CHUNKS,
+    ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "while" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < (1 << 30)
+
+
 def test_pallas_keccak_compiles_under_shard_map_for_four_v5e(topo, shape):
     """The mesh-sharded serving programs call the kernel under `shard_map`,
     whose vma check needs the kernel's output to say which mesh axes it

@@ -949,9 +949,9 @@ def test_root_engine_is_in_hostsync_scope(mutated_tree, monkeypatch):
     p = mutated_tree / "phant_tpu" / "ops" / "root_engine.py"
     src = p.read_text()
     mutated = src.replace(
-        "        merged, outs = merge_plans(plans, blob_out=blob)\n",
-        "        merged, outs = merge_plans(plans, blob_out=blob)\n"
-        "        _sync = blob.sum().item()\n",
+        "        merged, outs = merge_plans(plans, lease=lease)\n",
+        "        merged, outs = merge_plans(plans, lease=lease)\n"
+        "        _sync = merged.blob.sum().item()\n",
         1,
     )
     assert mutated != src
