@@ -15,7 +15,7 @@ Request execution goes through the continuous-batching scheduler
   threads (stateless execution shares nothing), and its witness
   verification coalesces with other in-flight requests into one
   engine/device `verify_batch` dispatch via the scheduler's batch
-  assembler (stateless.verify_witness_nodes) — with `--sched-mesh N`
+  assembler (stateless.admit_witness) — with `--sched-mesh N`
   those dispatches fan out over N device-pinned executors
   (serving/mesh_exec.py), and `/healthz` carries the per-device lane
   state under `scheduler.mesh` (any dead lane turns the probe 503
@@ -399,7 +399,7 @@ class EngineAPIServer:
 
     Owns a `VerificationScheduler` (phant_tpu/serving/): construction
     installs it as the process's active scheduler (so
-    stateless.verify_witness_nodes and `/healthz` see it) and shutdown
+    stateless.admit_witness and `/healthz` see it) and shutdown
     drains + uninstalls it. Pass `scheduler=` to share one across
     servers — then the CALLER owns its lifecycle (shutdown here only
     undoes this server's install, never drains a shared scheduler out

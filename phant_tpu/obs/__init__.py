@@ -12,7 +12,7 @@ died. This package is that layer:
   jobs it submits all carry the request's `trace_id`. The scheduler
   attaches a batch record (`batch_id`, `queue_wait_ms`, `bucket_bytes`,
   `batch_size`, `backend`, cache hit/miss counts) to every job it
-  executes, and `stateless.verify_witness_nodes` folds it into the
+  executes, and `stateless.join_witness` folds it into the
   request's top-level span — concurrent requests coalesced into one batch
   each get their own span linked by the shared `batch_id`.
 * **Flight recorder** (`flight.py`): a bounded thread-safe ring of span /

@@ -9,18 +9,23 @@ sink that, at every top-level `verify_block` close, TILES the request's
 wall clock into an exclusive phase breakdown:
 
   sig_rows       signature-row build on the handler thread
-  queue_wait     admission -> executor pickup (witness batch record)
-  prefetch       waiting on the 4th-stage decode/pre-scan plan
-  pack           begin_batch's lock-held scan (the batch's pack_ms)
-  dispatch       dispatched-and-in-flight: begin_batch return -> resolve
-                 start — the window the device (or the pipeline ahead of
-                 this batch) owns the request. A REMAINDER (witness_verify
-                 less the four batch-record numbers), not a measured
-                 wait: the seconds a host thread stood blocked on the
-                 chip are `device.host_seconds{op=sync}` (utils/trace.py
-                 device_host)
-  resolve        readback + commit + linkage join (the batch's resolve_ms)
-  witness_decode witness -> WitnessStateDB materialization
+  queue_wait     the handler's wait before its witness batch's first
+                 lane stage began: admission -> executor pickup
+  prefetch       the handler's wait under the 4th-stage decode/pre-scan
+  pack           ... under begin_batch's lock-held scan
+  dispatch       the handler waiting with NO lane stage of its batch
+                 running: between two stages (the plan's hand-over, the
+                 resolve worker still busy with another lane's batch)
+                 and after the last (the batch's completion tail). The
+                 seconds a host thread stood blocked on the chip are
+                 `device.host_seconds{op=sync}` (utils/trace.py
+                 device_host), not this
+  resolve        ... under readback + commit + linkage join
+  witness_decode witness -> WitnessStateDB materialization, at its
+                 measured width. With a scheduler it runs BETWEEN the
+                 handler's two waits (to the launch; to the verdict),
+                 under the device's work: what the lanes did meanwhile
+                 is hidden time and lies in no phase
   sig_wait       the sig-lane join block before EVM execution
   evm            block execution minus the sig join
   root_plan      fused post-root hash-plan build on the handler thread
@@ -28,14 +33,19 @@ wall clock into an exclusive phase breakdown:
   post_root      the rest of the post-root phase: merged dispatch +
                  readback + apply, or the host walk
 
-The tiling is HIERARCHICAL and clipped: batch-record stage timings are
-clipped into the request-side phase that contains them (a stage number
-can never claim more than the request actually waited), and each level's
-remainder goes to the enclosing catch-all (`dispatch` inside
-witness_verify, `evm` inside execute, `post_root` inside the post-root
-phase) — so the sub-tilings sum EXACTLY to their parent phases and the
-only unattributed residual is real: span overhead and gaps between
-phases. That residual is the honesty check: `critpath.unattributed_pct`
+The tiling is HIERARCHICAL and exclusive. The handler's waits for the
+witness lane (`stateless.witness_verify`: one interval without a
+scheduler, two with one) are cut by the lane stages' MEASURED intervals
+(`stages` of the batch record, on the span's clock, PR 26): a stage
+claims exactly the part of a wait it overlaps, whatever ran while the
+handler was busy elsewhere claims nothing (`tile_wait`). A record
+without measured stages (an old one, a hand-made one) keeps the older
+rule: the record's `*_ms` numbers clipped into the phase in pipeline
+order. Each level's remainder goes to the enclosing catch-all
+(`dispatch` inside witness_verify, `evm` inside execute, `post_root`
+inside the post-root phase) — so the sub-tilings sum EXACTLY to their
+parent phases and the only unattributed residual is real: span overhead
+and gaps between phases. That residual is the honesty check: `critpath.unattributed_pct`
 (and the coverage twin) gauge the cumulative attributed share, and the
 test suite asserts >= 95% on the serving path at pipeline depths 1 AND 2
 across all three engine lanes. Everything lands in the
@@ -249,17 +259,71 @@ def _num(v) -> Optional[float]:
     return float(v) if isinstance(v, (int, float)) and v == v else None
 
 
+#: the witness lane's stages in pipeline order (where two overlap, the
+#: earlier one claims the overlap)
+_LANE_STAGES: Tuple[str, ...] = ("prefetch", "pack", "resolve")
+
+
+def _stage_spans(stages) -> Tuple[list, Optional[int]]:
+    """([(label, start_ns, end_ns)] of the lane stages in pipeline order,
+    the earliest start of ANY stage entry) of a batch record's `stages`;
+    a depth-1 batch has one fused `dispatch` entry, which labels nothing
+    but still ends the queue wait."""
+    spans, first = [], None
+    if isinstance(stages, dict):
+        for label, se in stages.items():
+            if (
+                isinstance(se, (list, tuple))
+                and len(se) == 2
+                and all(isinstance(t, int) for t in se)
+                and se[1] >= se[0]
+            ):
+                first = se[0] if first is None else min(first, se[0])
+                if label in _LANE_STAGES:
+                    spans.append((label, se[0], se[1]))
+    spans.sort(key=lambda sp: _LANE_STAGES.index(sp[0]))
+    return spans, first
+
+
+def tile_wait(t0: int, t1: int, stages) -> list:
+    """[(phase, start_ns, end_ns)]: one wait `[t0, t1]` of the handler for
+    the witness lane, cut at the lane stages' measured intervals.
+    Contiguous and exclusive, so the pieces sum to the wait exactly: a
+    stage gets the part of the wait it overlaps, the stretch before the
+    batch's first stage is `queue_wait`, and where no stage of the batch
+    ran the pipeline or the device owned the request (`dispatch`)."""
+    return _tile(t0, t1, *_stage_spans(stages))
+
+
+def _tile(t0: int, t1: int, spans: list, first: Optional[int]) -> list:
+    edges = [t for _l, a, b in spans for t in (a, b)]
+    if first is not None:
+        edges.append(first)
+    cuts = sorted({t0, t1} | {t for t in edges if t0 < t < t1})
+    out: list = []
+    for a, b in zip(cuts, cuts[1:]):
+        label = next((l for l, sa, sb in spans if sa <= a and b <= sb), None)
+        if label is None:
+            label = "queue_wait" if first is not None and b <= first else "dispatch"
+        if out and out[-1][0] == label:
+            out[-1] = (label, out[-1][1], b)
+        else:
+            out.append((label, a, b))
+    return out
+
+
 def attribute(record: dict) -> Tuple[Dict[str, float], float, float]:
     """(breakdown_ms, unattributed_ms, wall_ms) for one top-level
     `verify_block` span record. Pure function of the record — the
     unit-testable core of the rollup.
 
-    Tiling rules (see the module docstring for the phase meanings):
-    every batch-record stage timing is clipped into the remaining width
-    of the request-side phase that contains it, in pipeline order, and
-    the remainder goes to that level's catch-all — so the sub-tilings
-    sum exactly to their parent phases and attributed time can never
-    exceed the phases the request actually measured."""
+    Tiling rules (see the module docstring for the phase meanings): the
+    handler's waits for the witness lane are cut by the stages' measured
+    intervals (`tile_wait`); without them the batch record's `*_ms` are
+    clipped into the remaining width of the phase in pipeline order.
+    Either way the remainder goes to that level's catch-all — so the
+    sub-tilings sum exactly to their parent phases and attributed time
+    can never exceed the phases the request actually measured."""
     wall = _num(record.get("duration_ms")) or 0.0
     phases = record.get("phases") or {}
 
@@ -279,26 +343,41 @@ def attribute(record: dict) -> Tuple[Dict[str, float], float, float]:
     put("sig_rows", ph("stateless.sig_rows"))
     put("witness_decode", ph("stateless.witness_decode"))
 
-    # witness_verify sub-tiling: queue_wait/prefetch/pack/resolve come
-    # from the witness batch record (bare keys — the sig/root lanes
-    # prefix theirs), each clipped to what is left of the phase; the
-    # remainder is `dispatch`, the dispatched-and-in-flight window (a
-    # remainder by definition: the measured device wait is
-    # device.host_seconds{op=sync})
-    wv = ph("stateless.witness_verify")
-    rem = wv
-    for label, key in (
-        ("queue_wait", "queue_wait_ms"),
-        ("prefetch", "prefetch_ms"),
-        ("pack", "pack_ms"),
-        ("resolve", "resolve_ms"),
-    ):
-        v = _num(record.get(key))
-        if v is not None and v > 0.0:
-            v = min(v, rem)
-            put(label, v)
-            rem -= v
-    put("dispatch", rem)
+    # witness_verify sub-tiling, from the witness batch record (bare
+    # keys — the sig/root lanes prefix theirs). The handler's measured
+    # waits cut by the stages' measured intervals: what a stage did while
+    # the handler decoded (between its two waits) is in no phase
+    waits = [
+        (iv[1], iv[2])
+        for iv in record.get("intervals") or ()
+        if len(iv) == 3
+        and iv[0] == "stateless.witness_verify"
+        and isinstance(iv[1], int)
+        and isinstance(iv[2], int)
+    ]
+    spans, first = _stage_spans(record.get("stages"))
+    if waits and first is not None:
+        for t0, t1 in waits:
+            for label, a, b in _tile(t0, t1, spans, first):
+                put(label, (b - a) / 1e6)
+    else:
+        # no measured stages (a record from before PR 26, a hand-made
+        # one, the path without a scheduler): the batch record's numbers,
+        # each clipped to what is left of the phase, the remainder is
+        # `dispatch`
+        rem = ph("stateless.witness_verify")
+        for label, key in (
+            ("queue_wait", "queue_wait_ms"),
+            ("prefetch", "prefetch_ms"),
+            ("pack", "pack_ms"),
+            ("resolve", "resolve_ms"),
+        ):
+            v = _num(record.get(key))
+            if v is not None and v > 0.0:
+                v = min(v, rem)
+                put(label, v)
+                rem -= v
+        put("dispatch", rem)
 
     # execute sub-tiling: the sig-lane join block, then EVM proper
     ex = ph("stateless.execute")

@@ -290,10 +290,13 @@ def measured_slices(record: dict) -> List[Tuple[str, int, int]]:
     """(critpath phase, offset_us from the span's start, dur_us) of one
     `verify_block` record, from its measured intervals: the handler
     thread's phases as the span recorded them (`sig_wait` lies inside
-    `evm`, `root_plan` inside `post_root`: nested slices), and the
-    witness wait cut at the lane stages' own clock readings, which are on
-    the span's clock: `queue_wait` up to the first stage, `prefetch`,
-    `pack`, `dispatch` from pack's end to resolve's start, `resolve`."""
+    `evm`, `root_plan` inside `post_root`: nested slices), and each of
+    the handler's waits for the witness lane (to the launch, to the
+    verdict, `witness_decode` between them) cut at the lane stages' own
+    clock readings, which are on the span's clock (`critpath.tile_wait`):
+    `queue_wait` up to the first stage, `prefetch`, `pack`, `resolve`
+    where the handler waited under them, `dispatch` where no stage of
+    the batch ran."""
     t_span = record.get("start_ns")
     if not isinstance(t_span, int):
         return []
@@ -308,25 +311,9 @@ def measured_slices(record: dict) -> List[Tuple[str, int, int]]:
         if label is not None:
             put(label, t0, t1)
         elif name == "stateless.witness_verify":
-            stages = record.get("stages") or {}
-            cuts = []
-            for stage in ("prefetch", "pack", "resolve"):
-                se = stages.get(stage)
-                if se:
-                    # clipped: a stage can claim no more than the wait
-                    a, b = max(se[0], t0), min(se[1], t1)
-                    if b > a:
-                        cuts.append((stage, a, b))
-            if not cuts:
-                put("dispatch", t0, t1)  # no stage record: one wait
-                continue
-            put("queue_wait", t0, cuts[0][1])
-            for i, (stage, a, b) in enumerate(cuts):
-                put(stage, a, b)
-                nxt = cuts[i + 1][1] if i + 1 < len(cuts) else t1
-                # between stages (and after the last) the pipeline or
-                # the device owns the request
-                put("dispatch", b, nxt)
+            # critpath's own cut of a wait: the two cannot disagree
+            for piece in critpath.tile_wait(t0, t1, record.get("stages")):
+                put(*piece)
     return out
 
 

@@ -6,8 +6,9 @@ holds the process-global *active scheduler* slot:
 
 * the Engine API server installs its scheduler here on construction and
   uninstalls it on shutdown;
-* `stateless.verify_witness_nodes` routes witness verification through
-  the active scheduler when one is installed (so concurrent
+* `stateless.admit_witness` / `join_witness` (and their synchronous
+  face `verify_witness_nodes`) route witness verification through the
+  active scheduler when one is installed (so concurrent
   `engine_executeStatelessPayloadV1` handler threads coalesce their
   linked-multiproof checks into one engine/device dispatch) and falls
   back to the direct shared-engine path otherwise — offline callers,

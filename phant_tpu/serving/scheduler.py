@@ -88,6 +88,11 @@ shape instead:
   outlives a dead scheduler never leaks in-flight leases. Handle
   resolution order is a per-scheduler property only — the engine accepts
   any interleaving, so several schedulers can share one engine.
+  `witness_async` hands the submitter a `PendingVerdict`: it can wait
+  for its batch's LAUNCH (`begin_batch` returned; every path that ends
+  a job without one releases the waiter too), do host work that needs
+  nothing from the verdict while the device computes it, and join later
+  (stateless.execute_stateless decodes the witness in between).
 * **Mesh dispatch** (`mesh_devices` >= 1 via `--sched-mesh N` /
   PHANT_SCHED_MESH) — admission, tenant-fair head pick, and batch
   assembly stay GLOBAL, but execution fans out to a `MeshExecutorPool`
@@ -165,6 +170,7 @@ from phant_tpu.serving.qos import (
     parse_weights,
 )
 from phant_tpu.utils.trace import (
+    clock_ns,
     current_trace_id,
     fold_stages,
     lane_stage,
@@ -533,6 +539,53 @@ class _Job:
     # resolve ordering means a waiter that saw result() also sees meta)
     trace_id: Optional[str] = None
     meta: Optional[dict] = None
+    # the launch signal (PendingVerdict.wait_launched): set by the
+    # executor once the job's batch is on its way to the engine
+    # (_pipeline_handoff, begin_batch returned) and, through the future's
+    # done callback, by EVERY path that completes or fails the job —
+    # inline, depth-1 and mesh completion, shed at execution, expiry,
+    # eviction, _die — so a waiter waits for "launched or done", never
+    # for a launch that will not come. An Event: written by a scheduler
+    # thread, read by the handler (the lockset rule that caught
+    # _exec_stage).
+    launched: threading.Event = field(default_factory=threading.Event)
+
+    def __post_init__(self) -> None:
+        # the callback holds the event alone: no cycle through the job
+        self.future.add_done_callback(lambda _f, ev=self.launched: ev.set())
+
+
+class PendingVerdict:
+    """One admitted witness verification as its submitter holds it
+    (`VerificationScheduler.witness_async`): the verdict is JOINED where
+    it is first needed, not awaited where it is requested. The request
+    path (stateless.execute_stateless) waits for the launch, decodes the
+    witness while the lane threads stand in the device's readbacks, and
+    joins before anything acts on the witness."""
+
+    __slots__ = ("_job", "_done_ns")
+
+    def __init__(self, job: _Job):
+        self._job = job
+        self._done_ns: List[int] = []
+        job.future.add_done_callback(
+            lambda _f, at=self._done_ns: at.append(clock_ns())
+        )
+
+    def wait_launched(self) -> None:
+        """Block until the job's batch has been handed to the engine, or
+        the job is done (whichever path ended it)."""
+        self._job.launched.wait()
+
+    @property
+    def done_ns(self) -> Optional[int]:
+        """When the verdict (or the failure) arrived, on the span clock;
+        None while it has not."""
+        return self._done_ns[0] if self._done_ns else None
+
+    def join(self) -> Tuple[bool, Optional[dict]]:
+        """(verdict, batch record); scheduler rejections raise."""
+        return bool(self._job.future.result()), self._job.meta
 
 
 class VerificationScheduler:
@@ -784,6 +837,24 @@ class VerificationScheduler:
         self._admit(job, wait_for_space)
         return job.future
 
+    def witness_async(
+        self,
+        root: bytes,
+        nodes: Sequence[bytes],
+        deadline_s: Optional[float] = None,
+        tenant: Optional[str] = None,
+        priority: Optional[int] = None,
+    ) -> PendingVerdict:
+        """Admit one witness verification NOW and return its
+        `PendingVerdict` — the split face the request path uses
+        (stateless.execute_stateless; the witness twin of `sig_async`):
+        wait for the launch, decode under the device's work, join
+        `(verdict, batch record)` before execution. A shed at admission
+        raises here, as from `verify_traced`."""
+        job = self._witness_job(root, nodes, deadline_s, tenant, priority)
+        self._admit(job, False)
+        return PendingVerdict(job)
+
     def verify_traced(
         self,
         root: bytes,
@@ -796,11 +867,11 @@ class VerificationScheduler:
         (verdict, batch record). The record — `batch_id`, `batch_size`,
         `bucket_bytes`, `backend`, cache hit/miss deltas, `queue_wait_ms` —
         is what joins the caller's span to the shared engine dispatch that
-        served it (stateless.verify_witness_nodes folds it into the open
+        served it (stateless.join_witness folds it into the open
         `verify_block` span). Scheduler rejections raise as usual."""
-        job = self._witness_job(root, nodes, deadline_s, tenant, priority)
-        self._admit(job, False)
-        return bool(job.future.result()), job.meta
+        return self.witness_async(
+            root, nodes, deadline_s, tenant, priority
+        ).join()
 
     def submit_serial(
         self,
@@ -1789,6 +1860,12 @@ class VerificationScheduler:
         }
         if prefetch_ms is not None:
             pipe_item["prefetch_ms"] = prefetch_ms
+        # launched: the batch's device work is enqueued (no host sync),
+        # and from here to the verdict the lane threads stand in C
+        # readbacks with the interpreter lock released — the window a
+        # waiting handler decodes under (PendingVerdict.wait_launched)
+        for j in jobs:
+            j.launched.set()
         with self._lock:
             dead = self._dead
             if dead is None:

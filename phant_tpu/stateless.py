@@ -904,7 +904,7 @@ def dispatch_sender_recovery(chain_id: int, txs, rows=None):
     The request path calls this at DECODE time and joins just before EVM
     execution (`apply_body`'s `senders=` prefetch parameter is the join
     point), so the merged device ecrecover computes while this thread
-    verifies the witness and builds the node db. The signature rows —
+    admits the witness and builds the node db. The signature rows —
     host keccak over RLP, `TxSigner.signature_rows` — are built on THIS
     handler thread (embarrassingly parallel across requests); invalid
     signatures ride the placeholder lane and surface as None senders,
@@ -917,8 +917,9 @@ def dispatch_sender_recovery(chain_id: int, txs, rows=None):
     has a correct local fallback, so the lane may only ever help.
 
     The resolve-side block time is exported as `sched.sig_wait` — the
-    part of the recovery that did NOT hide under witness verification
-    (the overlap audit, same reading as `sched.prefetch_wait`).
+    part of the recovery that did NOT hide under the witness's decode
+    and the wait for its verdict (the overlap audit, same reading as
+    `sched.prefetch_wait`).
 
     `rows=` optionally supplies PRE-BUILT signature rows for the same
     txs: the replay engine's prefetch worker builds a whole segment's
@@ -1074,30 +1075,16 @@ def shared_witness_engine():
         return _witness_engine
 
 
-def verify_witness_nodes(state_root: bytes, nodes: List[bytes]) -> bool:
-    """Linked witness verification — the nodes must form a connected subtree
-    rooted at `state_root` — through the shared memoized engine. Semantics
-    are identical to the host BFS (mpt/proof.py verify_witness_linked) and
-    the device kernel (ops/witness_jax.witness_verify_fused); all three are
-    differential-tested against each other.
-
-    Serving mode: when a continuous-batching scheduler is installed
-    (phant_tpu/serving/ — the Engine API server installs one), the check
-    routes through it so concurrent handler threads coalesce into ONE
-    engine/device dispatch instead of paying a batch-of-1 each — and with
-    `pipeline_depth >= 2` (the default) that dispatch is PIPELINED: the
-    executor packs batch N+1 while batch N computes on the device and
-    batch N-1 resolves (ops/witness_engine.py begin_batch/resolve_batch).
-    The batch record the scheduler attaches (batch_id, batch_size,
-    bucket_bytes, backend, cache hit/miss, queue_wait_ms, and for
-    pipelined batches the stage + pack_ms/resolve_ms split) folds into
-    the caller's open span, so the request's `verify_block` trace names
-    the shared dispatch that served it AND the pipeline stage timings it
-    rode (phant_tpu/obs/). Scheduler rejections (queue full, deadline,
-    executor down) propagate as SchedulerError for the server to map to
-    JSON-RPC errors. Without a scheduler — offline tools, tests, the
-    spec runner by default — the direct shared-engine path is
-    unchanged."""
+def admit_witness(state_root: bytes, nodes: List[bytes]):
+    """The admission half of `verify_witness_nodes`: the verdict itself (a
+    bool) where it is decided on this thread — the empty pre-state's
+    contract, and the direct shared-engine path when no scheduler is
+    installed (offline tools, tests, the spec runner by default) — or,
+    in serving mode, the scheduler's `PendingVerdict`
+    (serving/scheduler.py witness_async) for `join_witness`. The caller
+    that has host work which needs nothing from the verdict does it in
+    between (execute_stateless); scheduler rejections at admission raise
+    here."""
     if state_root == EMPTY_TRIE_ROOT:
         # the empty pre-state needs (and admits) no witness nodes — same
         # contract as the host BFS (mpt/proof.py verify_witness_linked)
@@ -1108,15 +1095,79 @@ def verify_witness_nodes(state_root: bytes, nodes: List[bytes]) -> bool:
 
     sched = active_scheduler()
     if sched is not None and sched.accepts_witness():
-        ok, meta = sched.verify_traced(state_root, nodes)
-        if meta is not None:
-            from phant_tpu.utils.trace import current_span
-
-            sp = current_span()
-            if sp is not None:
-                sp.attrs.update(meta)
-        return ok
+        return sched.witness_async(state_root, nodes)
     return shared_witness_engine().verify(state_root, nodes)
+
+
+def join_witness(pending) -> bool:
+    """The verdict of what `admit_witness` returned. For a scheduler job
+    this blocks until its batch resolved and folds the batch record the
+    scheduler attached (batch_id, batch_size, bucket_bytes, backend, cache
+    hit/miss, queue_wait_ms, the stage timings and their measured
+    `stages`) into the caller's open span, so the request's
+    `verify_block` trace names the shared dispatch that served it
+    (phant_tpu/obs/). Scheduler rejections (deadline, eviction, executor
+    down) propagate as SchedulerError for the server to map to JSON-RPC
+    errors."""
+    if isinstance(pending, bool):
+        return pending
+    ok, meta = pending.join()
+    if meta is not None:
+        from phant_tpu.utils.trace import current_span
+
+        sp = current_span()
+        if sp is not None:
+            sp.attrs.update(meta)
+    return ok
+
+
+def verify_witness_nodes(state_root: bytes, nodes: List[bytes]) -> bool:
+    """Linked witness verification — the nodes must form a connected subtree
+    rooted at `state_root` — through the shared memoized engine. Semantics
+    are identical to the host BFS (mpt/proof.py verify_witness_linked) and
+    the device kernel (ops/witness_jax.witness_verify_fused); all three are
+    differential-tested against each other.
+
+    This is the synchronous face: admission and join back to back
+    (`admit_witness`, `join_witness`). Serving mode: when a
+    continuous-batching scheduler is installed (phant_tpu/serving/ — the
+    Engine API server installs one), the check routes through it so
+    concurrent handler threads coalesce into ONE engine/device dispatch
+    instead of paying a batch-of-1 each — and with `pipeline_depth >= 2`
+    (the default) that dispatch is PIPELINED: the executor packs batch
+    N+1 while batch N computes on the device and batch N-1 resolves
+    (ops/witness_engine.py begin_batch/resolve_batch). The request path
+    itself (`execute_stateless`) does not wait here: it admits, decodes
+    the witness while the verdict is computed, and joins where execution
+    first needs it. Without a scheduler the direct shared-engine path is
+    unchanged."""
+    return join_witness(admit_witness(state_root, nodes))
+
+
+_WITNESS_REJECTED = "witness rejected: not a subtree of preStateRoot"
+
+
+def _join_verdict(pending, t_decode: int) -> None:
+    """Join the verdict a handler decoded under (`t_decode`: when its
+    decode began, on the span clock): the second of its two waits, the
+    mechanism's counters — was the verdict there when the handler came to
+    join, and how much of the decode ran before it arrived — and the
+    rejection of a False verdict. SchedulerError propagates."""
+    from phant_tpu.utils.trace import clock_ns, metrics
+
+    t_decoded = clock_ns()
+    arrived = pending.done_ns
+    with metrics.phase("stateless.witness_verify"):
+        witness_ok = join_witness(pending)
+    metrics.count(
+        "stateless.verdict_joins", state="waited" if arrived is None else "ready"
+    )
+    hidden_ns = (t_decoded if arrived is None else min(arrived, t_decoded)) - t_decode
+    metrics.observe_hist(
+        "stateless.decode_hidden_seconds", max(hidden_ns, 0) / 1e9
+    )
+    if not witness_ok:
+        raise StatelessError(_WITNESS_REJECTED)
 
 
 def execute_stateless(
@@ -1146,12 +1197,25 @@ def execute_stateless(
     scheme-blind — the engine checks subtree-connectedness over the
     scheme's own node encodings.
 
+    The order of a run. Without a scheduler: verify the witness, decode
+    it, execute, check the post root. With one installed (serving), the
+    verdict is JOINED where it is first needed, not awaited where it is
+    requested: admit sender recovery, admit the witness, wait for the
+    witness batch's launch, decode the witness while the device computes
+    the verdict, join the verdict, then execute. `chain.run_block` never
+    starts before a True verdict, and an error of the early decode never
+    speaks before the verdict: a False verdict (or a SchedulerError) is
+    what the caller sees, whatever the decode raised.
+
     Observability: the whole run is one `span("verify_block", block=n)` —
     its JSON trace line carries the witness_verify / witness_decode /
-    execute / post_root phase split; failures count into
-    `stateless.errors{kind=...}`."""
+    execute / post_root phase split (witness_verify twice with a
+    scheduler: the wait for the launch and the wait at the join);
+    `stateless.verdict_joins{state=ready|waited}` and
+    `stateless.decode_hidden_seconds` say how much of the decode the wait
+    hid; failures count into `stateless.errors{kind=...}`."""
     from phant_tpu.blockchain.chain import Blockchain, BlockError
-    from phant_tpu.utils.trace import metrics, span
+    from phant_tpu.utils.trace import clock_ns, metrics, span
 
     with span(
         "verify_block",
@@ -1160,46 +1224,74 @@ def execute_stateless(
         codes=len(codes),
     ) as sp:
         try:
-            # sender recovery dispatches FIRST (the sig lane,
-            # ops/sig_engine.py): the merged device ecrecover computes
-            # while THIS thread verifies the witness and decodes the
-            # node db, and joins just before EVM execution below —
-            # apply_body's `senders=` prefetch parameter is the join
-            # point, so ecrecover latency hides under witness
-            # verification + warm-set prefill. None = no lane in play:
-            # apply_body runs today's in-request fused batch.
+            # The order of admission: sender recovery FIRST (the sig
+            # lane, ops/sig_engine.py), then the witness. The sig lane
+            # needs the shorter host preparation, so the chip starts on
+            # ecrecover while the witness is still being packed, and the
+            # device's one serial chain (ecrecover, then the table's
+            # update and verdict) ends earliest this way. Senders join
+            # just before EVM execution below — apply_body's `senders=`
+            # prefetch parameter is the join point. None = no lane in
+            # play: apply_body runs the in-request fused batch.
             resolve_senders = dispatch_sender_recovery(
                 chain_id, block.transactions
             )
+            # The verdict is JOINED where it is first needed, not awaited
+            # where it is requested. With a scheduler the handler waits
+            # only for its witness batch to be LAUNCHED (until then the
+            # executor, the prefetch worker and this thread would share
+            # one interpreter lock, and each hand-over of it can move the
+            # whole device chain later; from launch to verdict the lane
+            # threads stand in C readbacks with the lock released),
+            # decodes the witness meanwhile — host work that needs
+            # nothing from the verdict — and joins before run_block.
+            # Without one (offline tools, the spec runner, tests) the
+            # verdict is decided inline, before the decode, as ever.
             with metrics.phase("stateless.witness_verify"):
-                witness_ok = verify_witness_nodes(pre_state_root, nodes)
-            if not witness_ok:
-                raise StatelessError(
-                    "witness rejected: not a subtree of preStateRoot"
-                )
-            with metrics.phase("stateless.witness_decode"):
-                # ONE decode per request: the digest map is built here by
-                # a single batched C keccak and handed through — the
-                # counter-pinned contract (a second decode would double
-                # stateless.witness_nodes_decoded per payload)
-                state = WitnessStateDB(
-                    pre_state_root,
-                    nodes,
-                    codes,
-                    node_db=witness_node_db(nodes),
-                    scheme=scheme,
-                )
-                if fork is None and fork_factory is not None:
-                    fork = fork_factory(state)
-                # verify_state_root=False: the post-root check moves to
-                # the dedicated phase below so it can ride the BATCHED
-                # root lane (run_block's inline check would pay the
-                # serial host walk first and leave nothing dirty for the
-                # plan path — pre-PR-11 the root was in fact computed
-                # TWICE per request, once here and once below)
-                chain = Blockchain(
-                    chain_id, state, parent_header, fork=fork, verify_state_root=False
-                )
+                pending = admit_witness(pre_state_root, nodes)
+                if pending is False:
+                    raise StatelessError(_WITNESS_REJECTED)
+                if pending is not True:
+                    pending.wait_launched()
+            t_decode = clock_ns()
+            try:
+                with metrics.phase("stateless.witness_decode"):
+                    # ONE decode per request: the digest map is built
+                    # here by a single batched C keccak and handed
+                    # through — the counter-pinned contract (a second
+                    # decode would double stateless.witness_nodes_decoded
+                    # per payload)
+                    state = WitnessStateDB(
+                        pre_state_root,
+                        nodes,
+                        codes,
+                        node_db=witness_node_db(nodes),
+                        scheme=scheme,
+                    )
+                    if fork is None and fork_factory is not None:
+                        fork = fork_factory(state)
+                    # verify_state_root=False: the post-root check moves
+                    # to the dedicated phase below so it can ride the
+                    # BATCHED root lane (run_block's inline check would
+                    # pay the serial host walk first and leave nothing
+                    # dirty for the plan path — pre-PR-11 the root was in
+                    # fact computed TWICE per request, once here and once
+                    # below)
+                    chain = Blockchain(
+                        chain_id, state, parent_header, fork=fork, verify_state_root=False
+                    )
+            except Exception:
+                # a failure of the early decode never speaks before the
+                # verdict: join first (the future is always consumed). A
+                # False verdict or a SchedulerError is what the caller
+                # sees; only under a True verdict is the decode's own
+                # error raised
+                if pending is not True:
+                    _join_verdict(pending, t_decode)
+                raise
+            if pending is not True:
+                # the verdict gates everything that acts on the witness
+                _join_verdict(pending, t_decode)
             with metrics.phase("stateless.execute"):
                 # join the sig lane: senders recovered while the phases
                 # above ran (None entries = invalid signatures, raised

@@ -191,8 +191,10 @@ METRIC_HELP: Dict[str, str] = {
     # stateless execution
     "stateless.blocks_verified": "Stateless payloads fully executed and root-checked",
     "stateless.errors": "Stateless executions aborted, by exception kind",
-    "stateless.witness_verify": "Linked-multiproof witness verification phase",
-    "stateless.witness_decode": "Witness -> WitnessStateDB materialization phase",
+    "stateless.witness_verify": "The handler's waits for the linked-multiproof witness verdict: one inline verification without a scheduler; with one, the wait for its batch's launch and the wait at the join, the decode between them",
+    "stateless.witness_decode": "Witness -> WitnessStateDB materialization phase (with a scheduler it runs while the verdict is computed, before the join)",
+    "stateless.verdict_joins": "Witness verdicts joined before execution, by state: ready = the verdict was there when the handler came to join after decoding, waited = the handler still had to wait for it",
+    "stateless.decode_hidden_seconds": "Seconds of a request's witness_decode that ran before its verdict arrived: host work hidden under the witness lane's device time",
     "stateless.witness_nodes_decoded": "Witness nodes decoded (digest map built) on the request path — exactly once per payload; a doubled count per payload is a reintroduced second decode",
     "stateless.execute": "Block execution phase over the witness-backed state",
     "stateless.post_root": "Post-state-root recompute phase over the partial trie (host walk or the batched root lane)",

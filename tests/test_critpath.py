@@ -198,6 +198,107 @@ def test_front_door_histogram_rides_the_shared_bucket_table():
     assert server_mod.REQUEST_SECONDS_BUCKETS is REQUEST_SECONDS_BUCKETS
 
 
+MS = 1_000_000  # ns
+
+
+def _waited_record(stages: dict, waits, decode) -> dict:
+    """A verify_block record as the served path reports it since PR 29:
+    measured waits for the witness lane, the decode between them, the
+    batch's measured stages (all on one clock, ns)."""
+    intervals = [["stateless.witness_verify", a, b] for a, b in waits]
+    intervals.insert(1, ["stateless.witness_decode", *decode])
+    total = lambda name: sum(b - a for n, a, b in intervals if n == name) / MS  # noqa: E731
+    return {
+        "span": "verify_block",
+        "duration_ms": 100.0,
+        "start_ns": 0,
+        "end_ns": 100 * MS,
+        # the record's *_ms say what the lanes took, not what the handler
+        # waited: measured stages outrank them
+        "queue_wait_ms": 5.0, "prefetch_ms": 9.0, "pack_ms": 10.0, "resolve_ms": 50.0,
+        "stages": stages,
+        "intervals": intervals,
+        "phases": {
+            "stateless.witness_verify": {"count": len(waits), "total_ms": total("stateless.witness_verify")},
+            "stateless.witness_decode": {"count": 1, "total_ms": total("stateless.witness_decode")},
+        },
+    }
+
+
+def test_attribute_cuts_the_handlers_waits_at_the_measured_stages():
+    """Two waits around the decode; resolve runs 35..90, so 35..58 of it
+    (and the 30..35 the resolve worker spent on another lane's batch) lie
+    under witness_decode: in no phase. What is left tiles exactly."""
+    record = _waited_record(
+        {"prefetch": [5 * MS, 14 * MS], "pack": [20 * MS, 30 * MS], "resolve": [35 * MS, 90 * MS]},
+        waits=[(0, 30 * MS), (58 * MS, 92 * MS)],
+        decode=(30 * MS, 58 * MS),
+    )
+    breakdown, unattributed, wall = critpath.attribute(record)
+    assert breakdown == pytest.approx(
+        {
+            "queue_wait": 5.0,  # admission to the batch's first stage
+            "prefetch": 9.0,
+            "pack": 10.0,
+            "dispatch": 6.0 + 2.0,  # 14..20 between stages, 90..92 the tail
+            "resolve": 32.0,  # 58..90 of its 55 ms: the part waited for
+            "witness_decode": 28.0,  # its measured width, untouched
+        }
+    )
+    witness = sum(v for k, v in breakdown.items() if k != "witness_decode")
+    assert witness == pytest.approx(30.0 + 34.0)  # the two waits, exactly
+    assert unattributed == pytest.approx(wall - 92.0)
+
+
+def test_attribute_with_overlapping_stages_tiles_exactly_and_hides_the_decode():
+    """Stage intervals that overlap each other (a coalesced neighbour's
+    pack still running under this batch's resolve) and the decode: every
+    nanosecond of a wait goes to ONE phase, the earlier stage winning the
+    overlap; the seconds under witness_decode go to none."""
+    record = _waited_record(
+        {"prefetch": [2 * MS, 12 * MS], "pack": [10 * MS, 26 * MS], "resolve": [24 * MS, 70 * MS]},
+        waits=[(0, 25 * MS), (50 * MS, 75 * MS)],
+        decode=(25 * MS, 50 * MS),
+    )
+    breakdown, _unattributed, _wall = critpath.attribute(record)
+    assert breakdown == pytest.approx(
+        {
+            "queue_wait": 2.0,
+            "prefetch": 10.0,  # 2..12, the overlap 10..12 with pack is prefetch's
+            "pack": 13.0,  # 12..25: cut by the first wait's end
+            "resolve": 20.0,  # 50..70: 24..50 ran under pack's tail and the decode
+            "dispatch": 5.0,  # 70..75
+            "witness_decode": 25.0,
+        }
+    )
+    waited = (25 + 25) * 1.0
+    assert sum(v for k, v in breakdown.items() if k != "witness_decode") == pytest.approx(waited)
+    # hidden: resolve ran 46 ms, 20 of them waited for; pack's 25..26 too
+    assert breakdown["resolve"] + breakdown["pack"] < 46.0 + 16.0
+    # tile_wait itself: contiguous, exclusive, inside the wait
+    pieces = critpath.tile_wait(50 * MS, 75 * MS, record["stages"])
+    assert pieces == [("resolve", 50 * MS, 70 * MS), ("dispatch", 70 * MS, 75 * MS)]
+
+
+@pytest.mark.parametrize(
+    "stages",
+    [None, {}, {"resolve": "soon"}, {"pack": [5, 1]}, {"dispatch": [3 * MS, 9 * MS]}],
+    ids=["none", "empty", "malformed", "backwards", "depth1"],
+)
+def test_attribute_without_lane_stages_never_claims_more_than_the_wait(stages):
+    record = _waited_record(stages, waits=[(0, 12 * MS)], decode=(12 * MS, 20 * MS))
+    record["prefetch_ms"] = record["pack_ms"] = record["resolve_ms"] = None
+    breakdown, _un, _wall = critpath.attribute(record)
+    witness = {k: v for k, v in breakdown.items() if k != "witness_decode"}
+    assert sum(witness.values()) == pytest.approx(12.0)
+    if stages == {"dispatch": [3 * MS, 9 * MS]}:
+        # a depth-1 batch: one fused stage ends the queue wait
+        assert witness == pytest.approx({"queue_wait": 3.0, "dispatch": 9.0})
+    else:
+        # no measured stage: the record's own queue_wait_ms, clipped
+        assert witness == pytest.approx({"queue_wait": 5.0, "dispatch": 7.0})
+
+
 # ---------------------------------------------------------------------------
 # coverage >= 95% on the REAL serving path: depths 1 and 2, three lanes
 # ---------------------------------------------------------------------------
