@@ -1,5 +1,5 @@
 # Commit gate (VERDICT r2 #4): `make check` must be green before a snapshot.
-.PHONY: check check-fast check-device native sanitize sanitize-native sanitize-py metrics-lint lint soak trend loadgen
+.PHONY: check check-fast check-device native sanitize sanitize-native sanitize-py metrics-lint lint soak loadgen
 
 check:
 	./scripts/check.sh
@@ -13,9 +13,9 @@ check:
 # the full package lints in ~2s. Intentional hazards carry inline
 # `# phantlint: disable=RULE — reason` annotations; anything grandfathered
 # lives in scripts/phantlint_baseline.json (currently EMPTY — keep it so).
-# scripts/ gets a second pass under the concurrency rules only — soak,
-# loadgen, and bench spawn threads too, but the JAX-hygiene rules don't
-# apply to host-side driver scripts.
+# scripts/ gets a second pass under the concurrency rules only — soak
+# and loadgen spawn threads too, but the JAX-hygiene rules don't apply
+# to host-side driver scripts.
 lint:
 	JAX_PLATFORMS=cpu python scripts/phantlint.py phant_tpu/ \
 	  --baseline scripts/phantlint_baseline.json
@@ -31,7 +31,7 @@ check-fast:
 # Only the device-kernel files (CI runs this in parallel with check-fast).
 # Keep in sync with scripts/check.sh DEVICE_GROUPS.
 check-device:
-	python -m pytest tests/test_secp256k1_jax.py tests/test_secp256k1_glv.py \
+	python -m pytest tests/test_secp256k1_jax.py \
 	  tests/test_keccak_jax.py tests/test_keccak_pallas.py \
 	  tests/test_witness_jax.py tests/test_witness_fused.py \
 	  tests/test_mpt_jax.py tests/test_parallel.py tests/test_graft_entry.py -q
@@ -78,20 +78,11 @@ sanitize-py:
 soak:
 	JAX_PLATFORMS=cpu python scripts/soak.py
 
-# Open-loop serving load harness (minutes; the bench `serving_load`
-# section runs the same profile): Poisson arrivals + bursts + slow-loris
+# Open-loop serving load harness (minutes): Poisson arrivals + bursts + slow-loris
 # against a real EngineAPIServer, saturation curve + p50/p99/p999 +
 # per-tenant fairness verdicts. See README "Serving: QoS".
 loadgen:
 	JAX_PLATFORMS=cpu python scripts/loadgen.py --duration 30
-
-# Regression sentinel over the committed BENCH_r*/MULTICHIP_r* artifacts:
-# aligns every section metric across rounds and flags a latest-round value
-# outside the noise-aware bar (or a round that produced no artifact at
-# all — unless acknowledged in BENCH_ACK, the root-caused-and-fixed list).
-# check.sh runs the SAME strict mode as a real gate; exits 1 on a flag.
-trend:
-	python scripts/benchtrend.py
 
 # Metric-name drift gate: thin shim over phantlint's METRICNAME rule
 # (one checker — see `make lint`): every emitted name must be a literal,

@@ -13,7 +13,7 @@ and `WitnessEngine.verify_batch` by default. Flags:
 
   * `.item()` calls (always — a scalar pull is a sync no matter the type);
   * `.block_until_ready()` calls (an explicit sync; legitimate ones are
-    probes/benchmarks and carry a disable annotation with the reason);
+    probes and carry a disable annotation with the reason);
   * `jax.device_get(...)`;
   * `int()` / `bool()` / `float()` / `np.asarray()` / `np.array()` over a
     device-tainted expression (see rules/_taint.py).
@@ -86,7 +86,7 @@ DEFAULT_ENTRIES: Tuple[str, ...] = (
     "phant_tpu.serving.scheduler.VerificationScheduler._resolve_run",
     # pluggable commitment schemes (PR 12): the binary backend's witness
     # pack loop (full-subtree node collection) and proof-path walk feed
-    # the serving differential/bench spans and the fixture-translation
+    # the serving differential spans and the fixture-translation
     # harness — pure host-bytes work by design; a reintroduced `.item()`
     # or device readback in these walks would put a sync inside the
     # per-block witness generation loop

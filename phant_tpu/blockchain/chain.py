@@ -312,7 +312,7 @@ class Blockchain:
         (src/blockchain/vm.zig:472); this is that TODO done.
 
         The header-trusting fallback is for CONFIG-LESS chains only —
-        trusted inputs by construction (fixtures, synthetic benches).
+        trusted inputs by construction (fixtures, synthetic chains).
         Every network entry point (the Engine API server, __main__)
         constructs its Blockchain with a config, so untrusted payload
         bytes never pick their own fork here."""
@@ -337,7 +337,7 @@ class Blockchain:
     def blob_schedule(self, header: BlockHeader) -> tuple:
         """(max_blob_gas, target_blob_gas, fee_update_fraction) for this
         block — EIP-7691 raised all three at Prague. Config-less chains
-        (fixtures, synthetic benches) derive the schedule from the fork
+        (fixtures, synthetic chains) derive the schedule from the fork
         instance they were constructed with."""
         from phant_tpu.blockchain.fork import PragueFork
 

@@ -19,7 +19,7 @@ This package makes that seam explicit. A `CommitmentScheme` bundles the
 scheme-specific pieces — key digitization, node codec, partial-trie
 construction from a witness, hash-plan lowering, witness generation —
 behind one object, and everything scheme-dependent in stateless.py /
-spec/runner.py / bench resolves through it. Two backends ship:
+spec/runner.py resolves through it. Two backends ship:
 
   * `mpt` (commitment/mpt_scheme.py): the paper's hexary keccak MPT,
     byte-identical to the pre-plugin code path (the default);

@@ -266,8 +266,8 @@ class BinaryScheme(CommitmentScheme):
     def collect_nodes(self, trie: Trie, nodes: Dict[bytes, None]) -> None:
         """The binary witness pack loop: EVERY node encoding ships (all
         children are digest-referenced, so all nodes are witness units).
-        Serving-hot (witness generation for the differential/bench
-        spans) — phantlint HOSTSYNC watches it."""
+        Serving-hot (witness generation for the differential spans) —
+        phantlint HOSTSYNC watches it."""
         if trie.root is None:
             return
         stack = [trie.root]

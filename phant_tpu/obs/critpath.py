@@ -75,9 +75,8 @@ Config is resolved ONCE from the environment and memoized (the env-read-
 per-request pattern is exactly what the PR 14 signer bugfix removed from
 the hot path); `refresh_from_env()` re-reads it (the Engine API server
 calls it at construction, after the CLI has written its flags into the
-env), and `configure()` overrides it directly (tests, the bench A/B).
-`PHANT_OBS_ATTRIBUTION=0` disables the whole layer — the off leg of the
-`obs_overhead` bench section.
+env), and `configure()` overrides it directly (tests).
+`PHANT_OBS_ATTRIBUTION=0` disables the whole layer.
 
 Thread-safety: the rollup runs on request threads; the cumulative
 coverage totals sit under one small lock, the metrics registry and the
@@ -199,8 +198,8 @@ def configure(
     near_sample_n: Optional[int] = None,
     near_rng: Optional[random.Random] = None,
 ) -> None:
-    """Override the memoized config directly (tests, the bench A/B legs);
-    None leaves a field as-is. `near_rng` replaces the near-tier sampler's
+    """Override the memoized config directly (tests); None leaves a
+    field as-is. `near_rng` replaces the near-tier sampler's
     generator (determinism for tests)."""
     global _cfg, _near_rng
     with _cfg_lock:
@@ -242,8 +241,7 @@ _tot_attr_s = 0.0
 
 def totals() -> Tuple[float, float]:
     """(wall_s, attributed_s) cumulative since process start / last reset —
-    the bench section and tests compute coverage over a window from the
-    delta of two calls."""
+    tests compute coverage over a window from the delta of two calls."""
     with _tot_lock:
         return _tot_wall_s, _tot_attr_s
 

@@ -1,8 +1,8 @@
 """Flight recorder: a bounded ring of recent observability records.
 
-A dead server must leave a postmortem without log scraping (BENCH_r05:
-the driver killed the process and the round produced NO artifact at all —
-the flight recorder is the serving-side answer to the same failure mode).
+A dead server must leave a postmortem without log scraping (a killed
+process once left NO artifact at all — the flight recorder is the
+serving-side answer to that failure mode).
 The ring holds the most recent span records, error records, and scheduler
 state transitions (admit / shed / batch-start / batch-done / crash /
 stall), each stamped with a wall clock, a monotonic sequence number, and —

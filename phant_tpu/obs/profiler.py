@@ -1,7 +1,7 @@
 """On-demand TPU profiler capture (PR 15): POST /debug/profile.
 
 `jax_profile` (utils/trace.py) existed since PR 1 — but only as a
-context manager reachable from bench.py and the `--trace-logdir` flag,
+context manager reachable from the `--trace-logdir` flag,
 i.e. you had to DECIDE to profile before starting the server. A real-v5e
 load run wants the opposite: the server is mid-traffic, a latency gauge
 looks wrong, grab an XLA trace of the NEXT T seconds without restarting.

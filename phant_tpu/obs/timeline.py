@@ -42,17 +42,16 @@ TAIL-SAMPLED at span close, in priority order:
 
 and everything else drops with `reason=sampled_out`. Sampling is never
 silent: `obs.timeline_kept{reason=}` + `obs.timeline_dropped{reason=
-sampled_out}` reconcile EXACTLY with offered load (the bench section
-asserts it), and a kept entry later evicted by ring overflow counts
+sampled_out}` reconcile EXACTLY with offered load (tests assert it),
+and a kept entry later evicted by ring overflow counts
 `reason=ring_full` separately.
 
 Config is resolved ONCE and memoized (`_Config`, exactly the critpath
 pattern — the env-read-per-event anti-pattern the r14 signer fix
 removed stays dead): `refresh_from_env()` re-reads (the Engine API
 server calls it at construction, after the CLI wrote its flags into
-the env), `configure()` overrides directly (tests, the bench A/B).
-`PHANT_TIMELINE=0` disables the whole layer — the off leg of the
-`timeline_overhead` bench section.
+the env), `configure()` overrides directly (tests).
+`PHANT_TIMELINE=0` disables the whole layer.
 
 Thread-safety: one module lock guards the rings, the tail-sample
 counters, and the p99 state; every tap is O(1) dict work under it.
@@ -130,7 +129,7 @@ def _config_from_env() -> _Config:
 _cfg: _Config = _config_from_env()
 _lock = threading.Lock()
 
-#: uniform 1-in-N sampler; tests/bench inject a seeded Random via
+#: uniform 1-in-N sampler; tests inject a seeded Random via
 #: configure(rng=...) so the sample decision sequence is pinned
 _rng = random.Random()
 
@@ -175,8 +174,8 @@ def configure(
     keep: Optional[int] = None,
     rng: Optional[random.Random] = None,
 ) -> None:
-    """Override the memoized config directly (tests, the bench A/B
-    legs); None leaves a field as-is. `rng` replaces the uniform
+    """Override the memoized config directly (tests); None leaves a
+    field as-is. `rng` replaces the uniform
     sampler's generator (determinism for tests)."""
     global _cfg, _rng
     with _lock:
@@ -221,7 +220,7 @@ def stats() -> Dict[str, Dict[str, int]]:
 
 def reset() -> None:
     """Clear the rings, the tail-sample counters, and the p99 state
-    (tests and the bench section start from a clean slate)."""
+    (tests and `scripts/soak.py` start from a clean slate)."""
     global _since_recache
     with _lock:
         _requests.clear()

@@ -11,8 +11,8 @@ where it is not, the directory is `<checkout>/build/jax_cache`, always
 (the path is part of the cache's key, so a directory that moves never
 hits). jax SEGFAULTS — not raises — reading or writing a cache entry
 corrupted by concurrent writers, so process classes that may run side by
-side (the test suite, the bench-contract subprocesses, the driver dry
-run) each place their own directory through that variable.
+side (the test suite, the benchmark's own tests, the driver dry run)
+each place their own directory through that variable.
 PHANT_NO_COMPILE_CACHE=1 (PHANT_NO_JAX_CACHE is a legacy alias) switches
 jax's persistent cache off for the process, wherever its directory is.
 """

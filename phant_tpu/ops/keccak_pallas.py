@@ -7,7 +7,7 @@ their padded rate chunks once from its VMEM block, and writes only the
 MPT node shapes (~13.5 GB/s of keccak input) — 1.25x the jnp/XLA program
 in ops/keccak_jax.py and ~34x the host 8-way AVX-512 batch — figures
 from before PR 5, taken by chaining data-dependent batches in one
-dispatch (bench.py _slope_time_chunked): a forced readback per call
+dispatch: a forced readback per call
 times the host<->device round trip, not the ~0.4ms kernel.
 
 Layout: instances are laid across (sublane, lane) = (SUB, 128) tiles —

@@ -445,7 +445,7 @@ class RootEngine:
 
     def root_many(self, plans: Sequence) -> List[List[bytes]]:
         """K requests' out digests in one engine call — begin + resolve
-        fused (the depth-1 scheduler path and the offline bench face)."""
+        fused (the depth-1 scheduler path and offline callers)."""
         return self.resolve_batch(self.begin_batch(plans))
 
     def stats_snapshot(self) -> dict:

@@ -26,7 +26,6 @@ Differential-tested bit-exactly against phant_tpu/crypto/secp256k1.py.
 
 from __future__ import annotations
 
-import os
 from typing import List, Optional, Sequence, Tuple
 
 import jax
@@ -77,22 +76,6 @@ _EXP_N_MINUS_2 = _bits_msb(N - 2)
 
 _G_X = _int_to_limbs_np(GX)
 _G_Y = _int_to_limbs_np(GY)
-# 2G, precomputed host-side for the (cryptographically improbable) R == G
-# exceptional case of the one-off G+R affine add
-_G2 = None  # filled below once CPU helpers are importable
-
-
-def _cpu_g2() -> Tuple[np.ndarray, np.ndarray]:
-    global _G2
-    if _G2 is None:
-        from phant_tpu.crypto.secp256k1 import _point_add
-
-        g2 = _point_add((GX, GY), (GX, GY))
-        # idempotent pure precompute: racing writers store identical
-        # tuples, and this runs at jit-trace time where a lock would
-        # serialize tracing for no benefit
-        _G2 = (_int_to_limbs_np(g2[0]), _int_to_limbs_np(g2[1]))  # phantlint: disable=LOCK — benign double-compute of a constant
-    return _G2
 
 
 # ---------------------------------------------------------------------------
@@ -352,8 +335,11 @@ def _to_affine(X, Y, Z):
 
 
 def _bits_matrix(a: jnp.ndarray) -> jnp.ndarray:
-    """(B,16) -> (256, B) scalar bit per ladder step, msb first."""
-    return _bits_matrix_w(a, 256)
+    """(B,16) limbs -> (256, B) scalar bit per ladder step, msb first."""
+    shifts = jnp.arange(16, dtype=jnp.uint32)
+    bits = (a[:, :, None] >> shifts[None, None, :]) & 1  # (B, 16, 16)
+    flat = bits.reshape(a.shape[0], LIMBS * 16)  # lsb-first
+    return jnp.flip(flat, axis=1).T
 
 
 # ---------------------------------------------------------------------------
@@ -364,7 +350,7 @@ def _bits_matrix(a: jnp.ndarray) -> jnp.ndarray:
 def _be_words(v):
     """(B,16) limbs -> (B,8) LE u32 words of the big-endian 32 bytes.
     Consensus-critical: this is the byte layout keccak sees for the
-    recovered pubkey (shared by both recovery kernels)."""
+    recovered pubkey."""
     sw = ((v & 0xFF) << 8) | (v >> 8)  # byteswap16 each limb
     hi = sw[:, ::-1]  # most significant limb first
     return hi[:, 0::2] | (hi[:, 1::2] << 16)
@@ -464,305 +450,16 @@ def ecrecover_kernel(e, r, s, parity):
 
 
 # ---------------------------------------------------------------------------
-# GLV-accelerated kernel
-#
-# The endomorphism phi(x, y) = (beta*x, y) equals multiplication by lambda
-# (lambda^3 = 1 mod n, beta^3 = 1 mod p), so any scalar k splits as
-# k = k1 + k2*lambda with |k1|, |k2| <~ 2^128 (lattice basis below, exact
-# split verified by tests against bigint math). Q = u1*G + u2*R therefore
-# becomes a FOUR-scalar half-width ladder
-#     s1*(+-G) + s2*(+-phiG) + t1*(+-R) + t2*(+-phiR)
-# over a 16-entry combined table: ~130 doublings instead of 256, one table
-# add per step. The mod-n inverse of r and the GLV split are host-side
-# bigints (microseconds, and they remove a whole 256-step device ladder).
-#
-# Exceptional add cases (operands equal / inverse) are astronomically
-# impossible for honest signatures but craftable by an adversary who picks
-# R = m*G with known m; instead of paying the branch-free exceptional
-# machinery on every ladder step, the kernel FLAGS any step whose add
-# degenerates and the host replays just those signatures on the exact CPU
-# path. Consensus-exact at full speed.
-# ---------------------------------------------------------------------------
-
-_GLV_LAMBDA = 0x5363AD4CC05C30E0A5261C028812645A122E22EA20816678DF02967C1B23BD72
-_GLV_BETA = 0x7AE96A2B657C07106E64479EAC3434E99CF0497512F58995C1396C28719501EE
-_GLV_A1 = 0x3086D221A7D46BCDE86C90E49284EB15
-_GLV_B1 = -0xE4437ED6010E88286F547FA90ABFE4C3
-_GLV_A2 = 0x114CA50F7A8E2F3F657C1108D9D44CFD8
-_GLV_B2 = _GLV_A1
-_GLV_BITS = 130  # |ki| <= 2^128 + margin
-_GLV_LIMBS = 9  # 144 bits of limb storage
-
-_GLV_CONSTS = None
-
-
-def _glv_consts():
-    """Host-precomputed affine tables: phiG and the four +-G +- phiG combos."""
-    global _GLV_CONSTS
-    if _GLV_CONSTS is None:
-        from phant_tpu.crypto.secp256k1 import _point_add
-
-        phigx = (_GLV_BETA * GX) % P
-        cpp = _point_add((GX, GY), (phigx, GY))  # G + phiG
-        cpm = _point_add((GX, GY), (phigx, P - GY))  # G - phiG
-        # idempotent pure precompute (see _cpu_g2): identical values from
-        # any racing writer, evaluated at jit-trace time
-        # phantlint: disable=LOCK — benign double-compute of constants
-        _GLV_CONSTS = {
-            "phig_x": _int_to_limbs_np(phigx),
-            "cpp_x": _int_to_limbs_np(cpp[0]),
-            "cpp_y": _int_to_limbs_np(cpp[1]),
-            "cpm_x": _int_to_limbs_np(cpm[0]),
-            "cpm_y": _int_to_limbs_np(cpm[1]),
-        }
-    return _GLV_CONSTS
-
-
-def glv_split(k: int) -> Tuple[int, int]:
-    """k -> (k1, k2) with k1 + k2*lambda = k (mod n), |ki| <~ 2^128."""
-    c1 = (_GLV_B2 * k + N // 2) // N
-    c2 = (-_GLV_B1 * k + N // 2) // N
-    k1 = k - c1 * _GLV_A1 - c2 * _GLV_A2
-    k2 = -c1 * _GLV_B1 - c2 * _GLV_B2
-    return k1, k2
-
-
-def pack_glv_inputs(
-    msg_hashes: Sequence[bytes], rs: Sequence[int], ss: Sequence[int]
-) -> Tuple[np.ndarray, np.ndarray]:
-    """(mags (B,4,9) u32, signs (B,4) u32) for `ecrecover_kernel_glv`: the
-    host-bigint half of recovery — r^-1 mod n, u1/u2, and the lambda
-    decomposition of each. The single packing recipe shared by the dispatch
-    path, the driver dryrun, and the differential tests.
-
-    PRECONDITION (validated here): r, s in (0, N). The device kernel checks
-    r's range itself but trusts s entirely — an out-of-range s would pack a
-    garbage lambda split and recover to a wrong-but-plausible address, so
-    it is rejected at the boundary (_dispatch_glv pre-screens and never
-    passes one; direct callers hit this raise)."""
-    B = len(msg_hashes)
-    for i in range(B):
-        if not (0 < rs[i] < N and 0 < ss[i] < N):
-            raise ValueError(
-                f"signature {i}: r,s must be pre-screened into (0,N) "
-                "(ecrecover_kernel_glv trusts the packed split)"
-            )
-    mags = np.zeros((B, 4, _GLV_LIMBS), np.uint32)
-    signs = np.zeros((B, 4), np.uint32)
-    for i in range(B):
-        z = int.from_bytes(msg_hashes[i], "big") % N
-        r_inv = pow(rs[i], -1, N)
-        s1, s2 = glv_split((-z * r_inv) % N)
-        t1, t2 = glv_split((ss[i] * r_inv) % N)
-        mags[i] = _ints_to_limbs_w(
-            [abs(s1), abs(s2), abs(t1), abs(t2)], _GLV_LIMBS
-        )
-        signs[i] = [int(s1 < 0), int(s2 < 0), int(t1 < 0), int(t2 < 0)]
-    return mags, signs
-
-
-def _ints_to_limbs_w(xs: Sequence[int], width: int) -> np.ndarray:
-    out = np.zeros((len(xs), width), np.uint32)
-    for i, v in enumerate(xs):
-        for j in range(width):
-            out[i, j] = (v >> (16 * j)) & 0xFFFF
-    return out
-
-
-def _neg_mod_p(v):
-    zero = v ^ v
-    return jnp.where(_is_zero(v)[:, None], v, _sub_mod(zero, v, P_SPEC))
-
-
-def _pt_add_plain(X1, Y1, Z1, x2, y2):
-    """Jacobian + affine WITHOUT the exceptional-double machinery: 11 muls
-    in 4 stacked groups. Returns (X3, Y3, Z3, degenerate) where degenerate
-    flags the equal/inverse cases this formula cannot represent (H == 0
-    with P finite); callers replay flagged elements on the exact CPU path.
-    P at infinity selects the affine operand."""
-    (Z1Z1,) = _mul_many([(Z1, Z1)], P_SPEC)
-    U2, Z1c = _mul_many([(x2, Z1Z1), (Z1, Z1Z1)], P_SPEC)
-    (S2,) = _mul_many([(y2, Z1c)], P_SPEC)
-    H = _sub_mod(U2, X1, P_SPEC)
-    Rr = _sub_mod(S2, Y1, P_SPEC)
-    HH, RR, Z3 = _mul_many([(H, H), (Rr, Rr), (Z1, H)], P_SPEC)
-    HHH, V = _mul_many([(H, HH), (X1, HH)], P_SPEC)
-    X3 = _sub_mod(_sub_mod(RR, HHH, P_SPEC), _add_mod(V, V, P_SPEC), P_SPEC)
-    Y1HHH, RrVX3 = _mul_many([(Y1, HHH), (Rr, _sub_mod(V, X3, P_SPEC))], P_SPEC)
-    Y3 = _sub_mod(RrVX3, Y1HHH, P_SPEC)
-
-    p_inf = _is_zero(Z1)
-    degenerate = _is_zero(H) & ~p_inf
-    one_l = (X1 ^ X1).at[..., 0].set(1)
-    out = _select_pt(p_inf, (x2, y2, one_l), (X3, Y3, Z3))
-    return out[0], out[1], out[2], degenerate
-
-
-def _bits_matrix_w(a: jnp.ndarray, nbits: int) -> jnp.ndarray:
-    """(B, W) 16-bit limbs -> (nbits, B) bits, msb-first."""
-    shifts = jnp.arange(16, dtype=jnp.uint32)
-    bits = (a[:, :, None] >> shifts[None, None, :]) & 1  # (B, W, 16)
-    flat = bits.reshape(a.shape[0], a.shape[1] * 16)  # lsb-first
-    return jnp.flip(flat[:, :nbits], axis=1).T
-
-
-@jax.jit
-def ecrecover_kernel_glv(r, parity, mags, signs):
-    """Batched GLV ecrecover -> keccak digest of the recovered pubkey.
-
-    Args:
-      r: (B,16) uint32 limbs — signature r (x-coordinate of R).
-      parity: (B,) uint32 — y-parity of R.
-      mags: (B,4,9) uint32 limbs — |s1|,|s2|,|t1|,|t2| where
-        u1 = s1 + s2*lambda, u2 = t1 + t2*lambda (host glv_split).
-      signs: (B,4) uint32 — 1 where the corresponding ki is negative.
-
-    Returns (digest_words, valid, degenerate); `degenerate` elements carry
-    garbage and must be replayed on the exact CPU path.
-
-    PRECONDITION: mags/signs must come from `pack_glv_inputs` (or an
-    equivalent that screened 0 < s < N). The kernel validates r's range and
-    curve membership on-device but cannot see s — `valid` does NOT cover an
-    out-of-range s, whose split packs to garbage.
-    """
-    from phant_tpu.ops.keccak_jax import keccak256_chunked_auto
-
-    B = r.shape[0]
-    zero16 = r ^ r
-    c = _glv_consts()
-
-    r_ok = ~_is_zero(r) & _lt_const(r, N)
-
-    # decompress R
-    x = r
-    x2 = _mul_mod(x, x, P_SPEC)
-    x3 = _mul_mod(x2, x, P_SPEC)
-    seven = np.zeros(LIMBS, np.uint32)
-    seven[0] = 7
-    y_sq = _add_mod(x3, jnp.broadcast_to(jnp.asarray(seven), x.shape), P_SPEC)
-    y = _pow_fixed(y_sq, _EXP_SQRT, P_SPEC)
-    on_curve = _eq(_mul_mod(y, y, P_SPEC), y_sq)
-    flip = (y[:, 0] & 1) != (parity & 1)
-    y = jnp.where(flip[:, None], _neg_mod_p(y), y)
-
-    # phiR x-coordinate (one field mul)
-    beta = jnp.broadcast_to(jnp.asarray(_int_to_limbs_np(_GLV_BETA)), x.shape)
-    xb = _mul_mod(beta, x, P_SPEC)
-
-    sgn = signs.astype(bool)  # (B,4): s1, s2, t1, t2
-    neg_y = _neg_mod_p(y)
-
-    gx = jnp.broadcast_to(jnp.asarray(_G_X), x.shape)
-    gy = jnp.broadcast_to(jnp.asarray(_G_Y), x.shape)
-    phigx = jnp.broadcast_to(jnp.asarray(c["phig_x"]), x.shape)
-    neg_gy = _neg_mod_p(gy)
-
-    # G-part entries (affine, per-element sign selects)
-    g1x, g1y = gx, jnp.where(sgn[:, 0][:, None], neg_gy, gy)
-    g2x, g2y = phigx, jnp.where(sgn[:, 1][:, None], neg_gy, gy)
-    # +-G +- phiG combos: (+,+)->Cpp (+,-)->Cpm (-,-)->-Cpp (-,+)->-Cpm
-    cppx = jnp.broadcast_to(jnp.asarray(c["cpp_x"]), x.shape)
-    cppy = jnp.broadcast_to(jnp.asarray(c["cpp_y"]), x.shape)
-    cpmx = jnp.broadcast_to(jnp.asarray(c["cpm_x"]), x.shape)
-    cpmy = jnp.broadcast_to(jnp.asarray(c["cpm_y"]), x.shape)
-    same = (sgn[:, 0] == sgn[:, 1])[:, None]
-    g3x = jnp.where(same, cppx, cpmx)
-    g3y = jnp.where(same, cppy, cpmy)
-    g3y = jnp.where(sgn[:, 0][:, None], _neg_mod_p(g3y), g3y)
-
-    # R-part entries
-    r1x, r1y = x, jnp.where(sgn[:, 2][:, None], neg_y, y)
-    r2x, r2y = xb, jnp.where(sgn[:, 3][:, None], neg_y, y)
-
-    one_l = zero16.at[:, 0].set(1)
-    degenerate = jnp.zeros((B,), bool)
-
-    # 16-entry table: T[4h+g] = Rc[h] + Gc[g] (Jacobian; Z=0 identity)
-    gx_l = [None, g1x, g2x, g3x]
-    gy_l = [None, g1y, g2y, g3y]
-    TX = [zero16, g1x, g2x, g3x]
-    TY = [one_l, g1y, g2y, g3y]
-    TZ = [zero16, one_l, one_l, one_l]
-    r3x, r3y, r3z, dg = _pt_add_plain(r1x, r1y, one_l, r2x, r2y)
-    degenerate = degenerate | dg
-    rc = [(r1x, r1y, one_l), (r2x, r2y, one_l), (r3x, r3y, r3z)]
-    for h in range(1, 4):
-        RX, RY, RZ = rc[h - 1]
-        TX.append(RX)
-        TY.append(RY)
-        TZ.append(RZ)
-        for g in range(1, 4):
-            X3, Y3, Z3, dg = _pt_add_plain(RX, RY, RZ, gx_l[g], gy_l[g])
-            degenerate = degenerate | dg
-            TX.append(X3)
-            TY.append(Y3)
-            TZ.append(Z3)
-    Tx = jnp.stack(TX)  # (16, B, 16)
-    Ty = jnp.stack(TY)
-    Tz = jnp.stack(TZ)
-
-    # normalize the table to affine via one batched inversion (Montgomery
-    # trick over the 16 entries; identity Z=0 contributes a neutral 1 and
-    # is only ever selected at idx==0, which the ladder skips)
-    inf_mask = _is_zero(Tz.reshape(-1, LIMBS)).reshape(16, B, 1)
-    z_safe = jnp.where(inf_mask, jnp.broadcast_to(one_l, Tz.shape), Tz)
-    prefix = [z_safe[0]]
-    for i in range(1, 16):
-        (nxt,) = _mul_many([(prefix[-1], z_safe[i])], P_SPEC)
-        prefix.append(nxt)
-    total_inv = _pow_fixed(prefix[-1], _EXP_P_MINUS_2, P_SPEC)
-    zinv = [None] * 16
-    acc = total_inv
-    for i in range(15, 0, -1):
-        zi, acc2 = _mul_many([(acc, prefix[i - 1]), (acc, z_safe[i])], P_SPEC)
-        zinv[i] = zi
-        acc = acc2
-    zinv[0] = acc
-    zinv = jnp.stack(zinv)  # (16, B, 16)
-    zi2 = _mul_mod(zinv.reshape(-1, LIMBS), zinv.reshape(-1, LIMBS), P_SPEC)
-    zi3 = _mul_mod(zi2, zinv.reshape(-1, LIMBS), P_SPEC)
-    Tax = _mul_mod(Tx.reshape(-1, LIMBS), zi2, P_SPEC).reshape(16, B, LIMBS)
-    Tay = _mul_mod(Ty.reshape(-1, LIMBS), zi3, P_SPEC).reshape(16, B, LIMBS)
-
-    # ladder index per step: s1 + 2*s2 + 4*t1 + 8*t2, msb-first
-    b = [_bits_matrix_w(mags[:, i, :], _GLV_BITS) for i in range(4)]
-    idx = (b[0] + 2 * b[1] + 4 * b[2] + 8 * b[3]).astype(jnp.int32)  # (130,B)
-
-    def step(carry, idx_t):
-        S, deg = carry
-        S = _pt_dbl(*S)
-        sel = jnp.broadcast_to(idx_t[None, :, None], (1,) + Tax.shape[1:])
-        ax = jnp.take_along_axis(Tax, sel, axis=0)[0]
-        ay = jnp.take_along_axis(Tay, sel, axis=0)[0]
-        X3, Y3, Z3, dg = _pt_add_plain(S[0], S[1], S[2], ax, ay)
-        skip = idx_t == 0
-        S = _select_pt(skip, S, (X3, Y3, Z3))
-        deg = deg | (dg & ~skip)
-        return (S, deg), None
-
-    S0 = (one_l, one_l, zero16)
-    (Q, deg_ladder), _ = jax.lax.scan(step, (S0, degenerate), idx)
-    degenerate = deg_ladder
-
-    qx, qy, q_inf = _to_affine(*Q)
-    valid = r_ok & on_curve & ~q_inf
-
-    words = jnp.zeros((B, 1, 34), jnp.uint32)
-    words = words.at[:, 0, 0:8].set(_be_words(qx))
-    words = words.at[:, 0, 8:16].set(_be_words(qy))
-    words = words.at[:, 0, 16].set(jnp.uint32(0x00000001))
-    words = words.at[:, 0, 33].set(jnp.uint32(0x80000000))
-    digest = keccak256_chunked_auto(words, jnp.ones((B,), jnp.int32), max_chunks=1)
-    return digest, valid, degenerate
-
-
-# ---------------------------------------------------------------------------
 # host API
 # ---------------------------------------------------------------------------
 
 
 def ints_to_limbs(xs: Sequence[int]) -> np.ndarray:
-    return _ints_to_limbs_w(xs, LIMBS)
+    out = np.zeros((len(xs), LIMBS), np.uint32)
+    for i, v in enumerate(xs):
+        for j in range(LIMBS):
+            out[i, j] = (v >> (16 * j)) & 0xFFFF
+    return out
 
 
 def digest_words_to_addresses(words: np.ndarray) -> List[bytes]:
@@ -801,13 +498,7 @@ def ecrecover_batch_async(
                 out[i] = None
     if not device_idx:
         return lambda: out
-    # default = the measured winner: BENCH r4 on a v5e-1 clocked the Shamir
-    # interleaved ladder at 5474.5 recoveries/s vs 2666.2/s for the GLV
-    # ladder (the endomorphism split halves the ladder length but its extra
-    # inversions + wider per-step muxing cost more than it saves at these
-    # batch shapes) — GLV stays selectable for A/B runs
-    if os.environ.get("PHANT_ECRECOVER_KERNEL", "shamir") == "glv":
-        return _dispatch_glv(out, device_idx, msg_hashes, rs, ss, recovery_ids)
+    # one ladder: Shamir's interleaved double-scalar multiplication
     return _dispatch_shamir(out, device_idx, msg_hashes, rs, ss, recovery_ids)
 
 
@@ -821,8 +512,7 @@ def _bucket_pad(n: int) -> int:
 
 
 def _dispatch_shamir(out, device_idx, msg_hashes, rs, ss, recovery_ids):
-    """The 256-step Shamir interleaved ladder — the production default
-    (BENCH r4: 5474.5/s vs GLV 2666.2/s on a v5e-1)."""
+    """Pack, pad to a bucket and launch the 256-step Shamir ladder."""
     pad = _bucket_pad(len(device_idx)) - len(device_idx)
     e = ints_to_limbs(
         [int.from_bytes(msg_hashes[i], "big") for i in device_idx] + [1] * pad
@@ -843,61 +533,6 @@ def _dispatch_shamir(out, device_idx, msg_hashes, rs, ss, recovery_ids):
         valid_np = np.asarray(valid)  # phantlint: disable=HOSTSYNC — resolve() is the chosen sync point
         for k, i in enumerate(device_idx):
             out[i] = addrs[k] if bool(valid_np[k]) else None
-        return out
-
-    return resolve
-
-
-def _dispatch_glv(out, device_idx, msg_hashes, rs, ss, recovery_ids):
-    """GLV path: host bigints compute r^-1 and the lambda-decomposition
-    (microseconds per signature), the device runs the ~130-step four-scalar
-    ladder. Host pre-screens range-invalid signatures and the u1=u2=0
-    corner; kernel-flagged degenerate adds (adversarially craftable only)
-    replay on the exact CPU path at resolve time."""
-    from phant_tpu.crypto.keccak import keccak256
-    from phant_tpu.crypto.secp256k1 import SignatureError, recover_pubkey
-
-    ship: List[int] = []
-    for i in device_idx:
-        if not (0 < rs[i] < N and 0 < ss[i] < N):
-            out[i] = None
-            continue
-        ship.append(i)
-    if not ship:
-        return lambda: out
-
-    pad = _bucket_pad(len(ship)) - len(ship)
-    r_arr = ints_to_limbs([rs[i] for i in ship] + [1] * pad)
-    par = np.array([recovery_ids[i] & 1 for i in ship] + [0] * pad, np.uint32)
-    mags_s, signs_s = pack_glv_inputs(
-        [msg_hashes[i] for i in ship],
-        [rs[i] for i in ship],
-        [ss[i] for i in ship],
-    )
-    mags = np.zeros((len(ship) + pad, 4, _GLV_LIMBS), np.uint32)
-    mags[: len(ship)] = mags_s
-    signs = np.zeros((len(ship) + pad, 4), np.uint32)
-    signs[: len(ship)] = signs_s
-    digest, valid, degenerate = ecrecover_kernel_glv(
-        jnp.asarray(r_arr), jnp.asarray(par), jnp.asarray(mags), jnp.asarray(signs)
-    )
-
-    def resolve() -> List[Optional[bytes]]:
-        # deliberate sync point (see _dispatch_shamir's resolve)
-        addrs = digest_words_to_addresses(np.asarray(digest))  # phantlint: disable=HOSTSYNC — resolve() is the chosen sync point
-        valid_np = np.asarray(valid)  # phantlint: disable=HOSTSYNC — resolve() is the chosen sync point
-        deg_np = np.asarray(degenerate)  # phantlint: disable=HOSTSYNC — resolve() is the chosen sync point
-        for k, i in enumerate(ship):
-            if bool(deg_np[k]):  # exact replay for adversarial corner cases
-                try:
-                    pub = recover_pubkey(
-                        msg_hashes[i], rs[i], ss[i], recovery_ids[i]
-                    )
-                    out[i] = keccak256(pub[1:])[12:]
-                except SignatureError:
-                    out[i] = None
-            else:
-                out[i] = addrs[k] if bool(valid_np[k]) else None
         return out
 
     return resolve

@@ -34,10 +34,7 @@ below-break-even-alone / wins-when-batched shape that already
 rehabilitated witness keccak and the root lane. `device_floor` >= 0
 overrides the floor (0 forces the device — the XLA-CPU proxy/tests knob;
 the env twin is PHANT_SIG_DEVICE_FLOOR). The device route runs the
-Shamir interleaved ladder (`ops/secp256k1_jax.ecrecover_kernel`, the
-BENCH-r4-measured production winner; the GLV A/B kernel stays on the
-offline `ecrecover_batch_async` path — its host bigint pre-decomposition
-does not belong on a serving handler thread).
+Shamir interleaved ladder (`ops/secp256k1_jax.ecrecover_kernel`).
 
 Protocol: `prefetch_batch` / `begin_batch` / `resolve_batch` /
 `abandon_batch` / the fused `sig_many` — deliberately the same names and
@@ -399,7 +396,7 @@ class SigEngine:
 
     def sig_many(self, rows_list: Sequence) -> List[List[Optional[bytes]]]:
         """K requests' sender slices in one engine call — begin + resolve
-        fused (the depth-1 scheduler path and the offline bench face)."""
+        fused (the depth-1 scheduler path and offline callers)."""
         return self.resolve_batch(self.begin_batch(rows_list))
 
     def stats_snapshot(self) -> dict:

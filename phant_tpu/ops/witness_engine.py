@@ -730,7 +730,7 @@ class WitnessEngine:
         """Route this engine's verdicts through the device-resident
         table? Auto-on under `--crypto_backend=tpu` on a real
         accelerator; PHANT_RESIDENT=1 forces (XLA-CPU tests/proxy), =0
-        disables; the constructor arg overrides the env. A bench hasher
+        disables; the constructor arg overrides the env. A hasher
         override always wins — its batches must surface to the host
         hashing route."""
         if self._hasher is not None or self._resident_opt is False:
@@ -801,8 +801,8 @@ class WitnessEngine:
 
     def reset(self) -> None:
         """Release EVERYTHING: host tables (all cores), the python
-        twins, the device-resident arrays, and the depth memo. The bench
-        and soak use this between timed passes — constructing a fresh
+        twins, the device-resident arrays, and the depth memo. The soak
+        uses this between timed passes — constructing a fresh
         engine resets the HOST state, but with residency the old
         engine's device arrays would linger until GC, so pass 2 could
         silently measure a warm resident table (or accumulate device
@@ -2104,7 +2104,7 @@ class WitnessEngine:
         uses it to keep the zero-round-trip finish_native fast path for
         host-routed batches), so the two can never disagree.
 
-        A bench hasher override returns True — the batch must surface to
+        A hasher override returns True — the batch must surface to
         the Python-visible path for the override to apply."""
         from phant_tpu.backend import (
             crypto_backend,
@@ -2133,7 +2133,7 @@ class WitnessEngine:
     def _native_route_certain(self) -> bool:
         """True when _hash_batch could only ever pick the native hasher —
         then finish_native may hash in C without consulting the route. Any
-        override (bench hasher, device floor) or a cost model that could
+        override (injected hasher, device floor) or a cost model that could
         favor the device falls back to the Python-visible path."""
         if self._hasher is not None or self._device_batch_floor >= 0:
             return False
@@ -2146,8 +2146,8 @@ class WitnessEngine:
 
     def _verify_native(self, witnesses, all_nodes, counts, n_blocks):
         """Scan/hash/commit/verdict against the C++ core. The hashing of
-        novel nodes stays here so the device/native backend route (and the
-        bench's hasher override) applies identically to both cores."""
+        novel nodes stays here so the device/native backend route (and an
+        injected hasher) applies identically to both cores."""
         core = self._core
         n = len(all_nodes)
         if self._pin is not None:
@@ -2232,7 +2232,7 @@ class WitnessEngine:
 
     def resident_table(self):
         """The live device-resident table, or None (not yet engaged /
-        dropped). Bench + tests read its arrays and upload accounting."""
+        dropped). Tests read its arrays and upload accounting."""
         with self._lock:
             return self._resident
 

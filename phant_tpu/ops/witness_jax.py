@@ -26,7 +26,7 @@ from phant_tpu.crypto.keccak import RATE
 from phant_tpu.ops.keccak_jax import keccak256_chunked_auto
 
 # Bucket bound for witness nodes: RLP trie nodes are <= 576B (BASELINE.md),
-# and 576 < 5 * 136. Shared by bench.py / __graft_entry__.py / tests.
+# and 576 < 5 * 136. Shared by __graft_entry__.py / tests.
 WITNESS_MAX_CHUNKS = 5
 
 
@@ -113,7 +113,7 @@ def _referenced(digests, block_id, refs, ref_block, ref_live):
     sort-join instead of an (N, M) compare matrix: stack refs and digests as
     rows keyed by (block, 8 digest words), order them lexicographically, mark
     equal-key runs, and flag a digest row iff its run contains a live ref.
-    O((N+M) log(N+M)) work vs O(N*M*8) for the matrix — at bench shapes the
+    O((N+M) log(N+M)) work vs O(N*M*8) for the matrix — at mainnet shapes the
     matrix would rival the keccak cost itself.
 
     The lexicographic order is nine STABLE single-key sorts, least

@@ -23,10 +23,9 @@ batches:
   * **Row index** — a hash-bucketed open-addressing table over 64-bit
     digest fingerprints (ops/keccak_jax.index_insert / index_lookup),
     resident next to the rows. The production verdict never needs it
-    (host rows are exact); it is the DEVICE-side scan: the chained
-    slope protocol resolves rows on device from fingerprints alone
-    (8 bytes/node up, nothing else), and tests cross-check it against
-    the host dict.
+    (host rows are exact); it is the DEVICE-side scan: it resolves
+    rows on device from fingerprints alone (8 bytes/node up, nothing
+    else), and tests cross-check it against the host dict.
   * **Per-batch traffic** — truly-novel bytes (the host scan prunes
     anything already resident, including cross-batch pipelined
     duplicates the engine cores re-report) + 4 bytes/node of row ids +
@@ -53,7 +52,6 @@ engine lock (the engine calls in, never the reverse).
 
 from __future__ import annotations
 
-import functools
 import os
 import threading
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -67,7 +65,6 @@ __all__ = [
     "ResidentBatch",
     "ResidentTable",
     "resident_default_cap",
-    "slope_time_resident",
 ]
 
 
@@ -464,8 +461,8 @@ class ResidentTable:
             )
 
     def arrays(self) -> tuple:
-        """The live (digests, refs, ref_live, index, fps) handles — the
-        bench slope protocol and tests read them; treat as immutable."""
+        """The live (digests, refs, ref_live, index, fps) handles —
+        `chip_smoke.py` and tests read them; treat as immutable."""
         with self._lock:
             if self._arrays is None:
                 raise RuntimeError("resident table has no device arrays yet")
@@ -473,8 +470,8 @@ class ResidentTable:
 
     def device_lookup(self, fps: np.ndarray) -> np.ndarray:
         """Device-side row resolution from (N, 2) u32 fingerprints — the
-        on-device scan (forced sync: a test/bench surface, not the
-        serving hot path)."""
+        on-device scan (forced sync: a test surface, not the serving
+        hot path)."""
         arrays = self.arrays()
         return np.asarray(self._lookup_fn(arrays[3], arrays[4], self._put(fps)))
 
@@ -629,68 +626,3 @@ class ResidentTable:
         self.stats["pruned_nodes"] += pruned
         self.stats["batches"] += 1
         return h
-
-
-# ---------------------------------------------------------------------------
-# slope-timed chained dispatch (the RTT-insensitive steady-state rate)
-# ---------------------------------------------------------------------------
-
-
-def slope_time_resident(
-    table: ResidentTable,
-    node_fps: np.ndarray,
-    node_live: np.ndarray,
-    block_id: np.ndarray,
-    roots_words: np.ndarray,
-    *,
-    k_hi: int = 65,
-    reps: int = 3,
-) -> float:
-    """Per-iteration device seconds of the resident fused witness step,
-    isolated from the link: chain k data-dependent iterations — device
-    row LOOKUP from fingerprints (the on-device scan) + resident verdict
-    join — inside ONE jit call and fit the slope between k=1 and k=k_hi,
-    reading back a single u32. The same methodology as the keccak
-    kernel's bench (_slope_time_chunked): a forced full readback per
-    call measures host<->device round trips, not compute, and on a slow
-    link that floor is orders of magnitude above the actual step.
-
-    The chained steady state uploads NOTHING per iteration (fingerprints
-    ride up once); the data dependence between iterations is
-    `vs // (vs + 1)` — zero at runtime for any verdict sum, but opaque
-    to constant folding, so XLA must serialize the chain."""
-    import time
-
-    import jax
-    import jax.numpy as jnp
-
-    digests, refs, ref_live, index, fps = table.arrays()
-    q = table._put(node_fps.astype(np.uint32))
-    live = table._put(node_live.astype(bool))
-    bid = table._put(block_id.astype(np.int32))
-    roots = table._put(roots_words.astype(np.uint32))
-
-    @functools.partial(jax.jit, static_argnames=("k",))
-    def chain(digests, refs, ref_live, index, fps, q, live, bid, roots, k):
-        def body(_i, carry):
-            acc, qc = carry
-            rows = _lookup_impl(index, fps, qc)
-            v = _verdict_impl(digests, refs, ref_live, rows, live, bid, roots)
-            vs = jnp.sum(v.astype(jnp.uint32))
-            dep = vs // (vs + jnp.uint32(1))  # 0 at runtime, data-dependent
-            return (acc ^ vs, qc ^ dep)
-
-        acc, _ = jax.lax.fori_loop(0, k, body, (jnp.uint32(0), q))
-        return acc
-
-    args = (digests, refs, ref_live, index, fps, q, live, bid, roots)
-    times = {}
-    for k in (1, k_hi):
-        np.asarray(chain(*args, k=k))  # compile + warm (bench: sync is fine)
-        best = float("inf")
-        for _ in range(max(reps, 1)):
-            t0 = time.perf_counter()
-            np.asarray(chain(*args, k=k))
-            best = min(best, time.perf_counter() - t0)
-        times[k] = best
-    return max((times[k_hi] - times[1]) / (k_hi - 1), 1e-9)

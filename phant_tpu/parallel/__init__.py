@@ -1,7 +1,6 @@
 """Multi-chip / multi-host scaling (device meshes + sharded kernels)."""
 
 from phant_tpu.parallel.mesh import (
-    ecrecover_glv_sharded,
     ecrecover_sharded,
     init_distributed,
     make_mesh,
@@ -12,7 +11,6 @@ from phant_tpu.parallel.mesh import (
 )
 
 __all__ = [
-    "ecrecover_glv_sharded",
     "ecrecover_sharded",
     "init_distributed",
     "make_mesh",

@@ -96,10 +96,12 @@ def _no_compile_cache():
     """Serializing SOME multi-device (shard_map) executables still
     SEGFAULTS jax 0.9.0 in the persistent compilation cache's write path
     (`compilation_cache.put_executable_and_time`; the sharded GLV
-    ecrecover does, in PR 24's whole-suite run, while the sharded witness
-    programs were written and re-read fine), so every sharded compile
-    below runs with the cache SWITCHED OFF — `jax_enable_compilation_cache`,
-    the directory is left alone. Single-device kernels keep the cache."""
+    ecrecover, deleted in PR 30, did in PR 24's whole-suite run, while the
+    sharded witness programs were written and re-read fine; whether the
+    window can close is for a four-chip run to settle), so every sharded
+    compile below runs with the cache SWITCHED OFF —
+    `jax_enable_compilation_cache`, the directory is left alone.
+    Single-device kernels keep the cache."""
     from jax.experimental.compilation_cache import compilation_cache
 
     with _CACHE_TOGGLE_LOCK:
@@ -349,39 +351,6 @@ def ecrecover_sharded(mesh: Mesh, e, r, s, parity):
     # sharded array carrying the whole batch
     args = [jax.device_put(jnp.asarray(v), shard) for v in (e, r, s, parity)]  # phantlint: disable=JNPHOSTLOOP — fixed argument tuple, not per-element
     key = ("ecrecover", _mesh_key(mesh)) + _arg_key(args)
-    return _compiled_call(key, build, args)
-
-
-def ecrecover_glv_sharded(mesh: Mesh, r, parity, mags, signs):
-    """The GLV half-width ladder (ops/secp256k1_jax.ecrecover_kernel_glv)
-    with the signature axis sharded over `dp` — same embarrassingly
-    parallel layout as ecrecover_sharded, ~2x the per-chip throughput.
-    Returns (digests, valid, degenerate); degenerate elements must replay
-    on the exact CPU path, exactly as in the single-chip dispatch.
-
-    PRECONDITION: mags/signs must come from pack_glv_inputs (which screens
-    0 < r,s < N) — the kernel cannot detect an out-of-range s itself."""
-    from phant_tpu.ops.secp256k1_jax import ecrecover_kernel_glv
-
-    axis = mesh.axis_names[0]
-
-    def build():
-        @functools.partial(
-            shard_map,
-            mesh=mesh,
-            in_specs=(P(axis), P(axis), P(axis), P(axis)),
-            out_specs=(P(axis), P(axis), P(axis)),
-        )
-        def inner(r_s, p_s, m_s, s_s):
-            return ecrecover_kernel_glv(r_s, p_s, m_s, s_s)
-
-        return inner
-
-    shard = NamedSharding(mesh, P(axis))
-    args = [
-        jax.device_put(jnp.asarray(v), shard) for v in (r, parity, mags, signs)  # phantlint: disable=JNPHOSTLOOP — fixed argument tuple, not per-element
-    ]
-    key = ("ecrecover_glv", _mesh_key(mesh)) + _arg_key(args)
     return _compiled_call(key, build, args)
 
 

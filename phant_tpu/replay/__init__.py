@@ -7,8 +7,7 @@ serving stack's witness/root/sig lanes at far-past-serving batch shapes
 per-lane resident intern tables, K block-state roots per vmapped device
 program — with a prefetch pipeline that builds segment N+1's inputs
 under segment N's EVM execution. `python -m phant_tpu.replay
-<fixture-chain> --segment K` is the CLI face; bench.py's `replay_sync`
-section is the committed number.
+<fixture-chain> --segment K` is the CLI face.
 """
 
 from phant_tpu.replay.engine import (
@@ -22,7 +21,6 @@ from phant_tpu.replay.fixture import (
     ReplayFixture,
     attach_witnesses,
     build_synthetic_chain,
-    from_bench_tuple,
     load_fixture,
     save_fixture,
 )
@@ -35,7 +33,6 @@ __all__ = [
     "ReplayFixture",
     "attach_witnesses",
     "build_synthetic_chain",
-    "from_bench_tuple",
     "load_fixture",
     "replay_fixture",
     "save_fixture",

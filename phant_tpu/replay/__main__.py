@@ -2,14 +2,12 @@
 
     python -m phant_tpu.replay <fixture-chain> --segment K
 
-The fixture is a `phant_tpu.replay.fixture` pickle (or a raw bench
-`_build_replay_chain` cache tuple). `--scheduler` installs a
-VerificationScheduler so segments ride the real sig/witness lanes
+The fixture is a `phant_tpu.replay.fixture` pickle. `--scheduler`
+installs a VerificationScheduler so segments ride the real sig/witness lanes
 (`--mesh N` puts a MeshExecutorPool behind it); without it every stage
 uses its local megabatch fallback. `--serial-check` re-imports the same
 chain through serial `run_blocks` and asserts final-state-root
-byte-identity — the CLI face of the differential contract the tests and
-the `replay_sync` bench section pin.
+byte-identity — the CLI face of the differential contract the tests pin.
 """
 
 from __future__ import annotations

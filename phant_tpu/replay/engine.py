@@ -559,8 +559,8 @@ def replay_fixture(
     verify_state_root: bool = True,
     use_witnesses: bool = True,
 ) -> ReplayReport:
-    """Convenience: replay a fixture (fixture.load_fixture /
-    from_bench_tuple) on a fresh chain through the segment pipeline."""
+    """Convenience: replay a fixture (fixture.load_fixture) on a fresh
+    chain through the segment pipeline."""
     chain = fix.fresh_chain(verify_state_root=verify_state_root)
     eng = ReplayEngine(
         segment_blocks=segment_blocks,

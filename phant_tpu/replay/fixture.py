@@ -3,8 +3,7 @@ consumes.
 
 A fixture is a pickled dict carrying a genesis header, the genesis
 account set, and an ordered block list (the picklable shapes
-`build_synthetic_chain` below makes and bench.py caches), optionally
-enriched with
+`build_synthetic_chain` below makes), optionally enriched with
 per-block witnesses: `(claimed_root, nodes)` pairs generated against
 each block's PARENT state under a named commitment scheme
 (phant_tpu/commitment/). Witnessed fixtures let the replay engine drive
@@ -56,18 +55,6 @@ class ReplayFixture:
     @property
     def total_txs(self) -> int:
         return sum(len(b.transactions) for b in self.blocks)
-
-
-def from_bench_tuple(built: tuple, chain_id: int = 1) -> ReplayFixture:
-    """Adapt bench.py's `_build_replay_chain` cache tuple
-    `(genesis, blocks, genesis_accounts, total_txs, n_calls)`."""
-    genesis, blocks, genesis_accounts, _total_txs, _n_calls = built
-    return ReplayFixture(
-        chain_id=chain_id,
-        genesis=genesis,
-        genesis_accounts=genesis_accounts,
-        blocks=list(blocks),
-    )
 
 
 def build_synthetic_chain(
@@ -273,12 +260,9 @@ def save_fixture(path: str, fix: ReplayFixture) -> None:
 
 
 def load_fixture(path: str) -> ReplayFixture:
-    """Load a fixture file; the raw bench `_build_replay_chain` tuple is
-    accepted too (a cached bench chain replays as-is)."""
+    """Load a file `save_fixture` wrote."""
     with open(path, "rb") as f:
         payload = pickle.load(f)
-    if isinstance(payload, tuple):
-        return from_bench_tuple(payload)
     if not isinstance(payload, dict) or payload.get("format") != FORMAT:
         raise ValueError(f"{path}: not a {FORMAT} file")
     if payload.get("version") != VERSION:

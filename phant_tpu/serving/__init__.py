@@ -11,9 +11,8 @@ holds the process-global *active scheduler* slot:
   active scheduler when one is installed (so concurrent
   `engine_executeStatelessPayloadV1` handler threads coalesce their
   linked-multiproof checks into one engine/device dispatch) and falls
-  back to the direct shared-engine path otherwise — offline callers,
-  tests, and bench sections that never installed a scheduler are
-  untouched;
+  back to the direct shared-engine path otherwise — offline callers
+  and tests that never installed a scheduler are untouched;
 * `/healthz` (engine_api/server.py) reads the active scheduler's state
   and turns an executor crash into a 503.
 """

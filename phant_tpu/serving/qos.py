@@ -15,7 +15,7 @@ unit-tested in isolation (tests/test_qos.py):
   request header (engine_api/server.py) exactly the way `trace_context`
   binds the trace id. Scheduler submissions made inside the context
   inherit it; everything else lands in `DEFAULT_TENANT` — which is why
-  offline callers (verify_many, the spec runner, bench) see byte-identical
+  offline callers (verify_many, the spec runner) see byte-identical
   single-tenant behavior.
 * **Priority classes** — `PRIORITY_HEAD` (head-of-chain work: the serial
   mutation lane's `engine_newPayload*`/`engine_forkchoiceUpdated`, or a
@@ -56,7 +56,7 @@ PRIORITY_HEAD = 0
 PRIORITY_BACKFILL = 1
 
 #: the lane every untagged submission lands in — offline callers
-#: (verify_many, spec runner, bench) never leave it, which is what keeps
+#: (verify_many, spec runner) never leave it, which is what keeps
 #: single-tenant behavior identical to the pre-QoS scheduler.
 DEFAULT_TENANT = "default"
 

@@ -118,7 +118,7 @@ shape instead:
   record names the pipeline STAGE that died (pack/dispatch/resolve).
 
 `verify_many()` is the synchronous offline face of the same machinery:
-bench.py, the spec runner (`--sched`), and tests push whole witness
+the spec runner (`--sched`), `scripts/soak.py` and tests push whole witness
 spans through the identical admission/assembly/executor code and get an
 (n,) bool verdict array back — the batching code measured offline is the
 batching code serving traffic.
@@ -327,14 +327,14 @@ class SchedulerConfig:
     # megabatch backlog trigger: fuse when queued same-bucket work >=
     # mesh width x k (0 = full-batch-only, the pre-trigger behavior)
     megabatch_backlog_k: int = field(default_factory=_default_megabatch_backlog_k)
-    # per-lane engine injection (tests/bench: doubles, shared engines);
+    # per-lane engine injection (tests: doubles, shared engines);
     # None = one device-pinned WitnessEngine per lane
     mesh_engine_factory: Optional[Callable] = None
-    # root-lane engine injection (tests/bench: poisoned engines, forced
+    # root-lane engine injection (tests/soak: poisoned engines, forced
     # device floors); None = the process-shared ops/root_engine.py engine
     # (mesh lanes build one PINNED RootEngine per device instead)
     root_engine_factory: Optional[Callable] = None
-    # sig-lane engine injection (tests/bench: poisoned engines, forced
+    # sig-lane engine injection (tests/soak: poisoned engines, forced
     # device floors); None = the process-shared ops/sig_engine.py engine
     # (mesh lanes build one PINNED SigEngine per device instead)
     sig_engine_factory: Optional[Callable] = None
@@ -963,7 +963,7 @@ class VerificationScheduler:
     def root_many(self, plans: Sequence) -> List[List[bytes]]:
         """Out digests for a span of plans, pushed through the SAME
         admission/assembly/executor path the server uses — the offline
-        face of the root lane (bench, tests). Blocks on queue space and
+        face of the root lane (soak, tests). Blocks on queue space and
         applies no deadline, like verify_many."""
         if threading.current_thread() in (
             self._thread,
@@ -1092,7 +1092,7 @@ class VerificationScheduler:
     def sig_many(self, rows_list: Sequence) -> List[List[Optional[bytes]]]:
         """Sender slices for a span of requests' rows, pushed through the
         SAME admission/assembly/executor path the server uses — the
-        offline face of the sig lane (bench, soak, tests). Blocks on
+        offline face of the sig lane (soak, tests). Blocks on
         queue space and applies no deadline, like verify_many."""
         if threading.current_thread() in (
             self._thread,
@@ -1367,7 +1367,7 @@ class VerificationScheduler:
     ) -> np.ndarray:
         """(n,) bool verdicts for a span of (root, nodes) witnesses, pushed
         through the SAME admission/assembly/executor path the server uses —
-        the offline API for bench.py, the spec runner, and tests. Blocks on
+        the offline API for the spec runner, `scripts/soak.py` and tests. Blocks on
         queue space instead of rejecting (offline callers want completion,
         not load shedding) and applies no deadline."""
         if threading.current_thread() in (
@@ -2092,7 +2092,7 @@ class VerificationScheduler:
     def _shed_expired(self, job: _Job) -> None:
         """Deadline shed at execution time: one place keeps the stats
         snapshot and the `sched.rejected` metric in agreement (the soak
-        gate and bench artifacts assert on the snapshot)."""
+        gate asserts on the snapshot)."""
         with self._lock:
             self.stats["rejected"] += 1
             self._tenant_locked(job.tenant)["shed"] += 1

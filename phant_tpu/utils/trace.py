@@ -336,7 +336,7 @@ METRIC_HELP: Dict[str, str] = {
     "keccak.bytes": "Payload bytes submitted to batched keccak by backend",
     "keccak.device_dispatch": "Host->device upload + kernel dispatch phase",
     "keccak.host_readback": "Device->host digest readback (the honest sync) phase",
-    "backend.selected": "Crypto-backend selections by backend (process start + bench flips)",
+    "backend.selected": "Crypto-backend selections by backend (process start + later switches)",
     "backend.device_fallbacks": "Run-time degradations after a healthy start: a device or device-lane failure made this site serve its batch from the host (by site; chip_smoke.py requires zero)",
     "backend.offload_decisions": "Adaptive offload-gate verdicts by outcome (device/native)",
 }

@@ -28,7 +28,6 @@ DEVICE_GROUPS=(
   tests/test_keccak_jax.py
   tests/test_keccak_pallas.py
   tests/test_secp256k1_jax.py
-  tests/test_secp256k1_glv.py
   tests/test_mpt_jax.py
   tests/test_witness_jax.py
   tests/test_witness_fused.py
@@ -65,9 +64,9 @@ rc=$?
 echo "[check] group phantlint: rc=$rc in $(( $(date +%s) - t0 ))s"
 if [ "$rc" -ne 0 ]; then fail=1; fi
 
-# Second lint pass: scripts/ under the concurrency rules only (soak,
-# loadgen, and bench spawn threads too; the JAX-hygiene rules don't
-# apply to host-side driver scripts). Same EMPTY baseline.
+# Second lint pass: scripts/ under the concurrency rules only (soak and
+# loadgen spawn threads too; the JAX-hygiene rules don't apply to
+# host-side driver scripts). Same EMPTY baseline.
 t0=$(date +%s)
 JAX_PLATFORMS=cpu python scripts/phantlint.py scripts/ \
   --rules LOCK,LOCKORDER,LOCKBLOCK,THREADSHARE \
@@ -136,17 +135,6 @@ JAX_PLATFORMS=cpu python scripts/soak.py > build/logs/soak.log 2>&1
 rc=$?
 echo "[check] group soak: rc=$rc in $(( $(date +%s) - t0 ))s"
 if [ "$rc" -ne 0 ]; then cat build/logs/soak.log; fail=1; fi
-
-# Bench-trend sentinel, STRICT: a BENCH_ACK file next to the artifacts
-# carries root-caused dead rounds, so the sentinel is a real gate — a new
-# dead round or a beyond-noise-bar section regression goes red here
-# instead of hiding in a report nobody reads.
-t0=$(date +%s)
-python scripts/benchtrend.py > build/logs/trend.log 2>&1
-rc=$?
-echo "[check] group trend (strict): rc=$rc in $(( $(date +%s) - t0 ))s"
-tail -n 5 build/logs/trend.log | sed 's/^/[trend] /'
-if [ "$rc" -ne 0 ]; then cat build/logs/trend.log; fail=1; fi
 
 total=$(( $(date +%s) - start ))
 if [ "$fail" -ne 0 ]; then

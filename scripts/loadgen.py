@@ -6,8 +6,8 @@ overload shedding — phant_tpu/serving/) claims to keep head-of-chain
 latency bounded and every tenant progressing while the scheduler is
 saturated. Nothing in the tree could PRODUCE that saturation: the soak is
 closed-loop (each thread waits for its reply, so offered load politely
-collapses to service rate — the classic coordinated-omission trap), and
-the bench drives `verify_many` offline. This harness closes the gap: an
+collapses to service rate — the classic coordinated-omission trap).
+This harness closes the gap: an
 OPEN-LOOP generator (arrivals fire on a Poisson clock regardless of how
 slow replies are, so queueing delay is measured, not hidden) that drives
 the REAL HTTP server with a mixed-tenant profile and reports what the QoS
@@ -45,11 +45,8 @@ the overload point — come from the server's own flight recorder
 (`/debug/flight`, PR 4) and `/metrics`, not from client-side bookkeeping.
 
 Faces: `python scripts/loadgen.py` (self-serves an EngineAPIServer on an
-ephemeral port; `--base URL` aims at an external server instead),
-`make soak` runs a <=60s fixed-seed phase (scripts/soak.py), and bench.py
-embeds `run_profile()` as the `serving_load` section whose keys
-scripts/benchtrend.py trend-gates (percentiles lower-is-better, `_rps`
-higher-is-better).
+ephemeral port; `--base URL` aims at an external server instead), and
+`make soak` runs a <=60s fixed-seed phase (scripts/soak.py).
 """
 
 from __future__ import annotations
@@ -670,48 +667,6 @@ def run_profile(
     return result
 
 
-def bench_keys(result: dict) -> dict:
-    """Flatten a run_profile() result into the `serving_load` bench-detail
-    keys scripts/benchtrend.py trends: `_rps` higher-is-better, `_ms`
-    (the latency percentiles) lower-is-better, the rest informational."""
-    points = result.get("points", [])
-    if not points:
-        return {"serving_load_error": "no points"}
-    by_mult = {p["multiplier"]: p for p in points}
-    nominal = by_mult.get(1.0) or points[len(points) // 2]
-    overload = max(points, key=lambda p: p["multiplier"])
-    checks = result.get("checks", {})
-    out = {
-        "serving_load_capacity_rps": result.get("capacity_rps_est"),
-        "serving_load_peak_tput_rps": max(p["tput_rps"] for p in points),
-        "serving_load_p50_ms": nominal.get("p50_ms"),
-        "serving_load_p99_ms": nominal.get("p99_ms"),
-        "serving_load_p999_ms": nominal.get("p999_ms"),
-        "serving_load_head_p99_overload_ms": overload.get("head_p99_ms"),
-        "serving_load_shed_rate_overload": overload.get("shed_rate"),
-        "serving_load_serial_sheds": checks.get("serial_lane_sheds"),
-        "serving_load_adaptive_adjustments": checks.get(
-            "adaptive_wait_adjustments"
-        ),
-        "serving_load_starved_tenants": len(checks.get("starved_tenants", [])),
-        # the saturation curve itself: offered vs achieved goodput per
-        # point (a list — trend-ignored, human/plot-read)
-        "serving_load_curve": [
-            {
-                "multiplier": p["multiplier"],
-                "offered_rps": p["offered_rps"],
-                "tput_rps": p["tput_rps"],
-                "shed_rate": p["shed_rate"],
-                "p50_ms": p.get("p50_ms"),
-                "p99_ms": p.get("p99_ms"),
-                "p999_ms": p.get("p999_ms"),
-            }
-            for p in points
-        ],
-    }
-    return out
-
-
 def main(argv=None) -> int:
     p = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     p.add_argument("--base", default=None, help="target server URL (default: self-serve)")
@@ -758,7 +713,6 @@ def main(argv=None) -> int:
         profile=args.profile,
         mesh_devices=args.sched_mesh,
     )
-    result["bench"] = bench_keys(result)
     if args.out:
         with open(args.out, "w") as f:
             json.dump(result, f, indent=1)

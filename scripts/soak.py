@@ -886,7 +886,7 @@ def _replay_phase() -> int:
     from phant_tpu.replay import (
         ReplayEngine,
         attach_witnesses,
-        from_bench_tuple,
+        build_synthetic_chain,
     )
     from phant_tpu.replay.engine import (
         STAGE_DISPATCH,
@@ -895,16 +895,12 @@ def _replay_phase() -> int:
         STAGE_RESOLVE,
     )
 
-    from bench import _build_replay_chain
-
     failures: list = []
     stages = (STAGE_PREFETCH, STAGE_PACK, STAGE_DISPATCH, STAGE_RESOLVE)
     prev_sig = os.environ.get("PHANT_BATCHED_SIG")
     os.environ["PHANT_BATCHED_SIG"] = "1"
     try:
-        fix = attach_witnesses(
-            from_bench_tuple(_build_replay_chain(n_blocks=12, txs_per_block=3))
-        )
+        fix = attach_witnesses(build_synthetic_chain(12, 3))
         serial = fix.fresh_chain()
         serial.run_blocks(fix.blocks)
         want_root = serial.state.state_root()

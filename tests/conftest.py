@@ -14,7 +14,7 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["PHANT_ALLOW_JAX_CPU"] = "1"
 # test-suite compile cache: jax segfaults (not raises) on a cache
 # entry corrupted by concurrent writers, so each process CLASS places
-# its own dir — bench and the serving CLI use build/jax_cache, check.sh
+# its own dir — the serving CLI uses build/jax_cache, check.sh
 # groups use build/jax_cache_tests (sequential), and direct pytest
 # invocations default to build/jax_cache_pytest here. The dir is
 # persistent on purpose: a throwaway per-session tmpdir made EVERY

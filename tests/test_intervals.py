@@ -339,9 +339,9 @@ def tpu_backend(monkeypatch):
 def _run_lane(lane: str) -> None:
     if lane == "witness":
         from phant_tpu.ops.witness_engine import WitnessEngine
-        from test_witness_resident import _build_witnesses
+        from _witnesses import build_witnesses
 
-        _root, wits = _build_witnesses(n_blocks=2, picks=2, trie_n=32)
+        _root, wits = build_witnesses(n_blocks=2, picks=2, trie_n=32)
         assert all(WitnessEngine(resident=True, resident_cap=1024).verify_batch(wits))
     elif lane == "keccak":
         from phant_tpu.ops.keccak_jax import keccak256_batch_jax

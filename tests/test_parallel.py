@@ -126,9 +126,9 @@ def test_witness_engine_sharded_hash_path(mesh8, monkeypatch):
     from phant_tpu.mpt.proof import verify_witness_linked
 
     monkeypatch.setenv("PHANT_ENGINE_SHARDED", "1")
-    from bench import build_witnesses
+    from _witnesses import build_witnesses
 
-    witnesses = build_witnesses(6, accounts_per_block=3, trie_size=128)
+    _root, witnesses = build_witnesses(n_blocks=6, picks=3, trie_n=128)
     eng = WitnessEngine(hasher=WitnessEngine._hash_batch_device)
     got = eng.verify_batch(witnesses)
     want = np.array(

@@ -7,9 +7,9 @@ from dataclasses import replace
 
 import pytest
 
-from bench import _build_replay_chain
 from phant_tpu.backend import set_crypto_backend
 from phant_tpu.blockchain.chain import BlockError, Blockchain
+from phant_tpu.replay.fixture import build_synthetic_chain
 from phant_tpu.types.block import Block
 
 
@@ -19,22 +19,12 @@ def _fresh_chain(genesis, fresh_state):
 
 @pytest.fixture(scope="module")
 def small_chain():
-    # _build_replay_chain returns picklable (…, genesis_accounts, …) so the
-    # bench can disk-cache chains; rebuild the fresh_state factory locally
-    from phant_tpu.state.statedb import StateDB
-
-    genesis, blocks, accounts, total, calls = _build_replay_chain(
-        n_blocks=12, txs_per_block=3
-    )
-
-    def fresh_state():
-        return StateDB({a: acct.copy() for a, acct in accounts.items()})
-
-    return genesis, blocks, fresh_state, total, calls
+    fix = build_synthetic_chain(n_blocks=12, txs_per_block=3)
+    return fix.genesis, fix.blocks, fix.fresh_state
 
 
 def test_run_blocks_matches_serial(small_chain, monkeypatch):
-    genesis, blocks, fresh_state, _total, _calls = small_chain
+    genesis, blocks, fresh_state = small_chain
     monkeypatch.setenv("PHANT_TPU_PREFETCH_SIGS", "8")  # force several windows
 
     serial = _fresh_chain(genesis, fresh_state)
@@ -54,7 +44,7 @@ def test_run_blocks_matches_serial(small_chain, monkeypatch):
 def test_run_blocks_invalid_signature_attributed(small_chain, monkeypatch):
     """A corrupt signature prefetched several blocks ahead must fail when
     ITS block runs, with earlier blocks already imported."""
-    genesis, blocks, fresh_state, _total, _calls = small_chain
+    genesis, blocks, fresh_state = small_chain
     monkeypatch.setenv("PHANT_TPU_PREFETCH_SIGS", "6")
     bad_idx = 7
     bad_tx = replace(blocks[bad_idx].transactions[1], r=12345)
@@ -80,7 +70,7 @@ def test_run_blocks_invalid_signature_attributed(small_chain, monkeypatch):
 
 
 def test_run_blocks_cpu_path(small_chain):
-    genesis, blocks, fresh_state, _total, _calls = small_chain
+    genesis, blocks, fresh_state = small_chain
     chain = _fresh_chain(genesis, fresh_state)
     results = chain.run_blocks(blocks)
     assert len(results) == len(blocks)
@@ -92,7 +82,7 @@ def test_run_blocks_survives_device_loss(small_chain, monkeypatch):
     lost / preemption) must degrade to CPU recovery, not sink the import."""
     import phant_tpu.ops.secp256k1_jax as secp_jax
 
-    genesis, blocks, fresh_state, _total, _calls = small_chain
+    genesis, blocks, fresh_state = small_chain
     monkeypatch.setenv("PHANT_TPU_PREFETCH_SIGS", "8")
 
     calls = {"n": 0}

@@ -26,7 +26,6 @@ import pytest
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
-from bench import _build_replay_chain
 from phant_tpu import serving
 from phant_tpu.obs.flight import flight
 from phant_tpu.ops.sig_engine import SigEngine
@@ -34,7 +33,7 @@ from phant_tpu.ops.witness_engine import WitnessEngine
 from phant_tpu.replay import (
     ReplayEngine,
     attach_witnesses,
-    from_bench_tuple,
+    build_synthetic_chain,
     load_fixture,
     save_fixture,
 )
@@ -55,14 +54,19 @@ STAGES = (STAGE_PREFETCH, STAGE_PACK, STAGE_DISPATCH, STAGE_RESOLVE)
 
 @pytest.fixture(scope="module")
 def built():
-    return _build_replay_chain(n_blocks=N_BLOCKS, txs_per_block=TXS_PER_BLOCK)
+    return build_synthetic_chain(N_BLOCKS, TXS_PER_BLOCK)
+
+
+def _own(built):
+    """The module's chain as a fixture a test may edit: its own block list."""
+    return replace(built, blocks=list(built.blocks))
 
 
 @pytest.fixture(scope="module")
 def serial_root(built):
     """The serial `run_blocks` oracle: final state root with per-block
     root verification ON (the fixture headers carry the real roots)."""
-    fix = from_bench_tuple(built)
+    fix = _own(built)
     chain = fix.fresh_chain()
     chain.run_blocks(fix.blocks)
     return chain.state.state_root()
@@ -72,12 +76,12 @@ def serial_root(built):
 def mpt_witnesses(built):
     """Per-block full-state witnesses under the default hexary scheme
     (witness generation is scheme-dependent; roots are not)."""
-    fix = attach_witnesses(from_bench_tuple(built))
+    fix = attach_witnesses(_own(built))
     return fix.witnesses
 
 
 def _witnessed(built, mpt_witnesses):
-    fix = from_bench_tuple(built)
+    fix = _own(built)
     fix.witnesses = list(mpt_witnesses)
     fix.scheme = "mpt"
     return fix
@@ -172,9 +176,9 @@ def test_replay_commitment_scheme_matrix(
     header chain (and the final state root) stays hexary-identical."""
     monkeypatch.setenv("PHANT_COMMITMENT", scheme_name)
     monkeypatch.setenv("PHANT_BATCHED_SIG", "1")
-    fix = attach_witnesses(from_bench_tuple(built))
+    fix = attach_witnesses(_own(built))
     assert fix.scheme == scheme_name
-    # the bench genesis header doesn't carry its state root; compute it
+    # the synthetic genesis header doesn't carry its state root; compute it
     hexary_roots = [fix.fresh_state().state_root()] + [
         b.header.state_root for b in fix.blocks[:-1]
     ]
@@ -203,7 +207,7 @@ def test_replay_commitment_scheme_matrix(
 def test_replay_no_scheduler_local_fallbacks(built, serial_root):
     """With no scheduler installed every stage takes its local megabatch
     fallback — still byte-identical, still one fused batch per segment."""
-    fix = from_bench_tuple(built)
+    fix = _own(built)
     rep = ReplayEngine(segment_blocks=SEGMENT, pipeline_depth=2).run(
         fix.fresh_chain(), fix.blocks
     )
@@ -219,7 +223,7 @@ def test_deferred_segment_roots_device_batched(
     verdicts and final root stay byte-identical and the chain's own
     per-block check is restored on exit."""
     monkeypatch.setenv("PHANT_REPLAY_ROOT", "1")
-    fix = from_bench_tuple(built)
+    fix = _own(built)
     chain = fix.fresh_chain()
     assert chain.verify_state_root is True
     rep = ReplayEngine(segment_blocks=SEGMENT, pipeline_depth=2).run(
@@ -237,7 +241,7 @@ def test_deferred_roots_catch_header_mismatch(built, monkeypatch):
     """Deferred mode still VERIFIES: a tampered header state root fails
     exactly that block at the segment boundary."""
     monkeypatch.setenv("PHANT_REPLAY_ROOT", "1")
-    fix = from_bench_tuple(built)
+    fix = _own(built)
     bad = 7
     hdr = replace(fix.blocks[bad].header, state_root=b"\xde" * 32)
     fix.blocks[bad] = Block(
@@ -286,7 +290,7 @@ def test_corrupt_mid_segment_block_fails_only_that_block(
     from phant_tpu.blockchain.chain import BlockError
 
     monkeypatch.setenv("PHANT_BATCHED_SIG", "1")
-    fix = from_bench_tuple(built)
+    fix = _own(built)
     bad = 7
     bad_tx = replace(
         fix.blocks[bad].transactions[1],
@@ -456,7 +460,7 @@ def test_sig_backlog_counts_rows(built):
             _t.sleep(0.3)
             return np.ones(len(w), bool)
 
-    _genesis, blocks, *_ = built
+    blocks = built.blocks
     signer = TxSigner(1)
     rows = signer.signature_rows(list(blocks[0].transactions))
     s = _lane_sched(engine=_Slow(), pipeline_depth=1, max_wait_ms=1.0)
@@ -482,7 +486,7 @@ def test_run_blocks_windows_ride_sig_lane(built, serial_root, monkeypatch):
     silently bypassing the lane for the raw device path."""
     monkeypatch.setenv("PHANT_BATCHED_SIG", "1")
     monkeypatch.setenv("PHANT_TPU_PREFETCH_SIGS", "8")  # 2-block windows
-    fix = from_bench_tuple(built)
+    fix = _own(built)
     total_txs = fix.total_txs
     engines = []
 
@@ -525,19 +529,13 @@ def test_run_blocks_windows_ride_sig_lane(built, serial_root, monkeypatch):
 def test_fixture_roundtrip_and_cli(
     built, mpt_witnesses, tmp_path, monkeypatch, capsys
 ):
-    """save/load fixture round trip (+ raw bench-tuple acceptance), then
-    the CLI face end-to-end: scheduler lanes, serial-check identity."""
+    """save/load fixture round trip, then the CLI face end-to-end: scheduler lanes, serial-check identity."""
     fix = _witnessed(built, mpt_witnesses)
     p = tmp_path / "chain.fix"
     save_fixture(str(p), fix)
     back = load_fixture(str(p))
     assert back.scheme == "mpt" and len(back.blocks) == N_BLOCKS
     assert back.witnesses == fix.witnesses
-
-    raw = tmp_path / "chain.raw"
-    with open(raw, "wb") as f:
-        pickle.dump(built, f)
-    assert load_fixture(str(raw)).total_txs == fix.total_txs
 
     with open(tmp_path / "junk.fix", "wb") as f:
         pickle.dump({"format": "nope"}, f)

@@ -206,36 +206,20 @@ def test_ecrecover_sharded_matches_single():
     assert (np.asarray(shard_d) == np.asarray(single_d)).all()
 
 
-def test_ecrecover_glv_sharded_matches_single():
-    """dp-sharded GLV ladder over an 8-device mesh == single-device GLV
-    kernel (digests, validity, and degenerate flags)."""
-    import jax.numpy as jnp
-
-    from phant_tpu.parallel import ecrecover_glv_sharded, make_mesh
-
-    rng = np.random.default_rng(23)
-    B = 32
-    msgs, rs, ss, pars = [], [], [], []
-    for i in range(B):
-        key = int.from_bytes(rng.bytes(32), "big") % N or 1
-        msg = keccak256(rng.bytes(16 + i))
-        r, s, par = sign(msg, key)
-        msgs.append(msg)
-        rs.append(r)
-        ss.append(s)
-        pars.append(par)
-    mags, signs = sj.pack_glv_inputs(msgs, rs, ss)
-    r_l = sj.ints_to_limbs(rs)
-    par_a = np.array(pars, np.uint32)
-
-    single_d, single_v, single_g = sj.ecrecover_kernel_glv(
-        jnp.asarray(r_l), jnp.asarray(par_a), jnp.asarray(mags), jnp.asarray(signs)
-    )
-    mesh = make_mesh(8)
-    shard_d, shard_v, shard_g = ecrecover_glv_sharded(mesh, r_l, par_a, mags, signs)
-    assert (np.asarray(shard_v) == np.asarray(single_v)).all()
-    assert (np.asarray(shard_g) == np.asarray(single_g)).all()
-    assert (np.asarray(shard_d) == np.asarray(single_d)).all()
+def test_adversarial_r_equals_gx_matches_cpu():
+    """Signatures whose r is GX (attacker knows dlog of R, so R = +-G and
+    the ladder's adds can meet equal or inverse operands): the public API
+    must agree with the exact CPU recovery for every (z, s) tried."""
+    rng = np.random.default_rng(11)
+    msgs, rs, ss, recids = [], [], [], []
+    for _ in range(32):
+        msgs.append(rng.bytes(32))
+        rs.append(GX)
+        ss.append(int.from_bytes(rng.bytes(32), "big") % N or 1)
+        recids.append(int(rng.integers(0, 2)))
+    got = sj.ecrecover_batch(msgs, rs, ss, recids)
+    for i in range(32):
+        assert got[i] == _cpu_address(msgs[i], rs[i], ss[i], recids[i]), i
 
 
 def test_ecrecover_eip155_canonical_vector():

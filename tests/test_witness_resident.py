@@ -16,10 +16,10 @@ import pytest
 
 from phant_tpu import rlp
 from phant_tpu.crypto.keccak import keccak256
-from phant_tpu.mpt.mpt import Trie
-from phant_tpu.mpt.proof import generate_proof
 from phant_tpu.ops.witness_engine import WitnessEngine
 from phant_tpu.utils.trace import metrics
+
+from _witnesses import build_witnesses
 
 
 @pytest.fixture(autouse=True)
@@ -63,27 +63,6 @@ def engine_core(request, monkeypatch):
     return request.param
 
 
-def _build_witnesses(n_blocks=10, picks=4, trie_n=128, seed=5):
-    rng = np.random.default_rng(seed)
-    trie = Trie()
-    keys = []
-    for _ in range(trie_n):
-        k = keccak256(rng.bytes(20))
-        trie.put(k, rlp.encode([rlp.encode_uint(1), rng.bytes(8)]))
-        keys.append(k)
-    root = trie.root_hash()
-    r = np.random.default_rng(seed + 4)
-    wits = []
-    for _ in range(n_blocks):
-        idx = r.choice(len(keys), size=picks, replace=False)
-        nodes = {}
-        for i in idx:
-            for n in generate_proof(trie, keys[i]):
-                nodes[n] = None
-        wits.append((root, list(nodes.keys())))
-    return root, wits
-
-
 def _with_corruptions(root, wits):
     """The witness set plus every corruption class (expected verdicts
     come from the host oracle, so the classes just need coverage)."""
@@ -115,7 +94,7 @@ def _host_oracle(wits):
 
 
 def test_resident_matches_host_all_cores(engine_core):
-    root, wits = _build_witnesses()
+    root, wits = build_witnesses()
     batch = _with_corruptions(root, wits)
     want = _host_oracle(batch)
     eng = WitnessEngine(resident=True, resident_cap=4096)
@@ -142,7 +121,7 @@ def test_resident_through_scheduler_depths(engine_core):
         VerificationScheduler,
     )
 
-    root, wits = _build_witnesses(n_blocks=12)
+    root, wits = build_witnesses(n_blocks=12)
     batch = list(wits)
     batch[3] = (b"\x11" * 32, batch[3][1])  # corrupt: must stay False
     want = _host_oracle(batch)
@@ -178,7 +157,7 @@ def _node_fps(nodes):
 
 
 def test_device_index_agrees_with_host_map():
-    root, wits = _build_witnesses()
+    root, wits = build_witnesses()
     eng = WitnessEngine(resident=True, resident_cap=4096)
     assert np.asarray(eng.verify_batch(wits)).all()
     table = eng.resident_table()
@@ -233,7 +212,7 @@ def test_resident_generation_flush_under_inflight(engine_core):
     when the pipeline drains, host AND resident tables flush together
     (one generation), and verification after the flush is still
     byte-identical with the uploads starting over."""
-    root, wits = _build_witnesses(n_blocks=8, picks=3)
+    root, wits = build_witnesses(n_blocks=8, picks=3)
     u_first = {n for _r, ns in wits[:4] for n in ns}
     u_all = {n for _r, ns in wits for n in ns}
     assert len(u_all) - len(u_first) >= 2, "fixture lost its novel tail"
@@ -265,7 +244,7 @@ def test_resident_generation_flush_under_inflight(engine_core):
 
 
 def test_reset_releases_resident_table():
-    root, wits = _build_witnesses()
+    root, wits = build_witnesses()
     eng = WitnessEngine(resident=True, resident_cap=4096)
     assert np.asarray(eng.verify_batch(wits)).all()
     table = eng.resident_table()
@@ -280,7 +259,7 @@ def test_reset_releases_resident_table():
 
 
 def test_reset_refuses_inflight():
-    root, wits = _build_witnesses(n_blocks=4)
+    root, wits = build_witnesses(n_blocks=4)
     eng = WitnessEngine(resident=True, resident_cap=4096)
     h = eng.begin_batch(wits)
     with pytest.raises(RuntimeError):
@@ -294,7 +273,7 @@ def test_abandon_keeps_resident_consistent(engine_core):
     stands (rows resident), the host core never committed — the next
     batch re-reports those nodes as novel, the prune skips the
     re-upload, and verdicts stay byte-identical."""
-    root, wits = _build_witnesses(n_blocks=6)
+    root, wits = build_witnesses(n_blocks=6)
     want = _host_oracle(wits)
     eng = WitnessEngine(resident=True, resident_cap=4096)
     h = eng.begin_batch(wits)
@@ -322,8 +301,8 @@ def test_mesh_lanes_keep_independent_resident_tables():
     affinity routing preserves."""
     from phant_tpu.serving.mesh_exec import MeshExecutorPool
 
-    _root_a, wits_a = _build_witnesses(seed=5)
-    _root_b, wits_b = _build_witnesses(seed=17)
+    _root_a, wits_a = build_witnesses(seed=5)
+    _root_b, wits_b = build_witnesses(seed=17)
     pool = MeshExecutorPool(2, prewarm=False)
     try:
         e0, e1 = pool.engines()
@@ -357,7 +336,7 @@ def test_depth_histogram_skew(monkeypatch):
 
     set_crypto_backend("cpu")  # host route: the histogram is route-blind
     monkeypatch.setenv("PHANT_RESIDENT", "0")
-    root, wits = _build_witnesses(n_blocks=24, picks=3, trie_n=256)
+    root, wits = build_witnesses(n_blocks=24, picks=3, trie_n=256)
     eng = WitnessEngine(resident=False, depth_hist=True)
     snap0 = metrics.snapshot()["counters"]
     # replay: every block verified twice (consecutive-span overlap is
@@ -400,7 +379,7 @@ def test_depth_histogram_memo_overflow(monkeypatch):
 
     set_crypto_backend("cpu")
     monkeypatch.setenv("PHANT_RESIDENT", "0")
-    root, wits = _build_witnesses(n_blocks=12, picks=3)
+    root, wits = build_witnesses(n_blocks=12, picks=3)
     eng = WitnessEngine(resident=False, depth_hist=True)
     eng._depth._max = 8  # force an overflow clear on every batch
     assert np.asarray(eng.verify_batch(wits)).all()
