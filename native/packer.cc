@@ -37,6 +37,22 @@ int phant_pack_keccak(const uint8_t* in, const uint64_t* offsets,
   return 0;
 }
 
+// Lay payload i out as row i of out (row_bytes a row), zero past its length
+// and with NO keccak padding: the row form the device-resident witness table
+// hashes and parses (phant_tpu/ops/witness_jax.py pack_node_rows). out must
+// be zero-initialised to rows * row_bytes by the caller. Returns 0 on
+// success, -1 if a payload does not leave the row one free byte (the
+// device pads inside the row).
+int phant_pack_rows(const uint8_t* in, const uint64_t* offsets,
+                    const uint32_t* lens, size_t n, size_t row_bytes,
+                    uint8_t* out) {
+  for (size_t i = 0; i < n; ++i) {
+    if (lens[i] >= row_bytes) return -1;
+    std::memcpy(out + i * row_bytes, in + offsets[i], lens[i]);
+  }
+  return 0;
+}
+
 // --- witness child-ref scanner ---------------------------------------------
 // Finds the byte offsets (into the witness blob) of every child hash
 // reference inside each RLP trie node: the 32-byte string children of a

@@ -31,6 +31,9 @@ void phant_keccak256_batch_fast(const uint8_t* in, const uint64_t* offsets,
 int phant_pack_keccak(const uint8_t* in, const uint64_t* offsets,
                       const uint32_t* lens, size_t n, size_t max_chunks,
                       uint8_t* out, int32_t* nchunks);
+int phant_pack_rows(const uint8_t* in, const uint64_t* offsets,
+                    const uint32_t* lens, size_t n, size_t row_bytes,
+                    uint8_t* out);
 long phant_scan_refs(const uint8_t* blob, const uint64_t* offsets,
                      const uint32_t* lens, size_t n, int64_t* out_off,
                      int32_t* out_node, size_t cap);
@@ -129,6 +132,21 @@ static void test_packer() {
   expect(phant_pack_keccak(huge.data(), off0, big, 1, max_chunks, out.data(),
                            nchunks) != 0,
          "oversize payload rejected");
+  // the row form: the payload, then zeros, no padding bytes
+  std::vector<uint8_t> rows(3 * max_chunks * kRate, 0);
+  expect(phant_pack_rows(payloads.data(), offsets, lens, 3, max_chunks * kRate,
+                         rows.data()) == 0,
+         "rows ok");
+  for (int i = 0; i < 3; ++i) {
+    const uint8_t* row = rows.data() + i * max_chunks * kRate;
+    expect(std::memcmp(row, payloads.data() + offsets[i], lens[i]) == 0,
+           "row holds its payload");
+    expect(row[lens[i]] == 0 && row[max_chunks * kRate - 1] == 0,
+           "row is zero past its payload");
+  }
+  expect(phant_pack_rows(huge.data(), off0, big, 1, max_chunks * kRate,
+                         rows.data()) != 0,
+         "row without a free byte rejected");
   std::puts("packer OK");
 }
 

@@ -313,7 +313,8 @@ def keccak256_batch_jax(payloads: Sequence[bytes], max_chunks: int | None = None
 #: deterministically goes to the lowest slot id.
 INDEX_EMPTY = 1 << 30
 
-#: probe-sequence bound (a fori_loop trip count). With the index sized
+#: probe-sequence bound (the most rounds an insert or a lookup makes).
+#: With the index sized
 #: at 4x the row capacity (load factor <= 0.25; measured: 2x/16 probes
 #: dropped 17 of 32k inserts — linear-probe clusters grow fast with
 #: load), clusters beyond this bound are vanishingly rare; inserts that
@@ -343,25 +344,30 @@ def index_insert(
 
     Returns (index, dropped): dropped counts rows still unplaced after
     INDEX_PROBES rounds (they stay resident by ROW — only device-side
-    lookup misses them)."""
+    lookup misses them). The rounds stop when nothing is pending: at the
+    table's load (under 0.25) one or two place a whole batch."""
     mask = jnp.uint32(index.shape[0] - 1)
     h = fingerprint_mix(new_fps[:, 0], new_fps[:, 1])
     empty = jnp.int32(INDEX_EMPTY)
 
-    def body(rnd, carry):
-        # a fori_loop, not an unrolled Python loop: one compiled body
-        # (the unrolled form made XLA chew through PROBES scatter/gather
+    def body(carry):
+        # a loop, not an unrolled Python loop: one compiled body (the
+        # unrolled form made XLA chew through PROBES scatter/gather
         # rounds at trace time — minutes of compile on the CPU backend)
-        index, pending = carry
-        pos = ((h + rnd.astype(jnp.uint32)) & mask).astype(jnp.int32)
+        rnd, index, pending = carry
+        pos = ((h + rnd) & mask).astype(jnp.int32)
         cur = index[pos]
         want = pending & (cur >= empty)
         bid = jnp.where(want, slots, empty)
         index = index.at[pos].min(bid)
         won = want & (index[pos] == slots)
-        return index, pending & ~won
+        return rnd + 1, index, pending & ~won
 
-    index, pending = jax.lax.fori_loop(0, INDEX_PROBES, body, (index, live))
+    _, index, pending = jax.lax.while_loop(
+        lambda c: (c[0] < INDEX_PROBES) & c[2].any(),
+        body,
+        (jnp.uint32(0), index, live),
+    )
     return index, pending.sum(dtype=jnp.int32)
 
 

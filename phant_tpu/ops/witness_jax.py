@@ -29,6 +29,8 @@ from phant_tpu.ops.keccak_jax import keccak256_chunked_auto
 # and 576 < 5 * 136. Shared by __graft_entry__.py / tests.
 WITNESS_MAX_CHUNKS = 5
 
+CHUNK_WORDS = RATE // 4  # u32 words of one rate chunk of a node row
+
 
 def _pow2ceil(n: int) -> int:
     p = 1
@@ -46,21 +48,38 @@ def _gather_node_rows(blob, offsets, lens, row: int):
     return jnp.where(pos < lens[:, None], data, jnp.uint8(0))
 
 
-def _digests_from_rows(data, lens, *, max_chunks: int):
-    """Keccak-pad gathered node rows and hash them (shared by the meta and
-    fused kernels so a fused program hashes the same rows it parses)."""
-    row = max_chunks * RATE
-    pos = jnp.arange(row, dtype=jnp.int32)[None, :]
+def _row_words(data):
+    """(B, row // 4) u32 little-endian words of (B, row) u8 node rows: the
+    ROW FORM every per-node function below takes. A blob-form program packs
+    the rows it gathered once; the resident update is handed this form by
+    the host (`pack_node_rows`) and never sees a byte-granular array."""
+    b = data.reshape(data.shape[0], -1, 4).astype(jnp.uint32)
+    return b[..., 0] | (b[..., 1] << 8) | (b[..., 2] << 16) | (b[..., 3] << 24)
+
+
+def _digests_from_rows(words, lens, *, max_chunks: int):
+    """Keccak-pad node rows (u32 words, zero past each node's length) and
+    hash them (shared by the meta, fused and resident programs, so each
+    hashes the same rows it parses)."""
+    wpos = jnp.arange(max_chunks * CHUNK_WORDS, dtype=jnp.int32)[None, :]
     # keccak multi-rate padding: 0x01 after the payload, 0x80 at the end of
-    # the last rate block
+    # the last rate block, each XORed into the word that holds its byte
     nchunks = lens // RATE + 1
-    pad01 = (pos == lens[:, None]).astype(jnp.uint8)
-    pad80 = (pos == nchunks[:, None] * RATE - 1).astype(jnp.uint8) << 7
-    padded = data ^ pad01 ^ pad80
-    # u8 -> little-endian u32 lanes
-    b = padded.reshape(padded.shape[0], max_chunks, RATE // 4, 4).astype(jnp.uint32)
-    words = b[..., 0] | (b[..., 1] << 8) | (b[..., 2] << 16) | (b[..., 3] << 24)
-    return keccak256_chunked_auto(words, nchunks, max_chunks=max_chunks)
+    pad01 = jnp.uint32(1) << (8 * (lens & 3)).astype(jnp.uint32)
+    padded = (
+        words
+        ^ jnp.where(wpos == (lens >> 2)[:, None], pad01[:, None], jnp.uint32(0))
+        ^ jnp.where(
+            wpos == (nchunks * CHUNK_WORDS - 1)[:, None],
+            jnp.uint32(0x80000000),
+            jnp.uint32(0),
+        )
+    )
+    return keccak256_chunked_auto(
+        padded.reshape(words.shape[0], max_chunks, CHUNK_WORDS),
+        nchunks,
+        max_chunks=max_chunks,
+    )
 
 
 @functools.partial(jax.jit, static_argnames=("max_chunks",))
@@ -83,8 +102,8 @@ def witness_digests(
     Returns:
       (B, 8) uint32 digests (little-endian words).
     """
-    data = _gather_node_rows(blob, offsets, lens, max_chunks * RATE)
-    return _digests_from_rows(data, lens, max_chunks=max_chunks)
+    words = _row_words(_gather_node_rows(blob, offsets, lens, max_chunks * RATE))
+    return _digests_from_rows(words, lens, max_chunks=max_chunks)
 
 
 # ---------------------------------------------------------------------------
@@ -244,21 +263,37 @@ def witness_verify_linked(
 # ---------------------------------------------------------------------------
 
 
-def _take_at(data, idx):
-    """(B,) byte of each node row at per-node position idx (clamped)."""
-    j = jnp.clip(idx, 0, data.shape[1] - 1)
-    return jnp.take_along_axis(data, j[:, None], axis=1)[:, 0].astype(jnp.int32)
+def _bytes_at(words, pos):
+    """(B,) u32 holding the four bytes of each node row at byte positions
+    pos .. pos+3, lowest first; bytes past the row's end read 0 (as a row
+    reads past its node's length). Two dense compare-select-sum passes over
+    the row's words and a funnel shift: no gather, whatever the position."""
+    q = pos >> 2
+    wpos = jnp.arange(words.shape[1], dtype=jnp.int32)[None, :]
+    zero = jnp.uint32(0)
+    w0 = jnp.sum(jnp.where(wpos == q[:, None], words, zero), axis=1, dtype=jnp.uint32)
+    w1 = jnp.sum(
+        jnp.where(wpos == q[:, None] + 1, words, zero), axis=1, dtype=jnp.uint32
+    )
+    sh = (8 * (pos & 3)).astype(jnp.uint32)
+    return jnp.where(sh == 0, w0, (w0 >> sh) | (w1 << (32 - sh)))
 
 
-def _decode_rlp_header(data, pos):
+def _take_at(words, idx):
+    """(B,) byte of each node row at per-node byte position idx."""
+    return (_bytes_at(words, idx) & 0xFF).astype(jnp.int32)
+
+
+def _decode_rlp_header(words, pos):
     """Vectorized RLP item-header decode at per-node byte position `pos`.
 
     Returns (payload_start, payload_len, next_pos, ok, is_list, is_ref)
     where is_ref flags exactly the 0xa0 header (32-byte string). Length-of-
     length > 2 cannot occur in <=679B nodes and flags not-ok."""
-    b0 = _take_at(data, pos)
-    b1 = _take_at(data, pos + 1)
-    b2 = _take_at(data, pos + 2)
+    head = _bytes_at(words, pos)
+    b0 = (head & 0xFF).astype(jnp.int32)
+    b1 = ((head >> 8) & 0xFF).astype(jnp.int32)
+    b2 = ((head >> 16) & 0xFF).astype(jnp.int32)
     single = b0 < 0x80
     short_str = (b0 >= 0x80) & (b0 <= 0xB7)
     long_str = (b0 >= 0xB8) & (b0 <= 0xBF)
@@ -277,13 +312,13 @@ def _decode_rlp_header(data, pos):
     return ps, plen, ps + plen, lnl <= 2, short_list | long_list, b0 == 0xA0
 
 
-def _extract_ref_positions(data, lens):
+def _extract_ref_positions(words, lens):
     """(B, 17) int32 node-relative offsets of every child hash reference
     (-1 = no ref in that slot). Slots 0..15 are branch children; slot 16 is
     the extension child or the account-leaf storage root."""
     end = lens.astype(jnp.int32)
     zero = jnp.zeros_like(end)
-    ps0, _plen0, pe0, ok0, islist0, _ = _decode_rlp_header(data, zero)
+    ps0, _plen0, pe0, ok0, islist0, _ = _decode_rlp_header(words, zero)
     bad = ~(ok0 & islist0 & (pe0 == end) & (end > 0))
 
     pos = ps0
@@ -292,7 +327,7 @@ def _extract_ref_positions(data, lens):
     item_ref = []
     item_valid = []
     for _k in range(17):
-        ps, _plen, nxt, ok, is_list, is_ref = _decode_rlp_header(data, pos)
+        ps, _plen, nxt, ok, is_list, is_ref = _decode_rlp_header(words, pos)
         valid = (pos < end) & ~bad
         overrun = valid & (~ok | (nxt > end))
         bad = bad | overrun
@@ -314,7 +349,7 @@ def _extract_ref_positions(data, lens):
     ]
 
     # pair: hex-prefix flag byte of item 0 (empty path = malformed)
-    p0 = _take_at(data, item_ps[0])
+    p0 = _take_at(words, item_ps[0])
     nonempty0 = (item_pe[0] - item_ps[0]) > 0
     is_ext = is_pair & nonempty0 & ((p0 & 0x20) == 0)
     is_leaf = is_pair & nonempty0 & ((p0 & 0x20) != 0)
@@ -323,16 +358,16 @@ def _extract_ref_positions(data, lens):
     # leaf: item1 must be a string whose content is a 4-string account list
     # with 32-byte items 2 and 3 (mirrors _account_storage_root_off)
     v_ps, v_pe = item_ps[1], item_pe[1]
-    l_ps, _lp, l_pe, l_ok, l_islist, _ = _decode_rlp_header(data, v_ps)
+    l_ps, _lp, l_pe, l_ok, l_islist, _ = _decode_rlp_header(words, v_ps)
     acct = is_leaf & ~item_ref[1] & l_ok & l_islist & (l_pe == v_pe)
-    q_ps, _qp, q_pe, q_ok, q_islist, _ = _decode_rlp_header(data, l_ps)  # nonce
+    q_ps, _qp, q_pe, q_ok, q_islist, _ = _decode_rlp_header(words, l_ps)  # nonce
     acct = acct & q_ok & ~q_islist & (q_pe <= l_pe)
-    r_ps, _rp, r_pe, r_ok, r_islist, _ = _decode_rlp_header(data, q_pe)  # balance
+    r_ps, _rp, r_pe, r_ok, r_islist, _ = _decode_rlp_header(words, q_pe)  # balance
     acct = acct & r_ok & ~r_islist & (r_pe <= l_pe)
     acct = (
         acct
-        & (_take_at(data, r_pe) == 0xA0)
-        & (_take_at(data, r_pe + 33) == 0xA0)
+        & (_take_at(words, r_pe) == 0xA0)
+        & (_take_at(words, r_pe + 33) == 0xA0)
         & (r_pe + 66 == l_pe)
     )
     leaf_ref = jnp.where(acct, r_pe + 1, -1)
@@ -341,34 +376,60 @@ def _extract_ref_positions(data, lens):
     return jnp.stack(branch_refs + [slot16], axis=1)
 
 
-def _ref_words_from_rows(data, ref_pos):
-    """(B, 17, 8) u32 LE words of the 32-byte refs at node-relative ref_pos
-    (dead slots gather garbage; callers mask with ref_pos >= 0)."""
-    B = data.shape[0]
-    idx = jnp.clip(ref_pos, 0, data.shape[1] - 33)[:, :, None] + jnp.arange(
-        32, dtype=jnp.int32
-    )[None, None, :]
-    b = jnp.take_along_axis(data, idx.reshape(B, -1), axis=1).reshape(
-        B, 17, 8, 4
-    ).astype(jnp.uint32)
-    return b[..., 0] | (b[..., 1] << 8) | (b[..., 2] << 16) | (b[..., 3] << 24)
+def _ref_words_from_rows(words, ref_pos):
+    """(B, 17, 8) u32 LE words of the 32-byte refs at node-relative byte
+    positions ref_pos; a dead slot (ref_pos < 0) reads all zero.
+
+    A ref at byte p lies in the nine row words from p >> 2 on. They are
+    brought to the front by a barrel shifter over the row's words — one
+    select between two static slices per bit of the word offset, the
+    window narrowing as the remaining shift does — and a funnel shift by
+    the byte remainder makes the eight ref words: dense moves whose unit
+    is the row, where a byte gather moved 17 x 32 single bytes a node."""
+    B, W = words.shape
+    live = ref_pos >= 0
+    p = jnp.where(live, ref_pos, 0)
+    q = (p >> 2)[:, :, None]
+    top = 1
+    while 2 * top < W:
+        top *= 2
+    x = jnp.pad(words, ((0, 0), (0, max(0, 2 * top + 8 - W))))[:, None, :]
+    bit = top
+    while bit:
+        keep = bit + 8  # the shifts still to come are below `bit`, then 9 words
+        x = jnp.where((q & bit) != 0, x[..., bit : bit + keep], x[..., :keep])
+        bit //= 2
+    sh = (8 * (p & 3)).astype(jnp.uint32)[:, :, None]
+    lo, hi = x[..., :8], x[..., 1:9]
+    out = jnp.where(sh == 0, lo, (lo >> sh) | (hi << (32 - sh)))
+    return jnp.where(live[:, :, None], out, jnp.uint32(0))
+
+
+def node_row_features(words, lens, *, max_chunks: int):
+    """(digests, ref_words, ref_live) of node rows in the row form: (B,
+    max_chunks * 34) u32 words, one node a row, zero past its length
+    (`lens`; 0 = a pad row). The per-node features the device-resident
+    intern table persists (ops/witness_resident.py): digest (B, 8), the
+    up-to-17 child-hash reference words (B, 17, 8; dead slots zero) and
+    which ref slots are live (B, 17). Composes inside jit. The hashing and
+    the ref parse are the functions `witness_verify_fused` runs inline, on
+    the same bytes: the two can never diverge on ref semantics (malformed
+    nodes are ref-less on both)."""
+    digests = _digests_from_rows(words, lens, max_chunks=max_chunks)
+    ref_pos = _extract_ref_positions(words, lens)
+    refs = _ref_words_from_rows(words, ref_pos)
+    ref_live = (ref_pos >= 0) & (lens[:, None] > 0)
+    return digests, refs, ref_live
 
 
 def witness_node_features(blob, offsets, lens, *, max_chunks: int):
-    """(digests, ref_words, ref_live) of every node sliced out of `blob` —
-    the per-node features the device-resident intern table persists
-    (ops/witness_resident.py): digest (B, 8), the up-to-17 child-hash
-    reference words (B, 17, 8), and which ref slots are live (B, 17).
-    Composes inside jit; exactly the gather/hash/ref-extraction pipeline
-    of `witness_verify_fused`, factored so the resident update scatters
-    the SAME features the fused kernel computes inline (the two can never
-    diverge on ref semantics — malformed nodes are ref-less on both)."""
-    data = _gather_node_rows(blob, offsets, lens, max_chunks * RATE)
-    digests = _digests_from_rows(data, lens, max_chunks=max_chunks)
-    ref_pos = _extract_ref_positions(data, lens)
-    refs = _ref_words_from_rows(data, ref_pos)
-    ref_live = (ref_pos >= 0) & (lens[:, None] > 0)
-    return digests, refs, ref_live
+    """`node_row_features` of every node sliced out of `blob` on the
+    device: the BLOB form, one byte gather a row position
+    (`_gather_node_rows`). The resident update left it in PR 31 (the host
+    lays the rows out, `pack_node_rows`); it stays as the form the row
+    form is tested against, and for callers that hold a blob."""
+    words = _row_words(_gather_node_rows(blob, offsets, lens, max_chunks * RATE))
+    return node_row_features(words, lens, max_chunks=max_chunks)
 
 
 @functools.partial(jax.jit, static_argnames=("max_chunks", "n_blocks"))
@@ -394,10 +455,10 @@ def witness_verify_fused(
     lens = meta16[0].astype(jnp.int32)
     block_id = meta16[1].astype(jnp.int32)
     offsets = jnp.cumsum(lens) - lens  # exclusive
-    data = _gather_node_rows(blob, offsets, lens, max_chunks * RATE)
-    digests = _digests_from_rows(data, lens, max_chunks=max_chunks)
-    ref_pos = _extract_ref_positions(data, lens)
-    refs = _ref_words_from_rows(data, ref_pos).reshape(-1, 8)
+    words = _row_words(_gather_node_rows(blob, offsets, lens, max_chunks * RATE))
+    digests = _digests_from_rows(words, lens, max_chunks=max_chunks)
+    ref_pos = _extract_ref_positions(words, lens)
+    refs = _ref_words_from_rows(words, ref_pos).reshape(-1, 8)
     ref_live = (ref_pos >= 0).reshape(-1)
     ref_block = jnp.broadcast_to(block_id[:, None], ref_pos.shape).reshape(-1)
     root_hit, all_ok = linked_verdict(
@@ -484,6 +545,52 @@ def pack_witness_blob(
     meta[2, :B] = np.repeat(np.arange(len(node_lists), dtype=np.int32), counts)
     blob = np.frombuffer(b"".join(parts) + b"\x00" * (max_chunks * RATE), dtype=np.uint8)
     return blob, meta
+
+
+def _pack_rows_np(nodes: Sequence[bytes], rows: int, row_bytes: int) -> np.ndarray:
+    """numpy twin of native `pack_rows` (one flat scatter of the joined
+    bytes): the form where the native library is absent."""
+    lens = np.fromiter(map(len, nodes), np.int64, len(nodes))
+    if len(nodes) and int(lens.max()) >= row_bytes:
+        raise ValueError(f"payload fills its row of {row_bytes} bytes")
+    buf = np.zeros(rows * row_bytes, np.uint8)
+    total = int(lens.sum())
+    if total:
+        # byte j of the join lands at its row's start plus its offset in
+        # the node: row_start - node_start, repeated over the node's bytes
+        shift = np.arange(len(nodes), dtype=np.int64) * row_bytes - (
+            np.cumsum(lens) - lens
+        )
+        buf[np.arange(total, dtype=np.int64) + np.repeat(shift, lens)] = (
+            np.frombuffer(b"".join(nodes), np.uint8)
+        )
+    return buf.reshape(rows, row_bytes)
+
+
+def pack_node_rows(
+    nodes: Sequence[bytes], max_chunks: int, pad_rows_to: int | None = None
+) -> Tuple[np.ndarray, np.ndarray]:
+    """(words, lens) — the ROW FORM of `nodes` for `node_row_features`:
+    words (R, max_chunks * 34) u32, node i the little-endian words of row i,
+    zero past its length; lens (R,) int32, 0 in the pad rows. R is
+    `pad_rows_to` (default: the next power of two). One native memcpy loop
+    (native/packer.cc phant_pack_rows), numpy where the library is absent.
+    The upload is R * max_chunks * 136 bytes whatever the nodes hold: the
+    padding the blob form gathered on the device is laid out on the host."""
+    from phant_tpu.utils.native import load_native
+
+    row_bytes = max_chunks * RATE
+    rows = _pow2ceil(len(nodes)) if pad_rows_to is None else pad_rows_to
+    if len(nodes) > rows:
+        raise ValueError(f"{len(nodes)} nodes exceed pad_rows_to={rows}")
+    native = load_native()
+    if native is not None:
+        buf = native.pack_rows(nodes, rows, row_bytes)
+    else:
+        buf = _pack_rows_np(nodes, rows, row_bytes)
+    lens = np.zeros(rows, np.int32)
+    lens[: len(nodes)] = np.fromiter(map(len, nodes), np.int32, len(nodes))
+    return buf.view("<u4"), lens
 
 
 def roots_to_words(roots: Sequence[bytes]) -> np.ndarray:

@@ -12,9 +12,15 @@ This module keeps the intern table ON the device, persistent across
 batches:
 
   * **Resident rows** — `digests` (cap, 8) u32, the child-reference
-    words `refs` (cap, 17, 8) and their liveness (cap, 17), one row per
-    unique interned node, scattered in place by the update program the
-    moment a novel batch is dispatched. Rows are assigned by the HOST
+    words `refs` (cap, 128: slots 0..15, a branch's children) and `tail`
+    (cap, 9: slot 16, an extension's child or an account leaf's storage
+    root, then the liveness of all 17 as a bit mask), one row per unique
+    interned node, scattered in place by the update program the moment a
+    novel batch is dispatched. The shapes are the ones XLA writes and
+    reads a row at a time on the TPU: a (cap, 17, 8) table is kept with
+    the row index minor-most and was re-laid out whole, 4.3 GB of
+    temporaries at 2^20 rows, by every update (PERF.md section 5, PR 31).
+    Rows are assigned by the HOST
     (`slot_of_bytes`, the authoritative commit — exact byte equality,
     no fingerprint trust on the verdict path) and grow in power-of-two
     generations; a generation FLUSH drops everything and is synchronized
@@ -26,9 +32,18 @@ batches:
     (host rows are exact); it is the DEVICE-side scan: it resolves
     rows on device from fingerprints alone (8 bytes/node up, nothing
     else), and tests cross-check it against the host dict.
-  * **Per-batch traffic** — truly-novel bytes (the host scan prunes
+  * **Per-batch traffic** — the truly-novel nodes (the host scan prunes
     anything already resident, including cross-batch pipelined
-    duplicates the engine cores re-report) + 4 bytes/node of row ids +
+    duplicates the engine cores re-report) in the ROW FORM: one row of
+    680 bytes a node, zero past its length, the row count padded to a
+    power of two (`witness_jax.pack_node_rows`: one native memcpy loop),
+    with 8 bytes a row of length and slot. A lone block's 1,400 novel
+    nodes of 0.47 MB go up as 2,048 rows, 1.39 MB: three times the
+    bytes, 0.26 ms more on the link, and in exchange the update program
+    moves no single byte (as a blob it moved 2.5 M of them a batch
+    through XLA's gather, 25 of its 46 ms) and its jit key holds no byte
+    count (`witness_resident.update_rows` / `update_bytes` /
+    `update_programs` on /metrics). Then 4 bytes/node of row ids +
     32 bytes/block of roots up; 1 byte/block of verdicts + 32 bytes per
     CORE-novel digest down (the engine's host tables commit from the
     device digests, so the host hashes nothing on this route). Steady
@@ -59,7 +74,11 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from phant_tpu.utils.trace import device_host, metrics
-from phant_tpu.ops.witness_jax import WITNESS_MAX_CHUNKS, _pow2ceil
+from phant_tpu.crypto.keccak import RATE
+from phant_tpu.ops.witness_jax import WITNESS_MAX_CHUNKS, _pow2ceil, pack_node_rows
+
+#: bytes of one node row; a node must leave one free for the keccak pad
+_ROW_BYTES = WITNESS_MAX_CHUNKS * RATE
 
 __all__ = [
     "ResidentBatch",
@@ -69,9 +88,10 @@ __all__ = [
 
 
 def resident_default_cap() -> int:
-    """PHANT_RESIDENT_CAP: hard row bound of a resident table (~613 B of
-    HBM per row: digest + 17 ref words + liveness + fingerprint + 2
-    index buckets). The default fits comfortably in a v5e's 16 GB."""
+    """PHANT_RESIDENT_CAP: hard row bound of a resident table (~632 B of
+    HBM per row: digest + 17 ref words + liveness bits + fingerprint + 4
+    index buckets, the narrow arrays padded to whole tiles). The default
+    fits comfortably in a v5e's 16 GB."""
     return int(os.environ.get("PHANT_RESIDENT_CAP", 1 << 20))
 
 
@@ -80,29 +100,48 @@ def resident_default_cap() -> int:
 # ---------------------------------------------------------------------------
 
 
-def _update_impl(digests, refs, ref_live, index, fps, blob, offsets, lens, slots, *, max_chunks):
-    """Scatter one novel batch into the resident arrays: hash the nodes,
-    extract their child references, write rows at the host-assigned
+def _update_impl(digests, refs, tail, index, fps, words, lens, slots, *, max_chunks):
+    """Scatter one novel batch into the resident arrays: hash the node
+    rows, extract their child references, write rows at the host-assigned
     slots, insert digest fingerprints into the index. Pad rows carry
-    slot -1 and drop out of bounds."""
+    slot -1 and drop out of bounds. The batch arrives in the row form
+    (`witness_jax.pack_node_rows`): every move of this program has a row
+    for its unit, and its cost follows the batch, not the table."""
     import jax.numpy as jnp
 
     from phant_tpu.ops.keccak_jax import index_insert
-    from phant_tpu.ops.witness_jax import witness_node_features
+    from phant_tpu.ops.witness_jax import node_row_features
 
     cap = digests.shape[0]
-    d, r, rl = witness_node_features(blob, offsets, lens, max_chunks=max_chunks)
+    d, r, rl = node_row_features(words, lens, max_chunks=max_chunks)
     ok = slots >= 0
     tgt = jnp.where(ok, slots, cap)  # out of bounds -> dropped by the mode
+    live_bits = jnp.sum(
+        rl.astype(jnp.uint32) << jnp.arange(17, dtype=jnp.uint32),
+        axis=1,
+        dtype=jnp.uint32,
+    )
     digests = digests.at[tgt].set(d, mode="drop")
-    refs = refs.at[tgt].set(r, mode="drop")
-    ref_live = ref_live.at[tgt].set(rl, mode="drop")
+    refs = refs.at[tgt].set(r[:, :16].reshape(-1, 128), mode="drop")
+    tail = tail.at[tgt].set(
+        jnp.concatenate([r[:, 16], live_bits[:, None]], axis=1), mode="drop"
+    )
     fps = fps.at[tgt].set(d[:, :2], mode="drop")
     index, dropped = index_insert(index, d[:, :2], slots, ok)
-    return digests, refs, ref_live, index, fps, dropped
+    return digests, refs, tail, index, fps, dropped
 
 
-def _verdict_impl(digests, refs, ref_live, rows, node_live, block_id, roots):
+def _rows_at(refs, tail, rc):
+    """((B, 17, 8) ref words, (B, 17) liveness) of the resident rows rc."""
+    import jax.numpy as jnp
+
+    t = tail[rc]
+    r17 = jnp.concatenate([refs[rc].reshape(-1, 16, 8), t[:, None, :8]], axis=1)
+    live = ((t[:, 8:9] >> jnp.arange(17, dtype=jnp.uint32)) & 1) != 0
+    return r17, live
+
+
+def _verdict_impl(digests, refs, tail, rows, node_live, block_id, roots):
     """(n_blocks,) bool linked-multiproof verdict from resident rows.
 
     `node_live` marks real nodes (False = batch padding); a live node
@@ -119,8 +158,8 @@ def _verdict_impl(digests, refs, ref_live, rows, node_live, block_id, roots):
     present = node_live & (rows >= 0)
     rc = jnp.clip(rows, 0, cap - 1)
     d = digests[rc]  # (B, 8); garbage for non-present rows, masked below
-    r17 = refs[rc]  # (B, 17, 8)
-    rl = (ref_live[rc] & present[:, None]).reshape(-1)
+    r17, live = _rows_at(refs, tail, rc)
+    rl = (live & present[:, None]).reshape(-1)
     rb = jnp.broadcast_to(block_id[:, None], (rows.shape[0], 17)).reshape(-1)
     is_root = jnp.all(d == roots[block_id], axis=1) & present
     referenced = _referenced(d, block_id, r17.reshape(-1, 8), rb, rl)
@@ -166,6 +205,11 @@ def _lookup_impl(index, fps, q):
 
 _JIT_PROGRAMS: dict = {}
 _JIT_LOCK = threading.Lock()
+
+#: the (device, table rows, batch rows) triples `_update_impl` has run on
+#: in this process (`witness_resident.update_programs`): the row form has
+#: no blob length in its key, so a served table adds one per batch size
+_update_shapes: set = set()
 
 
 def _jit_programs(donate: bool) -> dict:
@@ -282,7 +326,7 @@ class ResidentTable:
         self._slot_of_bytes: Dict[bytes, int] = {}
         self._n_rows = 0
         self._cap = 0
-        self._arrays = None  # (digests, refs, ref_live, index, fps)
+        self._arrays = None  # (digests, refs, tail, index, fps)
         self._deferred_dropped: list = []  # reindex drop counts, unread
         self.generation = 0
         self.stats = {
@@ -298,7 +342,7 @@ class ResidentTable:
         # mesh pool builds one table per lane, and per-table jit wrappers
         # would recompile the same HLO once per lane). Buffer DONATION is
         # enabled on real accelerators so the update rewrites the
-        # resident arrays in place instead of copying ~cap*613B per
+        # resident arrays in place instead of copying ~cap*632B per
         # novel batch; the CPU backend does not support donation and
         # would warn per call.
         fns = _jit_programs(on_device)
@@ -323,8 +367,8 @@ class ResidentTable:
         self._cap = cap
         self._arrays = (
             self._put(np.zeros((cap, 8), np.uint32)),
-            self._put(np.zeros((cap, 17, 8), np.uint32)),
-            self._put(np.zeros((cap, 17), bool)),
+            self._put(np.zeros((cap, 128), np.uint32)),
+            self._put(np.zeros((cap, 9), np.uint32)),
             self._put(np.full((4 * cap,), INDEX_EMPTY, np.int32)),
             self._put(np.zeros((cap, 2), np.uint32)),
         )
@@ -346,15 +390,12 @@ class ResidentTable:
             new_cap *= 2
         if new_cap <= self._cap:
             return
-        d, r, rl, _idx, fps = self._arrays
-        pad = new_cap - self._cap
-        d = jnp.pad(d, ((0, pad), (0, 0)))
-        r = jnp.pad(r, ((0, pad), (0, 0), (0, 0)))
-        rl = jnp.pad(rl, ((0, pad), (0, 0)))
-        fps = jnp.pad(fps, ((0, pad), (0, 0)))
+        pad = ((0, new_cap - self._cap), (0, 0))
+        d, r, t, _idx, fps = self._arrays
+        d, r, t, fps = jnp.pad(d, pad), jnp.pad(r, pad), jnp.pad(t, pad), jnp.pad(fps, pad)
         idx, dropped = self._reindex_fn(fps, jnp.int32(self._n_rows))
         self._deferred_dropped.append(dropped)
-        self._arrays = (d, r, rl, idx, fps)
+        self._arrays = (d, r, t, idx, fps)
         self._cap = new_cap
         self.stats["grows"] += 1
 
@@ -388,12 +429,9 @@ class ResidentTable:
         max_cap, are silently dropped from the device set: the HOST
         keeps them pinned and the prune re-uploads on next use — a perf
         miss, never an inconsistency."""
-        from phant_tpu.crypto.keccak import RATE
-
-        limit = WITNESS_MAX_CHUNKS * RATE
         with self._lock:
             self._flush_locked()
-            keep = [n for n in nodes if len(n) < limit][: self._max_cap]
+            keep = [n for n in nodes if len(n) < _ROW_BYTES][: self._max_cap]
             if not keep:
                 return
             self._grow_locked(len(keep))
@@ -401,29 +439,7 @@ class ResidentTable:
             for j, nb in enumerate(keep):
                 sob[nb] = j
             self._n_rows = len(keep)
-            raw = b"".join(keep)
-            blob_len = _pow2ceil(len(raw) + WITNESS_MAX_CHUNKS * RATE)
-            np_b = _pow2ceil(len(keep))
-            blob = np.zeros(blob_len, np.uint8)
-            blob[: len(raw)] = np.frombuffer(raw, np.uint8)
-            lens = np.zeros(np_b, np.int32)
-            lens[: len(keep)] = [len(nb) for nb in keep]
-            offsets = np.zeros(np_b, np.int32)
-            np.cumsum(lens[:-1], out=offsets[1:])
-            slots = np.full(np_b, -1, np.int32)
-            slots[: len(keep)] = np.arange(len(keep), dtype=np.int32)
-            out = self._update_fn(
-                *self._arrays,
-                self._put(blob),
-                self._put(offsets),
-                self._put(lens),
-                self._put(slots),
-                max_chunks=WITNESS_MAX_CHUNKS,
-            )
-            self._arrays = out[:5]
-            self._deferred_dropped.append(out[5])
-            self.stats["uploaded_nodes"] += len(keep)
-            self.stats["uploaded_bytes"] += len(raw)
+            self._deferred_dropped.append(self._update_locked(keep, 0))
             self.stats["retained_rows"] = len(keep)
 
     def note_index_dropped(self, n: int) -> None:
@@ -461,8 +477,12 @@ class ResidentTable:
             )
 
     def arrays(self) -> tuple:
-        """The live (digests, refs, ref_live, index, fps) handles —
-        `chip_smoke.py` and tests read them; treat as immutable."""
+        """The live (digests, refs, tail, index, fps) handles —
+        `chip_smoke.py` and tests read them; treat as immutable. A row of
+        `refs` (cap, 128) holds the 8 words of ref slots 0..15, a row of
+        `tail` (cap, 9) those of slot 16 and, last, the liveness of all 17
+        as a bit mask: rows XLA scatters and gathers whole, where the
+        (cap, 17, 8) form was re-laid out table-wide in every update."""
         with self._lock:
             if self._arrays is None:
                 raise RuntimeError("resident table has no device arrays yet")
@@ -490,14 +510,46 @@ class ResidentTable:
         resident (a node past the kernel's absorb capacity, or more
         unique nodes than max_cap) — the caller falls back to the
         classic route."""
-        from phant_tpu.crypto.keccak import RATE
-
-        limit = WITNESS_MAX_CHUNKS * RATE
         with metrics.phase("witness_resident.dispatch"):
             with self._lock:
-                return self._dispatch_locked(witnesses, core_novel, limit)
+                return self._dispatch_locked(witnesses, core_novel)
 
-    def _dispatch_locked(self, witnesses, core_novel, limit: int):
+    def _update_locked(self, nodes: List[bytes], base: int):
+        """Enqueue the update program over `nodes` (rows base.. of the
+        table) in the row form; returns its unread drop count. Counted at
+        dispatch: what the row form uploads against what the nodes hold."""
+        words, lens = pack_node_rows(nodes, WITNESS_MAX_CHUNKS)
+        slots = np.full(lens.shape[0], -1, np.int32)
+        slots[: len(nodes)] = np.arange(base, base + len(nodes), dtype=np.int32)
+        n_bytes = int(lens.sum())
+        metrics.count("witness_resident.update_rows", len(nodes), kind="real")
+        metrics.count(
+            "witness_resident.update_rows", lens.shape[0] - len(nodes), kind="pad"
+        )
+        metrics.count("witness_resident.update_bytes", n_bytes, kind="payload")
+        metrics.count(
+            "witness_resident.update_bytes", words.nbytes - n_bytes, kind="pad"
+        )
+        # device.host_seconds{lane=witness,op=enqueue}: the uploads and
+        # launches of this batch (update, verdict, gather), and not the
+        # host's numpy work between them
+        with device_host("witness", "enqueue"):
+            out = self._update_fn(
+                *self._arrays,
+                self._put(words),
+                self._put(lens),
+                self._put(slots),
+                max_chunks=WITNESS_MAX_CHUNKS,
+            )
+        self._arrays = out[:5]
+        with _JIT_LOCK:
+            _update_shapes.add((self._device, self._cap, lens.shape[0]))
+            metrics.gauge_set("witness_resident.update_programs", len(_update_shapes))
+        self.stats["uploaded_nodes"] += len(nodes)
+        self.stats["uploaded_bytes"] += n_bytes
+        return out[5]
+
+    def _dispatch_locked(self, witnesses, core_novel):
         n_blocks = len(witnesses)
         if n_blocks == 0:
             return None
@@ -515,7 +567,7 @@ class ResidentTable:
             for n in all_nodes:
                 if n in sob or n in seen:
                     continue
-                if len(n) >= limit:
+                if len(n) >= _ROW_BYTES:
                     return None  # device kernel cannot hash this node
                 seen.add(n)
                 cand.append(n)
@@ -548,35 +600,9 @@ class ResidentTable:
             sob[nb] = base + j
         self._n_rows = base + len(cand)
 
-        # update program: upload ONLY the pruned novel bytes
+        # update program: upload ONLY the pruned novel nodes, laid out
         if cand:
-            raw = b"".join(cand)
-            from phant_tpu.crypto.keccak import RATE as _RATE
-
-            blob_len = _pow2ceil(len(raw) + WITNESS_MAX_CHUNKS * _RATE)
-            np_b = _pow2ceil(len(cand))
-            blob = np.zeros(blob_len, np.uint8)
-            blob[: len(raw)] = np.frombuffer(raw, np.uint8)
-            lens = np.zeros(np_b, np.int32)
-            lens[: len(cand)] = [len(nb) for nb in cand]
-            offsets = np.zeros(np_b, np.int32)
-            np.cumsum(lens[:-1], out=offsets[1:])
-            slots = np.full(np_b, -1, np.int32)
-            slots[: len(cand)] = np.arange(base, base + len(cand), dtype=np.int32)
-            # device.host_seconds{lane=witness,op=enqueue}: the uploads
-            # and launches of this batch (update, verdict, gather), and
-            # not the host's numpy work between them
-            with device_host("witness", "enqueue"):
-                out = self._update_fn(
-                    *self._arrays,
-                    self._put(blob),
-                    self._put(offsets),
-                    self._put(lens),
-                    self._put(slots),
-                    max_chunks=WITNESS_MAX_CHUNKS,
-                )
-            self._arrays = out[:5]
-            h.dropped_outs.append(out[5])
+            h.dropped_outs.append(self._update_locked(cand, base))
         h.dropped_outs.extend(self._deferred_dropped)
         self._deferred_dropped = []
 
@@ -595,13 +621,13 @@ class ResidentTable:
         roots_w = np.zeros((nb_pad, 8), np.uint32)
         for b, (root, _nodes) in enumerate(witnesses):
             roots_w[b] = np.frombuffer(root, dtype="<u4")
-        digests, refs, ref_live = self._arrays[:3]
+        digests, refs, tail = self._arrays[:3]
         with device_host("witness", "enqueue"):
             rows_d = self._put(rows)
             h.verdict_out = self._verdict_fn(
                 digests,
                 refs,
-                ref_live,
+                tail,
                 rows_d,
                 rows_d >= 0,
                 self._put(block_id),
@@ -621,8 +647,6 @@ class ResidentTable:
 
         h.uploaded_nodes = len(cand)
         h.uploaded_bytes = sum(map(len, cand))
-        self.stats["uploaded_nodes"] += h.uploaded_nodes
-        self.stats["uploaded_bytes"] += h.uploaded_bytes
         self.stats["pruned_nodes"] += pruned
         self.stats["batches"] += 1
         return h

@@ -31,6 +31,7 @@ from phant_tpu.ops.witness_jax import (
     _gather_node_rows,
     _gather_refs,
     _ref_words_from_rows,
+    _row_words,
     linked_verdict,
     witness_digests,
 )
@@ -193,10 +194,12 @@ def witness_verify_fused_sharded(
             off_all = jnp.cumsum(lens_all) - lens_all  # exclusive global offsets
             i = jax.lax.axis_index(axis)
             offsets_l = jax.lax.dynamic_slice(off_all, (i * nloc,), (nloc,))
-            data = _gather_node_rows(blob_s, offsets_l, lens_l, max_chunks * RATE)
-            digests = _digests_from_rows(data, lens_l, max_chunks=max_chunks)
-            ref_pos = _extract_ref_positions(data, lens_l)
-            refs_l = _ref_words_from_rows(data, ref_pos).reshape(-1, 8)
+            words = _row_words(
+                _gather_node_rows(blob_s, offsets_l, lens_l, max_chunks * RATE)
+            )
+            digests = _digests_from_rows(words, lens_l, max_chunks=max_chunks)
+            ref_pos = _extract_ref_positions(words, lens_l)
+            refs_l = _ref_words_from_rows(words, ref_pos).reshape(-1, 8)
             live_l = (ref_pos >= 0).reshape(-1)
             rblock_l = jnp.broadcast_to(block_l[:, None], ref_pos.shape).reshape(-1)
             refs = jax.lax.all_gather(refs_l, axis, axis=0, tiled=True)

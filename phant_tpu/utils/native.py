@@ -140,6 +140,15 @@ class NativeLib:
             ctypes.c_void_p,
         ]
         lib.phant_pack_keccak.restype = ctypes.c_int
+        lib.phant_pack_rows.argtypes = [
+            ctypes.c_char_p,
+            ctypes.POINTER(ctypes.c_uint64),
+            ctypes.POINTER(ctypes.c_uint32),
+            ctypes.c_size_t,
+            ctypes.c_size_t,
+            ctypes.c_void_p,
+        ]
+        lib.phant_pack_rows.restype = ctypes.c_int
         lib.phant_scan_refs.argtypes = [
             ctypes.c_void_p,
             ctypes.c_void_p,
@@ -235,6 +244,22 @@ class NativeLib:
         if rc != 0:
             raise ValueError(f"payload exceeds bucket bound {max_chunks}")
         return buf, nchunks
+
+    def pack_rows(self, payloads: Sequence[bytes], rows: int, row_bytes: int):
+        """(rows, row_bytes) u8: payload i in row i, zero past its length
+        and in the rows past the last payload (no keccak padding: the
+        device pads). Raises if a payload fills its row."""
+        import numpy as np
+
+        n = len(payloads)
+        blob, offsets, lens = self._layout(payloads)
+        buf = np.zeros((rows, row_bytes), dtype=np.uint8)
+        rc = self._lib.phant_pack_rows(
+            blob, offsets, lens, n, row_bytes, buf.ctypes.data_as(ctypes.c_void_p)
+        )
+        if rc != 0:
+            raise ValueError(f"payload fills its row of {row_bytes} bytes")
+        return buf
 
     def ecrecover(self, msg_hash: bytes, r: int, s: int, recid: int) -> Optional[bytes]:
         """64-byte uncompressed pubkey (X||Y) or None if unrecoverable

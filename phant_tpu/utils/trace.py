@@ -249,6 +249,9 @@ METRIC_HELP: Dict[str, str] = {
     "witness_resident.rows": "Rows resident on device (digest + child-ref rows, persistent across batches)",
     "witness_resident.uploaded_nodes": "Truly-novel nodes uploaded to the resident table (after the host prune)",
     "witness_resident.uploaded_bytes": "Truly-novel bytes uploaded to the resident table — the ONLY recurring h2d payload of the resident route",
+    "witness_resident.update_rows": "Rows of the batches handed to the resident update program, by kind: real = novel nodes, pad = the zero rows up to the batch's power of two (hashed and dropped)",
+    "witness_resident.update_bytes": "Bytes the resident update's row form uploads, by kind: payload = what the novel nodes hold, pad = the zeros that fill each row to 680 bytes and the pad rows",
+    "witness_resident.update_programs": "Distinct shapes (device, table rows, batch rows) the resident update program has run on in this process: the row form's key has no blob length, so one per batch size",
     "witness_resident.dispatch": "Resident dispatch phase: prune + row assignment + update/verdict enqueue, no host sync",
     "witness_resident.resolve": "Resident resolve phase: verdict (1 B/block) + core-novel digest readback (the honest sync)",
     # continuous-batching scheduler (phant_tpu/serving/)

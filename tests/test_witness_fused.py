@@ -137,6 +137,7 @@ def test_fused_device_refs_match_host_scanner():
     from phant_tpu.ops.witness_jax import (
         _extract_ref_positions,
         _gather_node_rows,
+        _row_words,
     )
 
     rng = np.random.default_rng(11)
@@ -152,11 +153,13 @@ def test_fused_device_refs_match_host_scanner():
     want_off, want_node = scan_refs_py(blob.tobytes(), offsets, lens)
     want = {(int(n), int(o)) for n, o in zip(want_node, want_off)}
 
-    data = _gather_node_rows(
-        jnp.asarray(blob),
-        jnp.asarray(offsets.astype(np.int32)),
-        jnp.asarray(lens.astype(np.int32)),
-        WITNESS_MAX_CHUNKS * 136,
+    data = _row_words(
+        _gather_node_rows(
+            jnp.asarray(blob),
+            jnp.asarray(offsets.astype(np.int32)),
+            jnp.asarray(lens.astype(np.int32)),
+            WITNESS_MAX_CHUNKS * 136,
+        )
     )
     ref_pos = np.asarray(
         jax.jit(_extract_ref_positions)(data, jnp.asarray(lens.astype(np.int32)))

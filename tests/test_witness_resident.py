@@ -384,3 +384,210 @@ def test_depth_histogram_memo_overflow(monkeypatch):
     eng._depth._max = 8  # force an overflow clear on every batch
     assert np.asarray(eng.verify_batch(wits)).all()
     assert np.asarray(eng.verify_batch(wits)).all()  # used to KeyError
+
+
+# ---------------------------------------------------------------------------
+# the row form (PR 31): the table against the old program's semantics
+# ---------------------------------------------------------------------------
+
+
+def _ref_slots_py(node: bytes):
+    """The 17 ref slots of a node as the update program has always filled
+    them (slots 0..15 a branch's hashed children, slot 16 an extension's
+    child or an account leaf's storage root; anything malformed ref-less),
+    from a plain RLP decode: the reference the device parser is held to."""
+    slots = [None] * 17
+    try:
+        items = rlp.decode(node)
+    except Exception:
+        return slots
+    if not isinstance(items, list):
+        return slots
+    if len(items) == 17:
+        for k in range(16):
+            if isinstance(items[k], bytes) and len(items[k]) == 32:
+                slots[k] = items[k]
+    elif len(items) == 2 and isinstance(items[0], bytes) and items[0]:
+        path, val = items
+        if not path[0] & 0x20:  # extension
+            if isinstance(val, bytes) and len(val) == 32:
+                slots[16] = val
+        elif isinstance(val, bytes) and len(val) != 32:
+            try:
+                acct = rlp.decode(val)
+            except Exception:
+                return slots
+            if (
+                isinstance(acct, list)
+                and len(acct) == 4
+                and all(isinstance(a, bytes) for a in acct)
+                and len(acct[2]) == 32
+                and len(acct[3]) == 32
+            ):
+                slots[16] = acct[2]
+    return slots
+
+
+def _index_insert_fixed_rounds(index, fps, slots, live, probes):
+    """`keccak_jax.index_insert` as it was until PR 31, in numpy: a fixed
+    count of rounds, each a read, a scatter-min and a read."""
+    from phant_tpu.ops.keccak_jax import INDEX_EMPTY
+
+    def mix(d0, d1):
+        h = d0 ^ (d1 * np.uint32(0x9E3779B9))
+        h = h * np.uint32(0x85EBCA6B)
+        h = h ^ (h >> np.uint32(13))
+        h = h * np.uint32(0xC2B2AE35)
+        return h ^ (h >> np.uint32(16))
+
+    index = index.copy()
+    h = mix(fps[:, 0], fps[:, 1])
+    pending = live.copy()
+    for rnd in range(probes):
+        pos = ((h + np.uint32(rnd)) & np.uint32(len(index) - 1)).astype(np.int64)
+        want = pending & (index[pos] >= INDEX_EMPTY)
+        np.minimum.at(index, pos[want], slots[want])
+        pending &= ~(want & (index[pos] == slots))
+    return index, int(pending.sum())
+
+
+class _OldTable:
+    """A table built by the old update program's semantics, on the host."""
+
+    def __init__(self, cap):
+        from phant_tpu.ops.keccak_jax import INDEX_EMPTY
+
+        self.cap = cap
+        self.digests = np.zeros((cap, 8), np.uint32)
+        self.refs = np.zeros((cap, 17, 8), np.uint32)
+        self.live = np.zeros((cap, 17), bool)
+        self.index = np.full(4 * cap, INDEX_EMPTY, np.int32)
+        self.n = 0
+        self.dropped = 0
+
+    def update(self, nodes):
+        from phant_tpu.ops.keccak_jax import INDEX_PROBES
+
+        base = self.n
+        for j, nb in enumerate(nodes):
+            self.digests[base + j] = np.frombuffer(keccak256(nb), "<u4")
+            for k, ref in enumerate(_ref_slots_py(nb)):
+                if ref is not None:
+                    self.refs[base + j, k] = np.frombuffer(ref, "<u4")
+                    self.live[base + j, k] = True
+        self.n += len(nodes)
+        sl = np.arange(base, self.n, dtype=np.int32)
+        self.index, dropped = _index_insert_fixed_rounds(
+            self.index, self.digests[sl, :2], sl, np.ones(len(sl), bool), INDEX_PROBES
+        )
+        self.dropped += dropped
+
+
+def _assert_table_equals(table, old):
+    import jax
+
+    from phant_tpu.ops.witness_resident import _rows_at
+
+    digests, refs, tail, index, fps = table.arrays()
+    assert table.rows() == old.n and digests.shape[0] == old.cap
+    n = old.n
+    assert (np.asarray(digests)[:n] == old.digests[:n]).all()
+    assert (np.asarray(fps)[:n] == old.digests[:n, :2]).all()
+    r17, live = jax.jit(_rows_at)(refs, tail, np.arange(n, dtype=np.int32))
+    assert (np.asarray(live) == old.live[:n]).all()
+    assert (np.asarray(r17) == old.refs[:n]).all()  # dead slots zero on both
+    assert (np.asarray(index) == old.index).all()
+    got = table.device_lookup(old.digests[:n, :2].copy())
+    assert (got == np.arange(n)).all()
+
+
+def test_table_after_updates_and_tiered_flush_equals_the_old_programs():
+    """Two updates and a `flush_retaining`: digests, ref words, liveness,
+    fingerprints, the index's every bucket and `device_lookup` are what the
+    old program (byte gathers, (cap, 17, 8) refs, 32 fixed probe rounds)
+    left for the same batches."""
+    from phant_tpu.ops.witness_resident import ResidentTable
+
+    rng = np.random.default_rng(9)
+    _root, wits = build_witnesses(n_blocks=6, picks=6, trie_n=256)
+    extra = [
+        rlp.encode([b"\x00\xab", rng.bytes(32)]),  # extension
+        rlp.encode(  # account leaf
+            [b"\x20" + rng.bytes(20), rlp.encode([b"\x01", b"\x02", rng.bytes(32), rng.bytes(32)])]
+        ),
+        rng.bytes(64),  # garbage: hashed, ref-less
+    ]
+    first = list(dict.fromkeys(n for _r, ns in wits[:3] for n in ns))
+    second = [
+        n for n in dict.fromkeys([n for _r, ns in wits[3:] for n in ns] + extra)
+        if n not in set(first)
+    ]
+    table = ResidentTable(max_cap=1024, start_cap=1024)
+    old = _OldTable(1024)
+    for batch in (first, second):
+        h = table.dispatch([(b"\x00" * 32, batch)], [])
+        assert h is not None and h.uploaded_nodes == len(batch)
+        h.resolve()
+        old.update(batch)
+        _assert_table_equals(table, old)
+    keep = first[::2] + second[:5]
+    table.flush_retaining(keep)
+    old = _OldTable(1024)
+    old.update(keep)
+    _assert_table_equals(table, old)
+    assert table.stats_snapshot()["index_dropped"] == old.dropped == 0
+
+
+def _gauge(name):
+    return int(metrics.snapshot()["gauges"].get(name, 0))
+
+
+def _counter(name, **labels):
+    snap = metrics.snapshot()["counters"]
+    key = name + "{" + ",".join(f'{k}="{v}"' for k, v in sorted(labels.items())) + "}"
+    return int(snap.get(key, 0))
+
+
+def test_same_row_count_different_byte_totals_run_one_update_program():
+    """The blob form keyed the program on the blob's padded length; the row
+    form has rows only: 1,000 bytes or 20,000 in 64 rows are one program."""
+    from phant_tpu.ops.witness_resident import ResidentTable
+
+    rng = np.random.default_rng(3)
+    # a row space no other test of this process uses, so the shape is new
+    table = ResidentTable(max_cap=2048, start_cap=2048)
+    small = [rng.bytes(20) for _ in range(40)]
+    large = [rng.bytes(500) for _ in range(40)]
+    before = _gauge("witness_resident.update_programs")
+    built = table._update_fn._cache_size()
+    table.dispatch([(b"\x00" * 32, small)], []).resolve()
+    assert _gauge("witness_resident.update_programs") == before + 1
+    table.dispatch([(b"\x00" * 32, large)], []).resolve()
+    assert _gauge("witness_resident.update_programs") == before + 1
+    assert table._update_fn._cache_size() == built + 1
+
+
+def test_update_counters_say_what_the_row_form_uploads():
+    from phant_tpu.ops.witness_resident import ResidentTable
+
+    rng = np.random.default_rng(4)
+    nodes = [rng.bytes(int(n)) for n in rng.integers(1, 600, 37)]
+    table = ResidentTable(max_cap=1024, start_cap=1024)
+    was = {
+        (n, k): _counter(n, kind=k)
+        for n, k in [
+            ("witness_resident.update_rows", "real"),
+            ("witness_resident.update_rows", "pad"),
+            ("witness_resident.update_bytes", "payload"),
+            ("witness_resident.update_bytes", "pad"),
+        ]
+    }
+    table.dispatch([(b"\x00" * 32, nodes)], []).resolve()
+    grew = {key: _counter(key[0], kind=key[1]) - v for key, v in was.items()}
+    payload = sum(map(len, nodes))
+    assert grew[("witness_resident.update_rows", "real")] == 37
+    assert grew[("witness_resident.update_rows", "pad")] == 64 - 37
+    assert grew[("witness_resident.update_bytes", "payload")] == payload
+    assert grew[("witness_resident.update_bytes", "pad")] == 64 * 680 - payload
+    st = table.stats_snapshot()
+    assert st["uploaded_nodes"] == 37 and st["uploaded_bytes"] == payload
