@@ -321,7 +321,7 @@ class RootEngine:
                 if pf is not None:
                     pf.release()  # host route: the merge goes unused
         if route:
-            with metrics.phase("witness_engine.root_dispatch"):
+            with metrics.phase("witness_engine.root_dispatch", rung=h.merged.rung):
                 try:
                     with device_host("root", "enqueue"):
                         h.device_out = self._dispatch(h.merged)

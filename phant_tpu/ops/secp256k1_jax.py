@@ -33,6 +33,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from phant_tpu.crypto.secp256k1 import GX, GY, N, P
+from phant_tpu.utils.rungs import launches, note_launch, note_split
+from phant_tpu.utils.trace import metrics
 
 LIMBS = 16  # 16-bit limbs per u256
 MASK16 = np.uint32(0xFFFF)
@@ -502,37 +504,111 @@ def ecrecover_batch_async(
     return _dispatch_shamir(out, device_idx, msg_hashes, rs, ss, recovery_ids)
 
 
-def _bucket_pad(n: int) -> int:
-    # power-of-two buckets (>= 32): repeated calls reuse a handful of
-    # compiled programs instead of retracing per batch size
-    bucket = 32
-    while bucket < n:
-        bucket *= 2
-    return bucket
+#: THE shape set of `ecrecover_kernel` on the served path, in signature
+#: rows: ONE rung, a request of mainnet's shape (150-250 transactions; a
+#: lone block's 225 signatures sat on 256 under the open power-of-two
+#: buckets this replaces). A merged batch above it goes out as launches of
+#: 256 (rows are independent), so the first request of any wave builds the
+#: only shape there is and no later wave can meet another. Why one, and
+#: why the first request's and not the boot's: a rung is 104-131 s of
+#: backend compile on an empty cache and, cache warm, still 33 s of
+#: tracing, lowering and loading (10 MB of StableHLO; my chip runs, PR 34:
+#: three rungs at boot took a warm set-up from 125 s to 272 s), and seconds
+#: in a server's constructor are seconds nobody can overlap with anything.
+#: What one rung costs: a merged wave of four requests is four launches in
+#: a row (4 x 24.5 ms; PERF.md section 6, PR 34), replay's 7,200
+#: signatures are 29. Add a rung only with a cell that needs it and a way
+#: to load it that does not trace it again (PERF.md section 7, w).
+SIG_LADDER: Tuple[int, ...] = (256,)
+
+#: Under the ladder, on the CPU only, where tests and dry runs recover a
+#: handful of signatures and XLA-CPU takes seconds a launch at 256 rows.
+#: An accelerator never runs these and a server's boot never builds them.
+SIG_SMALL_RUNGS: Tuple[int, ...] = (32, 64, 128)
+
+
+def sig_ladder() -> Tuple[int, ...]:
+    """The rungs `ecrecover_kernel` may be launched on in this process."""
+    if jax.default_backend() == "cpu":
+        return SIG_SMALL_RUNGS + SIG_LADDER
+    return SIG_LADDER
+
+
+def pack_signatures(
+    es: Sequence[int], rs: Sequence[int], ss: Sequence[int], parities: Sequence[int]
+) -> Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray, Tuple[int, ...]]:
+    """(e, r, s, parity, rungs): the kernel's inputs for these signatures,
+    padded with well-formed rows whose result is discarded (1, 1, 1, 0) to
+    the sum of `rungs`, the launches they go out as (`rungs.launches`).
+    Pure host work: the u256 -> limb encode."""
+    rungs = tuple(launches(sig_ladder(), len(es)))
+    pad = sum(rungs) - len(es)
+    return (
+        ints_to_limbs(list(es) + [1] * pad),
+        ints_to_limbs(list(rs) + [1] * pad),
+        ints_to_limbs(list(ss) + [1] * pad),
+        np.array(list(parities) + [0] * pad, np.uint32),
+        rungs,
+    )
+
+
+def launch_ecrecover(packed, n_real: int, device=None) -> list:
+    """Upload and launch what `pack_signatures` made, one launch a rung,
+    with no host sync: the unresolved (digest_words, valid) of each launch,
+    in row order. `device` commits the inputs to one chip of a mesh (the
+    compute follows them). Counted here, at dispatch: `sig.rows{kind=}`,
+    the shapes run, and a batch that went out split."""
+    e, r, s, par, rungs = packed
+
+    def put(a):
+        return jnp.asarray(a) if device is None else jax.device_put(a, device)
+
+    outs, at = [], 0
+    for rung in rungs:
+        outs.append(
+            ecrecover_kernel(*(put(a[at : at + rung]) for a in (e, r, s, par)))
+        )
+        note_launch("ecrecover", rung, device)
+        at += rung
+    metrics.count("sig.rows", n_real, kind="real")
+    metrics.count("sig.rows", at - n_real, kind="pad")
+    note_split("ecrecover", len(rungs))
+    return outs
+
+
+def read_ecrecover(outs: list) -> Tuple[np.ndarray, np.ndarray]:
+    """The readback of `launch_ecrecover`'s outputs, launches joined in
+    row order: (digest words, valid). The honest sync, and nothing else."""
+    digests = np.concatenate([np.asarray(d) for d, _v in outs])  # phantlint: disable=HOSTSYNC — the caller's chosen sync point
+    valid = np.concatenate([np.asarray(v) for _d, v in outs])  # phantlint: disable=HOSTSYNC — the caller's chosen sync point
+    return digests, valid
+
+
+def senders_of(
+    digests: np.ndarray, valid: np.ndarray, n_real: int
+) -> List[Optional[bytes]]:
+    """The address of each of the first `n_real` rows `read_ecrecover`
+    gave, None where the signature is invalid."""
+    addrs = digest_words_to_addresses(digests[:n_real])
+    return [a if ok else None for a, ok in zip(addrs, valid[:n_real].tolist())]
 
 
 def _dispatch_shamir(out, device_idx, msg_hashes, rs, ss, recovery_ids):
-    """Pack, pad to a bucket and launch the 256-step Shamir ladder."""
-    pad = _bucket_pad(len(device_idx)) - len(device_idx)
-    e = ints_to_limbs(
-        [int.from_bytes(msg_hashes[i], "big") for i in device_idx] + [1] * pad
+    """Pack, pad to the ladder and launch the 256-step Shamir ladder."""
+    packed = pack_signatures(
+        [int.from_bytes(msg_hashes[i], "big") for i in device_idx],
+        [rs[i] for i in device_idx],
+        [ss[i] for i in device_idx],
+        [recovery_ids[i] & 1 for i in device_idx],
     )
-    r = ints_to_limbs([rs[i] for i in device_idx] + [1] * pad)
-    s = ints_to_limbs([ss[i] for i in device_idx] + [1] * pad)
-    par = np.array(
-        [recovery_ids[i] & 1 for i in device_idx] + [0] * pad, np.uint32
-    )
-    digest, valid = ecrecover_kernel(
-        jnp.asarray(e), jnp.asarray(r), jnp.asarray(s), jnp.asarray(par)
-    )
+    outs = launch_ecrecover(packed, len(device_idx))
 
     def resolve() -> List[Optional[bytes]]:
         # resolve() IS the deliberate sync point of the async dispatch:
         # the caller chose when to materialize (cross-block pipelining)
-        addrs = digest_words_to_addresses(np.asarray(digest))  # phantlint: disable=HOSTSYNC — resolve() is the chosen sync point
-        valid_np = np.asarray(valid)  # phantlint: disable=HOSTSYNC — resolve() is the chosen sync point
-        for k, i in enumerate(device_idx):
-            out[i] = addrs[k] if bool(valid_np[k]) else None
+        senders = senders_of(*read_ecrecover(outs), len(device_idx))
+        for i, addr in zip(device_idx, senders):
+            out[i] = addr
         return out
 
     return resolve

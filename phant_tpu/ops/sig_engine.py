@@ -56,15 +56,7 @@ import os
 import threading
 from typing import List, Optional, Sequence
 
-import numpy as np
-
 from phant_tpu.utils.trace import device_host, metrics
-
-#: padding row for the device kernel: (e=1, r=1, s=1, parity=0) — the
-#: same filler `ecrecover_batch_async` pads its pow2 buckets with (a
-#: well-formed lane whose result is discarded)
-_PAD_SCALAR = 1
-
 
 class SigPrefetch:
     """Output of `SigEngine.prefetch_batch`: the merged rows + limb-packed
@@ -80,7 +72,7 @@ class SigPrefetch:
 
     def __init__(self, rows_list, packed, n_rows):
         self.rows_list = rows_list
-        self.packed = packed  # (e, r, s, parity) numpy arrays, or None
+        self.packed = packed  # `pack_signatures`' arrays and rungs, or None
         self.n_rows = n_rows
 
     def release(self) -> None:
@@ -95,7 +87,7 @@ class SigHandle:
     __slots__ = (
         "rows_list",
         "n_rows",      # merged signature rows across the batch's requests
-        "device_out",  # unresolved (digest_words, valid) device arrays
+        "device_out",  # unresolved (digest_words, valid) of each launch
         "backend",     # "device" | "native" | "scalar"
         "resolved",
     )
@@ -189,30 +181,24 @@ class SigEngine:
 
     @staticmethod
     def _merge(rows_list: Sequence):
-        """(e, r, s, parity) device-kernel inputs for the batch's merged
-        rows, pow2-bucket-padded so repeat batches land on a handful of
-        compiled shapes (ops/secp256k1_jax._bucket_pad — the same shape
-        discipline as `ecrecover_batch_async`). Pure host work: list
+        """The device kernel's inputs for the batch's merged rows, padded
+        to the launches they go out as: one rung of
+        `secp256k1_jax.SIG_LADDER`, or above its top several launches of
+        the top rung (`pack_signatures`, the one packing
+        `ecrecover_batch_async` uses too). Pure host work: list
         concatenation + the u256 -> limb encode."""
-        from phant_tpu.ops.secp256k1_jax import _bucket_pad, ints_to_limbs
+        from phant_tpu.ops.secp256k1_jax import pack_signatures
 
-        msgs: List[bytes] = []
+        es: List[int] = []
         rs: List[int] = []
         ss: List[int] = []
         pars: List[int] = []
         for rows in rows_list:
-            msgs.extend(rows.msgs)
+            es.extend(int.from_bytes(m, "big") for m in rows.msgs)
             rs.extend(rows.rs)
             ss.extend(rows.ss)
             pars.extend(rid & 1 for rid in rows.recids)
-        pad = _bucket_pad(len(msgs)) - len(msgs)
-        e = ints_to_limbs(
-            [int.from_bytes(m, "big") for m in msgs] + [_PAD_SCALAR] * pad
-        )
-        r = ints_to_limbs(rs + [_PAD_SCALAR] * pad)
-        s = ints_to_limbs(ss + [_PAD_SCALAR] * pad)
-        par = np.array(pars + [0] * pad, np.uint32)
-        return e, r, s, par
+        return pack_signatures(es, rs, ss, pars)
 
     # -- two-phase protocol (scheduler pipeline shape) ------------------------
 
@@ -259,10 +245,14 @@ class SigEngine:
                 if pf is not None:
                     pf.release()
         if route:
-            with metrics.phase("witness_engine.sig_dispatch"):
+            with metrics.phase("witness_engine.sig_dispatch", rung=sum(packed[4])):
                 try:
+                    from phant_tpu.ops.secp256k1_jax import launch_ecrecover
+
                     with device_host("sig", "enqueue"):
-                        h.device_out = self._dispatch(packed)
+                        h.device_out = launch_ecrecover(
+                            packed, h.n_rows, self._pinned_device()
+                        )
                     h.backend = "device"
                 except Exception:
                     import logging
@@ -278,24 +268,6 @@ class SigEngine:
                     )
                     h.backend = "host"
         return h
-
-    def _dispatch(self, packed):
-        """Enqueue the merged ecrecover on the (possibly pinned) device —
-        upload + kernel launch, ZERO host sync; returns the unresolved
-        (digest_words, valid) device arrays."""
-        import jax
-        import jax.numpy as jnp
-
-        from phant_tpu.ops.secp256k1_jax import ecrecover_kernel
-
-        e, r, s, par = packed
-        device = self._pinned_device()
-        if device is not None:
-            # committed inputs pin the compute with them (mesh lanes)
-            args = tuple(jax.device_put(a, device) for a in (e, r, s, par))
-        else:
-            args = tuple(jnp.asarray(a) for a in (e, r, s, par))  # phantlint: disable=JNPHOSTLOOP — fixed 4-argument upload tuple, not a per-row loop
-        return ecrecover_kernel(*args)
 
     def resolve_batch(self, handle: SigHandle) -> List[List[Optional[bytes]]]:
         """Per-request sender slices (tx order within each request; None =
@@ -346,17 +318,11 @@ class SigEngine:
 
     @staticmethod
     def _resolve_device(handle: SigHandle) -> List[Optional[bytes]]:
-        from phant_tpu.ops.secp256k1_jax import digest_words_to_addresses
+        from phant_tpu.ops.secp256k1_jax import read_ecrecover, senders_of
 
-        digest, valid = handle.device_out
         with device_host("sig", "sync"):
-            digest_np = np.asarray(digest)  # phantlint: disable=HOSTSYNC — timed sender readback is the product
-            valid_np = np.asarray(valid)  # phantlint: disable=HOSTSYNC — timed sender readback is the product
-        addrs = digest_words_to_addresses(digest_np)
-        return [
-            addrs[k] if bool(valid_np[k]) else None
-            for k in range(handle.n_rows)
-        ]
+            digests, valid = read_ecrecover(handle.device_out)
+        return senders_of(digests, valid, handle.n_rows)
 
     @staticmethod
     def _resolve_host(handle: SigHandle) -> List[Optional[bytes]]:

@@ -777,6 +777,16 @@ class WitnessEngine:
                 self._resident = table
             return table
 
+    def prewarm_resident(self) -> int:
+        """Where this engine's verdicts go through the resident table,
+        build the table (at the cap it is born at) and its programs on
+        every rung of their ladders (`ResidentTable.prewarm`): a server's
+        boot on an accelerator. Returns the programs built, 0 off the
+        resident route."""
+        if not self._resident_wanted():
+            return 0
+        return self._resident_table().prewarm()
+
     def _resident_dispatch(self, witnesses, novel):
         """Enqueue the resident update + verdict for one batch; None =
         this batch cannot go resident (oversized node, table failure —
@@ -1504,8 +1514,14 @@ class WitnessEngine:
                 # set (the commit only uses it when it covers every
                 # fresh node)
                 h.ref_hint = plan.refs
-        with metrics.phase("witness_engine.dispatch"):
-            if self._resident_wanted():
+        resident = self._resident_wanted()
+        attrs = {}
+        if resident:
+            from phant_tpu.ops.witness_resident import verdict_rows
+
+            attrs["rung"] = verdict_rows([len(nodes) for _root, nodes in witnesses])
+        with metrics.phase("witness_engine.dispatch", **attrs):
+            if resident:
                 # device-resident route: update (novel bytes only) +
                 # verdict enqueued with no host sync; the host tables
                 # will commit from the device digests at resolve
@@ -1696,13 +1712,9 @@ class WitnessEngine:
             # digest outputs are dropped unread; the index drop-count
             # scalars go BACK to the table (the stat must not undercount
             # across a crash path).
-            handle.resident.verdict_out = None
-            handle.resident.digest_out = None
-            if handle.resident.dropped_outs and handle.resident._table is not None:
-                handle.resident._table.return_dropped(
-                    handle.resident.dropped_outs
-                )
-            handle.resident.dropped_outs = []
+            dropped = handle.resident.drop_outputs()
+            if dropped and handle.resident._table is not None:
+                handle.resident._table.return_dropped(dropped)
         handle.novel = []
         handle.witnesses = None
         handle.ext_batch = None

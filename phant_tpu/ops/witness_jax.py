@@ -24,19 +24,13 @@ import numpy as np
 
 from phant_tpu.crypto.keccak import RATE
 from phant_tpu.ops.keccak_jax import keccak256_chunked_auto
+from phant_tpu.utils.rungs import pow2ceil as _pow2ceil
 
 # Bucket bound for witness nodes: RLP trie nodes are <= 576B (BASELINE.md),
 # and 576 < 5 * 136. Shared by __graft_entry__.py / tests.
 WITNESS_MAX_CHUNKS = 5
 
 CHUNK_WORDS = RATE // 4  # u32 words of one rate chunk of a node row
-
-
-def _pow2ceil(n: int) -> int:
-    p = 1
-    while p < max(n, 1):
-        p *= 2
-    return p
 
 
 def _gather_node_rows(blob, offsets, lens, row: int):

@@ -42,8 +42,8 @@ batches:
     bytes, 0.26 ms more on the link, and in exchange the update program
     moves no single byte (as a blob it moved 2.5 M of them a batch
     through XLA's gather, 25 of its 46 ms) and its jit key holds no byte
-    count (`witness_resident.update_rows` / `update_bytes` /
-    `update_programs` on /metrics). Then 4 bytes/node of row ids +
+    count (`witness_resident.update_rows` / `update_bytes` and
+    `lanes.program_shapes{program=update}` on /metrics). Then 4 bytes/node of row ids +
     32 bytes/block of roots up; 1 byte/block of verdicts + 32 bytes per
     CORE-novel digest down (the engine's host tables commit from the
     device digests, so the host hashes nothing on this route). Steady
@@ -75,16 +75,76 @@ import numpy as np
 
 from phant_tpu.utils.trace import device_host, metrics
 from phant_tpu.crypto.keccak import RATE
-from phant_tpu.ops.witness_jax import WITNESS_MAX_CHUNKS, _pow2ceil, pack_node_rows
+from phant_tpu.ops.witness_jax import WITNESS_MAX_CHUNKS, pack_node_rows
+from phant_tpu.utils.rungs import launches, note_launch, note_split, pow2ceil
 
 #: bytes of one node row; a node must leave one free for the keccak pad
 _ROW_BYTES = WITNESS_MAX_CHUNKS * RATE
 
 __all__ = [
+    "ROW_LADDER",
+    "VERDICT_LADDER",
     "ResidentBatch",
     "ResidentTable",
     "resident_default_cap",
+    "verdict_rows",
+    "verdict_rung",
 ]
+
+#: THE shape set of `_verdict_impl`: (node rows, blocks) of one launch, as
+#: ONE index: one, two and four requests of mainnet's shape (a lone block
+#: under a 2^20 genesis is 1,400-1,580 nodes and sits on the first rung, as
+#: it did when the program was keyed on the wave's own powers of two). A
+#: wave above the top rung goes out as several launches, cut between
+#: blocks: a block's nodes only ever reference each other.
+VERDICT_LADDER: Tuple[Tuple[int, int], ...] = ((2048, 1), (4096, 2), (8192, 4))
+
+#: THE shape set of `_update_impl` and `_gather_impl`, in rows: ONE rung, a
+#: request's novel nodes (1,400-1,580 under a 2^20 genesis sat on 2,048
+#: when the programs were keyed on the batch's own power of two). A wave
+#: that brings more goes out as launches of 2,048 (rows are independent;
+#: 1.04 ms a launch, my chip run, PR 31), a batch of a few novel nodes (a
+#: block altered in one node) pads up to it. Why one: the boot builds every
+#: rung before the port answers, and with 64 / 2,048 / 4,096 / 8,192 a warm
+#: server on the host-walk configuration came up after 43 s where it had
+#: come up at once (`setup_s` +31 % against a bound of 0.25; my chip run,
+#: PR 34): `_update_impl` is the one table program that is dear to trace.
+ROW_LADDER: Tuple[int, ...] = (2048,)
+
+
+def verdict_rung(n_nodes: int, n_blocks: int) -> Optional[Tuple[int, int]]:
+    """The first rung of VERDICT_LADDER that holds a launch of `n_nodes`
+    rows in `n_blocks` blocks, or None above the top rung."""
+    for rows, blocks in VERDICT_LADDER:
+        if rows >= n_nodes and blocks >= n_blocks:
+            return rows, blocks
+    return None
+
+
+def _verdict_launches(counts: Sequence[int]) -> List[Tuple[int, int, Tuple[int, int]]]:
+    """Cut a wave of blocks of `counts` nodes into launches of whole
+    blocks, in order: (first block, one past the last, the launch's shape),
+    each as many blocks as the top rung holds. One block above the top
+    rung's rows stands alone on its own power of two, the one shape
+    outside the ladder (`lanes.oversize_launches`)."""
+    top_rows, top_blocks = VERDICT_LADDER[-1]
+    cuts = []
+    first = rows = 0
+    for b, n in enumerate(counts):
+        if b > first and (b - first == top_blocks or rows + n > top_rows):
+            cuts.append((first, b, rows))
+            first, rows = b, 0
+        rows += n
+    cuts.append((first, len(counts), rows))
+    return [
+        (lo, hi, verdict_rung(r, hi - lo) or (pow2ceil(r), 1)) for lo, hi, r in cuts
+    ]
+
+
+def verdict_rows(counts: Sequence[int]) -> int:
+    """The node rows a wave of blocks of `counts` nodes is launched on,
+    all its launches together (`rung=` of `phant/witness.dispatch`)."""
+    return sum(shape[0] for _lo, _hi, shape in _verdict_launches(counts))
 
 
 def resident_default_cap() -> int:
@@ -206,11 +266,6 @@ def _lookup_impl(index, fps, q):
 _JIT_PROGRAMS: dict = {}
 _JIT_LOCK = threading.Lock()
 
-#: the (device, table rows, batch rows) triples `_update_impl` has run on
-#: in this process (`witness_resident.update_programs`): the row form has
-#: no blob length in its key, so a served table adds one per batch size
-_update_shapes: set = set()
-
 
 def _jit_programs(donate: bool) -> dict:
     """The jitted resident programs, memoized per donation mode (which
@@ -242,11 +297,9 @@ class ResidentBatch:
     the ONLY downlink traffic of witness verification."""
 
     __slots__ = (
-        "verdict_out",
-        "digest_out",
+        "verdict_outs",  # (unresolved verdict bits, blocks) of each launch
+        "digest_outs",  # (unresolved digest rows, real rows) of each launch
         "dropped_outs",
-        "n_blocks",
-        "n_core_novel",
         "uploaded_nodes",
         "uploaded_bytes",
         "generation",
@@ -257,8 +310,18 @@ class ResidentBatch:
     def __init__(self):
         for name in self.__slots__:
             setattr(self, name, None)
+        self.verdict_outs = []
+        self.digest_outs = []
         self.dropped_outs = []
         self.resolved = False
+
+    def drop_outputs(self) -> list:
+        """Let go of the device outputs unread (an abandoned handle) and
+        hand back the drop counts, which the table still has to read."""
+        dropped, self.dropped_outs = self.dropped_outs, []
+        self.verdict_outs = []
+        self.digest_outs = []
+        return dropped
 
     def resolve(self) -> Tuple[np.ndarray, List[bytes]]:
         """(verdicts, core_novel_digests) — the honest sync of the
@@ -270,22 +333,22 @@ class ResidentBatch:
             # device.host_seconds{lane=witness,op=sync} is the time this
             # thread stands blocked on the chip, and nothing else
             with device_host("witness", "sync"):
-                verdicts = np.asarray(self.verdict_out)[: self.n_blocks]  # phantlint: disable=HOSTSYNC — timed resident verdict readback
-                digest_words = None
-                if self.digest_out is not None:
-                    digest_words = np.asarray(self.digest_out)  # phantlint: disable=HOSTSYNC — timed core-commit digest readback
+                verdicts = np.concatenate(
+                    [np.asarray(out)[:n] for out, n in self.verdict_outs]  # phantlint: disable=HOSTSYNC — timed resident verdict readback
+                )
+                digest_words = [
+                    np.asarray(out)[:n] for out, n in self.digest_outs  # phantlint: disable=HOSTSYNC — timed core-commit digest readback
+                ]
                 dropped = 0
                 for out in self.dropped_outs:
                     dropped += int(np.asarray(out))  # phantlint: disable=HOSTSYNC — rides the resolve sync above
             digests: List[bytes] = []
-            if digest_words is not None:
-                digests = digests_to_bytes(digest_words)[: self.n_core_novel]
+            for words in digest_words:
+                digests.extend(digests_to_bytes(words))
         if dropped and self._table is not None:
             self._table.note_index_dropped(dropped)
         self.resolved = True
-        self.dropped_outs = []
-        self.verdict_out = None  # release the device outputs
-        self.digest_out = None
+        self.drop_outputs()  # release the device outputs
         return verdicts.astype(bool), digests
 
 
@@ -300,7 +363,7 @@ class ResidentTable:
         start_cap: Optional[int] = None,
         device=None,
     ):
-        self._max_cap = _pow2ceil(max_cap or resident_default_cap())
+        self._max_cap = pow2ceil(max_cap or resident_default_cap())
         import jax
 
         on_device = jax.default_backend() != "cpu"
@@ -317,7 +380,7 @@ class ResidentTable:
                     self._max_cap if on_device else 1 << 10,
                 )
             )
-        self._start_cap = min(_pow2ceil(max(start_cap, 64)), self._max_cap)
+        self._start_cap = min(pow2ceil(max(start_cap, 64)), self._max_cap)
         self._device = device  # jax device handle or None (default placement)
         self._lock = threading.Lock()
         #: the authoritative commit: exact node bytes -> resident row.
@@ -439,7 +502,7 @@ class ResidentTable:
             for j, nb in enumerate(keep):
                 sob[nb] = j
             self._n_rows = len(keep)
-            self._deferred_dropped.append(self._update_locked(keep, 0))
+            self._deferred_dropped.extend(self._update_locked(keep, 0))
             self.stats["retained_rows"] = len(keep)
 
     def note_index_dropped(self, n: int) -> None:
@@ -514,22 +577,38 @@ class ResidentTable:
             with self._lock:
                 return self._dispatch_locked(witnesses, core_novel)
 
-    def _update_locked(self, nodes: List[bytes], base: int):
+    def _update_locked(self, nodes: List[bytes], base: int) -> list:
         """Enqueue the update program over `nodes` (rows base.. of the
-        table) in the row form; returns its unread drop count. Counted at
-        dispatch: what the row form uploads against what the nodes hold."""
-        words, lens = pack_node_rows(nodes, WITNESS_MAX_CHUNKS)
-        slots = np.full(lens.shape[0], -1, np.int32)
-        slots[: len(nodes)] = np.arange(base, base + len(nodes), dtype=np.int32)
-        n_bytes = int(lens.sum())
-        metrics.count("witness_resident.update_rows", len(nodes), kind="real")
-        metrics.count(
-            "witness_resident.update_rows", lens.shape[0] - len(nodes), kind="pad"
-        )
-        metrics.count("witness_resident.update_bytes", n_bytes, kind="payload")
-        metrics.count(
-            "witness_resident.update_bytes", words.nbytes - n_bytes, kind="pad"
-        )
+        table) in the row form, one launch a rung of ROW_LADDER (rows are
+        independent: above the top rung, several launches of it); returns
+        the launches' unread drop counts. Counted at dispatch: what the
+        row form uploads against what the nodes hold."""
+        dropped, at = [], 0
+        rungs = launches(ROW_LADDER, len(nodes))
+        for rung in rungs:
+            part = nodes[at : at + rung]
+            words, lens = pack_node_rows(part, WITNESS_MAX_CHUNKS, pad_rows_to=rung)
+            slots = np.full(rung, -1, np.int32)
+            slots[: len(part)] = np.arange(
+                base + at, base + at + len(part), dtype=np.int32
+            )
+            n_bytes = int(lens.sum())
+            metrics.count("witness_resident.update_rows", len(part), kind="real")
+            metrics.count("witness_resident.update_rows", rung - len(part), kind="pad")
+            metrics.count("witness_resident.update_bytes", n_bytes, kind="payload")
+            metrics.count(
+                "witness_resident.update_bytes", words.nbytes - n_bytes, kind="pad"
+            )
+            dropped.append(self._launch_update(words, lens, slots))
+            self.stats["uploaded_bytes"] += n_bytes
+            at += rung
+        note_split("update", len(rungs))
+        self.stats["uploaded_nodes"] += len(nodes)
+        return dropped
+
+    def _launch_update(self, words, lens, slots):
+        """One launch of the update program; the table's arrays are its
+        (donated) outputs. Returns the unread drop count."""
         # device.host_seconds{lane=witness,op=enqueue}: the uploads and
         # launches of this batch (update, verdict, gather), and not the
         # host's numpy work between them
@@ -542,12 +621,62 @@ class ResidentTable:
                 max_chunks=WITNESS_MAX_CHUNKS,
             )
         self._arrays = out[:5]
-        with _JIT_LOCK:
-            _update_shapes.add((self._device, self._cap, lens.shape[0]))
-            metrics.gauge_set("witness_resident.update_programs", len(_update_shapes))
-        self.stats["uploaded_nodes"] += len(nodes)
-        self.stats["uploaded_bytes"] += n_bytes
+        note_launch("update", lens.shape[0], self._device, self._cap)
         return out[5]
+
+    def _launch_verdict(self, rows, block_id, roots_w):
+        """One launch of the verdict program over `rows` (resident row of
+        each node, -1 = padding) of the blocks `block_id` names."""
+        digests, refs, tail = self._arrays[:3]
+        with device_host("witness", "enqueue"):
+            out = self._verdict_fn(
+                digests,
+                refs,
+                tail,
+                self._put(rows),
+                self._put(rows >= 0),
+                self._put(block_id),
+                self._put(roots_w),
+            )
+        note_launch(
+            "verdict", (rows.shape[0], roots_w.shape[0]), self._device, self._cap
+        )
+        return out
+
+    def _launch_gather(self, slots):
+        """One launch of the gather program: the digest rows at `slots`."""
+        with device_host("witness", "enqueue"):
+            out = self._gather_fn(self._arrays[0], self._put(slots))
+        note_launch("gather", slots.shape[0], self._device, self._cap)
+        return out
+
+    def prewarm(self) -> int:
+        """Build every served program of this table on every rung of its
+        ladder, on the table's own arrays at the cap it is born at, so
+        that no request ever waits for one: called when a server starts on
+        an accelerator (engine_api/server.py), before the port answers.
+        Each launch is empty (every slot and row -1: nothing is written,
+        no verdict is read). Returns the programs built or loaded."""
+        import jax
+
+        with self._lock:
+            if self._arrays is None:
+                self._grow_locked(0)
+            outs = []
+            for rung in ROW_LADDER:
+                none = np.full(rung, -1, np.int32)
+                words = np.zeros((rung, _ROW_BYTES // 4), np.uint32)
+                outs.append(self._launch_update(words, np.zeros(rung, np.int32), none))
+                outs.append(self._launch_gather(none))
+            for rows, blocks in VERDICT_LADDER:
+                none = np.full(rows, -1, np.int32)
+                outs.append(
+                    self._launch_verdict(
+                        none, np.zeros(rows, np.int32), np.zeros((blocks, 8), np.uint32)
+                    )
+                )
+        jax.block_until_ready(outs)  # phantlint: disable=HOSTSYNC — boot prewarm: the build is the point
+        return len(outs)
 
     def _dispatch_locked(self, witnesses, core_novel):
         n_blocks = len(witnesses)
@@ -591,7 +720,6 @@ class ResidentTable:
 
         h = ResidentBatch()
         h._table = self
-        h.n_blocks = n_blocks
         h.generation = self.generation
 
         # authoritative commit: assign rows to the truly-novel bytes
@@ -602,48 +730,48 @@ class ResidentTable:
 
         # update program: upload ONLY the pruned novel nodes, laid out
         if cand:
-            h.dropped_outs.append(self._update_locked(cand, base))
+            h.dropped_outs.extend(self._update_locked(cand, base))
         h.dropped_outs.extend(self._deferred_dropped)
         self._deferred_dropped = []
 
-        # verdict program: row ids + roots only (4 B/node + 32 B/block)
-        n_nodes = len(all_nodes)
-        np_pad = _pow2ceil(max(n_nodes, 1))
-        rows = np.full(np_pad, -1, np.int32)
-        rows[:n_nodes] = np.fromiter(
-            (sob[nb] for nb in all_nodes), np.int32, n_nodes
-        )
-        block_id = np.zeros(np_pad, np.int32)
-        block_id[:n_nodes] = np.repeat(
-            np.arange(n_blocks, dtype=np.int32), counts
-        )
-        nb_pad = _pow2ceil(n_blocks)
-        roots_w = np.zeros((nb_pad, 8), np.uint32)
-        for b, (root, _nodes) in enumerate(witnesses):
-            roots_w[b] = np.frombuffer(root, dtype="<u4")
-        digests, refs, tail = self._arrays[:3]
-        with device_host("witness", "enqueue"):
-            rows_d = self._put(rows)
-            h.verdict_out = self._verdict_fn(
-                digests,
-                refs,
-                tail,
-                rows_d,
-                rows_d >= 0,
-                self._put(block_id),
-                self._put(roots_w),
+        # verdict program: row ids + roots only (4 B/node + 32 B/block),
+        # one launch a rung of VERDICT_LADDER, whole blocks a launch
+        rows_of = np.fromiter((sob[nb] for nb in all_nodes), np.int32, len(all_nodes))
+        ends = np.cumsum(counts)
+        cuts = _verdict_launches(counts.tolist())
+        for lo, hi, (np_pad, nb_pad) in cuts:
+            n_at, n_end = int(ends[lo] - counts[lo]), int(ends[hi - 1])
+            n_nodes = n_end - n_at
+            rows = np.full(np_pad, -1, np.int32)
+            rows[:n_nodes] = rows_of[n_at:n_end]
+            block_id = np.zeros(np_pad, np.int32)
+            block_id[:n_nodes] = np.repeat(
+                np.arange(hi - lo, dtype=np.int32), counts[lo:hi]
             )
+            roots_w = np.zeros((nb_pad, 8), np.uint32)
+            for b in range(lo, hi):
+                roots_w[b - lo] = np.frombuffer(witnesses[b][0], dtype="<u4")
+            metrics.count("witness_resident.verdict_rows", n_nodes, kind="real")
+            metrics.count("witness_resident.verdict_rows", np_pad - n_nodes, kind="pad")
+            if (np_pad, nb_pad) not in VERDICT_LADDER:
+                metrics.count("lanes.oversize_launches", program="verdict")
+            h.verdict_outs.append((self._launch_verdict(rows, block_id, roots_w), hi - lo))
+        note_split("verdict", len(cuts))
 
         # core-commit digests: the engine's host tables intern from the
         # DEVICE digests, so the host never hashes on this route
-        h.n_core_novel = len(core_novel)
         if core_novel:
-            cslots = np.full(_pow2ceil(len(core_novel)), -1, np.int32)
-            cslots[: len(core_novel)] = np.fromiter(
+            cslots_of = np.fromiter(
                 (sob[nb] for nb in core_novel), np.int32, len(core_novel)
             )
-            with device_host("witness", "enqueue"):
-                h.digest_out = self._gather_fn(digests, self._put(cslots))
+            rungs, at = launches(ROW_LADDER, len(core_novel)), 0
+            for rung in rungs:
+                part = cslots_of[at : at + rung]
+                cslots = np.full(rung, -1, np.int32)
+                cslots[: len(part)] = part
+                h.digest_outs.append((self._launch_gather(cslots), len(part)))
+                at += rung
+            note_split("gather", len(rungs))
 
         h.uploaded_nodes = len(cand)
         h.uploaded_bytes = sum(map(len, cand))

@@ -44,7 +44,7 @@ shape instead:
 * **Batch assembler** — coalesces *witness-verification* requests into
   shape buckets (bucket key = total witness bytes rounded up to a power
   of two, the same rounding the device keccak path pads its blob buffer
-  to, ops/witness_jax._pow2ceil), so the padded device buffers of one
+  to, utils/rungs.pow2ceil), so the padded device buffers of one
   batch stay dense; same-bucket jobs coalesce ACROSS tenant lanes (the
   engine dispatch is tenant-blind; fairness is enforced at head pick).
   `sched.padding_waste` reports the unused fraction of the padded
@@ -169,6 +169,7 @@ from phant_tpu.serving.qos import (
     current_tenant,
     parse_weights,
 )
+from phant_tpu.utils.rungs import pow2ceil as _pow2ceil
 from phant_tpu.utils.trace import (
     clock_ns,
     current_trace_id,
@@ -368,13 +369,6 @@ _NO_BATCH = object()
 
 #: batch-size histogram buckets (requests per engine dispatch)
 _BATCH_BUCKETS: Tuple[float, ...] = (1, 2, 4, 8, 16, 32, 64, 128, 256)
-
-
-def _pow2ceil(n: int) -> int:
-    p = 1
-    while p < max(n, 1):
-        p *= 2
-    return p
 
 
 def _safe_resolve(future: Future, result) -> None:
@@ -2780,6 +2774,19 @@ class VerificationScheduler:
         record["resolve_ms"] = round((time.monotonic() - t0) * 1e3, 3)
         fold_stages(record, stages)
         finish(jobs, results, record, item["picked"])
+
+    def prewarm_lanes(self) -> int:
+        """Build the resident table's programs, which the witness lane
+        launches, on every rung of their ladders (utils/rungs.py): a
+        server's boot on an accelerator, before its port answers. Returns
+        the programs built or loaded. (`ecrecover_kernel` has one rung,
+        which the first request builds whatever its wave:
+        `secp256k1_jax.SIG_LADDER` says why.) A mesh pool's pinned engines
+        are not reached: each builds on its own chip when first met
+        (PERF.md section 7, m)."""
+        if self._pool is not None:
+            return 0
+        return self._resolve_engine().prewarm_resident()
 
     def _resolve_engine(self):
         with self._engine_lock:

@@ -249,9 +249,16 @@ METRIC_HELP: Dict[str, str] = {
     "witness_resident.rows": "Rows resident on device (digest + child-ref rows, persistent across batches)",
     "witness_resident.uploaded_nodes": "Truly-novel nodes uploaded to the resident table (after the host prune)",
     "witness_resident.uploaded_bytes": "Truly-novel bytes uploaded to the resident table — the ONLY recurring h2d payload of the resident route",
-    "witness_resident.update_rows": "Rows of the batches handed to the resident update program, by kind: real = novel nodes, pad = the zero rows up to the batch's power of two (hashed and dropped)",
+    "witness_resident.update_rows": "Rows of the batches handed to the resident update program, by kind: real = novel nodes, pad = the zero rows up to the launch's rung of witness_resident.ROW_LADDER (hashed and dropped)",
     "witness_resident.update_bytes": "Bytes the resident update's row form uploads, by kind: payload = what the novel nodes hold, pad = the zeros that fill each row to 680 bytes and the pad rows",
-    "witness_resident.update_programs": "Distinct shapes (device, table rows, batch rows) the resident update program has run on in this process: the row form's key has no blob length, so one per batch size",
+    "witness_resident.verdict_rows": "Node rows of the launches of the resident verdict program, by kind: real = witness nodes, pad = the empty rows up to the launch's rung of witness_resident.VERDICT_LADDER",
+    # the shapes served device programs run on (utils/rungs.py)
+    "lanes.program_shapes": "Distinct shapes each served device program has run on in this process, by program (ecrecover: device and signature rung; verdict, gather, update: device, table rows and rung): a server on an accelerator builds every rung of the table's ladders before its port answers and the first request ecrecover's one, so it stops there; exported from server start",
+    "lanes.launches": "Launches of served device programs by program and rung (verdict: rows x blocks), the boot's own among them; a rung that first appears after server start was built inside a request",
+    "lanes.split_launches": "Launches of waves above a ladder's top rung, which go out as several launches of the top rung, by program",
+    "lanes.oversize_launches": "Launches outside a ladder, by program: one block of more witness nodes than the verdict ladder's top rung holds keeps a shape of its own",
+    "lanes.prewarm_seconds": "Seconds the server's constructor took to build the resident table's update, verdict and gather programs on every rung of their ladders (only with an accelerator under it)",
+    "sig.rows": "Signature rows of the launches of the ecrecover kernel, by kind: real = signatures, pad = the filler rows up to the launch's rung of secp256k1_jax.SIG_LADDER",
     "witness_resident.dispatch": "Resident dispatch phase: prune + row assignment + update/verdict enqueue, no host sync",
     "witness_resident.resolve": "Resident resolve phase: verdict (1 B/block) + core-novel digest readback (the honest sync)",
     # continuous-batching scheduler (phant_tpu/serving/)
@@ -429,11 +436,12 @@ class Metrics:
             h.add(value)
 
     @contextlib.contextmanager
-    def phase(self, name: str) -> Iterator[None]:
+    def phase(self, name: str, **attrs) -> Iterator[None]:
         """Time a phase: `with metrics.phase("engine_api.new_payload"): ...`.
         Inside an open span the phase is a measured child interval of it,
-        and where ANNOTATIONS names it, an event of the profiler's trace."""
-        ann = annotate(ANNOTATIONS.get(name))
+        and where ANNOTATIONS names it, an event of the profiler's trace,
+        which carries `attrs` (a lane's dispatch says its `rung`)."""
+        ann = annotate(ANNOTATIONS.get(name), **attrs)
         t0 = clock_ns()
         try:
             yield

@@ -1053,9 +1053,9 @@ def test_sig_engine_is_in_hostsync_scope(mutated_tree, monkeypatch):
     p = mutated_tree / "phant_tpu" / "ops" / "sig_engine.py"
     src = p.read_text()
     mutated = src.replace(
-        "        par = np.array(pars + [0] * pad, np.uint32)\n",
-        "        par = np.array(pars + [0] * pad, np.uint32)\n"
-        "        _n = par.sum().item()\n",
+        "        return pack_signatures(es, rs, ss, pars)\n",
+        "        _n = pack_signatures(es, rs, ss, pars)[3].sum().item()\n"
+        "        return pack_signatures(es, rs, ss, pars)\n",
         1,
     )
     assert mutated != src

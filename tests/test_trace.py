@@ -130,6 +130,40 @@ def test_labeled_counters():
     assert m.snapshot()["counters"]['x{a="1",b="2"}'] == 2
 
 
+def test_a_phase_hands_its_attributes_to_its_annotation(monkeypatch):
+    """`phase(name, rung=...)`: where ANNOTATIONS names the phase and a
+    profiler runs, the event carries the attributes (a lane's dispatch
+    says its rung); the timer is observed either way."""
+    from phant_tpu.utils import trace
+
+    seen = []
+
+    class FakeAnnotation:
+        def __init__(self, name, **attrs):
+            seen.append((name, attrs))
+
+        @staticmethod
+        def is_enabled():
+            return True
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            seen.append("ended")
+
+    monkeypatch.setattr(trace, "_trace_me", FakeAnnotation)
+    name = next(iter(trace.ANNOTATIONS))
+    m = Metrics()
+    with m.phase(name, rung=4096):
+        pass
+    with m.phase("not.annotated", rung=1):
+        pass
+    assert seen[0][0] == trace.ANNOTATIONS[name] and seen[0][1]["rung"] == 4096
+    assert seen[1:] == ["ended"]
+    assert m.snapshot()["timers"][name]["count"] == 1
+
+
 def test_prometheus_text_parses_back():
     """The exposition must be machine-parseable standard text format:
     parse it back line by line and recover the recorded values."""

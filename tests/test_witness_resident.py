@@ -550,7 +550,7 @@ def _counter(name, **labels):
 
 def test_same_row_count_different_byte_totals_run_one_update_program():
     """The blob form keyed the program on the blob's padded length; the row
-    form has rows only: 1,000 bytes or 20,000 in 64 rows are one program."""
+    form has rows only: 1,000 bytes or 20,000 in a rung of rows are one program."""
     from phant_tpu.ops.witness_resident import ResidentTable
 
     rng = np.random.default_rng(3)
@@ -558,12 +558,13 @@ def test_same_row_count_different_byte_totals_run_one_update_program():
     table = ResidentTable(max_cap=2048, start_cap=2048)
     small = [rng.bytes(20) for _ in range(40)]
     large = [rng.bytes(500) for _ in range(40)]
-    before = _gauge("witness_resident.update_programs")
+    shapes = 'lanes.program_shapes{program="update"}'
+    before = _gauge(shapes)
     built = table._update_fn._cache_size()
     table.dispatch([(b"\x00" * 32, small)], []).resolve()
-    assert _gauge("witness_resident.update_programs") == before + 1
+    assert _gauge(shapes) == before + 1
     table.dispatch([(b"\x00" * 32, large)], []).resolve()
-    assert _gauge("witness_resident.update_programs") == before + 1
+    assert _gauge(shapes) == before + 1
     assert table._update_fn._cache_size() == built + 1
 
 
@@ -586,8 +587,8 @@ def test_update_counters_say_what_the_row_form_uploads():
     grew = {key: _counter(key[0], kind=key[1]) - v for key, v in was.items()}
     payload = sum(map(len, nodes))
     assert grew[("witness_resident.update_rows", "real")] == 37
-    assert grew[("witness_resident.update_rows", "pad")] == 64 - 37
+    assert grew[("witness_resident.update_rows", "pad")] == 2048 - 37
     assert grew[("witness_resident.update_bytes", "payload")] == payload
-    assert grew[("witness_resident.update_bytes", "pad")] == 64 * 680 - payload
+    assert grew[("witness_resident.update_bytes", "pad")] == 2048 * 680 - payload
     st = table.stats_snapshot()
     assert st["uploaded_nodes"] == 37 and st["uploaded_bytes"] == payload
