@@ -38,11 +38,19 @@
 // the GIL: the whole point of the pipelined protocol is that the resolve
 // worker's C time runs concurrently with the executor's Python time.
 // Engine-level exclusion of table mutation is WitnessEngine._lock.
+//
+// The module also carries the trie-node encoder (second half of this
+// file): one RLP writer under the host walk of a trie (encode_subtree),
+// under the hash-plan builder's templates (encode_node) and, being the
+// same bytes, under rlp.encode. It holds the GIL throughout: every step
+// reads Python objects.
 
 #define PY_SSIZE_T_CLEAN
 #include <Python.h>
 
 #include <cstdint>
+#include <cstdlib>
+#include <cstring>
 #include <vector>
 
 extern "C" {
@@ -63,6 +71,7 @@ int phant_engine_verdict(void*, const int64_t*, const uint64_t*, uint64_t,
                          const uint8_t*, uint8_t*);
 void phant_keccak256_ptrs_fast(const uint8_t* const*, const uint32_t*,
                                uint64_t, uint8_t*);
+void phant_keccak256(const uint8_t*, size_t, uint8_t*);
 }
 
 namespace {
@@ -528,11 +537,525 @@ PyTypeObject BatchType = {
     sizeof(BatchObject),                 /* tp_basicsize */
 };
 
+
+// --- the trie-node encoder ---------------------------------------------------
+//
+// One RLP writer. An item is bytes (bytearray and memoryview too), an int
+// (its minimal big-endian bytes, as rlp.encode has it), a list or tuple
+// of items and, in the top list of a template alone, a hole (32 zero
+// bytes under the header 0xA0) or a value hole (prefix + 32 zero bytes +
+// suffix as ONE string item). Anything else is a TypeError, as it is in
+// phant_tpu/rlp.py, the oracle this is tested against byte for byte.
+
+// A list's header goes in front of a payload whose length is known only
+// once it is written: every list leaves kHeaderRoom bytes free, writes
+// its payload behind them and moves it up against the header.
+constexpr size_t kHeaderRoom = 9;
+constexpr int kMaxDepth = 200;  // of nested lists; Python's own limit is lower
+constexpr int kMaxTrieDepth = 1024;  // of a walk: no key has as many digits
+
+struct Out {
+  uint8_t* data = nullptr;
+  size_t len = 0;
+  size_t cap = 0;
+  ~Out() { std::free(data); }
+
+  uint8_t* grow(size_t n) {  // n more bytes at the end, or nullptr
+    if (len + n > cap) {
+      size_t want = cap ? cap * 2 : 1024;
+      while (want < len + n) want *= 2;
+      uint8_t* p = static_cast<uint8_t*>(std::realloc(data, want));
+      if (!p) {
+        PyErr_NoMemory();
+        return nullptr;
+      }
+      data = p;
+      cap = want;
+    }
+    uint8_t* at = data + len;
+    len += n;
+    return at;
+  }
+};
+
+// Header of a string (base 0x80) or list (base 0xC0) of `n` payload
+// bytes, written to `to` (room for kHeaderRoom); returns its length.
+size_t write_header(uint8_t* to, size_t n, uint8_t base) {
+  if (n <= 55) {
+    to[0] = static_cast<uint8_t>(base + n);
+    return 1;
+  }
+  size_t ll = 0;
+  for (size_t v = n; v; v >>= 8) ++ll;
+  to[0] = static_cast<uint8_t>(base + 55 + ll);
+  for (size_t i = 0; i < ll; ++i)
+    to[1 + i] = static_cast<uint8_t>(n >> (8 * (ll - 1 - i)));
+  return 1 + ll;
+}
+
+bool put_string(Out* out, const uint8_t* p, size_t n) {
+  if (n == 1 && p[0] < 0x80) {
+    uint8_t* at = out->grow(1);
+    if (!at) return false;
+    at[0] = p[0];
+    return true;
+  }
+  uint8_t head[kHeaderRoom];
+  const size_t h = write_header(head, n, 0x80);
+  uint8_t* at = out->grow(h + n);
+  if (!at) return false;
+  std::memcpy(at, head, h);
+  if (n) std::memcpy(at + h, p, n);
+  return true;
+}
+
+// A bytes object as a string item; `raw` may be null (an error is set).
+bool put_bytes(Out* out, PyObject* raw) {
+  return raw &&
+         put_string(out, reinterpret_cast<uint8_t*>(PyBytes_AS_STRING(raw)),
+                    static_cast<size_t>(PyBytes_GET_SIZE(raw)));
+}
+
+struct Holes {
+  PyObject* hole;        // identity marks a hole; nullptr: none known
+  PyObject* value_hole;  // exact type of a value hole; nullptr: none
+  std::vector<size_t> at;  // byte offset of each hole in `Out`
+};
+
+bool put_int(Out* out, PyObject* item) {
+  const unsigned long long v = PyLong_AsUnsignedLongLong(item);
+  if (v == static_cast<unsigned long long>(-1) && PyErr_Occurred()) {
+    if (!PyErr_ExceptionMatches(PyExc_OverflowError)) return false;
+    PyErr_Clear();
+    PyObject* zero = PyLong_FromLong(0);
+    if (!zero) return false;
+    const int neg = PyObject_RichCompareBool(item, zero, Py_LT);
+    Py_DECREF(zero);
+    if (neg < 0) return false;
+    if (neg) {
+      PyErr_SetString(PyExc_ValueError, "cannot RLP-encode negative integer");
+      return false;
+    }
+    // wider than 64 bits: Python lays the bytes out
+    PyObject* bits = PyObject_CallMethod(item, "bit_length", nullptr);
+    if (!bits) return false;
+    const size_t nbytes = (PyLong_AsSize_t(bits) + 7) / 8;
+    Py_DECREF(bits);
+    PyObject* raw = PyObject_CallMethod(item, "to_bytes", "ns",
+                                        static_cast<Py_ssize_t>(nbytes), "big");
+    const bool ok = put_bytes(out, raw);
+    Py_XDECREF(raw);
+    return ok;
+  }
+  uint8_t be[8];
+  size_t n = 0;
+  for (unsigned long long w = v; w; w >>= 8) ++n;
+  for (size_t i = 0; i < n; ++i)
+    be[i] = static_cast<uint8_t>(v >> (8 * (n - 1 - i)));
+  return put_string(out, be, n);
+}
+
+// prefix + 32 zero bytes + suffix as ONE string item; the hole is the zeros.
+bool put_value_hole(Out* out, PyObject* item, Holes* holes) {
+  PyObject* prefix = PyObject_GetAttrString(item, "prefix");
+  PyObject* suffix = prefix ? PyObject_GetAttrString(item, "suffix") : nullptr;
+  bool ok = false;
+  if (suffix && !(PyBytes_Check(prefix) && PyBytes_Check(suffix))) {
+    PyErr_SetString(PyExc_TypeError, "a value hole's prefix and suffix are bytes");
+  } else if (suffix) {
+    const size_t np = static_cast<size_t>(PyBytes_GET_SIZE(prefix));
+    const size_t ns = static_cast<size_t>(PyBytes_GET_SIZE(suffix));
+    uint8_t head[kHeaderRoom];
+    const size_t h = write_header(head, np + 32 + ns, 0x80);
+    uint8_t* at = out->grow(h + np + 32 + ns);
+    if (at) {
+      std::memcpy(at, head, h);
+      std::memcpy(at + h, PyBytes_AS_STRING(prefix), np);
+      std::memset(at + h + np, 0, 32);
+      std::memcpy(at + h + np + 32, PyBytes_AS_STRING(suffix), ns);
+      holes->at.push_back(out->len - ns - 32);
+      ok = true;
+    }
+  }
+  Py_XDECREF(prefix);
+  Py_XDECREF(suffix);
+  return ok;
+}
+
+bool put_item(Out* out, PyObject* item, Holes* holes, int depth);
+
+constexpr size_t kFailed = static_cast<size_t>(-1);
+
+// The items of `seq` (a list or tuple) as one RLP list, written behind
+// kHeaderRoom free bytes with the header up against the payload: returns
+// where the list's encoding starts, or kFailed. `holes` is for the items
+// of a template's top list.
+size_t put_list(Out* out, PyObject* seq, Holes* holes, int depth) {
+  if (depth > kMaxDepth) {
+    PyErr_SetString(PyExc_RecursionError, "RLP nesting too deep");
+    return kFailed;
+  }
+  const size_t start = out->len;
+  if (!out->grow(kHeaderRoom)) return kFailed;
+  // an item's encoding can run Python code (an int subclass's to_bytes,
+  // a buffer's bytes()), so the size is read anew and the item is held
+  for (Py_ssize_t i = 0; i < PySequence_Fast_GET_SIZE(seq); ++i) {
+    PyObject* item = PySequence_Fast_GET_ITEM(seq, i);
+    Py_INCREF(item);
+    const bool ok = put_item(out, item, holes, depth + 1);
+    Py_DECREF(item);
+    if (!ok) return kFailed;
+  }
+  uint8_t head[kHeaderRoom];
+  const size_t h = write_header(head, out->len - start - kHeaderRoom, 0xC0);
+  const size_t from = start + kHeaderRoom - h;
+  std::memcpy(out->data + from, head, h);
+  return from;
+}
+
+bool put_item(Out* out, PyObject* item, Holes* holes, int depth) {
+  if (PyBytes_Check(item)) return put_bytes(out, item);
+  if (PyList_Check(item) || PyTuple_Check(item)) {
+    // a nested list closes the gap its header room left
+    const size_t start = out->len;
+    const size_t from = put_list(out, item, nullptr, depth);
+    if (from == kFailed) return false;
+    std::memmove(out->data + start, out->data + from, out->len - from);
+    out->len -= from - start;
+    return true;
+  }
+  if (holes && item == holes->hole) {
+    uint8_t* at = out->grow(33);
+    if (!at) return false;
+    at[0] = 0xA0;
+    std::memset(at + 1, 0, 32);
+    holes->at.push_back(out->len - 32);
+    return true;
+  }
+  if (holes && reinterpret_cast<PyObject*>(Py_TYPE(item)) == holes->value_hole)
+    return put_value_hole(out, item, holes);
+  if (PyLong_Check(item)) return put_int(out, item);
+  if (PyByteArray_Check(item) || PyMemoryView_Check(item)) {
+    PyObject* raw = PyBytes_FromObject(item);
+    const bool ok = put_bytes(out, raw);
+    Py_XDECREF(raw);
+    return ok;
+  }
+  PyErr_Format(PyExc_TypeError, "cannot RLP-encode %.200s",
+               Py_TYPE(item)->tp_name);
+  return false;
+}
+
+// Where the encoding of the top item lies in `out`.
+struct Span {
+  size_t start;
+  size_t len;
+};
+
+bool encode_top(Out* out, PyObject* item, Holes* holes, Span* span) {
+  if (PyList_Check(item) || PyTuple_Check(item)) {
+    span->start = put_list(out, item, holes, 0);
+    if (span->start == kFailed) return false;
+  } else {
+    span->start = 0;
+    if (!put_item(out, item, nullptr, 0)) return false;
+  }
+  span->len = out->len - span->start;
+  return true;
+}
+
+// rlp_encode(item) -> bytes: phant_tpu.rlp.encode's bytes.
+PyObject* ext_rlp_encode(PyObject*, PyObject* item) {
+  Out out;
+  Span span;
+  if (!encode_top(&out, item, nullptr, &span)) return nullptr;
+  return PyBytes_FromStringAndSize(
+      reinterpret_cast<char*>(out.data + span.start),
+      static_cast<Py_ssize_t>(span.len));
+}
+
+// encode_node(hole, value_hole_type, items) -> (bytes, [hole offsets]):
+// the RLP list of `items` with every hole zeroed, and where each hole's
+// 32 bytes start, in the order met. The items come last so that a caller
+// binds its two sentinels once (functools.partial).
+PyObject* ext_encode_node(PyObject*, PyObject* const* args, Py_ssize_t nargs) {
+  if (nargs != 3) {
+    PyErr_SetString(PyExc_TypeError,
+                    "encode_node(hole, value_hole_type, items)");
+    return nullptr;
+  }
+  PyObject* seq = PySequence_Fast(args[2], "a node's items are a sequence");
+  if (!seq) return nullptr;
+  Holes holes{args[0] == Py_None ? nullptr : args[0],
+              args[1] == Py_None ? nullptr : args[1],
+              {}};
+  Out out;
+  Span span;
+  PyObject* ret = nullptr;
+  if (encode_top(&out, seq, &holes, &span)) {
+    PyObject* offs = PyList_New(static_cast<Py_ssize_t>(holes.at.size()));
+    if (offs) {
+      for (size_t i = 0; i < holes.at.size(); ++i) {
+        PyObject* v = PyLong_FromSize_t(holes.at[i] - span.start);
+        if (!v) {
+          Py_CLEAR(offs);
+          break;
+        }
+        PyList_SET_ITEM(offs, static_cast<Py_ssize_t>(i), v);
+      }
+    }
+    if (offs) {
+      PyObject* enc = PyBytes_FromStringAndSize(
+          reinterpret_cast<char*>(out.data + span.start),
+          static_cast<Py_ssize_t>(span.len));
+      if (enc) ret = PyTuple_Pack(2, enc, offs);
+      Py_XDECREF(enc);
+      Py_DECREF(offs);
+    }
+  }
+  Py_DECREF(seq);
+  return ret;
+}
+
+// Yellow-paper hex-prefix of a sequence of nibbles (mpt.encode_hex_prefix).
+PyObject* hex_prefix(PyObject* path, bool is_leaf) {
+  PyObject* seq = PySequence_Fast(path, "a node's path is a sequence of nibbles");
+  if (!seq) return nullptr;
+  const Py_ssize_t n = PySequence_Fast_GET_SIZE(seq);
+  PyObject* raw = PyBytes_FromStringAndSize(nullptr, 1 + n / 2);
+  if (!raw) {
+    Py_DECREF(seq);
+    return nullptr;
+  }
+  uint8_t* to = reinterpret_cast<uint8_t*>(PyBytes_AS_STRING(raw));
+  std::memset(to, 0, static_cast<size_t>(1 + n / 2));
+  to[0] = is_leaf ? 0x20 : 0x00;
+  // an odd path's first nibble shares the flag's byte; the rest pair up
+  Py_ssize_t at = 1;  // index of the next half byte of `to`, in nibbles
+  if (n % 2) to[0] |= 0x10; else at = 2;
+  for (Py_ssize_t i = 0; i < n; ++i, ++at) {
+    const long v = PyLong_AsLong(PySequence_Fast_GET_ITEM(seq, i));
+    if (v < 0 || v > 15) {
+      if (!PyErr_Occurred())
+        PyErr_SetString(PyExc_ValueError, "a nibble lies in 0..15");
+      Py_DECREF(seq);
+      Py_DECREF(raw);
+      return nullptr;
+    }
+    to[at / 2] |= static_cast<uint8_t>(at % 2 ? v : v << 4);
+  }
+  Py_DECREF(seq);
+  return raw;
+}
+
+// hex_prefix(nibbles, is_leaf) -> bytes
+PyObject* ext_hex_prefix(PyObject*, PyObject* const* args, Py_ssize_t nargs) {
+  if (nargs != 2) {
+    PyErr_SetString(PyExc_TypeError, "hex_prefix(nibbles, is_leaf)");
+    return nullptr;
+  }
+  const int leaf = PyObject_IsTrue(args[1]);
+  if (leaf < 0) return nullptr;
+  return hex_prefix(args[0], leaf != 0);
+}
+
+// The host walk of a trie: every node below `node` that `cache` does not
+// hold is encoded, children first, and its (structure, encoding) kept in
+// `cache` under id(node), Trie._enc_cache's contract. A child enters its
+// parent as its structure where its encoding is shorter than
+// `embed_below` bytes, else as the keccak-256 of the encoding; a node of
+// none of the three kinds enters as its `.digest` (an unwitnessed
+// subtree), never encoded.
+struct Walk {
+  PyObject* cache;
+  PyObject* path_enc;  // callable(path, is_leaf), or nullptr: hex-prefix
+  Py_ssize_t embed_below;
+  PyTypeObject* leaf;
+  PyTypeObject* extension;
+  PyTypeObject* branch;
+  PyObject* empty;  // b""
+};
+
+PyObject* walk_node(Walk* w, PyObject* node, int depth);
+
+PyObject* walk_path(Walk* w, PyObject* node, bool is_leaf) {
+  PyObject* path = PyObject_GetAttrString(node, "path");
+  if (!path) return nullptr;
+  PyObject* enc =
+      w->path_enc
+          ? PyObject_CallFunctionObjArgs(w->path_enc, path,
+                                         is_leaf ? Py_True : Py_False, nullptr)
+          : hex_prefix(path, is_leaf);
+  Py_DECREF(path);
+  return enc;
+}
+
+// What a parent holds of `child`: a new reference.
+PyObject* walk_ref(Walk* w, PyObject* child, int depth) {
+  PyTypeObject* t = Py_TYPE(child);
+  if (t != w->leaf && t != w->extension && t != w->branch) {
+    PyObject* digest = PyObject_GetAttrString(child, "digest");
+    if (!digest && PyErr_ExceptionMatches(PyExc_AttributeError)) {
+      PyErr_Clear();
+      PyErr_Format(PyExc_TypeError, "cannot encode a trie node of type %.200s",
+                   t->tp_name);
+    }
+    return digest;
+  }
+  PyObject* pair = walk_node(w, child, depth);
+  if (!pair) return nullptr;
+  PyObject* enc = PyTuple_GET_ITEM(pair, 1);
+  PyObject* ref;
+  if (PyBytes_GET_SIZE(enc) < w->embed_below) {
+    ref = PyTuple_GET_ITEM(pair, 0);
+    Py_INCREF(ref);
+  } else {
+    ref = PyBytes_FromStringAndSize(nullptr, 32);
+    if (ref)
+      phant_keccak256(reinterpret_cast<uint8_t*>(PyBytes_AS_STRING(enc)),
+                      static_cast<size_t>(PyBytes_GET_SIZE(enc)),
+                      reinterpret_cast<uint8_t*>(PyBytes_AS_STRING(ref)));
+  }
+  Py_DECREF(pair);
+  return ref;
+}
+
+// The node's items as a new list, its children's references resolved.
+PyObject* walk_structure(Walk* w, PyObject* node, int depth) {
+  PyTypeObject* t = Py_TYPE(node);
+  if (t == w->branch) {
+    PyObject* children = PyObject_GetAttrString(node, "children");
+    if (!children) return nullptr;
+    PyObject* seq = PySequence_Fast(children, "a branch's children are a sequence");
+    Py_DECREF(children);
+    if (!seq) return nullptr;
+    const Py_ssize_t n = PySequence_Fast_GET_SIZE(seq);
+    PyObject* items = PyList_New(n + 1);
+    for (Py_ssize_t i = 0; items && i < n; ++i) {
+      PyObject* child = PySequence_Fast_GET_ITEM(seq, i);
+      PyObject* ref;
+      if (child == Py_None) {
+        ref = w->empty;
+        Py_INCREF(ref);
+      } else {
+        ref = walk_ref(w, child, depth);
+      }
+      if (!ref) Py_CLEAR(items); else PyList_SET_ITEM(items, i, ref);
+    }
+    Py_DECREF(seq);
+    if (!items) return nullptr;
+    PyObject* value = PyObject_GetAttrString(node, "value");
+    if (!value) {
+      Py_DECREF(items);
+      return nullptr;
+    }
+    if (value == Py_None) {
+      Py_DECREF(value);
+      value = w->empty;
+      Py_INCREF(value);
+    }
+    PyList_SET_ITEM(items, n, value);
+    return items;
+  }
+  const bool is_leaf = t == w->leaf;
+  PyObject* path = walk_path(w, node, is_leaf);
+  if (!path) return nullptr;
+  PyObject* second;
+  if (is_leaf) {
+    second = PyObject_GetAttrString(node, "value");
+  } else {
+    PyObject* child = PyObject_GetAttrString(node, "child");
+    second = child ? walk_ref(w, child, depth) : nullptr;
+    Py_XDECREF(child);
+  }
+  PyObject* items = second ? PyList_New(2) : nullptr;
+  if (!items) {
+    Py_DECREF(path);
+    Py_XDECREF(second);
+    return nullptr;
+  }
+  PyList_SET_ITEM(items, 0, path);
+  PyList_SET_ITEM(items, 1, second);
+  return items;
+}
+
+// (structure, encoding) of a leaf, extension or branch: a new reference
+// to the tuple the cache holds.
+PyObject* walk_node(Walk* w, PyObject* node, int depth) {
+  if (depth > kMaxTrieDepth) {
+    PyErr_SetString(PyExc_RecursionError, "trie deeper than any key");
+    return nullptr;
+  }
+  PyObject* key = PyLong_FromVoidPtr(node);
+  if (!key) return nullptr;
+  PyObject* pair = PyDict_GetItemWithError(w->cache, key);  // borrowed
+  if (pair) {
+    Py_INCREF(pair);
+    Py_DECREF(key);
+    return pair;
+  }
+  PyObject* structure = PyErr_Occurred() ? nullptr : walk_structure(w, node, depth + 1);
+  PyObject* enc = structure ? ext_rlp_encode(nullptr, structure) : nullptr;
+  pair = enc ? PyTuple_Pack(2, structure, enc) : nullptr;
+  Py_XDECREF(structure);
+  Py_XDECREF(enc);
+  if (pair && PyDict_SetItem(w->cache, key, pair) < 0) Py_CLEAR(pair);
+  Py_DECREF(key);
+  return pair;
+}
+
+// encode_subtree(node, cache, path_enc, embed_below, (Leaf, Extension,
+// Branch)) -> (structure, encoding) of `node`, the cache filled below it
+PyObject* ext_encode_subtree(PyObject*, PyObject* const* args, Py_ssize_t nargs) {
+  if (nargs != 5 || !PyDict_Check(args[1]) || !PyTuple_Check(args[4]) ||
+      PyTuple_GET_SIZE(args[4]) != 3) {
+    PyErr_SetString(PyExc_TypeError,
+                    "encode_subtree(node, cache: dict, path_enc, embed_below, "
+                    "(Leaf, Extension, Branch))");
+    return nullptr;
+  }
+  Walk w;
+  w.cache = args[1];
+  w.path_enc = args[2] == Py_None ? nullptr : args[2];
+  w.embed_below = PyLong_AsSsize_t(args[3]);
+  if (w.embed_below == -1 && PyErr_Occurred()) return nullptr;
+  w.leaf = reinterpret_cast<PyTypeObject*>(PyTuple_GET_ITEM(args[4], 0));
+  w.extension = reinterpret_cast<PyTypeObject*>(PyTuple_GET_ITEM(args[4], 1));
+  w.branch = reinterpret_cast<PyTypeObject*>(PyTuple_GET_ITEM(args[4], 2));
+  PyTypeObject* t = Py_TYPE(args[0]);
+  if (t != w.leaf && t != w.extension && t != w.branch) {
+    PyErr_Format(PyExc_TypeError, "cannot encode a trie node of type %.200s",
+                 t->tp_name);
+    return nullptr;
+  }
+  w.empty = PyBytes_FromStringAndSize("", 0);
+  if (!w.empty) return nullptr;
+  PyObject* pair = walk_node(&w, args[0], 0);
+  Py_DECREF(w.empty);
+  return pair;
+}
+
+PyMethodDef module_methods[] = {
+    {"rlp_encode", ext_rlp_encode, METH_O, "rlp_encode(item) -> bytes"},
+    {"encode_node", reinterpret_cast<PyCFunction>(ext_encode_node),
+     METH_FASTCALL,
+     "encode_node(hole, value_hole_type, items) -> (bytes, [hole offsets])"},
+    {"hex_prefix", reinterpret_cast<PyCFunction>(ext_hex_prefix), METH_FASTCALL,
+     "hex_prefix(nibbles, is_leaf) -> bytes"},
+    {"encode_subtree", reinterpret_cast<PyCFunction>(ext_encode_subtree),
+     METH_FASTCALL,
+     "encode_subtree(node, cache, path_enc, embed_below, node_types) -> "
+     "(structure, encoding)"},
+    {nullptr, nullptr, 0, nullptr},
+};
+
 PyModuleDef moduledef = {
     PyModuleDef_HEAD_INIT,
     "phant_engine_ext",
-    "CPython driver for the native witness-engine core",
+    "CPython driver for the native witness-engine core, and the trie-node "
+    "encoder",
     -1,
+    module_methods,
 };
 
 }  // namespace

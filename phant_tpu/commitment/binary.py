@@ -50,7 +50,6 @@ from typing import Dict, List, Tuple
 
 from phant_tpu import rlp
 from phant_tpu.commitment import CommitmentScheme, register_scheme
-from phant_tpu.crypto.keccak import keccak256
 from phant_tpu.mpt.mpt import (
     EMPTY_TRIE_ROOT,
     BranchNode,
@@ -130,16 +129,14 @@ class BinaryTrie(Trie):
     Reuses mpt.py's radix-generic structure algorithms wholesale: the
     digit alphabet is {0, 1} (so only `children[0]`/`children[1]` of the
     stock 16-slot BranchNode are ever populated), paths encode with the
-    bit-prefix codec, and `_ref` ALWAYS hashes — the fixed-shape rule
-    that makes every node a digest-referenced unit."""
+    bit-prefix codec, and a child is ALWAYS hashed — the fixed-shape
+    rule that makes every node a digest-referenced unit."""
 
     _digits = staticmethod(bytes_to_bits)
     _path_enc = staticmethod(encode_bit_prefix)
-
-    def _ref(self, node) -> bytes:
-        # no embedding: children are referenced by digest regardless of
-        # encoding size (fixed-shape 2-ary rule)
-        return keccak256(self.node_encoding(node)[1])
+    # no embedding: children are referenced by digest regardless of
+    # encoding size (fixed-shape 2-ary rule)
+    _embed_below = 0
 
 
 def _resolve_binary(digest: bytes, db: Dict[bytes, bytes]):
@@ -201,7 +198,7 @@ class PartialBinaryTrie(PartialTrie, BinaryTrie):
     witness semantics (HashNode edges and their `_ref` digest
     passthrough, insufficient-witness errors, deletion poisoning) — all
     radix-generic — BinaryTrie supplies the codec (`_digits`,
-    `_path_enc`, always-hash `_ref` via the MRO), and the one
+    `_path_enc`, the always-hash `_embed_below` via the MRO), and the one
     scheme-specific piece is the witness decoder hook."""
 
     _resolve_witness = staticmethod(_resolve_binary)

@@ -19,6 +19,8 @@ from typing import Dict, Iterable, List, Optional, Sequence, Tuple, Union
 
 from phant_tpu import rlp
 from phant_tpu.crypto.keccak import keccak256
+from phant_tpu.utils.native import load_engine_ext
+from phant_tpu.utils.trace import metrics
 
 EMPTY_TRIE_ROOT = keccak256(rlp.encode(b""))
 
@@ -83,6 +85,10 @@ class BranchNode:
 
 
 Node = Union[LeafNode, ExtensionNode, BranchNode]
+
+#: what the extension's walk tells apart by exact type; a node of any
+#: other type enters its parent as its `.digest`
+_NODE_KINDS = (LeafNode, ExtensionNode, BranchNode)
 
 
 def _common_prefix_len(a: Sequence[int], b: Sequence[int]) -> int:
@@ -278,15 +284,25 @@ class Trie:
     branching beyond `children[digit]` indexing. Commitment-scheme
     plugins (phant_tpu/commitment/) subclass with a different digit
     alphabet and node codec — `_digits` maps a key to its path digits
-    (nibbles here; bits for the binary scheme) and `_path_enc` encodes a
-    leaf/extension path (hex-prefix here; bit-prefix for binary). Both
-    hooks default to the hexary-MPT behavior, byte-identical to the
-    pre-plugin code."""
+    (nibbles here; bits for the binary scheme), `_path_enc` encodes a
+    leaf/extension path (hex-prefix here; bit-prefix for binary) and
+    `_embed_below` is the embedded-node rule (a child whose encoding is
+    shorter enters its parent as its structure; 0 for a scheme that
+    always references by digest). The hooks default to the hexary-MPT
+    behavior, byte-identical to the pre-plugin code.
+
+    Encoding runs in the extension where the program has it
+    (native/pyext.cc `encode_subtree`: one call walks everything below a
+    node that the memo does not hold) and in `_node_encoding_python`
+    where it does not. Either reads nothing of a scheme but the three
+    hooks: a subclass varies those, never `_ref`."""
 
     #: key -> path digits (hexary: nibbles; binary scheme: bits)
     _digits = staticmethod(bytes_to_nibbles)
     #: leaf/extension path encoding (hexary: yellow-paper hex-prefix)
     _path_enc = staticmethod(encode_hex_prefix)
+    #: a child encoding shorter than this is embedded, not hashed
+    _embed_below = 32
 
     def __init__(self):
         self.root: Optional[Node] = None
@@ -347,6 +363,17 @@ class Trie:
         cached = self._enc_cache.get(id(node))
         if cached is not None:
             return cached
+        ext = load_engine_ext()
+        if ext is None:
+            return self._node_encoding_python(node)
+        # the walk's own hex-prefix where the scheme's is the yellow paper's
+        path_enc = None if self._path_enc is encode_hex_prefix else self._path_enc
+        return ext.encode_subtree(
+            node, self._enc_cache, path_enc, self._embed_below, _NODE_KINDS
+        )
+
+    def _node_encoding_python(self, node: Node) -> Tuple[rlp.RLPItem, bytes]:
+        """`node_encoding` without the extension, and its oracle."""
         if isinstance(node, LeafNode):
             structure: rlp.RLPItem = [self._path_enc(node.path, True), node.value]
         elif isinstance(node, ExtensionNode):
@@ -357,7 +384,7 @@ class Trie:
                 slots.append(b"" if child is None else self._ref(child))
             slots.append(node.value if node.value is not None else b"")
             structure = slots
-        encoded = rlp.encode(structure)
+        encoded = rlp.encode_python(structure)
         result = (structure, encoded)
         self._enc_cache[id(node)] = result
         return result
@@ -370,14 +397,27 @@ class Trie:
         """Reference to a child: embedded structure if rlp < 32B, else hash
         (reference: src/mpt/mpt.zig:132-281 node encode paths)."""
         structure, encoded = self.node_encoding(node)
-        if len(encoded) < 32:
+        if len(encoded) < self._embed_below:
             return structure
         return keccak256(encoded)
 
     def root_hash(self) -> bytes:
         if self.root is None:
             return EMPTY_TRIE_ROOT
-        return keccak256(self.node_encoding(self.root)[1])
+        held = len(self._enc_cache)
+        encoded = self.node_encoding(self.root)[1]
+        fresh = len(self._enc_cache) - held
+        if fresh:
+            count_node_encodings(fresh)
+        return keccak256(encoded)
+
+
+def count_node_encodings(nodes: int) -> None:
+    """`mpt.node_encodings{impl=}`: once a root computation (a walk's
+    `root_hash`, a plan's `finish`), the nodes it encoded, under the
+    encoder that serves this process now."""
+    impl = "python" if load_engine_ext() is None else "native"
+    metrics.count("mpt.node_encodings", nodes, impl=impl)
 
 
 # --- public API mirroring the reference ----------------------------------

@@ -43,8 +43,10 @@ from phant_tpu.mpt.mpt import (
     ExtensionNode,
     LeafNode,
     Trie,
+    count_node_encodings,
     encode_hex_prefix,
 )
+from phant_tpu.utils.native import load_engine_ext
 from phant_tpu.ops.witness_jax import _pow2ceil as _pow2, witness_digests
 
 # state-trie branch nodes are <= 17*33 + 2 bytes; 5 rate chunks cover 676B
@@ -85,10 +87,24 @@ class _ValueHole:
         self.suffix = suffix
 
 
+def _template_encoder():
+    """items -> (template, hole offsets): one call of the extension's node
+    encoder where the program has it, else the Python encoder below."""
+    ext = load_engine_ext()
+    if ext is None:
+        return _encode_template_python
+    return functools.partial(ext.encode_node, _HOLE, _ValueHole)
+
+
 def _encode_template(items) -> Tuple[bytes, List[int]]:
     """RLP-encode a node whose child refs are 32-byte holes; returns the
     encoding (holes zeroed) and each hole's byte offset (in encounter
     order — standalone `_HOLE` items and `_ValueHole` inner holes alike)."""
+    return _template_encoder()(items)
+
+
+def _encode_template_python(items) -> Tuple[bytes, List[int]]:
+    """`_encode_template` without the extension, and its oracle."""
     payload = bytearray()
     holes: List[int] = []
     for it in items:
@@ -104,7 +120,7 @@ def _encode_template(items) -> Tuple[bytes, List[int]]:
             payload += b"\x00" * 32
             payload += it.suffix
         else:
-            payload += rlp.encode(it)
+            payload += rlp.encode_python(it)
     header = _list_header(len(payload))
     return bytes(header) + bytes(payload), [h + len(header) for h in holes]
 
@@ -170,6 +186,11 @@ class PlanBuilder:
     _min_template = 32
 
     def __init__(self):
+        # the encoders are chosen once a builder, not once a node
+        self._template = _template_encoder()
+        ext = load_engine_ext()
+        if ext is not None and self._path_enc is encode_hex_prefix:
+            self._path_enc = ext.hex_prefix
         # (level, template, [(hole_off, child_gi)])
         self.entries: List[Tuple[int, bytes, List[Tuple[int, int]]]] = []
         self._index: Dict[int, int] = {}
@@ -193,13 +214,13 @@ class PlanBuilder:
             vh = self.value_holes.get(nid)
             if vh is not None:
                 prefix, suffix, child_gi, child_level = vh
-                template, holes = _encode_template(
+                template, holes = self._template(
                     [self._path_enc(node.path, True), _ValueHole(prefix, suffix)]
                 )
                 level = child_level + 1
                 hole_refs: List[Tuple[int, int]] = [(holes[0], child_gi)]
             else:
-                template, _holes = _encode_template(
+                template, _holes = self._template(
                     [self._path_enc(node.path, True), node.value]
                 )
                 level = 0
@@ -207,13 +228,13 @@ class PlanBuilder:
         elif isinstance(node, ExtensionNode):
             ci, clvl, cdg = self.visit(node.child)
             if cdg is not None:
-                template, _holes = _encode_template(
+                template, _holes = self._template(
                     [self._path_enc(node.path, False), cdg]
                 )
                 level = 0
                 hole_refs = []
             else:
-                template, holes = _encode_template(
+                template, holes = self._template(
                     [self._path_enc(node.path, False), _HOLE]
                 )
                 level = clvl + 1
@@ -226,15 +247,19 @@ class PlanBuilder:
                 if child is None:
                     items.append(b"")
                     continue
-                ci, clvl, cdg = self.visit(child)
+                # a witness's edge, most of a dirty branch's children,
+                # is told here and spared the call
+                cdg = getattr(child, "digest", None)
                 if cdg is not None:
                     items.append(cdg)  # constant 32-byte digest ref
-                else:
-                    items.append(_HOLE)
-                    child_order.append(ci)
-                    level = max(level, clvl)
+                    continue
+                ci, clvl, _none = self.visit(child)
+                items.append(_HOLE)
+                child_order.append(ci)
+                if clvl > level:
+                    level = clvl
             items.append(node.value if node.value is not None else b"")
-            template, holes = _encode_template(items)
+            template, holes = self._template(items)
             level += 1  # -1 (all-constant children) -> level 0
             hole_refs = list(zip(holes, child_order))
         if len(template) < self._min_template:
@@ -275,60 +300,65 @@ class PlanBuilder:
             return None
         entries = self.entries
         n = len(entries)
-        offsets = np.zeros(n, np.int64)
-        pos = 0
-        for gi, (_lvl, template, _holes) in enumerate(entries):
-            offsets[gi] = pos
-            pos += len(template)
+        count_node_encodings(n)
+        templates = [template for _lvl, template, _holes in entries]
+        lens = np.fromiter(map(len, templates), np.int64, n)
+        offsets = np.cumsum(lens) - lens
+        pos = int(lens.sum())
         # pow2-pad the blob so repeated roots of similar tries hit a small
         # set of compiled shapes (the slack doubles as scatter scratch)
         blob = np.zeros(_pow2(pos + MPT_MAX_CHUNKS * RATE), np.uint8)
-        for gi, (_lvl, template, _holes) in enumerate(entries):
-            blob[offsets[gi] : offsets[gi] + len(template)] = np.frombuffer(
-                template, np.uint8
-            )
+        blob[:pos] = np.frombuffer(b"".join(templates), np.uint8)
 
-        max_level = max(lvl for lvl, _t, _h in entries)
-        levels = []
-        # digest rows are laid out level by level, each level padded to a
-        # power of two — remap must use the PADDED cumulative position,
-        # since that is where the fused executor writes each level's rows
-        remap = np.zeros(n, np.int64)
-        next_global = 0
-        scratch = len(blob) - 32  # scatter target for hole padding rows
-        for lvl in range(max_level + 1):
-            idxs = [gi for gi in range(n) if entries[gi][0] == lvl]
-            for k, gi in enumerate(idxs):
-                remap[gi] = next_global + k
-            npad = _pow2(len(idxs))
-            off = np.zeros(npad, np.int32)
-            ln = np.zeros(npad, np.int32)
-            for k, gi in enumerate(idxs):
-                off[k] = offsets[gi]
-                ln[k] = len(entries[gi][1])
-            hp: List[int] = []
-            hc: List[int] = []
-            for gi in idxs:
-                for hole_off, child_gi in entries[gi][2]:
-                    hp.append(int(offsets[gi]) + hole_off)
-                    hc.append(int(remap[child_gi]))
-            hpad = _pow2(len(hp)) if hp else 1
-            hole_pos = np.full(hpad, scratch, np.int32)
-            hole_child = np.zeros(hpad, np.int32)
-            hole_pos[: len(hp)] = hp
-            hole_child[: len(hc)] = hc
-            levels.append((off, ln, hole_pos, hole_child))
-            next_global += npad
+        level_of = np.fromiter((lvl for lvl, _t, _h in entries), np.int64, n)
+        per_level = np.bincount(level_of)
         # the root is the unique max-level node (level(parent) >
         # level(child) for every edge — including the value-hole edges —
         # and all planned nodes descend from the root)
-        top_real = [gi for gi in range(n) if entries[gi][0] == max_level]
-        assert top_real == [root_gi]
+        assert per_level[-1] == 1 and level_of[root_gi] == len(per_level) - 1
+        # digest rows are laid out level by level, each level padded to a
+        # power of two — remap must use the PADDED cumulative position,
+        # since that is where the fused executor writes each level's rows
+        padded = np.array([_pow2(int(c)) for c in per_level], np.int64)
+        # a level's entries in visit order, one level after another
+        by_level = np.argsort(level_of, kind="stable")
+        first = np.cumsum(per_level) - per_level  # each level's start there
+        rank = np.arange(n) - np.repeat(first, per_level)
+        remap = np.empty(n, np.int64)
+        remap[by_level] = np.repeat(np.cumsum(padded) - padded, per_level) + rank
+
+        # every hole of the plan in (entry, encounter) order, then the
+        # same grouping by its entry's level
+        holes_of = np.fromiter((len(h) for _l, _t, h in entries), np.int64, n)
+        holes = np.array(
+            [ref for _l, _t, refs in entries for ref in refs], np.int64
+        ).reshape(-1, 2)
+        owner = np.repeat(np.arange(n), holes_of)
+        hole_at = offsets[owner] + holes[:, 0]
+        hole_to = remap[holes[:, 1]]
+        hole_level = level_of[owner]
+        holes_by_level = np.argsort(hole_level, kind="stable")
+        hole_ends = np.cumsum(np.bincount(hole_level, minlength=len(per_level)))
+        hole_starts = np.concatenate(([0], hole_ends[:-1]))
+
+        levels = []
+        scratch = len(blob) - 32  # scatter target for hole padding rows
+        for lvl, real in enumerate(per_level):
+            idxs = by_level[first[lvl] : first[lvl] + real]
+            off = np.zeros(padded[lvl], np.int32)
+            ln = np.zeros(padded[lvl], np.int32)
+            off[:real] = offsets[idxs]
+            ln[:real] = lens[idxs]
+            mine = holes_by_level[hole_starts[lvl] : hole_ends[lvl]]
+            hpad = _pow2(len(mine)) if len(mine) else 1
+            hole_pos = np.full(hpad, scratch, np.int32)
+            hole_child = np.zeros(hpad, np.int32)
+            hole_pos[: len(mine)] = hole_at[mine]
+            hole_child[: len(mine)] = hole_to[mine]
+            levels.append((off, ln, hole_pos, hole_child))
         out_rows = None
         if out_gis:
-            out_rows = np.asarray(
-                [int(remap[g]) for g in out_gis], np.int32
-            )
+            out_rows = remap[np.asarray(out_gis, np.int64)].astype(np.int32)
         return HashPlan(
             blob=blob,
             levels=levels,

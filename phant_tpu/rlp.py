@@ -13,12 +13,14 @@ big-endian with no leading zeros via :func:`encode_uint`.
 
 from __future__ import annotations
 
+import functools
 from typing import Iterable, List, Tuple, Union
 
 RLPItem = Union[bytes, List["RLPItem"]]
 
 __all__ = [
     "encode",
+    "encode_python",
     "encode_uint",
     "decode",
     "decode_uint",
@@ -53,18 +55,36 @@ def _encode_length(length: int, short_offset: int) -> bytes:
     return bytes([short_offset + 55 + len(length_bytes)]) + length_bytes
 
 
+@functools.lru_cache(maxsize=1)
+def _native_encoder():
+    """The extension's encoder (native/pyext.cc `rlp_encode`), or None
+    where the process runs without the extension; asked once a process."""
+    from phant_tpu.utils.native import load_engine_ext
+
+    ext = load_engine_ext()
+    return ext.rlp_encode if ext is not None else None
+
+
 def encode(item: RLPItem) -> bytes:
+    native = _native_encoder()
+    if native is not None:
+        return native(item)
+    return encode_python(item)
+
+
+def encode_python(item: RLPItem) -> bytes:
+    """`encode` without the extension, and the oracle of its encoders."""
     if isinstance(item, (bytes, bytearray, memoryview)):
         data = bytes(item)
         if len(data) == 1 and data[0] < 0x80:
             return data
         return _encode_length(len(data), 0x80) + data
     if isinstance(item, (list, tuple)):
-        payload = b"".join(encode(sub) for sub in item)
+        payload = b"".join(encode_python(sub) for sub in item)
         return _encode_length(len(payload), 0xC0) + payload
     if isinstance(item, int):
         # Convenience: ints encode as their minimal big-endian bytes.
-        return encode(encode_uint(item))
+        return encode_python(encode_uint(item))
     raise TypeError(f"cannot RLP-encode {type(item).__name__}")
 
 

@@ -134,3 +134,19 @@ def pytest_sessionfinish(session, exitstatus):
             "the sanitized session\n"
         )
         session.exitstatus = 1
+
+
+@pytest.fixture(params=["native", "python"])
+def node_encoder(request, monkeypatch):
+    """The program's trie-node encoder: the extension's (native/pyext.cc),
+    or the Python one with the extension masked out the way a machine
+    without a compiler has it (`rlp.encode` alone keeps the encoder it
+    found at its first call in the process)."""
+    from phant_tpu.utils.native import load_engine_ext
+
+    if request.param == "python":
+        monkeypatch.setenv("PHANT_ENGINE_EXT", "0")
+        assert load_engine_ext() is None
+    elif load_engine_ext() is None:
+        pytest.skip("no extension on this machine")
+    return request.param

@@ -269,3 +269,251 @@ def test_delete_fuzz_against_rebuild():
     for k in list(d):
         t.delete(k)
     assert t.root_hash() == EMPTY_TRIE_ROOT
+
+
+# --- the node encoder: extension against oracle -----------------------------
+#
+# `Trie.node_encoding` runs in the extension (native/pyext.cc
+# `encode_subtree`) or, without it, in `_node_encoding_python`. Both are
+# held to the oracle below, which shares nothing with either but
+# `rlp.encode_python`; "python" masks the extension out the way a machine
+# without a compiler would have it.
+
+
+from phant_tpu.stateless import HashNode as _Edge  # an unwitnessed subtree
+from phant_tpu.stateless import PartialTrie
+
+
+def _witness_trie() -> Trie:
+    """An empty trie over a witness: the kind whose walk meets edges."""
+    return PartialTrie(EMPTY_TRIE_ROOT, {})
+
+
+def _oracle(node, path_enc=encode_hex_prefix, embed_below=32):
+    """(structure, encoding) by the yellow paper, appendix D."""
+    from phant_tpu.mpt.mpt import BranchNode, ExtensionNode, LeafNode
+
+    def ref(child):
+        if isinstance(child, _Edge):
+            return child.digest
+        structure, encoded = _oracle(child, path_enc, embed_below)
+        return structure if len(encoded) < embed_below else keccak256(encoded)
+
+    if isinstance(node, LeafNode):
+        structure = [path_enc(node.path, True), node.value]
+    elif isinstance(node, ExtensionNode):
+        structure = [path_enc(node.path, False), ref(node.child)]
+    else:
+        assert isinstance(node, BranchNode)
+        structure = [b"" if c is None else ref(c) for c in node.children]
+        structure.append(b"" if node.value is None else node.value)
+    return structure, rlp.encode_python(structure)
+
+
+def _leaf(path, size, fill=0xAB):
+    from phant_tpu.mpt.mpt import LeafNode
+
+    return LeafNode(tuple(path), bytes([fill]) * size)
+
+
+def _branch(children: dict, value=None):
+    from phant_tpu.mpt.mpt import BranchNode
+
+    node = BranchNode()
+    for i, child in children.items():
+        node.children[i] = child
+    node.value = value
+    return node
+
+
+def _node_cases():
+    from phant_tpu.mpt.mpt import ExtensionNode, LeafNode
+
+    rng = random.Random(35)
+    cases = {}
+    # a value's own RLP: one byte below and at 0x80, the short and long
+    # string headers, a length of three bytes
+    for size in (0, 55, 56, 255, 256, 70_000):
+        cases[f"leaf-{size}B"] = _leaf((1, 2, 3), size)
+    cases["leaf-byte-0x7f"] = LeafNode((4,), b"\x7f")
+    cases["leaf-byte-0x80"] = LeafNode((4,), b"\x80")
+    cases["leaf-empty-path"] = _leaf((), 40)
+    cases["leaf-64-nibbles"] = _leaf([rng.randrange(16) for _ in range(64)], 70)
+    for k in range(17):
+        slots = rng.sample(range(16), k)
+        cases[f"branch-{k}-children"] = _branch(
+            {i: _leaf((i, 7), 33 + i, fill=i) for i in slots}
+        )
+    cases["branch-value"] = _branch({3: _leaf((1,), 40)}, value=b"\x01" * 9)
+    cases["branch-all-edges"] = _branch(
+        {i: _Edge(bytes([i]) * 32) for i in range(16)}
+    )
+    cases["extension-over-branch"] = ExtensionNode(
+        (0xA, 0xB, 0xC), _branch({0: _leaf((5,), 50), 9: _leaf((6, 6), 50)})
+    )
+    cases["extension-over-edge"] = ExtensionNode((1,), _Edge(b"\x11" * 32))
+    # encodings under 32 bytes stay inside their parents, two deep: tiny
+    # leaves in a small branch in an extension in a branch
+    small = _branch({1: _leaf((2,), 1, fill=5), 2: _leaf((), 2, fill=9)})
+    assert len(_oracle(small)[1]) < 32
+    cases["embedded-two-deep"] = _branch(
+        {0: ExtensionNode((7,), small), 5: _leaf((3,), 3), 8: _leaf((1,), 60)}
+    )
+    cases["embedded-leaf-in-extension-branch"] = ExtensionNode(
+        (1, 2), _branch({4: _leaf((9,), 1, fill=0x80), 6: _leaf((), 1, fill=0x7F)})
+    )
+    return cases
+
+
+_NODE_CASES = _node_cases()
+
+
+@pytest.mark.parametrize("case", sorted(_NODE_CASES))
+def test_node_encoding_matches_the_oracle(node_encoder, case):
+    """Structure AND encoding, of the node and of everything under it that
+    the walk left in the memo."""
+    from phant_tpu.mpt.mpt import BranchNode, ExtensionNode
+
+    node = _NODE_CASES[case]
+    trie = _witness_trie()
+    assert trie.node_encoding(node) == _oracle(node)
+    assert trie._enc_cache[id(node)] == _oracle(node)
+    stack = [node]
+    while stack:
+        at = stack.pop()
+        if isinstance(at, _Edge):
+            assert id(at) not in trie._enc_cache
+            continue
+        assert trie._enc_cache[id(at)] == _oracle(at), type(at).__name__
+        if isinstance(at, ExtensionNode):
+            stack.append(at.child)
+        elif isinstance(at, BranchNode):
+            stack.extend(c for c in at.children if c is not None)
+    # the memo answers the second call with the same objects
+    assert trie.node_encoding(node) is trie._enc_cache[id(node)]
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_random_tries_root_and_memo_match_the_oracle(node_encoder, seed):
+    """Tries as `put` and `delete` build them, short values among them so
+    that embedded nodes occur where they occur in the wild."""
+    rng = random.Random(seed)
+    trie = Trie()
+    keys = [bytes(rng.randrange(256) for _ in range(rng.choice((1, 2, 32)))) for _ in range(120)]
+    for key in keys:
+        trie.put(key, bytes(rng.randrange(256) for _ in range(rng.choice((1, 3, 33, 70)))))
+    for key in keys[::7]:
+        trie.delete(key)
+    root = trie.root_hash()
+    assert root == keccak256(_oracle(trie.root)[1])
+    assert trie._enc_cache[id(trie.root)] == _oracle(trie.root)
+    # a put evicts its path alone; the walk fills in what is missing
+    held = len(trie._enc_cache)
+    trie.put(keys[1], b"\x05" * 40)
+    assert 0 < held - len(trie._enc_cache) < held
+    assert trie.root_hash() == keccak256(_oracle(trie.root)[1])
+
+
+def test_binary_scheme_hashes_every_child(node_encoder):
+    """The binary scheme's hooks (bit-prefix paths through the callback,
+    `_embed_below` 0) give the oracle's bytes under both encoders."""
+    from phant_tpu.commitment.binary import BinaryTrie, encode_bit_prefix
+
+    trie = BinaryTrie()
+    for i in range(40):
+        trie.put(keccak256(bytes([i])), bytes([i + 1]) * (1 + i % 5))
+    want = _oracle(trie.root, path_enc=encode_bit_prefix, embed_below=0)
+    assert trie.node_encoding(trie.root) == want
+    assert trie.root_hash() == keccak256(want[1])
+
+
+@pytest.mark.parametrize(
+    "bad", [None, "text", 1.5, object()], ids=["none", "str", "float", "object"]
+)
+def test_a_malformed_item_is_a_type_error(node_encoder, bad):
+    """As `rlp.encode` has it, and never a crash of the interpreter: as a
+    leaf's value, as a branch's value and as a child."""
+    from phant_tpu.mpt.mpt import LeafNode
+
+    with pytest.raises(TypeError):
+        rlp.encode_python([b"", bad])
+    nodes = [LeafNode((1,), bad)]
+    if bad is not None:  # a branch's None is an empty slot, or no value
+        nodes += [_branch({2: _leaf((1,), 40)}, value=bad), _branch({2: bad})]
+    for node in nodes:
+        trie = Trie()
+        with pytest.raises((TypeError, AttributeError)):
+            trie.node_encoding(node)
+        assert id(node) not in trie._enc_cache
+
+
+def test_an_int_is_its_minimal_big_endian_bytes(node_encoder):
+    """`rlp.encode` takes an int for its bytes (0 is the empty string), and
+    so does the extension: no trie node holds one, but the two agree."""
+    from phant_tpu.mpt.mpt import LeafNode
+
+    for value in (0, 1, 0x7F, 0x80, 1024, 2**64 - 1, 2**64, 2**255 + 7, True):
+        node = LeafNode((1,), value)
+        assert Trie().node_encoding(node)[1] == rlp.encode_python(
+            [encode_hex_prefix((1,), True), value]
+        )
+    with pytest.raises(ValueError):
+        Trie().node_encoding(LeafNode((1,), -1))
+
+
+@pytest.mark.parametrize("length", [0, 1, 2, 3, 31, 32, 63, 64, 65])
+def test_native_hex_prefix_matches(length):
+    from phant_tpu.utils.native import load_engine_ext
+
+    ext = load_engine_ext()
+    if ext is None:
+        pytest.skip("no extension on this machine")
+    rng = random.Random(length)
+    path = tuple(rng.randrange(16) for _ in range(length))
+    for is_leaf in (True, False):
+        assert ext.hex_prefix(path, is_leaf) == encode_hex_prefix(path, is_leaf)
+        assert decode_hex_prefix(ext.hex_prefix(list(path), is_leaf)) == (path, is_leaf)
+    with pytest.raises(ValueError):
+        ext.hex_prefix(path + (16,), True)
+    with pytest.raises(TypeError):
+        ext.hex_prefix(path + (None,), True)
+
+
+_RLP_ITEMS = {
+    "empty-string": b"",
+    "byte-0x00": b"\x00",
+    "byte-0x7f": b"\x7f",
+    "byte-0x80": b"\x80",
+    "55B": b"a" * 55,
+    "56B": b"a" * 56,
+    "255B": b"a" * 255,
+    "256B": b"a" * 256,
+    "70000B": b"a" * 70_000,
+    "empty-list": [],
+    "tuple": (b"cat", (b"dog",)),
+    "nested": [[], [[]], [[], [[]]]],
+    "list-55B-payload": [b"a" * 54],
+    "list-56B-payload": [b"a" * 55],
+    "list-70000B-payload": [b"a" * 35_000, [b"b" * 35_000]],
+    "bytearray-memoryview": [bytearray(b"ab"), memoryview(b"\x01")],
+    "ints": [0, 1, 127, 128, 2**64 - 1, 2**64, 2**256 - 1, True],
+    "a-transaction": [9, 20 * 10**9, 21000, b"\x35" * 20, 10**18, b"", 37, 2**255, 2**254],
+}
+
+
+@pytest.mark.parametrize("case", sorted(_RLP_ITEMS))
+def test_rlp_encode_rides_the_same_writer(node_encoder, case):
+    """`rlp.encode` is the extension's `rlp_encode` where the process has
+    it: the same bytes as the Python encoder, for every shape it takes."""
+    from phant_tpu.utils.native import load_engine_ext
+
+    item = _RLP_ITEMS[case]
+    want = rlp.encode_python(item)
+    assert rlp.encode(item) == want
+    ext = load_engine_ext()
+    if node_encoder == "native":
+        assert ext.rlp_encode(item) == want
+    else:
+        assert ext is None
+    if not isinstance(item, bytes):
+        assert rlp.decode(want) == rlp.decode(rlp.encode_python(rlp.decode(want)))

@@ -193,3 +193,118 @@ def test_batched_roots_reject_mismatched_structure():
     p1, p2 = build_hash_plan(t1), build_hash_plan(t2)
     with pytest.raises(ValueError):
         trie_roots_device_batched([p1, p2])
+
+
+# --- templates: the extension's node encoder against the Python one ---------
+
+
+def _template_cases():
+    from phant_tpu.ops.mpt_jax import _HOLE, _ValueHole
+
+    digest = bytes(range(32))
+    embedded = [b"\x20", [b"\x31", b"\x05"]]  # a child under 32 bytes, two deep
+    return {
+        "no-holes-leaf": [b"\x20\x12", b"v" * 70],
+        "no-holes-branch-of-constants": [digest] * 16 + [b""],
+        "one-hole-extension": [b"\x00\x12", _HOLE],
+        "one-hole-first-of-branch": [_HOLE] + [b""] * 16,
+        "one-hole-last-child": [b""] * 15 + [_HOLE, b""],
+        "17-holes": [_HOLE] * 17,
+        "16-holes-and-a-value": [_HOLE] * 16 + [b"\x07" * 40],
+        "holes-between-constants": [digest, _HOLE, b"", embedded, _HOLE] + [b""] * 12,
+        "value-hole": [b"\x20" + b"\x11" * 31, _ValueHole(b"\xf8\x44\x01\x80\xa0", b"\xa0" + digest)],
+        "value-hole-bare": [b"\x31", _ValueHole(b"", b"")],
+        "value-hole-long-string": [b"\x31", _ValueHole(b"p" * 200, b"s" * 100)],
+        "value-hole-and-hole": [_ValueHole(b"ab", b"cd"), _HOLE],
+        "value-byte-0x7f": [b"\x31", b"\x7f"],
+        "value-byte-0x80": [b"\x31", b"\x80"],
+        "payload-55B": [b"a" * 54],
+        "payload-56B": [b"a" * 55],
+        "payload-255B": [b"a" * 253],
+        "payload-256B": [b"a" * 253, b""],
+        "payload-70000B": [b"\x31", b"a" * 70_000, _HOLE],
+        "empty": [],
+    }
+
+
+_TEMPLATE_CASES = _template_cases()
+
+
+@pytest.mark.parametrize("case", sorted(_TEMPLATE_CASES))
+def test_encode_template_matches_the_python_encoder(node_encoder, case):
+    """The encoding AND every hole's offset; each offset points at 32 zero
+    bytes, which filled in give the node's own RLP."""
+    from phant_tpu.ops.mpt_jax import (
+        _HOLE,
+        _ValueHole,
+        _encode_template,
+        _encode_template_python,
+    )
+
+    items = _TEMPLATE_CASES[case]
+    template, holes = _encode_template(items)
+    assert (template, list(holes)) == _encode_template_python(items)
+    n_holes = sum(it is _HOLE or isinstance(it, _ValueHole) for it in items)
+    assert len(holes) == n_holes and list(holes) == sorted(holes)
+    # each hole filled in with a mark of its own gives the node's own RLP
+    filled = bytearray(template)
+    want = []
+    offsets = iter(holes)
+    for it in items:
+        mark = bytes([len(want) + 1]) * 32
+        if it is _HOLE:
+            want.append(mark)
+        elif isinstance(it, _ValueHole):
+            want.append(it.prefix + mark + it.suffix)
+        else:
+            want.append(it)
+            continue
+        at = next(offsets)
+        assert template[at : at + 32] == b"\x00" * 32
+        filled[at : at + 32] = mark
+    assert bytes(filled) == rlp.encode_python(want)
+
+
+@pytest.mark.parametrize(
+    "items",
+    [
+        [b"\x31", None],
+        [b"\x31", "text"],
+        [b"\x31", 1.5],
+        [b"\x31", [b"a", None]],
+    ],
+    ids=["none", "str", "float", "nested-none"],
+)
+def test_a_malformed_template_item_is_a_type_error(node_encoder, items):
+    from phant_tpu.ops.mpt_jax import _encode_template
+
+    with pytest.raises(TypeError):
+        _encode_template(items)
+
+
+def test_a_hole_below_the_top_list_is_a_type_error(node_encoder):
+    """Holes are children of the node itself; an embedded child holds none
+    (its subtree is unplannable), under either encoder."""
+    from phant_tpu.ops.mpt_jax import _HOLE, _ValueHole, _encode_template
+
+    for nested in ([b"\x31", [_HOLE]], [b"\x31", [_ValueHole(b"a", b"b")]]):
+        with pytest.raises(TypeError):
+            _encode_template(nested)
+
+
+def test_the_builder_counts_its_nodes_under_the_encoder_it_used(node_encoder):
+    from phant_tpu.utils.trace import metrics
+
+    rng = np.random.default_rng(5)
+    trie = Trie()
+    for _ in range(40):
+        trie.put(keccak256(rng.bytes(8)), _account_leaf(rng))
+
+    def counted(impl):
+        return metrics.snapshot()["counters"].get(f'mpt.node_encodings{{impl="{impl}"}}', 0)
+
+    other = "python" if node_encoder == "native" else "native"
+    before = counted(node_encoder), counted(other)
+    plan = build_hash_plan(trie)
+    assert counted(node_encoder) - before[0] == plan.n_nodes
+    assert counted(other) == before[1]

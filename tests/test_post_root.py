@@ -679,3 +679,403 @@ def test_device_host_seconds_grows_on_the_root_lane(forced_device, op):
     after = metrics.snapshot()["histograms"][key]
     assert after["count"] == before["count"] + 1
     assert after["sum"] > before["sum"]
+
+
+# ---------------------------------------------------------------------------
+# PR 35: one native node encoder under the walk and under the plan builder.
+# The plan that comes out is the parent's, byte for byte.
+# ---------------------------------------------------------------------------
+
+
+def _parent_encode_template(items):
+    """`ops/mpt_jax._encode_template` of commit 47c2d1e, verbatim but for
+    `rlp.encode`, which is `rlp.encode_python` here: the oracle shares no
+    encoder with what it judges."""
+    from phant_tpu.ops.mpt_jax import _HOLE, _ValueHole, _list_header, str_header
+
+    payload = bytearray()
+    holes = []
+    for it in items:
+        if it is _HOLE:
+            payload.append(0xA0)  # RLP string header for 32 bytes
+            holes.append(len(payload))
+            payload += b"\x00" * 32
+        elif isinstance(it, _ValueHole):
+            total = len(it.prefix) + 32 + len(it.suffix)
+            payload += str_header(total)
+            payload += it.prefix
+            holes.append(len(payload))
+            payload += b"\x00" * 32
+            payload += it.suffix
+        else:
+            payload += rlp.encode_python(it)
+    header = _list_header(len(payload))
+    return bytes(header) + bytes(payload), [h + len(header) for h in holes]
+
+
+def _parent_builder(cls):
+    """`cls` (a scheme's PlanBuilder) with the `visit` and `finish` of
+    commit 47c2d1e, verbatim: one template a call, one numpy element an
+    assignment."""
+    import numpy as np
+
+    from phant_tpu.crypto.keccak import RATE
+    from phant_tpu.mpt.mpt import ExtensionNode, LeafNode
+    from phant_tpu.ops.mpt_jax import (
+        _HOLE,
+        MPT_MAX_CHUNKS,
+        HashPlan,
+        _pow2,
+        _ValueHole,
+    )
+
+    _encode_template = _parent_encode_template
+
+    class ParentBuilder(cls):
+        def __init__(self):
+            super().__init__()
+            self.__dict__.pop("_path_enc", None)  # the scheme's Python one
+
+        def visit(self, node):
+            dg = getattr(node, "digest", None)
+            if dg is not None:
+                return None, 0, dg
+            nid = id(node)
+            if nid in self._index:
+                gi = self._index[nid]
+                return gi, self.entries[gi][0], None
+            if isinstance(node, LeafNode):
+                vh = self.value_holes.get(nid)
+                if vh is not None:
+                    prefix, suffix, child_gi, child_level = vh
+                    template, holes = _encode_template(
+                        [self._path_enc(node.path, True), _ValueHole(prefix, suffix)]
+                    )
+                    level = child_level + 1
+                    hole_refs = [(holes[0], child_gi)]
+                else:
+                    template, _holes = _encode_template(
+                        [self._path_enc(node.path, True), node.value]
+                    )
+                    level = 0
+                    hole_refs = []
+            elif isinstance(node, ExtensionNode):
+                ci, clvl, cdg = self.visit(node.child)
+                if cdg is not None:
+                    template, _holes = _encode_template(
+                        [self._path_enc(node.path, False), cdg]
+                    )
+                    level = 0
+                    hole_refs = []
+                else:
+                    template, holes = _encode_template(
+                        [self._path_enc(node.path, False), _HOLE]
+                    )
+                    level = clvl + 1
+                    hole_refs = [(holes[0], ci)]
+            else:  # BranchNode
+                items = []
+                child_order = []
+                level = -1
+                for child in node.children:
+                    if child is None:
+                        items.append(b"")
+                        continue
+                    ci, clvl, cdg = self.visit(child)
+                    if cdg is not None:
+                        items.append(cdg)  # constant 32-byte digest ref
+                    else:
+                        items.append(_HOLE)
+                        child_order.append(ci)
+                        level = max(level, clvl)
+                items.append(node.value if node.value is not None else b"")
+                template, holes = _encode_template(items)
+                level += 1  # -1 (all-constant children) -> level 0
+                hole_refs = list(zip(holes, child_order))
+            if len(template) < self._min_template:
+                self.too_small = True
+            if len(template) > MPT_MAX_CHUNKS * RATE - 1:
+                self.too_small = True  # oversized node: CPU path
+            gi = len(self.entries)
+            self.entries.append((level, template, hole_refs))
+            self._index[nid] = gi
+            self._order.append(nid)
+            return gi, level, None
+
+        def finish(self, root_gi, out_gis=()):
+            if self.too_small or not self.entries:
+                return None
+            entries = self.entries
+            n = len(entries)
+            offsets = np.zeros(n, np.int64)
+            pos = 0
+            for gi, (_lvl, template, _holes) in enumerate(entries):
+                offsets[gi] = pos
+                pos += len(template)
+            blob = np.zeros(_pow2(pos + MPT_MAX_CHUNKS * RATE), np.uint8)
+            for gi, (_lvl, template, _holes) in enumerate(entries):
+                blob[offsets[gi] : offsets[gi] + len(template)] = np.frombuffer(
+                    template, np.uint8
+                )
+
+            max_level = max(lvl for lvl, _t, _h in entries)
+            levels = []
+            remap = np.zeros(n, np.int64)
+            next_global = 0
+            scratch = len(blob) - 32  # scatter target for hole padding rows
+            for lvl in range(max_level + 1):
+                idxs = [gi for gi in range(n) if entries[gi][0] == lvl]
+                for k, gi in enumerate(idxs):
+                    remap[gi] = next_global + k
+                npad = _pow2(len(idxs))
+                off = np.zeros(npad, np.int32)
+                ln = np.zeros(npad, np.int32)
+                for k, gi in enumerate(idxs):
+                    off[k] = offsets[gi]
+                    ln[k] = len(entries[gi][1])
+                hp = []
+                hc = []
+                for gi in idxs:
+                    for hole_off, child_gi in entries[gi][2]:
+                        hp.append(int(offsets[gi]) + hole_off)
+                        hc.append(int(remap[child_gi]))
+                hpad = _pow2(len(hp)) if hp else 1
+                hole_pos = np.full(hpad, scratch, np.int32)
+                hole_child = np.zeros(hpad, np.int32)
+                hole_pos[: len(hp)] = hp
+                hole_child[: len(hc)] = hc
+                levels.append((off, ln, hole_pos, hole_child))
+                next_global += npad
+            top_real = [gi for gi in range(n) if entries[gi][0] == max_level]
+            assert top_real == [root_gi]
+            out_rows = None
+            if out_gis:
+                out_rows = np.asarray([int(remap[g]) for g in out_gis], np.int32)
+            return HashPlan(
+                blob=blob,
+                levels=levels,
+                n_nodes=n,
+                root_pos=int(remap[root_gi]),
+                out_rows=out_rows,
+                used=pos,
+            )
+
+    return ParentBuilder
+
+
+def _assert_same_plan(got, want) -> None:
+    """Everything `merge_plans`, the rungs and the device program read."""
+    import numpy as np
+
+    assert got.used == want.used and got.n_nodes == want.n_nodes
+    assert got.root_pos == want.root_pos
+    assert got.blob.shape == want.blob.shape and got.blob.dtype == want.blob.dtype
+    assert bytes(got.blob[: got.used]) == bytes(want.blob[: want.used])
+    assert not got.blob[got.used :].any()
+    assert len(got.levels) == len(want.levels)
+    for lvl, (mine, theirs) in enumerate(zip(got.levels, want.levels)):
+        for name, a, b in zip(("off", "ln", "hole_pos", "hole_child"), mine, theirs):
+            assert a.dtype == b.dtype == np.int32, (lvl, name)
+            assert np.array_equal(a, b), (lvl, name)
+    assert (got.out_rows is None) == (want.out_rows is None)
+    if want.out_rows is not None:
+        assert got.out_rows.dtype == want.out_rows.dtype == np.int32
+        assert np.array_equal(got.out_rows, want.out_rows)
+
+
+def _scheme_state(scheme, seed: int, mutate) -> WitnessStateDB:
+    root, nodes, codes = scheme.witness_of_state(_pre_accounts(seed))
+    db = WitnessStateDB(root, nodes, codes, scheme=scheme)
+    mutate(db)
+    return db
+
+
+@pytest.mark.parametrize("mutate", MUTATIONS, ids=lambda m: m.__name__)
+@pytest.mark.parametrize("scheme_name", ["mpt", "binary"])
+def test_hash_plan_is_the_parents_byte_for_byte(
+    node_encoder, scheme_name, mutate, monkeypatch
+):
+    """Twin states, one planned by the program and one by the parent's
+    builder: blob up to `used`, every level's four arrays, `out_rows`,
+    `root_pos`. Then the plan, run on the host, gives the walk's root."""
+    from phant_tpu.commitment import get_scheme
+    from phant_tpu.ops.mpt_jax import execute_plan_outputs_host
+
+    scheme = get_scheme(scheme_name)
+    seed = MUTATIONS.index(mutate)
+    want_root = _scheme_state(scheme, seed, mutate).state_root()
+    db = _scheme_state(scheme, seed, mutate)
+    prp = db.post_root_plan()
+    assert prp is not None
+    parent_cls = _parent_builder(type(scheme.plan_builder()))
+    monkeypatch.setattr(scheme, "plan_builder", parent_cls)
+    parent_prp = _scheme_state(scheme, seed, mutate).post_root_plan()
+    _assert_same_plan(prp.plan, parent_prp.plan)
+    assert [p.gi for p in prp.patches] == [p.gi for p in parent_prp.patches]
+    assert db.apply_post_root(prp, execute_plan_outputs_host(prp.plan)) == want_root
+
+
+@pytest.fixture(scope="module")
+def reference_block(tmp_path_factory):
+    """Block 2 of a chain of the benchmark's own reference
+    (benchmarks/reference/chain.py, which imports nothing of the program):
+    a small genesis, the cell's mix of transfers and contract calls."""
+    import json
+    import sys
+    from pathlib import Path
+
+    bench = Path(__file__).resolve().parent.parent / "benchmarks"
+    sys.path.insert(0, str(bench))
+    try:
+        from reference import keccak as ref_keccak
+        from reference.chain import Chain
+
+        try:
+            ref_keccak.load(tmp_path_factory.mktemp("refkeccak"))
+        except Exception as e:  # no C compiler on this machine
+            pytest.skip(f"the reference's keccak does not build here: {e}")
+        mix = json.loads((bench / "traffic" / "lone.json").read_text())["chain"]
+        chain = Chain(
+            35,
+            {
+                **mix,
+                "genesis_log2": 10,
+                "sender_pool": 128,
+                "contracts": 4,
+                "transfers_per_block": 40,
+                "calls_per_block": 20,
+                "slots_per_contract": 8,
+            },
+        )
+        chain.extend(2)
+        block = chain.blocks[1]
+        return block, json.loads(block.body(2))
+    finally:
+        sys.path.remove(str(bench))
+
+
+def _serve_reference_block(request_json, post_root):
+    """The request through the Engine API's handler with `post_root` in
+    `compute_post_root`'s place; returns the reply."""
+    import time
+
+    import phant_tpu.stateless as stateless
+    from phant_tpu.__main__ import make_genesis_parent_header
+    from phant_tpu.blockchain.chain import Blockchain
+    from phant_tpu.blockchain.fork import fork_for
+    from phant_tpu.config import ChainConfig
+    from phant_tpu.engine_api import handle_request
+    from phant_tpu.state.statedb import StateDB
+
+    config = ChainConfig.from_chain_id(1)
+    state = StateDB({})
+    chain = Blockchain(
+        chain_id=1,
+        state=state,
+        parent_header=make_genesis_parent_header(),
+        fork=fork_for(config, state, 0, int(time.time())),
+        config=config,
+    )
+    real = stateless.compute_post_root
+    stateless.compute_post_root = post_root
+    try:
+        code, reply = handle_request(chain, request_json)
+    finally:
+        stateless.compute_post_root = real
+    assert code == 200, reply
+    return reply
+
+
+def test_reference_block_walk_plan_and_reference_agree(
+    node_encoder, reference_block, monkeypatch
+):
+    """On a block of the benchmark's chain: `state_root()`,
+    `post_root_plan()` + `apply_post_root` and the reference's own root are
+    one root; the plan is the parent's; a repeated `state_root()` encodes
+    and hashes nothing."""
+    from phant_tpu.commitment import get_scheme
+    from phant_tpu.ops.mpt_jax import execute_plan_outputs_host
+    from phant_tpu.utils.trace import metrics
+
+    block, request_json = reference_block
+    seen = {}
+
+    def encoded() -> int:
+        counters = metrics.snapshot()["counters"]
+        return sum(v for k, v in counters.items() if k.startswith("mpt.node_encodings"))
+
+    def walk(state):
+        root = state.state_root()
+        before = encoded()
+        assert state.state_root() == root  # the memo
+        seen["encoded_by_repeat"] = encoded() - before
+        return root
+
+    def plan(state):
+        prp = state.post_root_plan()
+        assert prp is not None and prp.patches  # contract calls: storage holes
+        seen["plan"] = prp.plan
+        root = state.apply_post_root(prp, execute_plan_outputs_host(prp.plan))
+        assert state.state_root() == root  # tries left canonical
+        return root
+
+    def parent_plan(state):
+        seen["parent_plan"] = state.post_root_plan().plan
+        return state.state_root()
+
+    for post_root in (walk, plan):
+        reply = _serve_reference_block(request_json, post_root)
+        assert reply["result"]["status"] == "VALID", reply
+        assert bytes.fromhex(reply["result"]["stateRoot"][2:]) == block.header.state_root
+    assert seen["encoded_by_repeat"] == 0
+    scheme = get_scheme("mpt")
+    monkeypatch.setattr(
+        scheme, "plan_builder", _parent_builder(type(scheme.plan_builder()))
+    )
+    _serve_reference_block(request_json, parent_plan)
+    _assert_same_plan(seen["plan"], seen["parent_plan"])
+    assert seen["plan"].n_nodes > 100
+
+
+def test_a_served_request_counts_native_encodings_only():
+    """`mpt.node_encodings{impl=}` on a machine with the extension: a
+    stateless request through the server (transactions root in the front
+    end, receipts root in execution, the post-root's walk) grows `native`
+    and leaves `python` where it was. A silent fallback cannot hide."""
+    from test_serving import _post, _stateless_request
+
+    from phant_tpu.engine_api.server import EngineAPIServer
+    from phant_tpu.serving import SchedulerConfig
+    from phant_tpu.utils.native import load_engine_ext
+    from phant_tpu.utils.trace import metrics
+
+    if load_engine_ext() is None:
+        pytest.skip("no extension on this machine")
+
+    def counted(impl: str) -> int:
+        return metrics.snapshot()["counters"].get(
+            f'mpt.node_encodings{{impl="{impl}"}}', 0
+        )
+
+    chain, rpc, want_root = _stateless_request()
+    server = EngineAPIServer(
+        chain,
+        host="127.0.0.1",
+        port=0,
+        sched_config=SchedulerConfig(max_batch=8, max_wait_ms=10.0),
+    )
+    server.serve_in_background()
+    try:
+        before = counted("native"), counted("python")
+        base = f"http://127.0.0.1:{server.port}"
+        code, body = _post(base, rpc)
+        assert code == 200 and body["result"]["status"] == "VALID", body
+        assert body["result"]["stateRoot"] == want_root
+        assert counted("native") > before[0]
+        assert counted("python") == before[1]
+        import urllib.request
+
+        text = urllib.request.urlopen(f"{base}/metrics").read().decode()
+        assert 'phant_mpt_node_encodings_total{impl="native"}' in text
+    finally:
+        server.shutdown()
