@@ -1515,7 +1515,9 @@ class WitnessEngine:
                 # fresh node)
                 h.ref_hint = plan.refs
         resident = self._resident_wanted()
-        attrs = {}
+        # distinct pre-state roots: a wave of one head's copies or of
+        # different blocks (the scheduler's sched.batch_blocks beside it)
+        attrs = {"blocks": len({root for root, _nodes in witnesses})}
         if resident:
             from phant_tpu.ops.witness_resident import verdict_rows
 

@@ -488,6 +488,14 @@ def sig_record_from_handle(
     }
 
 
+def _first_stage_s(record: dict, picked: float) -> float:
+    """When a batch's first lane stage began, in `time.monotonic()`
+    seconds (the span clock, utils/trace.clock_ns, is the same clock in
+    nanoseconds); `picked` for a record that carries no measured stage."""
+    starts = [se[0] for se in (record.get("stages") or {}).values()]
+    return min(starts) / 1e9 if starts else picked
+
+
 def _abandon_handle(engine, handle) -> None:
     """Release a dispatched-but-unresolved engine handle on a crash path.
     The shared engine outlives a dead scheduler; a leaked handle would
@@ -2668,11 +2676,27 @@ class VerificationScheduler:
             trace_ids=[j.trace_id for j in jobs],
         )
         metrics.observe_hist("sched.batch_size", n, buckets=_BATCH_BUCKETS)
+        # a wave of copies or a wave of work: four clients' one head is 1
+        # here, four operators' four heads 4 (by pre-state root, as the
+        # witness engine's phant/witness.dispatch blocks= counts them)
+        metrics.observe_hist(
+            "sched.batch_blocks", len({j.root for j in jobs}), buckets=_BATCH_BUCKETS
+        )
         metrics.count("sched.batches", lane="witness")
         for tenant, cnt in served.items():
             # the per-tenant progress counter the no-starvation gates
             # (loadgen, soak) watch
             metrics.count("sched.tenant_served", cnt, tenant=tenant)
+        # beside it, what each tenant's jobs waited from admission to the
+        # start of their batch's first lane stage (critpath's queue_wait);
+        # `tenant` went through the max_tenants fold at admission
+        started = _first_stage_s(record, picked)
+        for j in jobs:
+            metrics.observe_hist(
+                "sched.tenant_wait_seconds",
+                max(started - j.admitted, 0.0),
+                tenant=j.tenant,
+            )
         metrics.gauge_set(
             "sched.padding_waste", round(1.0 - total / padded, 4) if padded else 0.0
         )

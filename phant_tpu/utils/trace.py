@@ -266,10 +266,12 @@ METRIC_HELP: Dict[str, str] = {
     "sched.queue_depth": "Verification requests currently in the scheduler admission queue (all lanes)",
     "sched.tenant_queue_depth": "Witness requests currently queued, by tenant lane",
     "sched.batch_size": "Assembled witness-batch sizes (requests per engine dispatch)",
+    "sched.batch_blocks": "Distinct blocks a witness batch, by pre-state root: 1 for a wave of one head's copies, the batch's size for a wave of different blocks",
     "sched.queue_wait_seconds": "Admission-to-execution wait per scheduled request",
     "sched.coalesced_requests": "Requests that shared an engine batch with at least one other request",
     "sched.rejected": "Overload rejections by reason (queue_full/tenant_quota/evicted/saturated/deadline/down/shutdown) and tenant",
     "sched.tenant_served": "Requests completed by the scheduler, by tenant (the no-starvation progress counter)",
+    "sched.tenant_wait_seconds": "A witness job's wait from admission to the start of its batch's first lane stage (the interval critpath calls queue_wait), by tenant lane; observed where sched.tenant_served is counted, label set bounded by max_tenants",
     "sched.backfill_evictions": "Witness jobs evicted to admit head-of-chain work (backfill first; head-class witness only for a serial mutation), by shed tenant",
     "sched.adaptive_wait_ms": "Current adaptive batching wait chosen by the queue-depth policy (serving/qos.py)",
     "sched.adaptive_wait_adjustments": "Times the adaptive policy changed the assembly wait (shrink under load, widen when idle)",
