@@ -51,7 +51,10 @@
 #include <cstdint>
 #include <cstdlib>
 #include <cstring>
+#include <initializer_list>
 #include <vector>
+
+#include "evm.h"
 
 extern "C" {
 void* phant_engine_new();
@@ -1035,6 +1038,710 @@ PyObject* ext_encode_subtree(PyObject*, PyObject* const* args, Py_ssize_t nargs)
   return pair;
 }
 
+// --- the EVM's host binding --------------------------------------------------
+//
+// native/evm.cc runs one frame of bytecode against a vtable of host
+// callbacks (native/evm.h). EvmHost IS that host, over a Python StateDB:
+// the callbacks turn the VM's 20- and 32-byte arguments into bytes and
+// ints, call the state object's methods by interned name and write the
+// answers into the VM's buffers. One EvmHost serves a block: what the
+// block fixes (state, coinbase, number, ...) is set when it is built,
+// what a transaction fixes (origin, gas price, blob hashes, the Evm whose
+// _nested_call/_nested_create the `call` callback re-enters, its tracer)
+// at every outermost execute(). Nested frames come back in through
+// execute() at any depth. The interpreter lock is held from a frame's
+// entry to its return: every callback needs it.
+//
+// A Python error in a callback cannot unwind through the VM's frames: it
+// is parked in `pending`, every later callback answers a default without
+// touching Python, and execute() raises it once the VM has returned (at
+// every depth: an inner frame's raise travels through the Python frames
+// between and is parked again by the outer `call` callback).
+
+struct EvmHostObject {
+  PyObject_HEAD
+  PhantHost host;
+  PhantTxContext txc;
+  PyObject* state;
+  PyObject* block_hash_fn;  // number -> 32 bytes, or None
+  // the host side's Python helpers, for the callbacks that are more than
+  // one StateDB method (phant_tpu/evm/native_vm.py hands them over)
+  PyObject* visible_code;       // (evm, addr) -> bytes
+  PyObject* visible_code_hash;  // (evm, addr) -> 32 bytes | None
+  PyObject* delegate_cost;      // (evm, addr) -> int
+  PyObject* selfdestruct;       // (state, addr, beneficiary)
+  PyObject* nested;  // (evm, kind, ...) -> (status, gas_left, output, created)
+  PyObject* log_type;           // Log(address, topics, data)
+  // of the transaction whose frames are on the stack (active > 0)
+  PyObject* evm;
+  PyObject* tracer;
+  PyObject* blob_hashes;  // the joined hashes txc.blob_hashes points into
+  // A child's output, alive until the VM has copied it: the VM does so
+  // straight after the `call` callback returns, before any other
+  // callback can run (native/evm.cc, the CREATE and CALL cases), and the
+  // slot is written at that callback's very end, so one slot is enough
+  // under any nesting.
+  PyObject* last_output;
+  PyObject* pending;  // the first exception a callback met
+  int active;         // frames of the VM on the stack
+  unsigned long long frames;  // frames run since frames_run() last asked
+};
+
+struct HostNames {
+  PyObject *access_address, *access_storage_key, *get_storage,
+      *get_original_storage, *set_storage, *get_balance, *is_empty, *add_log,
+      *add_refund, *get_transient, *set_transient, *env, *origin, *gas_price,
+      *blob_hashes, *blob_base_fee, *tracer, *join;
+};
+HostNames names;
+
+bool intern_host_names() {
+  struct {
+    PyObject** slot;
+    const char* text;
+  } all[] = {
+      {&names.access_address, "access_address"},
+      {&names.access_storage_key, "access_storage_key"},
+      {&names.get_storage, "get_storage"},
+      {&names.get_original_storage, "get_original_storage"},
+      {&names.set_storage, "set_storage"},
+      {&names.get_balance, "get_balance"},
+      {&names.is_empty, "is_empty"},
+      {&names.add_log, "add_log"},
+      {&names.add_refund, "add_refund"},
+      {&names.get_transient, "get_transient"},
+      {&names.set_transient, "set_transient"},
+      {&names.env, "env"},
+      {&names.origin, "origin"},
+      {&names.gas_price, "gas_price"},
+      {&names.blob_hashes, "blob_hashes"},
+      {&names.blob_base_fee, "blob_base_fee"},
+      {&names.tracer, "tracer"},
+      {&names.join, "join"},
+  };
+  for (auto& n : all) {
+    *n.slot = PyUnicode_InternFromString(n.text);
+    if (!*n.slot) return false;
+  }
+  return true;
+}
+
+inline EvmHostObject* host_of(void* ctx) {
+  return static_cast<EvmHostObject*>(ctx);
+}
+
+#if PY_VERSION_HEX < 0x030C0000
+PyObject* PyErr_GetRaisedException() {
+  PyObject *type, *value, *tb;
+  PyErr_Fetch(&type, &value, &tb);
+  PyErr_NormalizeException(&type, &value, &tb);
+  if (tb) PyException_SetTraceback(value, tb);
+  Py_XDECREF(type);
+  Py_XDECREF(tb);
+  return value;
+}
+
+void PyErr_SetRaisedException(PyObject* exc) {
+  PyErr_SetObject(reinterpret_cast<PyObject*>(Py_TYPE(exc)), exc);
+  Py_DECREF(exc);
+}
+#endif
+
+// Park the raised exception (the first one wins) and clear the indicator.
+void host_park(EvmHostObject* h) {
+  PyObject* exc = PyErr_GetRaisedException();
+  if (h->pending)
+    Py_XDECREF(exc);
+  else
+    h->pending = exc;
+}
+
+inline const char* chars(const uint8_t* p) {
+  return reinterpret_cast<const char*>(p);
+}
+
+inline PyObject* addr_bytes(const uint8_t* addr) {
+  return PyBytes_FromStringAndSize(chars(addr), 20);
+}
+
+inline PyObject* word_int(const uint8_t* word) {
+  return _PyLong_FromByteArray(word, 32, /*little_endian=*/0, /*signed=*/0);
+}
+
+// A Python int into a 32-byte big-endian word; -1 with an error set where
+// it is no int, negative or wider.
+int int_word(PyObject* value, uint8_t out[32]) {
+  if (!PyLong_Check(value)) {
+    PyErr_SetString(PyExc_TypeError, "the EVM host wants an int");
+    return -1;
+  }
+#if PY_VERSION_HEX >= 0x030D0000
+  return _PyLong_AsByteArray(reinterpret_cast<PyLongObject*>(value), out, 32,
+                             0, 0, 1);
+#else
+  return _PyLong_AsByteArray(reinterpret_cast<PyLongObject*>(value), out, 32,
+                             0, 0);
+#endif
+}
+
+// target.name(args...), or target(args...) where name is null, with every
+// argument's reference taken over; a null argument (an allocation that
+// failed) or a raising call is parked and gives null. With an exception
+// parked already nothing of Python runs: null at once.
+PyObject* host_call(EvmHostObject* h, PyObject* target, PyObject* name,
+                    std::initializer_list<PyObject*> owned) {
+  PyObject* args[4] = {target};
+  size_t n = 1;
+  bool whole = !h->pending;
+  for (PyObject* a : owned) {
+    args[n++] = a;
+    whole = whole && a;
+  }
+  PyObject* r = nullptr;
+  if (whole)
+    r = name ? PyObject_VectorcallMethod(
+                   name, args, n | PY_VECTORCALL_ARGUMENTS_OFFSET, nullptr)
+             : PyObject_Vectorcall(
+                   target, args + 1,
+                   (n - 1) | PY_VECTORCALL_ARGUMENTS_OFFSET, nullptr);
+  for (PyObject* a : owned) Py_XDECREF(a);
+  if (!r && !h->pending) host_park(h);
+  return r;
+}
+
+// The answer as the VM's int32 flag; 0 where the call was parked.
+int32_t host_flag(EvmHostObject* h, PyObject* r) {
+  if (!r) return 0;
+  const int v = PyObject_IsTrue(r);
+  Py_DECREF(r);
+  if (v < 0) {
+    host_park(h);
+    return 0;
+  }
+  return v;
+}
+
+// The answer as a 32-byte word into `out`; zeros where the call was parked.
+void host_word(EvmHostObject* h, PyObject* r, uint8_t out[32]) {
+  std::memset(out, 0, 32);
+  if (!r) return;
+  if (int_word(r, out) < 0) {
+    host_park(h);
+    std::memset(out, 0, 32);
+  }
+  Py_DECREF(r);
+}
+
+int32_t cb_access_account(void* ctx, const uint8_t* addr) {
+  EvmHostObject* h = host_of(ctx);
+  return host_flag(
+      h, host_call(h, h->state, names.access_address, {addr_bytes(addr)}));
+}
+
+int32_t cb_access_storage(void* ctx, const uint8_t* addr, const uint8_t* key) {
+  EvmHostObject* h = host_of(ctx);
+  return host_flag(h, host_call(h, h->state, names.access_storage_key,
+                                {addr_bytes(addr), word_int(key)}));
+}
+
+void host_load(void* ctx, PyObject* name, const uint8_t* addr,
+               const uint8_t* key, uint8_t* out) {
+  EvmHostObject* h = host_of(ctx);
+  host_word(h, host_call(h, h->state, name, {addr_bytes(addr), word_int(key)}),
+            out);
+}
+
+void host_store(void* ctx, PyObject* name, const uint8_t* addr,
+                const uint8_t* key, const uint8_t* val) {
+  EvmHostObject* h = host_of(ctx);
+  Py_XDECREF(host_call(h, h->state, name,
+                       {addr_bytes(addr), word_int(key), word_int(val)}));
+}
+
+void cb_get_storage(void* ctx, const uint8_t* addr, const uint8_t* key,
+                    uint8_t* out) {
+  host_load(ctx, names.get_storage, addr, key, out);
+}
+
+void cb_get_original_storage(void* ctx, const uint8_t* addr,
+                             const uint8_t* key, uint8_t* out) {
+  host_load(ctx, names.get_original_storage, addr, key, out);
+}
+
+void cb_set_storage(void* ctx, const uint8_t* addr, const uint8_t* key,
+                    const uint8_t* val) {
+  host_store(ctx, names.set_storage, addr, key, val);
+}
+
+void cb_get_transient(void* ctx, const uint8_t* addr, const uint8_t* key,
+                      uint8_t* out) {
+  host_load(ctx, names.get_transient, addr, key, out);
+}
+
+void cb_set_transient(void* ctx, const uint8_t* addr, const uint8_t* key,
+                      const uint8_t* val) {
+  host_store(ctx, names.set_transient, addr, key, val);
+}
+
+void cb_get_balance(void* ctx, const uint8_t* addr, uint8_t* out) {
+  EvmHostObject* h = host_of(ctx);
+  host_word(h, host_call(h, h->state, names.get_balance, {addr_bytes(addr)}),
+            out);
+}
+
+int32_t cb_is_empty(void* ctx, const uint8_t* addr) {
+  EvmHostObject* h = host_of(ctx);
+  return host_flag(h,
+                   host_call(h, h->state, names.is_empty, {addr_bytes(addr)}));
+}
+
+// helper(evm, addr) as bytes, or null with the failure parked.
+PyObject* host_code_bytes(EvmHostObject* h, PyObject* helper,
+                          const uint8_t* addr, bool none_ok) {
+  Py_INCREF(h->evm);
+  PyObject* r = host_call(h, helper, nullptr, {h->evm, addr_bytes(addr)});
+  if (!r) return nullptr;
+  if (PyBytes_Check(r) || (none_ok && r == Py_None)) return r;
+  Py_DECREF(r);
+  PyErr_SetString(PyExc_TypeError, "the EVM host wants code as bytes");
+  host_park(h);
+  return nullptr;
+}
+
+uint64_t cb_get_code_size(void* ctx, const uint8_t* addr) {
+  EvmHostObject* h = host_of(ctx);
+  PyObject* code = host_code_bytes(h, h->visible_code, addr, false);
+  if (!code) return 0;
+  const uint64_t n = static_cast<uint64_t>(PyBytes_GET_SIZE(code));
+  Py_DECREF(code);
+  return n;
+}
+
+void cb_copy_code(void* ctx, const uint8_t* addr, uint64_t offset,
+                  uint8_t* out, uint64_t size) {
+  EvmHostObject* h = host_of(ctx);
+  PyObject* code = host_code_bytes(h, h->visible_code, addr, false);
+  if (!code) return;
+  const uint64_t n = static_cast<uint64_t>(PyBytes_GET_SIZE(code));
+  if (offset < n) {
+    const uint64_t take = size < n - offset ? size : n - offset;
+    std::memcpy(out, PyBytes_AS_STRING(code) + offset, take);
+  }
+  Py_DECREF(code);
+}
+
+void cb_get_code_hash(void* ctx, const uint8_t* addr, uint8_t* out) {
+  EvmHostObject* h = host_of(ctx);
+  std::memset(out, 0, 32);
+  PyObject* hash = host_code_bytes(h, h->visible_code_hash, addr, true);
+  if (!hash) return;
+  if (hash != Py_None && PyBytes_GET_SIZE(hash) == 32)
+    std::memcpy(out, PyBytes_AS_STRING(hash), 32);
+  Py_DECREF(hash);
+}
+
+int64_t cb_delegate_access_cost(void* ctx, const uint8_t* addr) {
+  EvmHostObject* h = host_of(ctx);
+  Py_INCREF(h->evm);
+  PyObject* r =
+      host_call(h, h->delegate_cost, nullptr, {h->evm, addr_bytes(addr)});
+  if (!r) return 0;
+  const long long cost = PyLong_AsLongLong(r);
+  Py_DECREF(r);
+  if (cost == -1 && PyErr_Occurred()) {
+    host_park(h);
+    return 0;
+  }
+  return cost;
+}
+
+void cb_get_block_hash(void* ctx, uint64_t number, uint8_t* out) {
+  EvmHostObject* h = host_of(ctx);
+  std::memset(out, 0, 32);
+  if (h->block_hash_fn == Py_None) return;
+  PyObject* r = host_call(h, h->block_hash_fn, nullptr,
+                          {PyLong_FromUnsignedLongLong(number)});
+  if (!r) return;
+  if (PyBytes_Check(r) && PyBytes_GET_SIZE(r) == 32) {
+    std::memcpy(out, PyBytes_AS_STRING(r), 32);
+  } else {
+    PyErr_SetString(PyExc_TypeError, "a block hash is 32 bytes");
+    host_park(h);
+  }
+  Py_DECREF(r);
+}
+
+void cb_emit_log(void* ctx, const uint8_t* addr, const uint8_t* data,
+                 uint64_t len, const uint8_t* topics, int32_t ntopics) {
+  EvmHostObject* h = host_of(ctx);
+  PyObject* tops = PyTuple_New(ntopics);
+  for (int32_t i = 0; tops && i < ntopics; ++i) {
+    PyObject* t = PyBytes_FromStringAndSize(chars(topics) + 32 * i, 32);
+    if (!t) {
+      Py_CLEAR(tops);
+      break;
+    }
+    PyTuple_SET_ITEM(tops, i, t);
+  }
+  PyObject* payload =
+      PyBytes_FromStringAndSize(chars(data), static_cast<Py_ssize_t>(len));
+  PyObject* log = host_call(h, h->log_type, nullptr,
+                            {addr_bytes(addr), tops, payload});
+  if (log) Py_XDECREF(host_call(h, h->state, names.add_log, {log}));
+}
+
+void cb_add_refund(void* ctx, int64_t delta) {
+  EvmHostObject* h = host_of(ctx);
+  Py_XDECREF(
+      host_call(h, h->state, names.add_refund, {PyLong_FromLongLong(delta)}));
+}
+
+void cb_selfdestruct(void* ctx, const uint8_t* addr,
+                     const uint8_t* beneficiary) {
+  EvmHostObject* h = host_of(ctx);
+  Py_INCREF(h->state);
+  Py_XDECREF(host_call(h, h->selfdestruct, nullptr,
+                       {h->state, addr_bytes(addr), addr_bytes(beneficiary)}));
+}
+
+void cb_trace(void* ctx, uint64_t pc, int32_t op, int64_t gas, int32_t depth,
+              int32_t stack_size) {
+  EvmHostObject* h = host_of(ctx);
+  if (h->pending) return;
+  PyObject* r = PyObject_CallFunction(h->tracer, "KiLii",
+                                      static_cast<unsigned long long>(pc), op,
+                                      static_cast<long long>(gas), depth,
+                                      stack_size);
+  if (!r) host_park(h);
+  Py_XDECREF(r);
+}
+
+// A nested CALL*/CREATE*: the Python side builds the Message and re-enters
+// Evm._nested_call/_nested_create, which come back into execute() for the
+// child's frame. A host-side failure reads as a failed call to the VM and
+// is raised by the execute() under it.
+void cb_call(void* ctx, const PhantMsg* m, PhantResult* res) {
+  EvmHostObject* h = host_of(ctx);
+  res->status = 2;
+  res->gas_left = 0;
+  res->output = nullptr;
+  res->output_len = 0;
+  std::memset(res->create_address, 0, 20);
+  if (h->pending) return;
+  PyObject* value = word_int(m->value);
+  if (!value) {
+    host_park(h);
+    return;
+  }
+  PyObject* r = PyObject_CallFunction(
+      h->nested, "OiiiLy#y#y#Ny#y#", h->evm, static_cast<int>(m->kind),
+      static_cast<int>(m->is_static), static_cast<int>(m->depth),
+      static_cast<long long>(m->gas), chars(m->caller), Py_ssize_t{20},
+      chars(m->target), Py_ssize_t{20}, chars(m->code_address), Py_ssize_t{20},
+      value, m->data_len ? chars(m->data) : "",
+      static_cast<Py_ssize_t>(m->data_len), chars(m->salt), Py_ssize_t{32});
+  if (!r) {
+    host_park(h);
+    return;
+  }
+  int status;
+  long long gas_left;
+  PyObject *output, *created;
+  if (!PyArg_ParseTuple(r, "iLSO", &status, &gas_left, &output, &created) ||
+      (created != Py_None &&
+       !(PyBytes_Check(created) && PyBytes_GET_SIZE(created) == 20))) {
+    if (!PyErr_Occurred())
+      PyErr_SetString(PyExc_TypeError, "a created address is 20 bytes");
+    host_park(h);
+    Py_DECREF(r);
+    return;
+  }
+  res->status = status;
+  res->gas_left = gas_left;
+  if (PyBytes_GET_SIZE(output)) {
+    res->output = reinterpret_cast<const uint8_t*>(PyBytes_AS_STRING(output));
+    res->output_len = static_cast<uint64_t>(PyBytes_GET_SIZE(output));
+    Py_INCREF(output);
+    Py_XSETREF(h->last_output, output);
+  }
+  if (created != Py_None)
+    std::memcpy(res->create_address, PyBytes_AS_STRING(created), 20);
+  Py_DECREF(r);
+}
+
+// What a transaction fixes, read off the Evm whose outermost frame enters.
+int host_bind_tx(EvmHostObject* h, PyObject* evm) {
+  PyObject* env = PyObject_GetAttr(evm, names.env);
+  if (!env) return -1;
+  PyObject* origin = PyObject_GetAttr(env, names.origin);
+  PyObject* gas_price = PyObject_GetAttr(env, names.gas_price);
+  PyObject* blob_fee = PyObject_GetAttr(env, names.blob_base_fee);
+  PyObject* hashes = PyObject_GetAttr(env, names.blob_hashes);
+  PyObject* tracer = PyObject_GetAttr(evm, names.tracer);
+  Py_DECREF(env);
+  PyObject* joined = nullptr;
+  int rc = -1;
+  if (origin && gas_price && blob_fee && hashes && tracer) {
+    if (!PyBytes_Check(origin) || PyBytes_GET_SIZE(origin) != 20) {
+      PyErr_SetString(PyExc_TypeError, "env.origin is 20 bytes");
+    } else if (int_word(gas_price, h->txc.gas_price) == 0 &&
+               int_word(blob_fee, h->txc.blob_base_fee) == 0) {
+      PyObject* empty = PyBytes_FromStringAndSize(nullptr, 0);
+      if (empty && PyTuple_CheckExact(hashes) && !PyTuple_GET_SIZE(hashes)) {
+        joined = empty;  // every transaction but a blob transaction
+      } else if (empty) {
+        joined = PyObject_CallMethodOneArg(empty, names.join, hashes);
+        Py_DECREF(empty);
+      }
+      if (joined && (!PyBytes_Check(joined) ||
+                     PyBytes_GET_SIZE(joined) % 32 != 0)) {
+        PyErr_SetString(PyExc_ValueError, "a blob hash is 32 bytes");
+        Py_CLEAR(joined);
+      }
+      if (joined) rc = 0;
+    }
+  }
+  if (rc == 0) {
+    std::memcpy(h->txc.origin, PyBytes_AS_STRING(origin), 20);
+    const Py_ssize_t n = PyBytes_GET_SIZE(joined);
+    h->txc.blob_hashes =
+        n ? reinterpret_cast<const uint8_t*>(PyBytes_AS_STRING(joined))
+          : nullptr;
+    h->txc.n_blob_hashes = static_cast<uint64_t>(n / 32);
+    Py_XSETREF(h->blob_hashes, joined);
+    Py_INCREF(evm);
+    Py_XSETREF(h->evm, evm);
+    if (tracer == Py_None) {
+      Py_CLEAR(h->tracer);
+      h->host.trace = nullptr;  // the VM's loop pays one branch
+    } else {
+      Py_INCREF(tracer);
+      Py_XSETREF(h->tracer, tracer);
+      h->host.trace = cb_trace;
+    }
+  }
+  Py_XDECREF(origin);
+  Py_XDECREF(gas_price);
+  Py_XDECREF(blob_fee);
+  Py_XDECREF(hashes);
+  Py_XDECREF(tracer);
+  return rc;
+}
+
+// The transaction's objects go when its outermost frame has returned: a
+// binding at rest refers to nothing that refers back to it.
+void host_unbind_tx(EvmHostObject* h) {
+  h->txc.blob_hashes = nullptr;
+  h->txc.n_blob_hashes = 0;
+  h->host.trace = nullptr;
+  Py_CLEAR(h->evm);
+  Py_CLEAR(h->tracer);
+  Py_CLEAR(h->blob_hashes);
+  Py_CLEAR(h->last_output);
+}
+
+// execute(evm, code, caller, address, value, data, gas, depth, is_static)
+//   -> (status, gas_left, output): one frame, 0 success / 1 revert / 2 failure
+PyObject* EvmHost_execute(EvmHostObject* self, PyObject* const* args,
+                          Py_ssize_t nargs) {
+  if (nargs != 9) {
+    PyErr_SetString(PyExc_TypeError,
+                    "execute(evm, code, caller, address, value, data, gas, "
+                    "depth, is_static)");
+    return nullptr;
+  }
+  PyObject *evm = args[0], *code = args[1], *caller = args[2],
+           *address = args[3], *data = args[5];
+  if (!PyBytes_Check(code) || !PyBytes_Check(data) || !PyBytes_Check(caller) ||
+      PyBytes_GET_SIZE(caller) != 20 || !PyBytes_Check(address) ||
+      PyBytes_GET_SIZE(address) != 20) {
+    PyErr_SetString(PyExc_TypeError,
+                    "code and data are bytes, caller and address 20 bytes");
+    return nullptr;
+  }
+  PhantMsg msg;
+  std::memset(&msg, 0, sizeof(msg));
+  msg.kind = PHANT_CALL;
+  if (int_word(args[4], msg.value) < 0) return nullptr;
+  msg.gas = PyLong_AsLongLong(args[6]);
+  msg.depth = static_cast<int32_t>(PyLong_AsLong(args[7]));
+  const int is_static = PyObject_IsTrue(args[8]);
+  if (PyErr_Occurred()) return nullptr;
+  msg.is_static = is_static;
+  std::memcpy(msg.caller, PyBytes_AS_STRING(caller), 20);
+  std::memcpy(msg.target, PyBytes_AS_STRING(address), 20);
+  msg.data_len = static_cast<uint64_t>(PyBytes_GET_SIZE(data));
+  msg.data = msg.data_len
+                 ? reinterpret_cast<const uint8_t*>(PyBytes_AS_STRING(data))
+                 : nullptr;
+
+  const bool outermost = self->active == 0;
+  if (outermost) {
+    if (host_bind_tx(self, evm) < 0) return nullptr;
+  } else if (evm != self->evm) {
+    PyErr_SetString(PyExc_RuntimeError,
+                    "the EVM host was re-entered by another Evm");
+    return nullptr;
+  }
+  PhantResult res;
+  std::memset(&res, 0, sizeof(res));
+  const Py_ssize_t code_len = PyBytes_GET_SIZE(code);
+  ++self->active;
+  ++self->frames;
+  // code and data are the caller's arguments: alive for the whole call
+  phant_evm_execute(
+      &self->host, &self->txc, &msg,
+      code_len ? reinterpret_cast<const uint8_t*>(PyBytes_AS_STRING(code))
+               : nullptr,
+      static_cast<uint64_t>(code_len), &res);
+  --self->active;
+  PyObject* output = PyBytes_FromStringAndSize(
+      chars(res.output), static_cast<Py_ssize_t>(res.output_len));
+  if (res.output) phant_evm_free(res.output);
+  PyObject* pending = self->pending;
+  self->pending = nullptr;
+  if (outermost) host_unbind_tx(self);
+  if (pending) {
+    Py_XDECREF(output);
+    PyErr_SetRaisedException(pending);
+    return nullptr;
+  }
+  if (!output) return nullptr;
+  return Py_BuildValue("(iLN)", static_cast<int>(res.status),
+                       static_cast<long long>(res.gas_left), output);
+}
+
+// frames_run() -> frames of the VM run since the last call (nested too)
+PyObject* EvmHost_frames_run(EvmHostObject* self, PyObject*) {
+  const unsigned long long n = self->frames;
+  self->frames = 0;
+  return PyLong_FromUnsignedLongLong(n);
+}
+
+int EvmHost_traverse(EvmHostObject* self, visitproc visit, void* arg) {
+  Py_VISIT(self->state);
+  Py_VISIT(self->block_hash_fn);
+  Py_VISIT(self->visible_code);
+  Py_VISIT(self->visible_code_hash);
+  Py_VISIT(self->delegate_cost);
+  Py_VISIT(self->selfdestruct);
+  Py_VISIT(self->nested);
+  Py_VISIT(self->log_type);
+  Py_VISIT(self->evm);
+  Py_VISIT(self->tracer);
+  Py_VISIT(self->pending);
+  return 0;
+}
+
+int EvmHost_clear(EvmHostObject* self) {
+  Py_CLEAR(self->state);
+  Py_CLEAR(self->block_hash_fn);
+  Py_CLEAR(self->visible_code);
+  Py_CLEAR(self->visible_code_hash);
+  Py_CLEAR(self->delegate_cost);
+  Py_CLEAR(self->selfdestruct);
+  Py_CLEAR(self->nested);
+  Py_CLEAR(self->log_type);
+  Py_CLEAR(self->pending);
+  host_unbind_tx(self);
+  return 0;
+}
+
+void EvmHost_dealloc(EvmHostObject* self) {
+  PyObject_GC_UnTrack(self);
+  EvmHost_clear(self);
+  Py_TYPE(self)->tp_free(reinterpret_cast<PyObject*>(self));
+}
+
+// EvmHost(state, coinbase, block_number, timestamp, gas_limit, chain_id,
+//         prev_randao, base_fee, revision, block_hash_fn, helpers)
+// helpers = (visible_code, visible_code_hash, delegation_access_cost,
+//            selfdestruct, nested, Log)
+PyObject* EvmHost_new(PyTypeObject* type, PyObject* args, PyObject* kwds) {
+  if (kwds && PyDict_GET_SIZE(kwds)) {
+    PyErr_SetString(PyExc_TypeError, "EvmHost takes no keyword arguments");
+    return nullptr;
+  }
+  PyObject *state, *base_fee, *block_hash_fn;
+  PyObject* helpers[6];
+  const char *coinbase, *randao;
+  Py_ssize_t coinbase_len, randao_len;
+  unsigned long long number, timestamp, gas_limit, chain_id, revision;
+  if (!PyArg_ParseTuple(args, "Oy#KKKKy#OKO(OOOOOO)", &state, &coinbase,
+                        &coinbase_len, &number, &timestamp, &gas_limit,
+                        &chain_id, &randao, &randao_len, &base_fee, &revision,
+                        &block_hash_fn, &helpers[0], &helpers[1], &helpers[2],
+                        &helpers[3], &helpers[4], &helpers[5]))
+    return nullptr;
+  if (coinbase_len != 20 || randao_len != 32) {
+    PyErr_SetString(PyExc_ValueError,
+                    "coinbase is 20 bytes and prev_randao 32");
+    return nullptr;
+  }
+  EvmHostObject* self =
+      reinterpret_cast<EvmHostObject*>(type->tp_alloc(type, 0));
+  if (!self) return nullptr;  // tp_alloc zeroes the object
+  if (int_word(base_fee, self->txc.base_fee) < 0) {
+    Py_DECREF(self);
+    return nullptr;
+  }
+  std::memcpy(self->txc.coinbase, coinbase, 20);
+  std::memcpy(self->txc.prev_randao, randao, 32);
+  self->txc.block_number = number;
+  self->txc.timestamp = timestamp;
+  self->txc.gas_limit = gas_limit;
+  self->txc.chain_id = chain_id;
+  self->txc.revision = revision;
+  PyObject** slots[] = {&self->visible_code, &self->visible_code_hash,
+                        &self->delegate_cost, &self->selfdestruct,
+                        &self->nested,       &self->log_type};
+  for (int i = 0; i < 6; ++i) {
+    Py_INCREF(helpers[i]);
+    *slots[i] = helpers[i];
+  }
+  Py_INCREF(state);
+  self->state = state;
+  Py_INCREF(block_hash_fn);
+  self->block_hash_fn = block_hash_fn;
+  PhantHost* host = &self->host;
+  host->ctx = self;
+  host->access_account = cb_access_account;
+  host->access_storage = cb_access_storage;
+  host->get_storage = cb_get_storage;
+  host->get_original_storage = cb_get_original_storage;
+  host->set_storage = cb_set_storage;
+  host->get_balance = cb_get_balance;
+  host->get_code_size = cb_get_code_size;
+  host->copy_code = cb_copy_code;
+  host->get_code_hash = cb_get_code_hash;
+  host->is_empty = cb_is_empty;
+  host->get_block_hash = cb_get_block_hash;
+  host->emit_log = cb_emit_log;
+  host->add_refund = cb_add_refund;
+  host->selfdestruct = cb_selfdestruct;
+  host->call = cb_call;
+  host->get_transient = cb_get_transient;
+  host->set_transient = cb_set_transient;
+  host->trace = nullptr;  // set with a transaction's tracer
+  host->delegate_access_cost = cb_delegate_access_cost;
+  return reinterpret_cast<PyObject*>(self);
+}
+
+PyMethodDef EvmHost_methods[] = {
+    {"execute", reinterpret_cast<PyCFunction>(EvmHost_execute), METH_FASTCALL,
+     "execute(evm, code, caller, address, value, data, gas, depth, is_static)"
+     " -> (status, gas_left, output)"},
+    {"frames_run", reinterpret_cast<PyCFunction>(EvmHost_frames_run),
+     METH_NOARGS, "frames of the VM run since the last call, nested ones too"},
+    {nullptr, nullptr, 0, nullptr},
+};
+
+PyTypeObject EvmHostType = {
+    PyVarObject_HEAD_INIT(nullptr, 0)
+    "phant_engine_ext.EvmHost",          /* tp_name */
+    sizeof(EvmHostObject),               /* tp_basicsize */
+};
+
 PyMethodDef module_methods[] = {
     {"rlp_encode", ext_rlp_encode, METH_O, "rlp_encode(item) -> bytes"},
     {"encode_node", reinterpret_cast<PyCFunction>(ext_encode_node),
@@ -1071,12 +1778,30 @@ extern "C" PyObject* PyInit_phant_engine_ext() {
   BatchType.tp_methods = Batch_methods;
   // Batch objects are created only by scan_begin(); no tp_new exposed
   if (PyType_Ready(&BatchType) < 0) return nullptr;
+  EvmHostType.tp_dealloc = reinterpret_cast<destructor>(EvmHost_dealloc);
+  EvmHostType.tp_flags = Py_TPFLAGS_DEFAULT | Py_TPFLAGS_HAVE_GC;
+  EvmHostType.tp_traverse = reinterpret_cast<traverseproc>(EvmHost_traverse);
+  EvmHostType.tp_clear = reinterpret_cast<inquiry>(EvmHost_clear);
+  EvmHostType.tp_methods = EvmHost_methods;
+  EvmHostType.tp_new = EvmHost_new;
+  if (PyType_Ready(&EvmHostType) < 0) return nullptr;
   PyObject* m = PyModule_Create(&moduledef);
   if (!m) return nullptr;
   Py_INCREF(&EngineType);
   if (PyModule_AddObject(m, "Engine",
                          reinterpret_cast<PyObject*>(&EngineType)) < 0) {
     Py_DECREF(&EngineType);
+    Py_DECREF(m);
+    return nullptr;
+  }
+  if (!intern_host_names()) {
+    Py_DECREF(m);
+    return nullptr;
+  }
+  Py_INCREF(&EvmHostType);
+  if (PyModule_AddObject(m, "EvmHost",
+                         reinterpret_cast<PyObject*>(&EvmHostType)) < 0) {
+    Py_DECREF(&EvmHostType);
     Py_DECREF(m);
     return nullptr;
   }

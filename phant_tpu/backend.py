@@ -26,7 +26,9 @@ _VALID = ("cpu", "tpu")
 _probe_lock = threading.Lock()
 
 # EVM bytecode execution backend: "python" (phant_tpu/evm/interpreter.py) or
-# "native" (the C++ core in native/evm.cc, the reference's evmone analog).
+# "native" (the C++ core in native/evm.cc, the reference's evmone analog,
+# entered through the extension's host binding: phant_tpu/evm/native_vm.py;
+# where the extension does not load, the Python interpreter runs).
 _EVM_BACKEND = "python"
 _VALID_EVM = ("python", "native")
 

@@ -21,6 +21,7 @@ from phant_tpu.crypto.secp256k1 import SignatureError
 from phant_tpu.evm import gas as G
 from phant_tpu.evm.interpreter import Evm
 from phant_tpu.evm.message import Environment, Message
+from phant_tpu.evm.native_vm import BlockHost
 from phant_tpu.evm.precompiles import precompile_addresses
 from phant_tpu.blockchain.fork import Fork, FrontierFork
 from phant_tpu.signer.signer import TxSigner
@@ -84,6 +85,7 @@ class Blockchain:
         # chain config (fork-activation schedule); the stateless handler
         # uses it to pick the fork for witness-backed execution
         self.config = config
+        self._vm_host = None  # the running block's (run_block)
         # a config naming a known public network arms the KZG dev-setup
         # guard: 0x0A must refuse the forgeable dev tau there (crypto/kzg
         # set_public_network; config-less fixture chains stay unguarded)
@@ -122,11 +124,18 @@ class Blockchain:
             raise BlockError("post-merge blocks must have no uncles")
 
         self.state.begin_block()
+        # ONE host binding of the native VM for the block's transactions
+        # (evm/native_vm.BlockHost: built at the first frame the native VM
+        # runs, so none under the Python interpreter); it ends with the block
+        self._vm_host = BlockHost()
         try:
             return self._execute_block(block, check_body_roots, senders)
         except BaseException:
             self.state.rollback_block()
             raise
+        finally:
+            self._vm_host.close()
+            self._vm_host = None
 
     def run_blocks(
         self, blocks: List[Block], check_body_roots: bool = True
@@ -553,7 +562,7 @@ class Blockchain:
             block_hash_fn=self.fork.get_block_hash,
             revision=REVISION_PRAGUE,
         )
-        evm = Evm(env)
+        evm = Evm(env, self._vm_host)
         result = evm.execute_message(
             Message(
                 caller=req.SYSTEM_ADDRESS,
@@ -811,7 +820,7 @@ class Blockchain:
             if G.is_delegation_designator(to_code):
                 state.access_address(G.delegation_target(to_code))
 
-        evm = Evm(env)
+        evm = Evm(env, self._vm_host)
         msg = Message(
             caller=sender,
             target=tx.to,

@@ -138,9 +138,14 @@ def _int_to_addr(v: int) -> bytes:
 class Evm:
     """One EVM instance bound to an Environment (reference: vm.zig:33-65)."""
 
-    def __init__(self, env: Environment):
+    def __init__(self, env: Environment, host=None):
         self.env = env
         self.state = env.state
+        # the native VM's host binding (evm/native_vm.BlockHost): the one a
+        # block's transactions share, handed in by Blockchain.run_block;
+        # None = this Evm makes its own at its first native frame
+        self.host = host
+        self._own_host = None
         # optional per-instruction tracer: fn(pc, op, gas, depth, stack_size).
         # Same hook shape on both backends (native/evm.cc PhantHost.trace),
         # so a fixture divergence is localized by diffing the two traces.
@@ -160,13 +165,13 @@ class Evm:
                 return self._create(msg, addr)
             return self._call_inner(msg)
         finally:
-            # the native session and this Evm refer to each other, and its
-            # ctypes trampolines to it: taken apart here, the transaction's
-            # objects (and the block's state they hold) die by reference
-            # count and wait for no collector
-            session = self.__dict__.pop("_native_session", None)
-            if session is not None:
-                session.close()
+            # a binding this Evm made for itself ends with its message (one
+            # a block shares ends with the block): the state it holds dies
+            # by reference count and waits for no collector
+            own, self._own_host = self._own_host, None
+            if own is not None:
+                self.host = None
+                own.close()
 
     # ------------------------------------------------------------------
     # call path (reference: EVMOneHost.call vm.zig:382-522)
@@ -288,9 +293,12 @@ class Evm:
         from phant_tpu.backend import evm_backend
 
         if evm_backend() == "native":
-            from phant_tpu.evm.native_vm import execute_native
+            host = self.host
+            if host is None:
+                from phant_tpu.evm.native_vm import BlockHost
 
-            result = execute_native(self, code, msg, address)
+                host = self.host = self._own_host = BlockHost()
+            result = host.execute(self, code, msg, address)
             if result is not None:
                 return result  # None: toolchain unavailable, fall through
         frame = Frame(
@@ -665,6 +673,15 @@ def visible_code_hash(evm, addr: bytes):
     if _visible_code(evm, addr) == G.DELEGATION_MARKER:
         return G.DELEGATION_MARKER_HASH
     return evm.state.get_account(addr).code_hash()
+
+
+def selfdestruct_effects(state, address: bytes, beneficiary: bytes) -> None:
+    """What SELFDESTRUCT does to the state once its gas is paid; shared by
+    both backends (the native core through its host's `selfdestruct`)."""
+    state.add_balance(beneficiary, state.get_balance(address))
+    state.set_balance(address, 0)
+    state.touch(beneficiary)
+    state.mark_selfdestruct(address)
 
 
 def delegation_access_cost(evm, code_addr: bytes) -> int:
@@ -1167,8 +1184,5 @@ def _selfdestruct(evm, frame):
     balance = evm.state.get_balance(frame.address)
     if balance and evm.state.is_empty(beneficiary):
         frame.use_gas(G.NEW_ACCOUNT_GAS)
-    evm.state.add_balance(beneficiary, balance)
-    evm.state.set_balance(frame.address, 0)
-    evm.state.touch(beneficiary)
-    evm.state.mark_selfdestruct(frame.address)
+    selfdestruct_effects(evm.state, frame.address, beneficiary)
     return ExecResult(True, frame.gas)

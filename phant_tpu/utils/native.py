@@ -33,12 +33,13 @@ _load_failed = False
 
 def _sources() -> List[Path]:
     # selftest.cc is the standalone sanitizer harness (`make sanitize`);
-    # pyext.cc is the CPython extension (its own .so, load_engine_ext) —
-    # neither belongs in the ctypes shared library
+    # pyext.cc is the CPython extension (its own .so, load_ext) and evm.cc
+    # the VM that only the extension's host binding enters — none of them
+    # belongs in the ctypes shared library
     return sorted(
         p
         for p in _NATIVE_DIR.glob("*.cc")
-        if p.name not in ("selftest.cc", "pyext.cc")
+        if p.name not in ("selftest.cc", "pyext.cc", "evm.cc")
     )
 
 
@@ -58,12 +59,12 @@ def _host_cpu_flags() -> str:
 
 def _keyed_build(stem: str, srcs: Sequence[Path], flags: Sequence[str], verbose: bool = False) -> Path:
     """Compile `srcs` with `g++ flags` into build/<stem>-<key>.so unless
-    that exact file exists; the key covers source bytes, flags and the
-    host's CPU flags. The compiler writes a private temp file that is
+    that exact file exists; the key covers source bytes (native/*.h too),
+    flags and the host's CPU flags. The compiler writes a private temp file that is
     renamed into place, so concurrent first builds (pytest workers) never
     load a half-written library."""
     h = hashlib.sha256()
-    for src in srcs:
+    for src in (*srcs, *sorted(_NATIVE_DIR.glob("*.h"))):
         h.update(src.name.encode())
         h.update(src.read_bytes())
     h.update(" ".join(flags).encode())
@@ -432,17 +433,26 @@ _ext_failed = False
 
 
 def load_engine_ext():
-    """Build (if stale) and import the CPython extension driver for the
-    witness-engine core (native/pyext.cc + engine.cc). Returns the module
-    (with its `Engine` type) or None; PHANT_ENGINE_EXT=0 disables it (the
-    ctypes core then serves, PHANT_ENGINE_NATIVE=0 the Python twin)."""
-    global _ext_mod, _ext_failed
-    # env checks FIRST: the kill switches must keep working after the
-    # module has been cached in-process (the test matrix's "ctypes" run
-    # relies on PHANT_ENGINE_EXT=0 actually forcing the fallback)
-    if _ext_failed or os.environ.get("PHANT_NO_NATIVE"):
-        return None
+    """The extension (`load_ext`) for the witness engine's driver and the
+    trie-node encoder, or None; PHANT_ENGINE_EXT=0 masks it from THEM (the
+    ctypes core then serves, PHANT_ENGINE_NATIVE=0 the Python twin; the
+    Python encoders). The EVM's host binding asks `load_ext` itself: what
+    runs the VM is chosen by what loaded, never by a name."""
+    # env check FIRST: the kill switch must keep working after the module
+    # has been cached in-process (the test matrix's "ctypes" run relies on
+    # PHANT_ENGINE_EXT=0 actually forcing the fallback)
     if os.environ.get("PHANT_ENGINE_EXT", "1") != "1":
+        return None
+    return load_ext()
+
+
+def load_ext():
+    """Build (if stale) and import the CPython extension (native/pyext.cc +
+    engine.cc + keccak.cc + evm.cc): the witness-engine driver (`Engine`),
+    the trie-node encoder and the EVM's host binding (`EvmHost`). Returns
+    the module or None (no toolchain, PHANT_NO_NATIVE)."""
+    global _ext_mod, _ext_failed
+    if _ext_failed or os.environ.get("PHANT_NO_NATIVE"):
         return None
     if _ext_mod is not None:
         return _ext_mod
@@ -452,11 +462,13 @@ def load_engine_ext():
         try:
             import sysconfig
 
-            # keccak.cc backs the engine's finish_native in-C hashing
+            # keccak.cc backs the engine's finish_native in-C hashing and
+            # the VM's KECCAK256
             srcs = [
                 _NATIVE_DIR / "pyext.cc",
                 _NATIVE_DIR / "engine.cc",
                 _NATIVE_DIR / "keccak.cc",
+                _NATIVE_DIR / "evm.cc",
             ]
             flags = [
                 "-O3", *_arch_flags(), "-std=c++20", "-shared",

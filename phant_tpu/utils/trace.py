@@ -234,6 +234,8 @@ METRIC_HELP: Dict[str, str] = {
     "root.plan_rows": "Rows hashed by served root programs, by kind: real = trie nodes of the merged plans, pad = the empty rows of their strips (what the ladder's fixed strip costs in keccak work)",
     "root.plan_rung": "Root batches by the ladder rung their merged plan was laid out on (over = above the top rung: hashed on the host)",
     "mpt.node_encodings": "Trie nodes encoded, by the encoder that did it: native = the extension's node encoder (native/pyext.cc), python = the fallback where the process runs without the extension; counted once a root computation (a host walk's root_hash, a hash plan's finish) with the nodes it encoded",
+    "evm.native_frames": "Frames of bytecode the native VM (native/evm.cc) ran, nested ones too, by the host binding they went through: ext = the extension's EvmHost (native/pyext.cc), the only one there is; counted when a block's binding is closed (evm/native_vm.BlockHost.close). A plain transfer runs no frame; under the Python interpreter (no toolchain, --evm_backend=python) the family stands still",
+    "evm.host_bindings": "Host bindings of the native VM built: one a block whose transactions reach code (Blockchain.run_block), one a message for an Evm outside a block",
     "root.prewarm_seconds": "Seconds the server took at start to build the root program on every rung of the ladder (only with the device root lane on and an accelerator under it)",
     "witness_engine.root_plan_stale": "Root prefetch merges dropped stale at begin time (shed changed the batch) — a perf miss, never a correctness event",
     # coalesced sender recovery (ops/sig_engine.py)

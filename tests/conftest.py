@@ -150,3 +150,42 @@ def node_encoder(request, monkeypatch):
     elif load_engine_ext() is None:
         pytest.skip("no extension on this machine")
     return request.param
+
+
+@pytest.fixture(scope="module")
+def reference_block(tmp_path_factory):
+    """Block 2 of a chain of the benchmark's own reference
+    (benchmarks/reference/chain.py, which imports nothing of the program):
+    a small genesis, the cell's mix of transfers and contract calls."""
+    import json
+    import sys
+    from pathlib import Path
+
+    bench = Path(__file__).resolve().parent.parent / "benchmarks"
+    sys.path.insert(0, str(bench))
+    try:
+        from reference import keccak as ref_keccak
+        from reference.chain import Chain
+
+        try:
+            ref_keccak.load(tmp_path_factory.mktemp("refkeccak"))
+        except Exception as e:  # no C compiler on this machine
+            pytest.skip(f"the reference's keccak does not build here: {e}")
+        mix = json.loads((bench / "traffic" / "lone.json").read_text())["chain"]
+        chain = Chain(
+            35,
+            {
+                **mix,
+                "genesis_log2": 10,
+                "sender_pool": 128,
+                "contracts": 4,
+                "transfers_per_block": 40,
+                "calls_per_block": 20,
+                "slots_per_contract": 8,
+            },
+        )
+        chain.extend(2)
+        block = chain.blocks[1]
+        return block, json.loads(block.body(2))
+    finally:
+        sys.path.remove(str(bench))

@@ -915,45 +915,6 @@ def test_hash_plan_is_the_parents_byte_for_byte(
     assert db.apply_post_root(prp, execute_plan_outputs_host(prp.plan)) == want_root
 
 
-@pytest.fixture(scope="module")
-def reference_block(tmp_path_factory):
-    """Block 2 of a chain of the benchmark's own reference
-    (benchmarks/reference/chain.py, which imports nothing of the program):
-    a small genesis, the cell's mix of transfers and contract calls."""
-    import json
-    import sys
-    from pathlib import Path
-
-    bench = Path(__file__).resolve().parent.parent / "benchmarks"
-    sys.path.insert(0, str(bench))
-    try:
-        from reference import keccak as ref_keccak
-        from reference.chain import Chain
-
-        try:
-            ref_keccak.load(tmp_path_factory.mktemp("refkeccak"))
-        except Exception as e:  # no C compiler on this machine
-            pytest.skip(f"the reference's keccak does not build here: {e}")
-        mix = json.loads((bench / "traffic" / "lone.json").read_text())["chain"]
-        chain = Chain(
-            35,
-            {
-                **mix,
-                "genesis_log2": 10,
-                "sender_pool": 128,
-                "contracts": 4,
-                "transfers_per_block": 40,
-                "calls_per_block": 20,
-                "slots_per_contract": 8,
-            },
-        )
-        chain.extend(2)
-        block = chain.blocks[1]
-        return block, json.loads(block.body(2))
-    finally:
-        sys.path.remove(str(bench))
-
-
 def _serve_reference_block(request_json, post_root):
     """The request through the Engine API's handler with `post_root` in
     `compute_post_root`'s place; returns the reply."""
@@ -964,11 +925,13 @@ def _serve_reference_block(request_json, post_root):
     from phant_tpu.blockchain.chain import Blockchain
     from phant_tpu.blockchain.fork import fork_for
     from phant_tpu.config import ChainConfig
+    from phant_tpu.crypto import kzg
     from phant_tpu.engine_api import handle_request
     from phant_tpu.state.statedb import StateDB
 
     config = ChainConfig.from_chain_id(1)
     state = StateDB({})
+    public = kzg.public_network()
     chain = Blockchain(
         chain_id=1,
         state=state,
@@ -982,6 +945,8 @@ def _serve_reference_block(request_json, post_root):
         code, reply = handle_request(chain, request_json)
     finally:
         stateless.compute_post_root = real
+        # a mainnet chain names its network for the whole process
+        kzg.set_public_network(public)
     assert code == 200, reply
     return reply
 
