@@ -48,6 +48,9 @@
 #define PY_SSIZE_T_CLEAN
 #include <Python.h>
 
+#include <time.h>
+
+#include <atomic>
 #include <cstdint>
 #include <cstdlib>
 #include <cstring>
@@ -78,6 +81,55 @@ void phant_keccak256(const uint8_t*, size_t, uint8_t*);
 }
 
 namespace {
+
+// The places where this module gives the interpreter lock away around pure
+// C work, and two process-wide clocks a place: the nanoseconds the work ran
+// unlocked (from the release to the work's end), and the nanoseconds from
+// the work's end until the lock was back. The second is the one wait for
+// the lock the program can MEASURE rather than infer from wall less CPU;
+// utils/native reads both at every /metrics exposition (lock_clocks()).
+enum LockSite {
+  kSiteScan,
+  kSiteVerdict,
+  kSiteCommit,
+  kSiteCommitHash,
+  kSiteHash,
+  kSiteFinishCommit,
+  kLockSites
+};
+const char* const kLockSiteNames[kLockSites] = {
+    "scan", "verdict", "commit", "commit_hash", "hash", "finish_commit"};
+std::atomic<uint64_t> g_unlocked_ns[kLockSites];
+std::atomic<uint64_t> g_retake_ns[kLockSites];
+
+inline uint64_t mono_ns() {
+  timespec ts;
+  clock_gettime(CLOCK_MONOTONIC, &ts);
+  return static_cast<uint64_t>(ts.tv_sec) * 1000000000ull +
+         static_cast<uint64_t>(ts.tv_nsec);
+}
+
+// Py_BEGIN/END_ALLOW_THREADS as a scope, clocked: the lock is released at
+// construction and taken back when the scope (the native work) ends.
+class Unlocked {
+ public:
+  explicit Unlocked(LockSite site)
+      : site_(site), save_(PyEval_SaveThread()), released_(mono_ns()) {}
+  ~Unlocked() {
+    const uint64_t done = mono_ns();
+    PyEval_RestoreThread(save_);
+    const uint64_t back = mono_ns();
+    g_unlocked_ns[site_].fetch_add(done - released_, std::memory_order_relaxed);
+    g_retake_ns[site_].fetch_add(back - done, std::memory_order_relaxed);
+  }
+  Unlocked(const Unlocked&) = delete;
+  Unlocked& operator=(const Unlocked&) = delete;
+
+ private:
+  const LockSite site_;
+  PyThreadState* const save_;
+  const uint64_t released_;
+};
 
 // One scanned batch: node pointers (pinned via `keep`), scan rows, block
 // bounds, roots. Owned inline by the engine (classic protocol) or by a
@@ -223,10 +275,11 @@ PyObject* scan_into(EngineObject* self, PyObject* witnesses, BatchState* bs) {
   bs->novel_idx.resize(n ? n : 1);
   uint64_t counts[2] = {0, 0};
   // pure C from here: the scan loop touches only the pinned buffers
-  Py_BEGIN_ALLOW_THREADS
-  phant_engine_scan_ptrs(self->eng, ptrs.data(), lens.data(), n,
-                         bs->rows.data(), bs->novel_idx.data(), counts);
-  Py_END_ALLOW_THREADS
+  {
+    Unlocked unlocked(kSiteScan);
+    phant_engine_scan_ptrs(self->eng, ptrs.data(), lens.data(), n,
+                           bs->rows.data(), bs->novel_idx.data(), counts);
+  }
   bs->n_novel = counts[1];
 
   // the novel list shares the existing bytes objects (no copies) — they
@@ -267,10 +320,11 @@ PyObject* batch_verdict(EngineObject* self, BatchState* bs) {
                                             static_cast<Py_ssize_t>(n_blocks));
   if (!out) return nullptr;
   uint8_t* obuf = reinterpret_cast<uint8_t*>(PyBytes_AS_STRING(out));
-  Py_BEGIN_ALLOW_THREADS
-  phant_engine_verdict(self->eng, bs->rows.data(), bs->block_offs.data(),
-                       n_blocks, bs->roots.data(), obuf);
-  Py_END_ALLOW_THREADS
+  {
+    Unlocked unlocked(kSiteVerdict);
+    phant_engine_verdict(self->eng, bs->rows.data(), bs->block_offs.data(),
+                         n_blocks, bs->roots.data(), obuf);
+  }
   return out;
 }
 
@@ -297,12 +351,13 @@ int batch_commit(EngineObject* self, BatchState* bs, PyObject* digests_obj) {
     PyErr_SetString(PyExc_ValueError, "digests must be 32B per novel node");
     return -1;
   }
-  Py_BEGIN_ALLOW_THREADS
-  phant_engine_commit_ptrs(self->eng, bs->ptrs.data(), bs->lens.data(),
-                           bs->ptrs.size(), bs->rows.data(),
-                           bs->novel_idx.data(), bs->n_novel,
-                           reinterpret_cast<const uint8_t*>(dbuf));
-  Py_END_ALLOW_THREADS
+  {
+    Unlocked unlocked(kSiteCommit);
+    phant_engine_commit_ptrs(self->eng, bs->ptrs.data(), bs->lens.data(),
+                             bs->ptrs.size(), bs->rows.data(),
+                             bs->novel_idx.data(), bs->n_novel,
+                             reinterpret_cast<const uint8_t*>(dbuf));
+  }
   return 0;
 }
 
@@ -312,11 +367,12 @@ int batch_commit(EngineObject* self, BatchState* bs, PyObject* digests_obj) {
 // other serving threads).
 void batch_commit_native(EngineObject* self, BatchState* bs) {
   if (!bs->n_novel) return;
-  Py_BEGIN_ALLOW_THREADS
-  phant_engine_commit_hash_ptrs(self->eng, bs->ptrs.data(), bs->lens.data(),
-                                bs->ptrs.size(), bs->rows.data(),
-                                bs->novel_idx.data(), bs->n_novel);
-  Py_END_ALLOW_THREADS
+  {
+    Unlocked unlocked(kSiteCommitHash);
+    phant_engine_commit_hash_ptrs(self->eng, bs->ptrs.data(), bs->lens.data(),
+                                  bs->ptrs.size(), bs->rows.data(),
+                                  bs->novel_idx.data(), bs->n_novel);
+  }
 }
 
 // finish_native() -> verdict bytes; novel nodes are hashed IN C through
@@ -448,10 +504,11 @@ PyObject* Engine_hash_batch(EngineObject* self, PyObject* arg) {
       nptrs[k] = bs->ptrs[bs->novel_idx[k]];
       nlens[k] = bs->lens[bs->novel_idx[k]];
     }
-    Py_BEGIN_ALLOW_THREADS
-    phant_keccak256_ptrs_fast(nptrs.data(), nlens.data(), bs->n_novel,
-                              bs->digests.data());
-    Py_END_ALLOW_THREADS
+    {
+      Unlocked unlocked(kSiteHash);
+      phant_keccak256_ptrs_fast(nptrs.data(), nlens.data(), bs->n_novel,
+                                bs->digests.data());
+    }
   }
   Py_RETURN_NONE;
 }
@@ -468,12 +525,13 @@ PyObject* Engine_finish_batch(EngineObject* self, PyObject* args) {
   BatchState* bs = batch->bs;
   if (digests_obj == Py_None && bs->n_novel &&
       bs->digests.size() == 32 * bs->n_novel) {
-    Py_BEGIN_ALLOW_THREADS
-    phant_engine_commit_ptrs(self->eng, bs->ptrs.data(), bs->lens.data(),
-                             bs->ptrs.size(), bs->rows.data(),
-                             bs->novel_idx.data(), bs->n_novel,
-                             bs->digests.data());
-    Py_END_ALLOW_THREADS
+    {
+      Unlocked unlocked(kSiteFinishCommit);
+      phant_engine_commit_ptrs(self->eng, bs->ptrs.data(), bs->lens.data(),
+                               bs->ptrs.size(), bs->rows.data(),
+                               bs->novel_idx.data(), bs->n_novel,
+                               bs->digests.data());
+    }
   } else if (batch_commit(self, bs, digests_obj) < 0) {
     return nullptr;
   }
@@ -1742,7 +1800,28 @@ PyTypeObject EvmHostType = {
     sizeof(EvmHostObject),               /* tp_basicsize */
 };
 
+// lock_clocks() -> {site: (unlocked_seconds, retake_seconds)}
+PyObject* ext_lock_clocks(PyObject*, PyObject*) {
+  PyObject* out = PyDict_New();
+  if (!out) return nullptr;
+  for (int i = 0; i < kLockSites; ++i) {
+    PyObject* pair = Py_BuildValue(
+        "(dd)", g_unlocked_ns[i].load(std::memory_order_relaxed) / 1e9,
+        g_retake_ns[i].load(std::memory_order_relaxed) / 1e9);
+    if (!pair || PyDict_SetItemString(out, kLockSiteNames[i], pair) < 0) {
+      Py_XDECREF(pair);
+      Py_DECREF(out);
+      return nullptr;
+    }
+    Py_DECREF(pair);
+  }
+  return out;
+}
+
 PyMethodDef module_methods[] = {
+    {"lock_clocks", ext_lock_clocks, METH_NOARGS,
+     "lock_clocks() -> {site: (seconds run unlocked, seconds waited to take "
+     "the interpreter lock back)}"},
     {"rlp_encode", ext_rlp_encode, METH_O, "rlp_encode(item) -> bytes"},
     {"encode_node", reinterpret_cast<PyCFunction>(ext_encode_node),
      METH_FASTCALL,

@@ -5,7 +5,8 @@ into the analyzer (the script is now a thin shim over this rule), so the
 exposition checker and the static checker cannot drift apart:
 
   * M1 — a registry call (`metrics.count/gauge_set/gauge_add/observe/
-    observe_hist/phase`) whose metric name is not a string literal:
+    observe_hist/phase`, and `observe_split`, which names two families)
+    whose metric name is not a string literal:
     dynamic names are a cardinality hazard and invisible to this gate
     (annotate the few legitimate sites, e.g. names drawn from an adjacent
     literal table).
@@ -32,7 +33,7 @@ from phant_tpu.analysis.rules._taint import snippet
 from phant_tpu.analysis.symbols import ModuleInfo, Project, _dotted
 
 _NAME_RE = re.compile(r"^[a-z0-9_.]+$")
-_METHODS = ("count", "gauge_set", "gauge_add", "observe", "observe_hist", "phase")
+_METHODS = ("count", "gauge_set", "gauge_add", "observe", "observe_hist", "phase", "observe_split")
 
 
 class MetricNameRule(Rule):
@@ -116,49 +117,58 @@ class MetricNameRule(Rule):
         self, project: Project, mi: ModuleInfo, cat_module: str, keys: Dict[str, int]
     ) -> Iterator[Finding]:
         for call in iter_calls(mi.tree):
-            name_arg = self._metric_name_arg(mi, call, cat_module)
-            if name_arg is None:
-                continue
-            if not (
-                isinstance(name_arg, ast.Constant)
-                and isinstance(name_arg.value, str)
-            ):
-                yield self.finding(
-                    project,
-                    mi,
-                    call,
-                    f"`{snippet(call)}` uses a non-literal metric name — "
-                    "dynamic names defeat the static catalog gate and risk "
-                    "unbounded cardinality",
-                    context=mi.name,
-                )
-                continue
-            name = name_arg.value
-            if not _NAME_RE.match(name):
-                yield self.finding(
-                    project,
-                    mi,
-                    call,
-                    f"metric name {name!r} is not [a-z0-9_.]+ — the "
-                    "Prometheus family sanitization would be lossy",
-                    context=mi.name,
-                )
-            if name not in keys:
-                yield self.finding(
-                    project,
-                    mi,
-                    call,
-                    f"metric name {name!r} has no METRIC_HELP entry — add "
-                    "its help string to the registry catalog",
-                    context=mi.name,
-                )
+            for name_arg in self._metric_name_args(mi, call, cat_module):
+                yield from self._check_name(project, mi, call, name_arg, keys)
 
-    def _metric_name_arg(
+    def _check_name(
+        self,
+        project: Project,
+        mi: ModuleInfo,
+        call: ast.Call,
+        name_arg: ast.AST,
+        keys: Dict[str, int],
+    ) -> Iterator[Finding]:
+        if not (
+            isinstance(name_arg, ast.Constant)
+            and isinstance(name_arg.value, str)
+        ):
+            yield self.finding(
+                project,
+                mi,
+                call,
+                f"`{snippet(call)}` uses a non-literal metric name — "
+                "dynamic names defeat the static catalog gate and risk "
+                "unbounded cardinality",
+                context=mi.name,
+            )
+            return
+        name = name_arg.value
+        if not _NAME_RE.match(name):
+            yield self.finding(
+                project,
+                mi,
+                call,
+                f"metric name {name!r} is not [a-z0-9_.]+ — the "
+                "Prometheus family sanitization would be lossy",
+                context=mi.name,
+            )
+        if name not in keys:
+            yield self.finding(
+                project,
+                mi,
+                call,
+                f"metric name {name!r} has no METRIC_HELP entry — add "
+                "its help string to the registry catalog",
+                context=mi.name,
+            )
+
+    def _metric_name_args(
         self, mi: ModuleInfo, call: ast.Call, cat_module: str
-    ) -> Optional[ast.AST]:
-        """The metric-name argument of a registry call — positional OR
+    ) -> Tuple[ast.AST, ...]:
+        """The metric-name argument(s) of a registry call — positional OR
         `name=` keyword (a keyword-only dynamic name must not slip past
-        M1) — else None for non-registry calls. A registry call whose
+        M1); `observe_split` names two families, its first two arguments
+        — else () for non-registry calls. A registry call whose
         name cannot be located at all (e.g. `metrics.count(**kw)`) yields
         the call node itself, which is non-literal and so flags as M1."""
         func = call.func
@@ -172,10 +182,12 @@ class MetricNameRule(Rule):
         elif isinstance(func, ast.Name):
             is_registry = mi.imports.get(func.id) == f"{cat_module}.phase"
         if not is_registry:
-            return None
+            return ()
+        if isinstance(func, ast.Attribute) and func.attr == "observe_split":
+            return tuple(call.args[:2]) if len(call.args) >= 2 else (call,)
         if call.args:
-            return call.args[0]
+            return (call.args[0],)
         for kw in call.keywords:
             if kw.arg == "name":
-                return kw.value
-        return call
+                return (kw.value,)
+        return (call,)

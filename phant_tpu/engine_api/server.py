@@ -489,11 +489,26 @@ class EngineAPIServer:
                             metrics.gauge_add("engine_api.inflight", -1)
                 finally:
                     if req is not None:
+                        walls = dict.fromkeys(FRONTEND_PHASES, 0)
                         for name, t_a, t_b in req.intervals:
-                            if name in FRONTEND_PHASES:
+                            if name in walls:
+                                walls[name] += t_b - t_a
                                 metrics.observe_hist(
                                     "engine_api.phase_seconds",
                                     (t_b - t_a) / 1e9,
+                                    buckets=REQUEST_SECONDS_BUCKETS,
+                                    phase=name,
+                                )
+                        # the handler's CPU inside each mark (the thread's
+                        # CPU clock, read where the marks are set) and the
+                        # rest of the mark's wall: what it waited there
+                        for name, cpu in req.cpu_ns.items():
+                            if name in walls:
+                                metrics.observe_split(
+                                    "engine_api.phase_cpu_seconds",
+                                    "engine_api.phase_offcpu_seconds",
+                                    walls[name] / 1e9,
+                                    cpu / 1e9,
                                     buckets=REQUEST_SECONDS_BUCKETS,
                                     phase=name,
                                 )

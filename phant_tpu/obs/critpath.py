@@ -51,7 +51,12 @@ test suite asserts >= 95% on the serving path at pipeline depths 1 AND 2
 across all three engine lanes. Everything lands in the
 `critpath.phase_seconds{phase=}` histogram family, which the derived
 p50/p99 gauges (utils/trace.py prometheus_text) turn into per-phase
-quantiles at scrape time.
+quantiles at scrape time. Beside it, for a span that read the thread's CPU
+clock (`phases[name]["cpu_ms"]`), the same labels in
+`critpath.phase_cpu_seconds` and `critpath.phase_offcpu_seconds`
+(`attribute_cpu`): how much of each phase the handler thread ran, and how
+much it waited; in a phase that waits for nothing by design, for the
+interpreter lock.
 
 SLO exemplars: metrics tell you THAT requests are slow; the exemplar
 shows WHY. A request whose wall clock exceeds `--slo-budget-ms`
@@ -399,6 +404,62 @@ def attribute(record: dict) -> Tuple[Dict[str, float], float, float]:
     return out, unattributed, wall
 
 
+#: each parent phase of the span with the phases `attribute` tiles it into,
+#: the catch-all that takes its wall remainder first: the handler's CPU
+#: inside the parent goes there, and only what the catch-all's wall cannot
+#: hold goes on to the cuts a lane's stage claimed (the handler slept there)
+_CPU_TILING: Tuple[Tuple[str, Tuple[str, ...], Tuple[str, ...]], ...] = (
+    ("stateless.sig_rows", (), ("sig_rows",)),
+    ("stateless.witness_decode", (), ("witness_decode",)),
+    (
+        "stateless.witness_verify",
+        (),
+        ("dispatch", "queue_wait", "resolve", "pack", "prefetch"),
+    ),
+    ("stateless.execute", ("sched.sig_wait",), ("evm",)),
+    ("sched.sig_wait", (), ("sig_wait",)),
+    ("stateless.post_root", ("stateless.post_root_plan",), ("post_root", "root_wait")),
+    ("stateless.post_root_plan", (), ("root_plan",)),
+)
+
+
+def attribute_cpu(
+    record: dict, breakdown: Dict[str, float]
+) -> Optional[Tuple[Dict[str, float], Dict[str, float]]]:
+    """(cpu_ms, over_ms) of the phases of `breakdown` (what `attribute`
+    gave for the same record): the CPU the handler thread ran inside each,
+    from the `cpu_ms` the span keeps beside each phase's `total_ms`. None
+    for a record without them (one from before the second clock, a
+    hand-made one). A parent's CPU less its nested phases' own goes where
+    its wall remainder goes, so the sub-tilings sum to their parents, and
+    no phase is given more CPU than it has wall. What a parent's reading
+    holds beyond its phases' wall (a CPU clock that steps by ticks charges
+    a whole tick to the phase it fell in) is `over_ms` of the parent's
+    catch-all: `rollup` books it against the label's next observations
+    (`Metrics.observe_split`)."""
+    phases = record.get("phases") or {}
+
+    def cpu(name: str) -> Optional[float]:
+        st = phases.get(name)
+        return _num(st.get("cpu_ms")) if isinstance(st, dict) else None
+
+    if all(cpu(parent) is None for parent, _n, _l in _CPU_TILING):
+        return None
+    out: Dict[str, float] = {}
+    over: Dict[str, float] = {}
+    for parent, nested, labels in _CPU_TILING:
+        left = (cpu(parent) or 0.0) - sum(cpu(n) or 0.0 for n in nested)
+        for label in labels:
+            room = breakdown.get(label, 0.0) - out.get(label, 0.0)
+            take = min(max(left, 0.0), max(room, 0.0))
+            if take > 0.0:
+                out[label] = out.get(label, 0.0) + take
+                left -= take
+        if left > 0.0:
+            over[labels[0]] = left
+    return out, over
+
+
 def _capture_slow(
     record: dict,
     breakdown: Dict[str, float],
@@ -432,10 +493,19 @@ def rollup(record: dict) -> None:
     breakdown, unattributed, wall = attribute(record)
     if wall <= 0.0:
         return
+    split = attribute_cpu(record, breakdown)
     for label, v in breakdown.items():
         metrics.observe_hist("critpath.phase_seconds", v / 1e3, phase=label)
+        if split is not None:
+            cpu, over = split
+            metrics.observe_split(
+                "critpath.phase_cpu_seconds",
+                "critpath.phase_offcpu_seconds",
+                v / 1e3,
+                (cpu.get(label, 0.0) + over.get(label, 0.0)) / 1e3,
+                phase=label,
+            )
     metrics.observe_hist("critpath.wall_seconds", wall / 1e3)
-    metrics.observe_hist("critpath.unattributed_seconds", unattributed / 1e3)
     metrics.count("critpath.requests")
     global _tot_wall_s, _tot_attr_s
     with _tot_lock:

@@ -302,7 +302,6 @@ class RootEngine:
         if pf is not None and pf.plans is not plans:
             pf.release()  # not the batch this merge was computed for
             pf = None
-            metrics.count("witness_engine.root_plan_stale")
         h = RootHandle()
         h.plans = list(plans)
         with metrics.phase("witness_engine.root_pack"):
@@ -312,7 +311,6 @@ class RootEngine:
                 if pf is not None and pf.merged is not None:
                     h.merged, h.outs, h.lease = pf.merged, pf.outs, pf.lease
                     pf.lease = pf.merged = pf.outs = None  # ownership moves
-                    metrics.count("witness_engine.root_plan_hits")
                 else:
                     h.merged, h.outs, h.lease, _ = self._merge(plans)
                 route = h.merged is not None  # over the ladder: host
@@ -416,7 +414,6 @@ class RootEngine:
             self.stats["root_requests"] += n
             self.stats[backend + "_batches"] += 1
         metrics.count("witness_engine.root_batches", backend=backend)
-        metrics.count("witness_engine.root_requests", n)
         return out
 
     def abandon_batch(self, handle: RootHandle) -> None:

@@ -226,7 +226,6 @@ class SigEngine:
         if pf is not None and pf.rows_list is not rows_list:
             pf.release()  # not the batch this merge was computed for
             pf = None
-            metrics.count("witness_engine.sig_plan_stale")
         h = SigHandle()
         h.rows_list = list(rows_list)
         with metrics.phase("witness_engine.sig_pack"):
@@ -237,7 +236,6 @@ class SigEngine:
                 if pf is not None and pf.packed is not None:
                     packed = pf.packed
                     pf.packed = None  # ownership moves
-                    metrics.count("witness_engine.sig_plan_hits")
                 else:
                     packed = self._merge(rows_list)
             else:
@@ -312,8 +310,6 @@ class SigEngine:
             self.stats["sig_rows"] += handle.n_rows
             self.stats[backend + "_batches"] += 1
         metrics.count("witness_engine.sig_batches", backend=backend)
-        metrics.count("witness_engine.sig_requests", n)
-        metrics.count("witness_engine.sig_rows", handle.n_rows)
         return out
 
     @staticmethod

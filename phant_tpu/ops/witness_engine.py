@@ -1361,7 +1361,6 @@ class WitnessEngine:
                 metrics.count(metric, d)  # phantlint: disable=METRICNAME — names from the literal tuple above
         for tier, d in evict_tiers:
             metrics.count("witness_engine.evictions", d, tier=tier)
-        metrics.gauge_set("witness_engine.interned_nodes", snap["interned_nodes"])
         metrics.gauge_set(
             "witness_engine.interned_digests", snap["interned_digests"]
         )
@@ -1675,7 +1674,6 @@ class WitnessEngine:
             hits = handle.total - handle.miss
             if hits:
                 metrics.count("witness_engine.cache_hits", hits)
-        metrics.gauge_set("witness_engine.interned_nodes", snap["interned_nodes"])
         metrics.gauge_set(
             "witness_engine.interned_digests", snap["interned_digests"]
         )

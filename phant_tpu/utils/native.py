@@ -493,6 +493,22 @@ def load_ext():
     return _ext_mod
 
 
+#: where the extension gives the interpreter lock away around native work
+#: (native/pyext.cc `LockSite`): the witness engine's scan, hash and commit
+LOCK_SITES = ("scan", "verdict", "commit", "commit_hash", "hash", "finish_commit")
+
+
+def lock_clocks() -> dict:
+    """{site: (seconds the extension ran native work with the interpreter
+    lock released, seconds it then waited to take the lock back)} since
+    process start; zeros where the extension is not loaded (it is never
+    built for this)."""
+    mod = _ext_mod
+    if mod is None:
+        return dict.fromkeys(LOCK_SITES, (0.0, 0.0))
+    return mod.lock_clocks()
+
+
 def load_native() -> Optional[NativeLib]:
     """Build (if stale) and load the native runtime; None if unavailable."""
     global _loaded, _load_failed

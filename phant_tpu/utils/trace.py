@@ -38,6 +38,7 @@ import gc
 import itertools
 import json
 import logging
+import os
 import re
 import sys
 import threading
@@ -185,6 +186,8 @@ METRIC_HELP: Dict[str, str] = {
     "engine_api.inflight": "Engine API requests currently being handled",
     "engine_api.request_seconds": "Engine API request latency (decode + handle + reply)",
     "engine_api.phase_seconds": "The front end's own share of a POST, by phase, timed where the work happens (engine_api/server.py): read = headers parsed -> body read; json = json.loads; gate = the wait for a slot of the stateless gate; decode = payload and witness hex/RLP decode and the block-hash check, up to where verify_block opens; reply = result -> bytes -> written. With verify_block's wall clock they tile engine_api.request_seconds",
+    "engine_api.phase_cpu_seconds": "CPU seconds the handler thread ran inside each front-end phase of a POST (the thread's CPU clock read at the marks of engine_api.phase_seconds, one observation a phase a request, never above the phase's wall: what a reading of a clock that steps by ticks holds beyond it is booked against the series' next observations, Metrics.observe_split)",
+    "engine_api.phase_offcpu_seconds": "engine_api.phase_seconds less engine_api.phase_cpu_seconds, phase by phase: what the handler thread waited inside the phase. In json, decode and reply it waits for nothing by design, so that is its wait for a turn at the interpreter lock; read waits for the socket, gate for a slot",
     "engine_api.decode_payload": "JSON -> ExecutionPayload decode phase",
     "engine_api.new_payload": "engine_newPayloadV2/V3/V4 handler phase",
     "engine_api.execute_stateless": "engine_executeStatelessPayloadV1 handler phase",
@@ -201,7 +204,6 @@ METRIC_HELP: Dict[str, str] = {
     "stateless.post_root_plan": "Fused account+storage hash-plan build on the request thread (WitnessStateDB.post_root_plan) before root-lane submission",
     "stateless.sig_rows": "Signature-row build on the request thread (TxSigner.signature_rows — host keccak over RLP) before sig-lane submission",
     # memoized witness engine
-    "witness_engine.interned_nodes": "Unique trie nodes currently interned in the witness engine",
     "witness_engine.interned_digests": "Unique 32-byte digests currently interned (nodes + child refs)",
     "witness_engine.cache_hits": "Witness nodes served from the interning cache",
     "witness_engine.cache_misses": "Witness nodes that had to be hashed (novel nodes)",
@@ -228,8 +230,6 @@ METRIC_HELP: Dict[str, str] = {
     "witness_engine.root_dispatch": "Root-lane dispatch stage: merged-program device enqueue, no host sync",
     "witness_engine.root_resolve": "Root-lane resolve stage: out-row digest readback (device) or the per-plan host mirror",
     "witness_engine.root_batches": "Root batches executed, by backend (device = merged dispatch; host = the offload-gated host walk)",
-    "witness_engine.root_requests": "Requests whose post root was computed through the root engine",
-    "witness_engine.root_plan_hits": "Root prefetch merges consumed by begin_batch (identity-matched plans list)",
     "root.plan_shapes": "Distinct shapes of the served root program (ops/mpt_jax._hash_plan_outputs) this process has run: one a rung of mpt_jax.PLAN_LADDER and device, so it stops growing; exported from server start",
     "root.plan_rows": "Rows hashed by served root programs, by kind: real = trie nodes of the merged plans, pad = the empty rows of their strips (what the ladder's fixed strip costs in keccak work)",
     "root.plan_rung": "Root batches by the ladder rung their merged plan was laid out on (over = above the top rung: hashed on the host)",
@@ -237,17 +237,12 @@ METRIC_HELP: Dict[str, str] = {
     "evm.native_frames": "Frames of bytecode the native VM (native/evm.cc) ran, nested ones too, by the host binding they went through: ext = the extension's EvmHost (native/pyext.cc), the only one there is; counted when a block's binding is closed (evm/native_vm.BlockHost.close). A plain transfer runs no frame; under the Python interpreter (no toolchain, --evm_backend=python) the family stands still",
     "evm.host_bindings": "Host bindings of the native VM built: one a block whose transactions reach code (Blockchain.run_block), one a message for an Evm outside a block",
     "root.prewarm_seconds": "Seconds the server took at start to build the root program on every rung of the ladder (only with the device root lane on and an accelerator under it)",
-    "witness_engine.root_plan_stale": "Root prefetch merges dropped stale at begin time (shed changed the batch) — a perf miss, never a correctness event",
     # coalesced sender recovery (ops/sig_engine.py)
     "witness_engine.sig_prefetch": "Sig-lane prefetch stage: merging a batch's signature rows + the u256 -> limb encode OFF the serving critical path (SigEngine.prefetch_batch)",
     "witness_engine.sig_pack": "Sig-lane pack stage: offload-gate routing + row merge (or prefetch-merge consumption) (SigEngine.begin_batch)",
     "witness_engine.sig_dispatch": "Sig-lane dispatch stage: merged ecrecover kernel enqueue, no host sync",
     "witness_engine.sig_resolve": "Sig-lane resolve stage: sender-address readback (device) or the fused native batch / scalar fallback over the same merged rows",
     "witness_engine.sig_batches": "Sig batches executed, by backend (device = merged ecrecover dispatch; native/scalar = the offload-gated host routes)",
-    "witness_engine.sig_requests": "Requests whose senders were recovered through the sig engine",
-    "witness_engine.sig_rows": "Signature rows recovered through the sig engine (the merged-dispatch row counter: rows per batch >> rows per request under coalescing)",
-    "witness_engine.sig_plan_hits": "Sig prefetch merges consumed by begin_batch (identity-matched rows list)",
-    "witness_engine.sig_plan_stale": "Sig prefetch merges dropped stale at begin time (shed changed the batch) — a perf miss, never a correctness event",
     # device-resident intern table (ops/witness_resident.py)
     "witness_resident.rows": "Rows resident on device (digest + child-ref rows, persistent across batches)",
     "witness_resident.uploaded_nodes": "Truly-novel nodes uploaded to the resident table (after the host prune)",
@@ -304,6 +299,13 @@ METRIC_HELP: Dict[str, str] = {
     # measured host time at the device, the collector, compiles (utils/trace.py,
     # serving/deadline.py)
     "device.host_seconds": "Seconds a host thread spent at the device, by lane (witness/sig/root) and op: enqueue = upload + program launch with no wait (begin); sync = the readback, i.e. the thread stood BLOCKED on the chip (resolve). The measurement critpath's `dispatch` remainder is not",
+    "device.host_cpu_seconds": "CPU seconds the host thread ran inside each visit to the device that device.host_seconds times, by lane and op: a sync that burns CPU is a spin, an enqueue of far more wall than CPU is a launch that stood behind the interpreter lock",
+    "lanes.stage_seconds": "Wall seconds of each lane stage (the stage timers witness_engine.<lane_>prefetch|pack|dispatch|resolve), by lane (witness/sig/root) and stage: one observation a batch, on the scheduler or mesh-pool thread that ran the stage (utils/trace.Metrics.phase)",
+    "lanes.stage_cpu_seconds": "CPU seconds the lane's thread ran inside each stage of lanes.stage_seconds, booked as engine_api.phase_cpu_seconds is (native code run with the interpreter lock released counts: native.unlocked_seconds says how much of it the extension saw)",
+    "lanes.stage_offcpu_seconds": "lanes.stage_seconds less lanes.stage_cpu_seconds, observation by observation: what the lane's thread waited inside the stage. prefetch, pack and dispatch wait for nothing but a turn at the interpreter lock (and the engine's own lock); resolve waits for the device's readback too",
+    "runtime.process_cpu_seconds": "CPU seconds of the whole process by mode (os.times: user, and system = the kernel on the process's behalf, e.g. handing the interpreter lock from thread to thread; the two sum to time.process_time), every thread: XLA's pool, the collector, whatever shares the process; set at every exposition (/metrics)",
+    "native.unlocked_seconds": "Seconds the extension (native/pyext.cc) ran native work with the interpreter lock released, by site (scan, verdict, commit, commit_hash, hash, finish_commit: the witness engine's scan, hash and commit), from the lock's release to the work's end; read from the extension's own clocks at every exposition (/metrics), 0 in a process without it",
+    "native.lock_retake_seconds": "Seconds the extension waited to take the interpreter lock back after native work it ran unlocked, by site: the one place the program MEASURES a wait for the lock (everywhere else it is wall less CPU)",
     "jit.compiles": "Programs jax first built in this process, by thread (serving = a scheduler thread whose compile holds a job queue; other): one per backend compile and one per load from the persistent cache",
     "jit.serving_compile_seconds": "Wall-clock seconds the serving threads have spent compiling (union of jax's trace/lower/compile intervals): the credit the request deadline clock runs on (serving/deadline.py)",
     "runtime.gc_pause_seconds": "Pauses of CPython's collector in this process, by generation, from the one gc.callbacks entry the server installs (every collection; a full one, generation 2, is also a `gc` interval of every request span open then; generation=deep is the tenure policy's own full collection of everything, run while no request is in flight)",
@@ -315,8 +317,9 @@ METRIC_HELP: Dict[str, str] = {
     "flight.dumps": "Flight-recorder postmortem dumps written, by trigger reason",
     # per-request critical-path attribution (phant_tpu/obs/critpath.py)
     "critpath.phase_seconds": "Per-request critical-path phase time at verify_block span close, by phase (sig_rows/queue_wait/prefetch/pack/dispatch/resolve/witness_decode/sig_wait/evm/root_plan/root_wait/post_root) — phases tile the request's wall clock; derived from the span's own phase timers plus the batch records the serving lanes attach",
+    "critpath.phase_cpu_seconds": "CPU seconds the handler thread ran inside each critical-path phase of critpath.phase_seconds (the thread's CPU clock read beside the span clock at each phase's ends; a parent's CPU goes where its wall remainder goes: dispatch, evm, post_root), one observation for each of that family's, booked as engine_api.phase_cpu_seconds is",
+    "critpath.phase_offcpu_seconds": "critpath.phase_seconds less critpath.phase_cpu_seconds: what the handler thread waited inside the phase. In sig_rows, witness_decode, evm and root_plan it waits for nothing by design, so that is its wait for a turn at the interpreter lock; the other phases are waits for a lane by definition",
     "critpath.wall_seconds": "verify_block request wall clock as seen by the critical-path rollup (the denominator of the coverage gauges)",
-    "critpath.unattributed_seconds": "Per-request residual the phase tiling could NOT attribute (span overhead, gaps between phases) — the honesty check's raw series",
     "critpath.coverage_pct": "Cumulative attributed share of verify_block wall clock (the >=95% acceptance surface: anything lower means the phase tiling is missing a real cost)",
     "critpath.unattributed_pct": "Cumulative UNattributed share of verify_block wall clock (100 - coverage) — the honesty-check residual gauge",
     "critpath.requests": "verify_block spans rolled up by the critical-path attribution sink",
@@ -396,6 +399,7 @@ class Metrics:
         self._timers: Dict[str, TimerStat] = {}
         self._gauges: Dict[str, float] = {}
         self._hists: Dict[str, Histogram] = {}
+        self._cpu_carry: Dict[str, float] = {}  # observe_split: CPU read, not yet booked
 
     def count(self, name: str, delta: int = 1, **labels) -> None:
         key = _labels_key(name, labels)
@@ -413,18 +417,23 @@ class Metrics:
             self._gauges[key] = self._gauges.get(key, 0) + delta
 
     def observe(
-        self, name: str, seconds: float, end_ns: Optional[int] = None
+        self,
+        name: str,
+        seconds: float,
+        end_ns: Optional[int] = None,
+        cpu_ns: Optional[int] = None,
     ) -> None:
         """Add `seconds` to the phase timer `name` and, inside an open
         span, record the child interval that ends at `end_ns` (default:
-        now) on the span clock."""
+        now) on the span clock, with the `cpu_ns` this thread ran in it
+        where the caller read the thread's CPU clock at its two ends."""
         with self._lock:
             self._timers.setdefault(name, TimerStat()).add(seconds)
         sp = current_span()
         if sp is not None:
             if end_ns is None:
                 end_ns = clock_ns()
-            sp.add_interval(name, end_ns - int(seconds * 1e9), end_ns)
+            sp.add_interval(name, end_ns - int(seconds * 1e9), end_ns, cpu_ns)
 
     def observe_hist(
         self,
@@ -433,27 +442,77 @@ class Metrics:
         buckets: Optional[Tuple[float, ...]] = None,
         **labels,
     ) -> None:
-        key = _labels_key(name, labels)
         with self._lock:
-            h = self._hists.get(key)
-            if h is None:
-                h = self._hists[key] = Histogram(buckets or DEFAULT_BUCKETS)
-            h.add(value)
+            self._hist_add(_labels_key(name, labels), value, buckets)
+
+    def _hist_add(self, key: str, value: float, buckets) -> None:
+        """Under `self._lock`."""
+        h = self._hists.get(key)
+        if h is None:
+            h = self._hists[key] = Histogram(buckets or DEFAULT_BUCKETS)
+        h.add(value)
+
+    def observe_split(
+        self,
+        cpu_name: str,
+        offcpu_name: str,
+        wall_s: float,
+        cpu_s: float,
+        buckets: Optional[Tuple[float, ...]] = None,
+        **labels,
+    ) -> None:
+        """Book `cpu_s` of thread CPU against a phase of `wall_s`: one
+        observation of `cpu_name`, never above the wall, and one of
+        `offcpu_name`, the rest of the wall, so the two sum to the phase's
+        wall observation by observation. Where the thread's CPU clock
+        steps by a scheduler tick (10 ms on the chip's host, PERF.md
+        section 6, PR 38: a reading is then 0 or a whole tick, whatever
+        the phase's width), what a reading holds beyond its phase is
+        carried to the next observations of the same series: dropping it
+        would book too little CPU and too much waiting, phase after
+        phase, and the sums stay true to the clock this way."""
+        key, wall_s = _labels_key(cpu_name, labels), max(wall_s, 0.0)
+        with self._lock:
+            have = self._cpu_carry.get(key, 0.0) + max(cpu_s, 0.0)
+            booked = min(have, wall_s)
+            self._cpu_carry[key] = have - booked
+            self._hist_add(key, booked, buckets)
+            self._hist_add(_labels_key(offcpu_name, labels), wall_s - booked, buckets)
 
     @contextlib.contextmanager
     def phase(self, name: str, **attrs) -> Iterator[None]:
         """Time a phase: `with metrics.phase("engine_api.new_payload"): ...`.
         Inside an open span the phase is a measured child interval of it,
         and where ANNOTATIONS names it, an event of the profiler's trace,
-        which carries `attrs` (a lane's dispatch says its `rung`)."""
+        which carries `attrs` (a lane's dispatch says its `rung`). Both
+        clocks are read at both ends (`cpu_clock_ns` inside `clock_ns`):
+        wall less cpu is what the thread waited, for a device, a queue or
+        its turn at the interpreter lock. A lane's stage timer
+        (LANE_STAGES) is also one observation of the `lanes.stage_*`
+        families, on the thread that ran the stage."""
         ann = annotate(ANNOTATIONS.get(name), **attrs)
         t0 = clock_ns()
+        c0 = cpu_clock_ns()
         try:
             yield
         finally:
+            cpu = cpu_clock_ns() - c0
             t1 = clock_ns()
             end_annotation(ann)
-            self.observe(name, (t1 - t0) / 1e9, end_ns=t1)
+            self.observe(name, (t1 - t0) / 1e9, end_ns=t1, cpu_ns=cpu)
+            at = LANE_STAGES.get(name)
+            if at is not None:
+                lane, stage = at
+                wall = (t1 - t0) / 1e9
+                self.observe_hist("lanes.stage_seconds", wall, lane=lane, stage=stage)
+                self.observe_split(
+                    "lanes.stage_cpu_seconds",
+                    "lanes.stage_offcpu_seconds",
+                    wall,
+                    cpu / 1e9,
+                    lane=lane,
+                    stage=stage,
+                )
 
     def snapshot(self) -> dict:
         """Deep copy of every table under the lock: TimerStat/Histogram
@@ -491,6 +550,7 @@ class Metrics:
             self._timers.clear()
             self._gauges.clear()
             self._hists.clear()
+            self._cpu_carry.clear()
 
     def report(self) -> str:
         """Box table of every phase/counter (same presentation family as the
@@ -531,7 +591,19 @@ class Metrics:
         """Standard Prometheus text exposition (version 0.0.4) of every
         table. Counters export as `<family>_total`, phase timers as
         `<family>_seconds` summaries (count/sum), histograms with
-        cumulative `_bucket{le=...}` series."""
+        cumulative `_bucket{le=...}` series. What is read rather than
+        counted is read here, once an exposition: the process's CPU seconds,
+        all threads (XLA's pool, the collector and whatever shares the
+        process with the server, so that what no span covers has a size),
+        and the extension's lock clocks (zeros where it is not loaded)."""
+        from phant_tpu.utils import native  # here: it loads nothing until asked
+
+        cpu = os.times()  # what time.process_time() sums, apart
+        self.gauge_set("runtime.process_cpu_seconds", cpu.user, mode="user")
+        self.gauge_set("runtime.process_cpu_seconds", cpu.system, mode="system")
+        for site, (unlocked, retake) in native.lock_clocks().items():
+            self.gauge_set("native.unlocked_seconds", unlocked, site=site)
+            self.gauge_set("native.lock_retake_seconds", retake, site=site)
         snap = self.snapshot()
         out: List[str] = []
         emitted_help: set = set()
@@ -636,6 +708,15 @@ _span_tls = threading.local()
 #: span's `start_ns`/`end_ns` compare with both without conversion
 clock_ns = time.monotonic_ns
 
+#: the CPU clock of the calling thread (CLOCK_THREAD_CPUTIME_ID), read at the
+#: same two instants as `clock_ns` wherever a phase, a mark, a lane stage or
+#: a visit to the device is timed, on the thread that runs it: wall less cpu
+#: is what the thread WAITED (for the device, a queue, or its turn at the
+#: interpreter lock; in a phase that waits for nothing by design, the lock).
+#: It counts native code run with the lock released too (`ctypes`, XLA), so
+#: it bounds what a thread asked of the lock from above
+cpu_clock_ns = time.thread_time_ns
+
 #: span ids: process-unique, ascending (`next()` on a count is atomic)
 _span_ids = itertools.count(1)
 
@@ -710,20 +791,24 @@ def lane_stage(
     inside carry the batch's `trace_id`s (joined by "|": the profiler's
     event names keep their attributes comma-separated) and `batch_id`. The
     stage's measured `[start_ns, end_ns]` is written into `stages` (the
-    dict the batch record carries beside its `*_ms`), with `compile_ns`
-    where jax reported a compile on this thread meanwhile: the batch
-    stood behind it."""
+    dict the batch record carries beside its `*_ms`), the CPU nanoseconds
+    this thread ran between them under `cpu_ns`, and `compile_ns` where
+    jax reported a compile on this thread meanwhile: the batch stood
+    behind it."""
     ctx = {"batch_id": batch_id, "compile_ns": 0}
     prev = getattr(_span_tls, "lane", None)
     _span_tls.lane = ctx
     ids = "|".join(t for t in trace_ids if t)
     bound = trace_context(ids) if ids else contextlib.nullcontext()
     t0 = clock_ns()
+    c0 = cpu_clock_ns()
     try:
         with bound:
             yield
     finally:
+        cpu = cpu_clock_ns() - c0
         stages[stage] = [t0, clock_ns()]
+        stages.setdefault("cpu_ns", {})[stage] = cpu
         if ctx["compile_ns"]:
             stages["compile_ns"] = stages.get("compile_ns", 0) + ctx["compile_ns"]
         _span_tls.lane = prev
@@ -731,12 +816,16 @@ def lane_stage(
 
 def fold_stages(record: dict, stages: dict) -> None:
     """`lane_stage`'s measurements into the batch record: `stages`, each
-    stage's [start_ns, end_ns] beside the `*_ms` it is the ends of, and
+    stage's [start_ns, end_ns] beside the `*_ms` it is the ends of,
+    `stage_cpu_ms`, the CPU each stage's thread ran inside them, and
     `compile_ms` where the batch stood behind a compile."""
     stages = dict(stages)
     compile_ns = stages.pop("compile_ns", 0)
     if compile_ns:
         record["compile_ms"] = round(compile_ns / 1e6, 3)
+    cpu_ns = stages.pop("cpu_ns", None)
+    if cpu_ns:
+        record["stage_cpu_ms"] = {k: round(v / 1e6, 3) for k, v in cpu_ns.items()}
     if stages:
         record["stages"] = stages
 
@@ -771,6 +860,15 @@ ANNOTATIONS: Dict[str, str] = {
     "witness_engine.root_pack": "phant/root.pack",
     "witness_engine.root_dispatch": "phant/root.dispatch",
     "witness_engine.root_resolve": "phant/root.resolve",
+}
+
+#: the lanes' stage timers -> (lane, stage): `Metrics.phase` observes each
+#: into `lanes.stage_seconds{lane=,stage=}` and its cpu/offcpu twins, once a
+#: batch, on the scheduler (or mesh pool) thread that ran the stage
+LANE_STAGES: Dict[str, Tuple[str, str]] = {
+    name: tuple(ann[len("phant/"):].split("."))
+    for name, ann in ANNOTATIONS.items()
+    if name.startswith("witness_engine.")
 }
 
 _trace_me = None  # jax's TraceMe class, once jax is in the process
@@ -818,16 +916,22 @@ def device_host(lane: str, op: str) -> Iterator[None]:
     """Time a host thread at the device: `op` "enqueue" is an upload and a
     program launch that does not wait, "sync" is a readback, i.e. the
     seconds the thread stood blocked on the chip. Observed into
-    `device.host_seconds{lane=,op=}` and shown in the profiler's trace as
+    `device.host_seconds{lane=,op=}`, with the CPU the thread ran
+    meanwhile in `device.host_cpu_seconds` (a `sync` that burns CPU is a
+    spin; an `enqueue` of far more wall than CPU stood behind the
+    interpreter lock), and shown in the profiler's trace as
     `phant/device_enqueue` / `phant/device_sync`."""
     ann = annotate("phant/device_" + op, lane=lane)
     t0 = clock_ns()
+    c0 = cpu_clock_ns()
     try:
         yield
     finally:
+        cpu = (cpu_clock_ns() - c0) / 1e9
         dt = (clock_ns() - t0) / 1e9
         end_annotation(ann)
         metrics.observe_hist("device.host_seconds", dt, lane=lane, op=op)
+        metrics.observe_hist("device.host_cpu_seconds", cpu, lane=lane, op=op)
 
 
 def note_compile(seconds: float) -> None:
@@ -853,7 +957,11 @@ class Span:
     child intervals `(name, start_ns, end_ns)` of the phases that ran
     inside it (fed by Metrics.observe / Metrics.phase), and any child
     spans. `phases`, the per-name count and total the sinks read, is
-    derived from the intervals, so the two cannot disagree. Spans stack
+    derived from the intervals, so the two cannot disagree; `cpu_ns` is
+    the CPU nanoseconds this thread ran inside each name's intervals,
+    kept by name beside them (the intervals stay triples for their
+    readers, and the collector's `gc` entries have no CPU of the
+    request's). Spans stack
     per-thread (thread-local), which is the thread-safety mechanism:
     concurrent request threads each trace their own block without
     locking; the one writer from outside, the collector's callback, only
@@ -870,6 +978,7 @@ class Span:
         "attrs",
         "duration_s",
         "intervals",
+        "cpu_ns",
         "children",
         "span_id",
         "parent_id",
@@ -886,6 +995,7 @@ class Span:
         self.attrs = attrs
         self.duration_s = 0.0
         self.intervals: List[Tuple[str, int, int]] = []
+        self.cpu_ns: Dict[str, int] = {}
         self.children: List[dict] = []
         self.span_id = next(_span_ids)
         self.parent_id: Optional[int] = None
@@ -894,10 +1004,14 @@ class Span:
         self.frame = frame
         self.resume: Optional[str] = None  # the mark a closing child leaves
         self.reported = 0  # spans reported to the sinks from under a frame
-        self._mark = None  # (name, start_ns, annotation) of the open mark
+        self._mark = None  # (name, start_ns, annotation, cpu at start) of the open mark
 
-    def add_interval(self, name: str, start_ns: int, end_ns: int) -> None:
+    def add_interval(
+        self, name: str, start_ns: int, end_ns: int, cpu_ns: Optional[int] = None
+    ) -> None:
         self.intervals.append((name, start_ns, end_ns))
+        if cpu_ns is not None:
+            self.cpu_ns[name] = self.cpu_ns.get(name, 0) + cpu_ns
 
     def mark(self, name: Optional[str], at: Optional[int] = None) -> None:
         """End the interval the last `mark` began and begin `name` (None:
@@ -905,16 +1019,21 @@ class Span:
         clock reading `at` (default: now). Marks are contiguous, and
         `span` hands a frame the child's own start and end readings, so
         marks and children tile the frame exactly, less the stretches
-        marked None."""
-        if self._mark is not None and self._mark[0] == name:
-            return
+        marked None. The thread's CPU clock is read here, on the thread
+        the span stacks on, whatever `at` says: `span` calls within
+        microseconds of the reading it hands over. The reading is kept as
+        it is (where the clock steps by ticks it can pass the mark's
+        width): whoever observes it books it (`Metrics.observe_split`)."""
+        if (None if self._mark is None else self._mark[0]) == name:
+            return  # the open mark already, or nothing open and nothing to begin
         now = clock_ns() if at is None else at
+        cpu = cpu_clock_ns()
         if self._mark is not None:
-            prev, t0, ann = self._mark
+            prev, t0, ann, c0 = self._mark
             end_annotation(ann)
-            self.intervals.append((prev, t0, now))
+            self.add_interval(prev, t0, now, cpu - c0)
         self._mark = (
-            None if name is None else (name, now, annotate("phant/" + name))
+            None if name is None else (name, now, annotate("phant/" + name), cpu)
         )
 
     @property
@@ -936,6 +1055,10 @@ class Span:
                 k: {"count": c, "total_ms": round(t * 1e3, 3)}
                 for k, (c, t) in _phases_of(intervals).items()
             }
+            # the CPU this thread ran inside a name's intervals: total_ms
+            # less cpu_ms is what it waited there
+            for k, ns in tuple(self.cpu_ns.items()):
+                d["phases"][k]["cpu_ms"] = round(ns / 1e6, 3)
             # [name, start_ns, end_ns]: of this span, so of its trace_id
             d["intervals"] = [list(iv) for iv in intervals]
         if self.children:

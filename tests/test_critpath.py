@@ -300,6 +300,195 @@ def test_attribute_without_lane_stages_never_claims_more_than_the_wait(stages):
 
 
 # ---------------------------------------------------------------------------
+# the second clock (PR 38): the handler's CPU inside each phase
+# ---------------------------------------------------------------------------
+
+#: the span's phases with the labels `attribute` tiles each into
+_SUB_TILINGS = {
+    "stateless.sig_rows": ("sig_rows",),
+    "stateless.witness_decode": ("witness_decode",),
+    "stateless.witness_verify": ("queue_wait", "prefetch", "pack", "dispatch", "resolve"),
+    "stateless.execute": ("sig_wait", "evm"),
+    "stateless.post_root": ("root_plan", "root_wait", "post_root"),
+}
+
+
+def _flat_record(cpu: dict) -> dict:
+    """`test_attribute_tiles_wall_clock_exactly`'s record with `cpu_ms`
+    beside each `total_ms`, as `Span.to_dict` reports them since PR 38."""
+    walls = {
+        "stateless.sig_rows": 1.0,
+        "stateless.witness_verify": 40.0,
+        "stateless.witness_decode": 8.0,
+        "stateless.execute": 30.0,
+        "sched.sig_wait": 6.0,
+        "stateless.post_root": 20.0,
+        "stateless.post_root_plan": 3.0,
+    }
+    return {
+        "span": "verify_block",
+        "duration_ms": 100.0,
+        "queue_wait_ms": 5.0, "prefetch_ms": 2.0, "pack_ms": 3.0, "resolve_ms": 10.0,
+        "root_queue_wait_ms": 4.0,
+        "phases": {
+            k: {"count": 1, "total_ms": w, **({"cpu_ms": cpu[k]} if k in cpu else {})}
+            for k, w in walls.items()
+        },
+    }  # fmt: skip
+
+
+def _staged_record(cpu: dict) -> dict:
+    record = _waited_record(
+        {"prefetch": [5 * MS, 14 * MS], "pack": [20 * MS, 30 * MS], "resolve": [35 * MS, 90 * MS]},
+        waits=[(0, 30 * MS), (58 * MS, 92 * MS)],
+        decode=(30 * MS, 58 * MS),
+    )
+    for k, v in cpu.items():
+        record["phases"][k]["cpu_ms"] = v
+    return record
+
+
+_ALONE = {  # a lone request: every phase computes, the waits sleep
+    "stateless.sig_rows": 0.9, "stateless.witness_verify": 0.4, "stateless.witness_decode": 7.5,
+    "stateless.execute": 23.0, "sched.sig_wait": 0.1, "stateless.post_root": 15.0,
+    "stateless.post_root_plan": 2.9,
+}  # fmt: skip
+_IN_A_CROWD = {  # sixteen handlers on one lock: a tenth of each phase is work
+    "stateless.sig_rows": 0.1, "stateless.witness_verify": 0.2, "stateless.witness_decode": 0.8,
+    "stateless.execute": 3.0, "sched.sig_wait": 0.0, "stateless.post_root": 2.0,
+    "stateless.post_root_plan": 0.3,
+}  # fmt: skip
+_OVERSTATED = {  # more CPU than wall (a nested phase's rounding, a clock's step)
+    "stateless.sig_rows": 1.5, "stateless.witness_verify": 45.0, "stateless.witness_decode": 8.0,
+    "stateless.execute": 31.0, "sched.sig_wait": 7.0, "stateless.post_root": 25.0,
+    "stateless.post_root_plan": 3.5,
+}  # fmt: skip
+
+
+@pytest.mark.parametrize(
+    "record",
+    [
+        _flat_record(_ALONE),
+        _flat_record(_IN_A_CROWD),
+        _flat_record(_OVERSTATED),
+        _flat_record({"stateless.execute": 12.0}),  # one phase alone carries a reading
+        _staged_record({"stateless.witness_verify": 1.5, "stateless.witness_decode": 27.0}),
+        _staged_record({"stateless.witness_verify": 70.0, "stateless.witness_decode": 30.0}),
+    ],
+    ids=["alone", "crowd", "overstated", "one-phase", "staged", "staged-overstated"],
+)
+def test_attribute_cpu_never_passes_the_wall_and_tiles_its_parents(record):
+    """cpu <= wall for every phase, and each parent's CPU is the sum of
+    what its sub-tiling was given plus what the wall could not hold (a
+    record that overstates it: a CPU clock that steps by ticks), which
+    comes back apart, under the parent's catch-all: nothing counted
+    twice, nothing invented, nothing dropped."""
+    breakdown, _un, _wall = critpath.attribute(record)
+    cpu, over = critpath.attribute_cpu(record, breakdown)
+    assert set(cpu) <= set(breakdown)
+    assert set(over) <= {labels[0] for _p, _n, labels in critpath._CPU_TILING}  # the catch-alls
+    for label, c in cpu.items():
+        assert 0.0 < c <= breakdown[label] + 1e-9, label
+    phases = record["phases"]
+    for parent, labels in _SUB_TILINGS.items():
+        if parent not in phases:
+            continue
+        read = phases[parent].get("cpu_ms", 0.0)
+        if parent == "stateless.execute":  # sig_wait's CPU is its own reading's
+            own = min(phases["sched.sig_wait"].get("cpu_ms", 0.0), breakdown["sig_wait"])
+            read = max(read - phases["sched.sig_wait"].get("cpu_ms", 0.0), 0.0) + own
+        wall = sum(breakdown.get(l, 0.0) for l in labels)
+        given = sum(cpu.get(l, 0.0) for l in labels)
+        assert given == pytest.approx(min(read, wall)), parent
+    # nothing dropped: what the parents read is given to a phase or kept apart
+    read_of = lambda name: phases.get(name, {}).get("cpu_ms", 0.0)  # noqa: E731
+    total = sum(
+        max(read_of(parent) - sum(read_of(n) for n in nested), 0.0)
+        for parent, nested, _labels in critpath._CPU_TILING
+    )
+    assert sum(cpu.values()) + sum(over.values()) == pytest.approx(total)
+
+
+def test_attribute_cpu_goes_where_the_walls_remainder_goes():
+    """A parent's CPU lands in its catch-all first (`dispatch`, `evm`,
+    `post_root`): the cuts a lane's stage claimed get only what the
+    catch-all's wall cannot hold, the handler slept there."""
+    record = _flat_record(_ALONE)
+    breakdown, _un, _wall = critpath.attribute(record)
+    cpu, over = critpath.attribute_cpu(record, breakdown)
+    assert over == {}
+    assert cpu == pytest.approx(
+        {
+            "sig_rows": 0.9,
+            "witness_decode": 7.5,
+            "dispatch": 0.4,  # of its 20 ms of wall; queue_wait .. resolve: none
+            "evm": 22.9,  # execute's 23.0 less sig_wait's own 0.1
+            "sig_wait": 0.1,
+            "post_root": 12.1,  # post_root's 15.0 less the plan's 2.9
+            "root_plan": 2.9,
+        }
+    )
+    spill = _flat_record({"stateless.witness_verify": 26.0})
+    breakdown, _un, _wall = critpath.attribute(spill)
+    cpu, over = critpath.attribute_cpu(spill, breakdown)
+    assert cpu == pytest.approx({"dispatch": 20.0, "queue_wait": 5.0, "resolve": 1.0}) and over == {}
+    # a tick of 10 ms charged to a phase of 1 ms: the phase's wall, and the rest apart
+    tick = _flat_record({"stateless.sig_rows": 10.0})
+    cpu, over = critpath.attribute_cpu(tick, critpath.attribute(tick)[0])
+    assert cpu == pytest.approx({"sig_rows": 1.0}) and over == pytest.approx({"sig_rows": 9.0})
+
+
+def _hist(name: str, phase: str) -> tuple:
+    h = metrics.snapshot()["histograms"].get(f'{name}{{phase="{phase}"}}')
+    return (0, 0.0) if h is None else (h["count"], h["sum"])
+
+
+@pytest.mark.parametrize("with_cpu", [False, True], ids=["before-pr38", "since-pr38"])
+def test_rollup_observes_the_cpu_families_only_where_the_span_read_the_clock(with_cpu):
+    """A record from before the second clock (no `cpu_ms`: an old flight
+    dump replayed, a hand-made one) rolls up as it did and observes
+    neither new family; one with it observes both for every phase of
+    `critpath.phase_seconds`, and cpu + off-CPU is the wall."""
+    record = _flat_record(_IN_A_CROWD if with_cpu else {})
+    assert (critpath.attribute_cpu(record, critpath.attribute(record)[0]) is None) is not with_cpu
+    families = ("critpath.phase_seconds", "critpath.phase_cpu_seconds", "critpath.phase_offcpu_seconds")
+    before = {(f, p): _hist(f, p) for f in families for p in critpath.PHASES}
+    n0 = metrics.snapshot()["counters"].get("critpath.requests", 0)
+    critpath.rollup(record)
+    assert metrics.snapshot()["counters"]["critpath.requests"] == n0 + 1
+    for phase in critpath.PHASES:
+        wall, cpu, off = (
+            tuple(a - b for a, b in zip(_hist(f, phase), before[f, phase])) for f in families
+        )
+        assert wall[0] == 1 and wall[1] > 0.0, phase  # all twelve labels in this record
+        if not with_cpu:
+            assert cpu == off == (0, 0.0), phase
+            continue
+        assert cpu[0] == off[0] == 1, phase
+        assert 0.0 <= cpu[1] <= wall[1] and cpu[1] + off[1] == pytest.approx(wall[1]), phase
+
+
+def test_rollup_books_a_ticks_excess_against_the_labels_next_requests():
+    """The chip's host steps the thread's CPU clock by 10 ms: `sig_rows`
+    (1 ms) reads a whole tick in one request of ten and 0 in the rest. The
+    family never says more CPU than wall, and over the ten requests it
+    says all ten milliseconds: none dropped, none waited."""
+    metrics._cpu_carry.pop('critpath.phase_cpu_seconds{phase="sig_rows"}', None)
+    families = ("critpath.phase_seconds", "critpath.phase_cpu_seconds", "critpath.phase_offcpu_seconds")
+    before = [_hist(f, "sig_rows") for f in families]
+    critpath.rollup(_flat_record({"stateless.sig_rows": 10.0}))
+    mid = _hist("critpath.phase_cpu_seconds", "sig_rows")
+    assert mid[1] - before[1][1] == pytest.approx(0.001)  # this request's own wall, no more
+    for _ in range(10):
+        critpath.rollup(_flat_record({"stateless.sig_rows": 0.0}))
+    wall, cpu, off = (
+        tuple(a - b for a, b in zip(_hist(f, "sig_rows"), b0)) for f, b0 in zip(families, before)
+    )
+    assert wall == pytest.approx((11, 0.011)) and cpu == pytest.approx((11, 0.010))
+    assert off == pytest.approx((11, 0.001))  # the eleventh request's: the tick was spent
+
+
+# ---------------------------------------------------------------------------
 # coverage >= 95% on the REAL serving path: depths 1 and 2, three lanes
 # ---------------------------------------------------------------------------
 

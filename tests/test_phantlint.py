@@ -467,6 +467,28 @@ def test_metricname_rule(tmp_path, monkeypatch):
     assert not any("'good.metric'" in m for m in msgs), msgs
 
 
+def test_metricname_rule_holds_both_names_of_observe_split(tmp_path, monkeypatch):
+    """`observe_split` names two families (a phase's CPU and the rest of
+    its wall): each is held to the catalog, and a dynamic one is M1."""
+    app = '''
+from pkg.tracey import metrics
+
+def go(n):
+    metrics.observe_split("good.metric", "good.metric", 1.0, 0.5)
+    metrics.observe_split("good.metric", "missing.offcpu", 1.0, 0.5)
+    metrics.observe_split(n, "good.metric", 1.0, 0.5)
+    metrics.observe_split(*n)
+'''
+    tracey = TRACEY_SRC.replace('"dead.metric": "never emitted anywhere",', "")
+    res = run_fixture(
+        tmp_path, monkeypatch, {"tracey.py": tracey, "app.py": app}, [MetricNameRule()]
+    )
+    msgs = [f.message for f in res.new]
+    assert len(msgs) == 3, msgs
+    assert sum("'missing.offcpu' has no METRIC_HELP" in m for m in msgs) == 1, msgs
+    assert sum("non-literal metric name" in m for m in msgs) == 2, msgs
+
+
 # ---------------------------------------------------------------------------
 # SPANNAME
 # ---------------------------------------------------------------------------

@@ -2,8 +2,12 @@
 
 from __future__ import annotations
 
+import os
 import threading
 import time
+from pathlib import Path
+
+import pytest
 
 from phant_tpu.utils.trace import (
     Histogram,
@@ -277,3 +281,272 @@ def test_span_threads_do_not_interfere():
         t.join()
     assert "worker.phase" in got["phases"]
     assert "worker.phase" not in main_sp.phases
+
+
+# ---------------------------------------------------------------------------
+# the second clock (PR 38): thread-CPU seconds beside wall seconds
+# ---------------------------------------------------------------------------
+
+
+def _sleep(seconds: float) -> None:
+    time.sleep(seconds)
+
+
+def _spin(seconds: float) -> None:
+    end = time.monotonic() + seconds
+    while time.monotonic() < end:
+        pass
+
+
+def _split_of_a_phase(work) -> tuple:
+    with span("verify_block") as sp:
+        with metrics.phase("stateless.sig_rows"):
+            work(0.05)
+    st = sp.to_dict()["phases"]["stateless.sig_rows"]
+    return st["total_ms"], st["cpu_ms"]
+
+
+def _split_of_a_mark(work) -> tuple:
+    with span("request", frame=True) as sp:
+        sp.mark("json")
+        work(0.05)
+        sp.mark("reply")
+    st = sp.to_dict()["phases"]["json"]
+    assert [iv[0] for iv in sp.intervals] == ["json", "reply"]  # triples, as they were
+    return st["total_ms"], st["cpu_ms"]
+
+
+def _split_of_a_lane_stage(work) -> tuple:
+    from phant_tpu.utils.trace import fold_stages, lane_stage
+
+    stages: dict = {}
+    with lane_stage(stages, "pack", ["t1"], 7):
+        work(0.05)
+    record: dict = {}
+    fold_stages(record, stages)
+    t0, t1 = record["stages"]["pack"]
+    assert set(record["stages"]) == {"pack"}  # [start, end] pairs alone, as critpath reads them
+    return (t1 - t0) / 1e6, record["stage_cpu_ms"]["pack"]
+
+
+def _hist_sum(key: str) -> float:
+    return metrics.snapshot()["histograms"].get(key, {"sum": 0.0})["sum"]
+
+
+def _split_of_a_device_visit(work) -> tuple:
+    from phant_tpu.utils.trace import device_host
+
+    wall = 'device.host_seconds{lane="sig",op="sync"}'
+    cpu = 'device.host_cpu_seconds{lane="sig",op="sync"}'
+    w0, c0 = _hist_sum(wall), _hist_sum(cpu)
+    with device_host("sig", "sync"):
+        work(0.05)
+    return (_hist_sum(wall) - w0) * 1e3, (_hist_sum(cpu) - c0) * 1e3
+
+
+def _split_of_a_stage_timer(work) -> tuple:
+    label = '{lane="root",stage="pack"}'
+    names = ("lanes.stage_seconds", "lanes.stage_cpu_seconds", "lanes.stage_offcpu_seconds")
+    before = [_hist_sum(n + label) for n in names]
+    with metrics.phase("witness_engine.root_pack"):
+        work(0.05)
+    wall, cpu, off = (_hist_sum(n + label) - b for n, b in zip(names, before))
+    assert abs(cpu + off - wall) < 1e-9  # the wait is the wall less the CPU
+    return wall * 1e3, cpu * 1e3
+
+
+@pytest.mark.parametrize(
+    "split",
+    [_split_of_a_phase, _split_of_a_mark, _split_of_a_lane_stage, _split_of_a_device_visit, _split_of_a_stage_timer],
+)
+@pytest.mark.parametrize("work,low,high", [(_sleep, 0.0, 0.10), (_spin, 0.80, 1.001)])
+def test_every_timed_place_reads_the_threads_cpu_beside_the_wall(split, work, low, high):
+    """A phase that sleeps ran almost no CPU, one that spins ran little
+    else; and CPU is read inside the wall readings, so it does not pass the
+    wall (a mark's two clocks are read one after the other at each end: by
+    microseconds at most). Up to five goes: a spin can lose its core on a
+    busy machine."""
+    for _attempt in range(5):
+        wall_ms, cpu_ms = split(work)
+        assert 50.0 <= wall_ms < 5000.0 and 0.0 <= cpu_ms <= wall_ms + 0.05
+        if low <= cpu_ms / wall_ms <= high:
+            return
+    raise AssertionError(f"{split.__name__}/{work.__name__}: cpu {cpu_ms} of wall {wall_ms}")
+
+
+def test_two_threads_on_one_lock_wait_one_threads_wall_between_them():
+    """Two threads that spin in Python share the interpreter lock: each
+    runs about half the time, so their off-CPU seconds (wall less CPU) sum
+    to about one thread's wall. What `handler_lock_wait_ms` rests on."""
+    for _attempt in range(5):
+        out: list = []
+        gate = threading.Barrier(2)
+
+        def worker():
+            gate.wait()
+            out.append(_split_of_a_phase(lambda _s: _spin(0.3)))
+
+        ts = [threading.Thread(target=worker) for _ in range(2)]
+        for t in ts:
+            t.start()
+        for t in ts:
+            t.join()
+        wall = sum(w for w, _c in out) / 2
+        cpu = sum(c for _w, c in out)
+        off = sum(w - c for w, c in out)
+        assert all(0.0 <= c <= w for w, c in out)
+        if 0.7 <= cpu / wall <= 1.15 and 0.7 <= off / wall <= 1.3:
+            return
+    raise AssertionError(f"two spinning threads read {out}")
+
+
+def test_observe_split_books_a_ticks_excess_against_the_next_observations():
+    """On the chip's host the thread's CPU clock steps by 10 ms: a reading
+    is 0 or a whole tick whatever the phase's width. Booked observation by
+    observation the CPU never passes the wall and the two families sum to
+    it; what a tick holds beyond its phase is carried to the series' next
+    observations, so the sums stay true to the clock. A fine clock's
+    readings are booked as they are; series do not share a carry."""
+    m = Metrics()
+    names = ("lanes.stage_cpu_seconds", "lanes.stage_offcpu_seconds")
+    for cpu in (0.010, 0.0, 0.0, 0.0, 0.0, 0.0, 0.010):  # phases of 2 ms
+        m.observe_split(*names, 0.002, cpu, lane="sig", stage="pack")
+    for cpu in (0.0015, 0.0005):  # a fine clock, another series
+        m.observe_split(*names, 0.002, cpu, lane="root", stage="pack")
+    h = m.snapshot()["histograms"]
+    sums = lambda label: tuple(h[n + label][k] for n in names for k in ("count", "sum"))  # noqa: E731
+    # the first tick covers five phases, the sixth ran none, the second tick is still being booked
+    assert sums('{lane="sig",stage="pack"}') == pytest.approx((7, 0.012, 7, 0.002))
+    assert sums('{lane="root",stage="pack"}') == pytest.approx((2, 0.002, 2, 0.002))
+    m.reset()
+    m.observe_split(*names, 0.002, 0.0, lane="sig", stage="pack")
+    assert m.snapshot()["histograms"][names[0] + '{lane="sig",stage="pack"}']["sum"] == 0.0  # reset drops the carry
+
+
+def test_a_span_keeps_cpu_by_name_and_its_intervals_stay_triples():
+    """`phases[name]["cpu_ms"]` beside `total_ms`, summed over a name's
+    intervals; what is observed without a CPU reading (an `observe`, the
+    collector's `gc`) has none; nested spans carry theirs."""
+    with span("verify_block") as sp:
+        for _ in range(2):
+            with metrics.phase("stateless.witness_verify"):
+                _spin(0.01)
+        metrics.observe("sched.prefetch_wait", 0.001)
+        sp.intervals.append(("gc", sp.start_ns, sp.start_ns + 5))
+        with span("inner") as inner:
+            with metrics.phase("stateless.execute"):
+                _spin(0.01)
+    d = sp.to_dict()
+    assert all(len(iv) == 3 for iv in d["intervals"])
+    wv = d["phases"]["stateless.witness_verify"]
+    assert wv["count"] == 2 and 10.0 <= wv["cpu_ms"] <= wv["total_ms"]
+    assert "cpu_ms" not in d["phases"]["sched.prefetch_wait"] and "cpu_ms" not in d["phases"]["gc"]
+    assert d["children"][0]["phases"]["stateless.execute"]["cpu_ms"] > 5.0
+    assert inner.cpu_ns["stateless.execute"] <= 1e6 * d["children"][0]["phases"]["stateless.execute"]["total_ms"] + 1e3
+
+
+NEW_FAMILIES = {
+    "critpath.phase_cpu_seconds": "histogram",
+    "critpath.phase_offcpu_seconds": "histogram",
+    "engine_api.phase_cpu_seconds": "histogram",
+    "engine_api.phase_offcpu_seconds": "histogram",
+    "lanes.stage_seconds": "histogram",
+    "lanes.stage_cpu_seconds": "histogram",
+    "lanes.stage_offcpu_seconds": "histogram",
+    "device.host_cpu_seconds": "histogram",
+    "runtime.process_cpu_seconds": "gauge",
+    "native.unlocked_seconds": "gauge",
+    "native.lock_retake_seconds": "gauge",
+}
+
+
+def test_prometheus_text_carries_every_new_family_with_its_help():
+    """One served stateless request through the real server, then the
+    exposition: each family of the second clock is there under its help
+    line, and the catalog gate (phantlint METRICNAME) is green with them."""
+    from phant_tpu.analysis import Analyzer, default_rules
+    from phant_tpu.engine_api.server import EngineAPIServer
+    from phant_tpu.utils.trace import METRIC_HELP, device_host, prometheus_name
+
+    from test_serving import _post, _stateless_request
+
+    chain, rpc, _root = _stateless_request()
+    server = EngineAPIServer(chain, host="127.0.0.1", port=0)
+    server.serve_in_background()
+    try:
+        code, body = _post(f"http://127.0.0.1:{server.port}", rpc)
+        assert code == 200 and body["result"]["status"] == "VALID", body
+    finally:
+        server.shutdown()
+    with device_host("witness", "enqueue"):  # the cpu backend visits no device
+        pass
+    text = metrics.prometheus_text()
+    for name, kind in NEW_FAMILIES.items():
+        family = prometheus_name(name)
+        assert f"# HELP {family} {METRIC_HELP[name]}" in text, name
+        assert f"# TYPE {family} {kind}" in text, name
+    assert 'phant_critpath_phase_cpu_seconds_count{phase="evm"}' in text
+    assert 'phant_engine_api_phase_offcpu_seconds_count{phase="reply"}' in text
+    assert 'phant_lanes_stage_cpu_seconds_count{lane="witness",stage="pack"}' in text
+    assert 'phant_native_lock_retake_seconds{site="scan"}' in text
+    user, system = (
+        float(text.split('\nphant_runtime_process_cpu_seconds{mode="%s"} ' % mode)[1].split()[0])
+        for mode in ("user", "system")
+    )
+    assert 0.0 < user and 0.0 <= system and user + system <= time.process_time() + 0.011
+    repo = Path(__file__).resolve().parents[1]
+    result = Analyzer(
+        [repo / "phant_tpu"], default_rules(["METRICNAME"]), baseline=repo / "scripts/phantlint_baseline.json"
+    ).run()
+    assert not result.new, [f.render() for f in result.new]
+
+
+def test_the_extensions_lock_clocks_grow_across_a_begin_batch():
+    """The one place the program measures a wait for the lock: the
+    extension's scan gives the lock away, and both clocks of that site grow
+    across a `begin_batch` (the retake at least by a clock read)."""
+    from phant_tpu.ops.witness_engine import WitnessEngine
+    from phant_tpu.utils import native
+
+    from test_obs import _witness_set
+
+    if native.load_engine_ext() is None:
+        pytest.skip("no toolchain: the extension cannot be built here")
+    eng = WitnessEngine()
+    if eng._ext_core is None:
+        pytest.skip("the witness engine runs without the extension's driver here")
+    before = native.lock_clocks()
+    handle = eng.begin_batch(_witness_set(4))
+    assert eng.resolve_batch(handle).all()
+    after = native.lock_clocks()
+    assert set(after) == set(native.LOCK_SITES)
+    assert after["scan"][0] > before["scan"][0] and after["scan"][1] > before["scan"][1]
+    assert all(after[s][k] >= before[s][k] for s in native.LOCK_SITES for k in (0, 1))
+    grown = sum(after[s][0] - before[s][0] for s in native.LOCK_SITES)
+    assert 0.0 < grown < 5.0
+
+
+def test_without_the_extension_the_lock_clocks_read_zero():
+    """A process that never loaded the extension (no toolchain,
+    PHANT_NO_NATIVE) exports the two families at 0, site by site, and
+    builds nothing to do so."""
+    import subprocess
+    import sys
+
+    code = (
+        "from phant_tpu.utils import native\n"
+        "from phant_tpu.utils.trace import metrics\n"
+        "assert native.lock_clocks() == dict.fromkeys(native.LOCK_SITES, (0.0, 0.0))\n"
+        "text = metrics.prometheus_text()\n"
+        "assert native._ext_mod is None\n"
+        "for site in native.LOCK_SITES:\n"
+        "    assert 'phant_native_unlocked_seconds{site=\"%s\"} 0.0' % site in text\n"
+        "    assert 'phant_native_lock_retake_seconds{site=\"%s\"} 0.0' % site in text\n"
+        "print('zero')\n"
+    )
+    env = {**os.environ, "PHANT_NO_NATIVE": "1", "JAX_PLATFORMS": "cpu"}
+    proc = subprocess.run(
+        [sys.executable, "-c", code], capture_output=True, text=True, env=env,
+        cwd=str(Path(__file__).resolve().parents[1]), timeout=120,
+    )  # fmt: skip
+    assert proc.returncode == 0 and proc.stdout.strip() == "zero", proc.stderr
