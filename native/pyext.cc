@@ -44,6 +44,10 @@
 // under the hash-plan builder's templates (encode_node) and, being the
 // same bytes, under rlp.encode. It holds the GIL throughout: every step
 // reads Python objects.
+//
+// And the scalar hash, keccak256(data) -> bytes, under
+// phant_tpu.crypto.keccak.keccak256 (the end of this file): the GIL held
+// for a short input, released for a long one.
 
 #define PY_SSIZE_T_CLEAN
 #include <Python.h>
@@ -95,10 +99,12 @@ enum LockSite {
   kSiteCommitHash,
   kSiteHash,
   kSiteFinishCommit,
+  kSiteKeccak,
   kLockSites
 };
 const char* const kLockSiteNames[kLockSites] = {
-    "scan", "verdict", "commit", "commit_hash", "hash", "finish_commit"};
+    "scan",   "verdict", "commit", "commit_hash", "hash", "finish_commit",
+    "keccak"};
 std::atomic<uint64_t> g_unlocked_ns[kLockSites];
 std::atomic<uint64_t> g_retake_ns[kLockSites];
 
@@ -1818,10 +1824,60 @@ PyObject* ext_lock_clocks(PyObject*, PyObject*) {
   return out;
 }
 
+// The scalar hash. An input under this many bytes is hashed with the
+// interpreter lock HELD, a longer one with it released: the native hash
+// runs at about 300 MB/s (phant_tpu/backend.py NATIVE_HASH_BPS), so 4 KiB
+// are 14 us of it, and a hand-over of the lock that another thread waits
+// for costs the caller tens of microseconds to get it back (PERF.md
+// section 7 p): below the threshold a release costs more than it buys,
+// above it (contract code of 24 KB, a blob transaction's encoding) a
+// caller that held on would stall every other thread for the hash's length.
+constexpr Py_ssize_t kKeccakUnlockBytes = 4096;
+// calls by lock discipline, [0] held and [1] released; counted with the
+// lock held, so plain words
+uint64_t g_keccak_calls[2];
+
+// keccak256(data) -> the 32-byte digest of any contiguous buffer
+PyObject* ext_keccak256(PyObject*, PyObject* arg) {
+  Py_buffer view;
+  if (PyObject_GetBuffer(arg, &view, PyBUF_SIMPLE) < 0) return nullptr;
+  PyObject* out = PyBytes_FromStringAndSize(nullptr, 32);
+  if (out) {
+    const uint8_t* data = static_cast<const uint8_t*>(view.buf);
+    uint8_t* digest = reinterpret_cast<uint8_t*>(PyBytes_AS_STRING(out));
+    const size_t len = static_cast<size_t>(view.len);
+    if (view.len < kKeccakUnlockBytes) {
+      ++g_keccak_calls[0];
+      phant_keccak256(data, len, digest);
+    } else {
+      ++g_keccak_calls[1];
+      // the view keeps a bytearray from resizing under the hash
+      Unlocked unlocked(kSiteKeccak);
+      phant_keccak256(data, len, digest);
+    }
+  }
+  PyBuffer_Release(&view);
+  return out;
+}
+
+// keccak_calls() -> {"held": n, "released": n}
+PyObject* ext_keccak_calls(PyObject*, PyObject*) {
+  return Py_BuildValue("{s:K,s:K}", "held",
+                       static_cast<unsigned long long>(g_keccak_calls[0]),
+                       "released",
+                       static_cast<unsigned long long>(g_keccak_calls[1]));
+}
+
 PyMethodDef module_methods[] = {
     {"lock_clocks", ext_lock_clocks, METH_NOARGS,
      "lock_clocks() -> {site: (seconds run unlocked, seconds waited to take "
      "the interpreter lock back)}"},
+    {"keccak256", ext_keccak256, METH_O,
+     "keccak256(data) -> bytes: the lock held under KECCAK_UNLOCK_BYTES, "
+     "released from there up"},
+    {"keccak_calls", ext_keccak_calls, METH_NOARGS,
+     "keccak_calls() -> {'held': n, 'released': n}: keccak256 calls by what "
+     "they did with the interpreter lock"},
     {"rlp_encode", ext_rlp_encode, METH_O, "rlp_encode(item) -> bytes"},
     {"encode_node", reinterpret_cast<PyCFunction>(ext_encode_node),
      METH_FASTCALL,
@@ -1838,8 +1894,8 @@ PyMethodDef module_methods[] = {
 PyModuleDef moduledef = {
     PyModuleDef_HEAD_INIT,
     "phant_engine_ext",
-    "CPython driver for the native witness-engine core, and the trie-node "
-    "encoder",
+    "CPython driver for the native witness-engine core, the trie-node "
+    "encoder, the EVM's host binding and the scalar keccak256",
     -1,
     module_methods,
 };
@@ -1881,6 +1937,11 @@ extern "C" PyObject* PyInit_phant_engine_ext() {
   if (PyModule_AddObject(m, "EvmHost",
                          reinterpret_cast<PyObject*>(&EvmHostType)) < 0) {
     Py_DECREF(&EvmHostType);
+    Py_DECREF(m);
+    return nullptr;
+  }
+  if (PyModule_AddIntConstant(m, "KECCAK_UNLOCK_BYTES", kKeccakUnlockBytes) <
+      0) {
     Py_DECREF(m);
     return nullptr;
   }

@@ -2,10 +2,21 @@
 
 Three backends, selected transparently:
 
-1. ``native``  — C++ implementation in native/keccak.cc, loaded via ctypes.
-                 This is the CPU fast path (the reference links ethash's C keccak
-                 for evmone and uses Zig std's Keccak256 for the client side,
-                 reference: build.zig:94, src/crypto/hasher.zig:1-17).
+1. ``native``  — C++ implementation in native/keccak.cc. This is the CPU
+                 fast path (the reference links ethash's C keccak for evmone
+                 and uses Zig std's Keccak256 for the client side, reference:
+                 build.zig:94, src/crypto/hasher.zig:1-17). The scalar
+                 `keccak256` enters it through the CPython extension
+                 (native/pyext.cc `keccak256`, utils/native.load_ext): a
+                 short input is hashed with the interpreter lock held, one of
+                 `KECCAK_UNLOCK_BYTES` or more with it released, and
+                 `native.keccak_calls{lock=held|released}` counts both. A
+                 served request makes about a thousand scalar hashes of
+                 under 640 bytes; through `ctypes`, which gives the lock away
+                 at every call, each one queued behind the other handlers
+                 (PERF.md section 7 p). Where the extension does not load
+                 (no Python headers) the `ctypes` library serves; the batch
+                 entries are the `ctypes` library's either way.
 2. ``python``  — pure-Python fallback, also the readable spec used to
                  differential-test the native and TPU paths.
 3. the TPU path lives in phant_tpu/ops/keccak_jax.py and is batched; this
@@ -17,7 +28,7 @@ from __future__ import annotations
 
 from typing import List, Optional, Sequence
 
-from phant_tpu.utils.native import load_native
+from phant_tpu.utils.native import load_ext, load_native
 
 RATE = 136  # bytes; keccak-256 rate (1600 - 2*256 bits)
 
@@ -93,10 +104,16 @@ def _keccak256_python(data: bytes) -> bytes:
 
 
 _native = load_native()
+# what hashes is chosen by what loaded, as the EVM's binding is:
+# PHANT_ENGINE_EXT masks the witness driver and the node encoder, not this
+_ext_keccak256 = getattr(load_ext(), "keccak256", None)
 
 
 def keccak256(data: bytes) -> bytes:
-    """keccak256 over bytes (reference: src/crypto/hasher.zig:4-8)."""
+    """keccak256 over bytes (reference: src/crypto/hasher.zig:4-8): the
+    extension's, else the `ctypes` library's, else the Python spec."""
+    if _ext_keccak256 is not None:
+        return _ext_keccak256(data)
     if _native is not None:
         return _native.keccak256(data)
     return _keccak256_python(data)

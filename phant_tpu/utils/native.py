@@ -449,8 +449,9 @@ def load_engine_ext():
 def load_ext():
     """Build (if stale) and import the CPython extension (native/pyext.cc +
     engine.cc + keccak.cc + evm.cc): the witness-engine driver (`Engine`),
-    the trie-node encoder and the EVM's host binding (`EvmHost`). Returns
-    the module or None (no toolchain, PHANT_NO_NATIVE)."""
+    the trie-node encoder, the EVM's host binding (`EvmHost`) and the
+    scalar `keccak256`. Returns the module or None (no toolchain,
+    PHANT_NO_NATIVE)."""
     global _ext_mod, _ext_failed
     if _ext_failed or os.environ.get("PHANT_NO_NATIVE"):
         return None
@@ -462,8 +463,8 @@ def load_ext():
         try:
             import sysconfig
 
-            # keccak.cc backs the engine's finish_native in-C hashing and
-            # the VM's KECCAK256
+            # keccak.cc backs the engine's finish_native in-C hashing, the
+            # VM's KECCAK256 and the scalar keccak256
             srcs = [
                 _NATIVE_DIR / "pyext.cc",
                 _NATIVE_DIR / "engine.cc",
@@ -494,8 +495,10 @@ def load_ext():
 
 
 #: where the extension gives the interpreter lock away around native work
-#: (native/pyext.cc `LockSite`): the witness engine's scan, hash and commit
-LOCK_SITES = ("scan", "verdict", "commit", "commit_hash", "hash", "finish_commit")
+#: (native/pyext.cc `LockSite`): the witness engine's scan, hash and commit,
+#: and the scalar `keccak256` (crypto/keccak.py) for an input of
+#: `KECCAK_UNLOCK_BYTES` or more; under that it hashes with the lock held
+LOCK_SITES = ("scan", "verdict", "commit", "commit_hash", "hash", "finish_commit", "keccak")
 
 
 def lock_clocks() -> dict:
@@ -507,6 +510,16 @@ def lock_clocks() -> dict:
     if mod is None:
         return dict.fromkeys(LOCK_SITES, (0.0, 0.0))
     return mod.lock_clocks()
+
+
+def keccak_calls() -> dict:
+    """{"held": n, "released": n}: calls of the extension's scalar
+    `keccak256` since process start, by what they did with the interpreter
+    lock; zeros where the extension is not loaded."""
+    mod = _ext_mod
+    if mod is None:
+        return {"held": 0, "released": 0}
+    return mod.keccak_calls()
 
 
 def load_native() -> Optional[NativeLib]:

@@ -304,8 +304,9 @@ METRIC_HELP: Dict[str, str] = {
     "lanes.stage_cpu_seconds": "CPU seconds the lane's thread ran inside each stage of lanes.stage_seconds, booked as engine_api.phase_cpu_seconds is (native code run with the interpreter lock released counts: native.unlocked_seconds says how much of it the extension saw)",
     "lanes.stage_offcpu_seconds": "lanes.stage_seconds less lanes.stage_cpu_seconds, observation by observation: what the lane's thread waited inside the stage. prefetch, pack and dispatch wait for nothing but a turn at the interpreter lock (and the engine's own lock); resolve waits for the device's readback too",
     "runtime.process_cpu_seconds": "CPU seconds of the whole process by mode (os.times: user, and system = the kernel on the process's behalf, e.g. handing the interpreter lock from thread to thread; the two sum to time.process_time), every thread: XLA's pool, the collector, whatever shares the process; set at every exposition (/metrics)",
-    "native.unlocked_seconds": "Seconds the extension (native/pyext.cc) ran native work with the interpreter lock released, by site (scan, verdict, commit, commit_hash, hash, finish_commit: the witness engine's scan, hash and commit), from the lock's release to the work's end; read from the extension's own clocks at every exposition (/metrics), 0 in a process without it",
+    "native.unlocked_seconds": "Seconds the extension (native/pyext.cc) ran native work with the interpreter lock released, by site (scan, verdict, commit, commit_hash, hash, finish_commit: the witness engine's scan, hash and commit; keccak: the scalar keccak256 of a long input), from the lock's release to the work's end; read from the extension's own clocks at every exposition (/metrics), 0 in a process without it",
     "native.lock_retake_seconds": "Seconds the extension waited to take the interpreter lock back after native work it ran unlocked, by site: the one place the program MEASURES a wait for the lock (everywhere else it is wall less CPU)",
+    "native.keccak_calls": "Scalar keccak256 calls the extension served (crypto/keccak.keccak256 -> native/pyext.cc) since process start, by what they did with the interpreter lock: held = an input under KECCAK_UNLOCK_BYTES (4 KiB) hashed without a hand-over, released = a longer one hashed with the lock given away (clocked as native.unlocked_seconds{site=keccak}); counted in C++ and read at every exposition (/metrics), 0 in a process without the extension, where the ctypes library or the Python spec hashes uncounted",
     "jit.compiles": "Programs jax first built in this process, by thread (serving = a scheduler thread whose compile holds a job queue; other): one per backend compile and one per load from the persistent cache",
     "jit.serving_compile_seconds": "Wall-clock seconds the serving threads have spent compiling (union of jax's trace/lower/compile intervals): the credit the request deadline clock runs on (serving/deadline.py)",
     "runtime.gc_pause_seconds": "Pauses of CPython's collector in this process, by generation, from the one gc.callbacks entry the server installs (every collection; a full one, generation 2, is also a `gc` interval of every request span open then; generation=deep is the tenure policy's own full collection of everything, run while no request is in flight)",
@@ -595,7 +596,8 @@ class Metrics:
         counted is read here, once an exposition: the process's CPU seconds,
         all threads (XLA's pool, the collector and whatever shares the
         process with the server, so that what no span covers has a size),
-        and the extension's lock clocks (zeros where it is not loaded)."""
+        and the extension's lock clocks and its count of scalar hashes (zeros
+        where it is not loaded)."""
         from phant_tpu.utils import native  # here: it loads nothing until asked
 
         cpu = os.times()  # what time.process_time() sums, apart
@@ -604,6 +606,8 @@ class Metrics:
         for site, (unlocked, retake) in native.lock_clocks().items():
             self.gauge_set("native.unlocked_seconds", unlocked, site=site)
             self.gauge_set("native.lock_retake_seconds", retake, site=site)
+        for lock, calls in native.keccak_calls().items():
+            self.gauge_set("native.keccak_calls", calls, lock=lock)
         snap = self.snapshot()
         out: List[str] = []
         emitted_help: set = set()

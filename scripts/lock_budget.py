@@ -10,9 +10,10 @@ family that carries the second clock (PR 38) to
 table, ms a request: each critical-path phase, front-end mark, lane stage
 and visit to the device by wall, CPU and off-CPU (wall less CPU: the
 thread's wait; in a phase that waits for nothing by design, its wait for
-the lock), the extension's own lock clocks by site, the collector, and the
-process's CPU. It needs a TPU unless `--rehearse` is given; host figures of
-the machine it runs on, never a device number.
+the lock), the extension's own lock clocks by site, its scalar keccaks by
+what they did with the lock (a count), the collector, and the process's
+CPU. It needs a TPU unless `--rehearse` is given; host figures of the
+machine it runs on, never a device number.
 """
 
 from __future__ import annotations
@@ -64,6 +65,7 @@ def budget(obs: dict) -> dict:
         "requests": one("phant_critpath_requests_total"),
         "rows": rows,
         "native": sites,
+        "keccak_calls": {dict(labels)["lock"]: v for labels, v in grown("phant_native_keccak_calls").items()},
         "gc_pause_s": one("phant_runtime_gc_pause_seconds_sum"),
         "process_cpu_s": one("phant_runtime_process_cpu_seconds"),
         "process_system_cpu_s": sum(
@@ -80,6 +82,8 @@ def table(b: dict) -> str:
         out.append(f"{name:32s} {ms(r['wall_s'])} {ms(r['cpu_s'])} {ms(r['offcpu_s'])}")
     for site, r in sorted(b["native"].items()):
         out.append(f"{'native ' + site:32s} unlocked {ms(r.get('unlocked_s'))} retake {ms(r.get('retake_s'))}")
+    for lock, calls in sorted(b["keccak_calls"].items()):
+        out.append(f"{'scalar keccaks, lock ' + lock:32s} {calls / n:7.1f} a request")
     out.append(f"{'collector':32s} {ms(b['gc_pause_s'])}")
     out.append(
         f"window {b['window_s']:.2f} s, {b['requests']:.0f} requests; the process ran "

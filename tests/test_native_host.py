@@ -618,6 +618,45 @@ def test_a_served_block_counts_its_frames_and_one_binding(reference_block, backe
     assert set(after) <= {'evm.native_frames{binding="ext"}', "evm.host_bindings"}
 
 
+def test_a_served_block_hashes_through_the_extension_and_never_through_ctypes(reference_block, monkeypatch):
+    """The scalar `keccak256` of a served request is the extension's, the
+    interpreter lock held: a patched `NativeLib.keccak256` is never entered
+    and `native.keccak_calls{lock=held}` grows by at least a hash a
+    transaction (the signing hashes alone are that many), `released` by
+    none. With the extension masked from the hash in this test the same
+    block answers the same through the `ctypes` library."""
+    from phant_tpu.crypto import keccak
+    from phant_tpu.utils import native
+    from test_post_root import _serve_reference_block
+
+    block, request_json = reference_block
+    if native.load_ext() is None or native.load_native() is None:
+        pytest.skip("no toolchain: neither binding can be built here")
+    assert keccak._ext_keccak256 is native.load_ext().keccak256
+    set_evm_backend("native")
+    entered = []
+    real = native.NativeLib.keccak256
+
+    def counting(self, data):
+        entered.append(len(data))
+        return real(self, data)
+
+    monkeypatch.setattr(native.NativeLib, "keccak256", counting)
+    before = native.keccak_calls()
+    reply = _serve_reference_block(request_json, lambda db: db.state_root())
+    after = native.keccak_calls()
+    assert reply["result"]["status"] == "VALID", reply
+    assert reply["result"]["stateRoot"] == request_json["params"][0]["stateRoot"]
+    assert entered == []
+    assert after["held"] - before["held"] >= len(request_json["params"][0]["transactions"]) == 60
+    assert after["released"] == before["released"]
+
+    monkeypatch.setattr(keccak, "_ext_keccak256", None)
+    masked = _serve_reference_block(request_json, lambda db: db.state_root())
+    assert masked == reply
+    assert len(entered) >= 60 and native.keccak_calls() == after
+
+
 def test_the_counters_are_on_the_metrics_page():
     set_evm_backend("native")
     both(_chain_of_three("CALL"))

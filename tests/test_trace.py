@@ -288,14 +288,24 @@ def test_span_threads_do_not_interfere():
 # ---------------------------------------------------------------------------
 
 
+#: what the machine gave this thread's last piece of work: the share of a
+#: core a spin got by its OWN readings of the two clocks (1.0 on an idle
+#: machine, less beside five other xdist workers), so that a split is
+#: judged against that and not against a whole core
+_gave = threading.local()
+
+
 def _sleep(seconds: float) -> None:
     time.sleep(seconds)
+    _gave.share = 1.0
 
 
 def _spin(seconds: float) -> None:
-    end = time.monotonic() + seconds
+    t0, c0 = time.monotonic(), time.thread_time()
+    end = t0 + seconds
     while time.monotonic() < end:
         pass
+    _gave.share = (time.thread_time() - c0) / (time.monotonic() - t0)
 
 
 def _split_of_a_phase(work) -> tuple:
@@ -361,23 +371,29 @@ def _split_of_a_stage_timer(work) -> tuple:
 )
 @pytest.mark.parametrize("work,low,high", [(_sleep, 0.0, 0.10), (_spin, 0.80, 1.001)])
 def test_every_timed_place_reads_the_threads_cpu_beside_the_wall(split, work, low, high):
-    """A phase that sleeps ran almost no CPU, one that spins ran little
-    else; and CPU is read inside the wall readings, so it does not pass the
-    wall (a mark's two clocks are read one after the other at each end: by
-    microseconds at most). Up to five goes: a spin can lose its core on a
-    busy machine."""
+    """A phase that sleeps ran almost no CPU; one that spins reads at least
+    `low` of the CPU the spin itself read on the same thread's clock, the
+    share of a core the machine gave it (`_gave`), whatever that share was;
+    and CPU is read inside the wall readings, so it does not pass the wall
+    (a mark's two clocks are read one after the other at each end: by
+    microseconds at most). Up to five goes: a thread can lose its core
+    between a phase's reading and the spin's own."""
     for _attempt in range(5):
         wall_ms, cpu_ms = split(work)
         assert 50.0 <= wall_ms < 5000.0 and 0.0 <= cpu_ms <= wall_ms + 0.05
-        if low <= cpu_ms / wall_ms <= high:
+        if low * _gave.share <= cpu_ms / wall_ms <= high:
             return
-    raise AssertionError(f"{split.__name__}/{work.__name__}: cpu {cpu_ms} of wall {wall_ms}")
+    raise AssertionError(f"{split.__name__}/{work.__name__}: cpu {cpu_ms} of wall {wall_ms}, given {_gave.share}")
 
 
 def test_two_threads_on_one_lock_wait_one_threads_wall_between_them():
-    """Two threads that spin in Python share the interpreter lock: each
-    runs about half the time, so their off-CPU seconds (wall less CPU) sum
-    to about one thread's wall. What `handler_lock_wait_ms` rests on."""
+    """Two threads that spin in Python share the interpreter lock: they
+    never run at once, so the CPU the two phases read sums to at most one
+    thread's wall and their off-CPU seconds (wall less CPU) to at least
+    about one. What `handler_lock_wait_ms` rests on. From below the CPU is
+    judged against what the machine gave the PROCESS meanwhile
+    (`time.process_time`, all its threads): the two phases account for
+    most of it, be it a whole core or, beside other workers, half of one."""
     for _attempt in range(5):
         out: list = []
         gate = threading.Barrier(2)
@@ -387,6 +403,7 @@ def test_two_threads_on_one_lock_wait_one_threads_wall_between_them():
             out.append(_split_of_a_phase(lambda _s: _spin(0.3)))
 
         ts = [threading.Thread(target=worker) for _ in range(2)]
+        p0 = time.process_time()
         for t in ts:
             t.start()
         for t in ts:
@@ -394,10 +411,11 @@ def test_two_threads_on_one_lock_wait_one_threads_wall_between_them():
         wall = sum(w for w, _c in out) / 2
         cpu = sum(c for _w, c in out)
         off = sum(w - c for w, c in out)
+        given = min((time.process_time() - p0) * 1e3 / wall, 1.0)
         assert all(0.0 <= c <= w for w, c in out)
-        if 0.7 <= cpu / wall <= 1.15 and 0.7 <= off / wall <= 1.3:
+        if 0.7 * given <= cpu / wall <= 1.15 and 0.7 <= off / wall <= 2.0 - 0.7 * given:
             return
-    raise AssertionError(f"two spinning threads read {out}")
+    raise AssertionError(f"two spinning threads read {out} where the process got {given} of a core")
 
 
 def test_observe_split_books_a_ticks_excess_against_the_next_observations():
@@ -457,6 +475,7 @@ NEW_FAMILIES = {
     "runtime.process_cpu_seconds": "gauge",
     "native.unlocked_seconds": "gauge",
     "native.lock_retake_seconds": "gauge",
+    "native.keccak_calls": "gauge",
 }
 
 
@@ -542,6 +561,8 @@ def test_without_the_extension_the_lock_clocks_read_zero():
         "for site in native.LOCK_SITES:\n"
         "    assert 'phant_native_unlocked_seconds{site=\"%s\"} 0.0' % site in text\n"
         "    assert 'phant_native_lock_retake_seconds{site=\"%s\"} 0.0' % site in text\n"
+        "assert native.keccak_calls() == {'held': 0, 'released': 0}\n"
+        "assert 'phant_native_keccak_calls{lock=\"held\"} 0\\n' in text\n"
         "print('zero')\n"
     )
     env = {**os.environ, "PHANT_NO_NATIVE": "1", "JAX_PLATFORMS": "cpu"}
