@@ -42,6 +42,7 @@ from phant_tpu.types.transaction import (
 )
 from phant_tpu.types.withdrawal import GWEI
 from phant_tpu.mpt.mpt import ordered_trie_root
+from phant_tpu.utils.trace import clock_ns, cpu_clock_ns
 
 ELASTICITY_MULTIPLIER = 2  # reference: params.zig:36
 BASE_FEE_MAX_CHANGE_DENOMINATOR = 8  # reference: params.zig:37
@@ -86,6 +87,10 @@ class Blockchain:
         # uses it to pick the fork for witness-backed execution
         self.config = config
         self._vm_host = None  # the running block's (run_block)
+        # nanoseconds run_block has spent in `state.state_root()` so far, on
+        # the span clock and on the calling thread's CPU clock: the replay
+        # engine reads it around a block to tell the root walk from execution
+        self.root_clock = [0, 0]
         # a config naming a known public network arms the KZG dev-setup
         # guard: 0x0A must refuse the forgeable dev tau there (crypto/kzg
         # set_public_network; config-less fixture chains stay unguarded)
@@ -303,7 +308,12 @@ class Blockchain:
             raise BlockError("requests_hash before prague")
         if self.verify_state_root:
             # beyond reference (TODO-disabled at blockchain.zig:83-85)
-            computed = self.state.state_root()
+            t0, c0 = clock_ns(), cpu_clock_ns()
+            try:
+                computed = self.state.state_root()
+            finally:
+                self.root_clock[0] += clock_ns() - t0
+                self.root_clock[1] += cpu_clock_ns() - c0
             if computed != header.state_root:
                 raise BlockError(
                     f"state root mismatch: {computed.hex()} != {header.state_root.hex()}"

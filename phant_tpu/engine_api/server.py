@@ -70,16 +70,19 @@ from phant_tpu.engine_api import handle_request
 from phant_tpu.obs import critpath, flight, profiler, timeline
 from phant_tpu.obs.flight import refresh_from_env as _refresh_flight_ring
 from phant_tpu.serving import (
+    LANE_PROGRAMS,
     PRIORITY_BACKFILL,
     PRIORITY_HEAD,
     SchedulerConfig,
     SchedulerError,
     VerificationScheduler,
     active_scheduler,
+    boot_lanes,
     collector,
     current_priority,
     current_tenant,
     install,
+    on_cpu,
     sanitize_tenant,
     tenant_context,
     uninstall,
@@ -664,7 +667,7 @@ class EngineAPIServer:
         collector.install()
         try:
             _boot_root_lane()
-            _boot_lanes(scheduler)
+            boot_lanes(scheduler)
         except BaseException:
             # a build that fails leaves nothing of this server installed
             try:
@@ -716,45 +719,11 @@ def _boot_root_lane() -> None:
     from phant_tpu.stateless import _batched_root_wanted
 
     note_plan_shape()
-    if not _batched_root_wanted() or _on_cpu():
+    if not _batched_root_wanted() or on_cpu():
         return
     log.info("root lane: %d rungs built in %.1fs", *prewarm_ladder())
 
 
-#: the served device programs whose shapes `lanes.program_shapes` counts
-#: (the root program's are `root.plan_shapes`)
-LANE_PROGRAMS = ("ecrecover", "verdict", "gather", "update")
-
-
-def _boot_lanes(scheduler) -> None:
-    """`lanes.program_shapes{program=}` is on /metrics from the start; and
-    where this server's witness lane launches on an accelerator, the
-    resident table's update, verdict and gather programs are built here on
-    every rung of their ladders, on the table the server will use, before
-    the port answers (`_boot_root_lane`'s twin): which requests share a
-    wave is up to a 5 ms window, so no warm-up of a client's could reach
-    every rung (PERF.md section 7, fault 0c). `ecrecover_kernel` is not
-    built here: it has ONE rung, which the first request builds whatever
-    wave it comes in (`secp256k1_jax.SIG_LADDER` says why). On the CPU,
-    where tests and dry runs force the lanes, a rung is built when first
-    met."""
-    from phant_tpu.backend import crypto_backend, jax_device_ok
-    from phant_tpu.utils.rungs import export_shapes
-
-    export_shapes(LANE_PROGRAMS)
-    if crypto_backend() != "tpu" or not jax_device_ok() or _on_cpu():
-        return
-    t0 = time.monotonic()
-    n = scheduler.prewarm_lanes()
-    dt = time.monotonic() - t0
-    metrics.gauge_set("lanes.prewarm_seconds", dt)
-    log.info("witness lane: %d programs built in %.1fs", n, dt)
-
-
-def _on_cpu() -> bool:
-    import jax
-
-    return jax.default_backend() == "cpu"
 
 
 class MetricsServer:

@@ -18,7 +18,7 @@ import sys
 import time
 
 
-def main(argv=None) -> int:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser(
         prog="python -m phant_tpu.replay", description=__doc__
     )
@@ -40,7 +40,9 @@ def main(argv=None) -> int:
         choices=("auto", "host", "defer"),
         default="auto",
         help="segment root mode: host walk per block, or deferred "
-        "device megabatches per segment (auto keys on a live device)",
+        "device megabatches per segment. auto defers only on a live device "
+        "AND where a block's plan is over what the block dirtied; a state "
+        "that retains its trie keeps the host walk, on the chip too",
     )
     ap.add_argument(
         "--no-witnesses",
@@ -59,6 +61,21 @@ def main(argv=None) -> int:
         metavar="N",
         help="with --scheduler: N per-device mesh lanes",
     )
+    # the server's two backend flags, values and defaults
+    # (phant_tpu/__main__.py): the same process-wide choice
+    ap.add_argument(
+        "--crypto_backend",
+        choices=("cpu", "tpu"),
+        default="cpu",
+        help="Backend for the stateless crypto hot loop (keccak/MPT/ecrecover)",
+    )
+    ap.add_argument(
+        "--evm_backend",
+        choices=("python", "native"),
+        default="native",
+        help="EVM bytecode interpreter: native C++ core (evmone-equivalent) "
+        "or the pure-Python reference interpreter",
+    )
     ap.add_argument(
         "--serial-check",
         action="store_true",
@@ -67,23 +84,26 @@ def main(argv=None) -> int:
     ap.add_argument(
         "--stats", action="store_true", help="print replay.* metrics"
     )
-    args = ap.parse_args(argv)
+    return ap
 
-    from phant_tpu.replay import DEFAULT_SEGMENT_BLOCKS, ReplayEngine, load_fixture
 
+def build_engine(args):
+    """Everything `python -m phant_tpu.replay` does before it runs a
+    chain, from parsed args: select the backends (a `tpu` backend without
+    a TPU raises HERE), install the scheduler where `--scheduler` asks for
+    one, and build the engine. Returns (scheduler or None, ReplayEngine);
+    the caller uninstalls and shuts down the scheduler it was given.
+    `main` runs what this returns; benchmarks/drivers/replay.py too."""
+    from phant_tpu.backend import set_crypto_backend, set_evm_backend
+    from phant_tpu.replay import DEFAULT_SEGMENT_BLOCKS, ReplayEngine
+
+    set_crypto_backend(args.crypto_backend)
+    set_evm_backend(args.evm_backend)
     segment = args.segment
     if segment is None:
         segment = int(
             os.environ.get("PHANT_REPLAY_SEGMENT", str(DEFAULT_SEGMENT_BLOCKS))
         )
-    fix = load_fixture(args.fixture)
-    print(
-        f"[replay] {args.fixture}: {len(fix.blocks)} blocks, "
-        f"{fix.total_txs} txs, segment={segment}"
-        + (f", witnesses({fix.scheme})" if fix.witnesses else "")
-    )
-
-    root_mode = None if args.root == "auto" else args.root
     sched = None
     if args.scheduler:
         # the lane decision is stateless._batched_sig_wanted; on a pure
@@ -104,14 +124,50 @@ def main(argv=None) -> int:
             ),
         )
         serving.install(sched)
+        try:
+            # as the server's boot: the table's programs on every rung of
+            # their ladders before a segment can wait for one (which
+            # witnesses share a wave is up to the 20 ms window)
+            serving.boot_lanes(sched)
+        except BaseException:
+            serving.uninstall(sched)
+            sched.shutdown()
+            raise
+    eng = ReplayEngine(
+        segment_blocks=segment,
+        pipeline_depth=args.depth,
+        root_mode=None if args.root == "auto" else args.root,
+    )
+    return sched, eng
 
+
+def main(argv=None) -> int:
+    args = build_parser().parse_args(argv)
+
+    from phant_tpu.backend import (
+        crypto_backend,
+        evm_backend,
+        set_crypto_backend,
+        set_evm_backend,
+    )
+    from phant_tpu.replay import load_fixture
+    from phant_tpu.replay.lowering import auto_root_mode
+
+    fix = load_fixture(args.fixture)
+    backends = crypto_backend(), evm_backend()  # a caller's, given back at the end
+    sched = None
     try:
-        chain = fix.fresh_chain()
-        eng = ReplayEngine(
-            segment_blocks=segment,
-            pipeline_depth=args.depth,
-            root_mode=root_mode,
+        sched, eng = build_engine(args)
+        print(
+            f"[replay] {args.fixture}: {len(fix.blocks)} blocks, "
+            f"{fix.total_txs} txs, segment={eng.segment_blocks}"
+            + (f", witnesses({fix.scheme})" if fix.witnesses else "")
         )
+        chain = fix.fresh_chain()
+        if args.root == "auto":
+            _mode, passed_over = auto_root_mode()
+            if passed_over:
+                print(f"[replay] --root auto: {passed_over}")
         t0 = time.perf_counter()
         report = eng.run(
             chain,
@@ -168,6 +224,8 @@ def main(argv=None) -> int:
 
             serving.uninstall(sched)
             sched.shutdown()
+        set_crypto_backend(backends[0])
+        set_evm_backend(backends[1])
 
 
 if __name__ == "__main__":

@@ -30,10 +30,19 @@ already built (sender recovery always has a correct local fallback, so
 the lanes may only ever help). A consensus-invalid block fails exactly
 that block (`replay.block_failed`, stage-named) and stops the import at
 it — earlier blocks stand, the same contract as `run_blocks`.
+
+Where a segment's seconds go is counted phase by phase (`PHASES`), on
+both clocks, on the thread that ran the phase: `replay.phase_cpu_seconds`
+and `replay.phase_offcpu_seconds`, one observation a phase a segment,
+with `replay.execute_seconds`, `replay.root_seconds{backend=}` and
+`replay.ready_wait_seconds` beside the older `replay.*` timers; and every
+verdict carries the seconds its block was in the pipeline
+(`replay.block_latency_seconds`, `BlockVerdict.latency_s`).
 """
 
 from __future__ import annotations
 
+import contextlib
 import os
 import queue
 import threading
@@ -43,12 +52,31 @@ from typing import List, Optional, Sequence, Tuple
 
 from phant_tpu.blockchain.chain import BlockError
 from phant_tpu.obs.flight import flight
-from phant_tpu.utils.trace import metrics
+from phant_tpu.utils.trace import (
+    REQUEST_SECONDS_BUCKETS,
+    clock_ns,
+    cpu_clock_ns,
+    metrics,
+)
 
 STAGE_PREFETCH = "prefetch"
 STAGE_PACK = "pack"
 STAGE_DISPATCH = "dispatch"
 STAGE_RESOLVE = "resolve"
+
+#: what a segment's seconds are split into: the lookahead worker's three
+#: stages, then the run loop's wait for the worker, its two joins with the
+#: lanes, the blocks' execution and their post-state roots
+PHASES = (
+    "prefetch",
+    "pack",
+    "dispatch",
+    "ready_wait",
+    "sig_wait",
+    "witness_wait",
+    "execute",
+    "root",
+)
 
 #: default blocks per segment (`--segment` / PHANT_REPLAY_SEGMENT)
 DEFAULT_SEGMENT_BLOCKS = 32
@@ -67,12 +95,15 @@ def _default_depth() -> int:
 @dataclass
 class BlockVerdict:
     """Per-block outcome; `error` carries the BlockError text on failure
-    (byte-compatible with what serial `run_blocks` raises)."""
+    (byte-compatible with what serial `run_blocks` raises); `latency_s`
+    is the block's seconds in the pipeline, from its segment's hand-over
+    to this verdict (`replay.block_latency_seconds`)."""
 
     index: int
     block_number: int
     ok: bool
     error: Optional[str] = None
+    latency_s: Optional[float] = None
 
 
 @dataclass
@@ -107,6 +138,8 @@ class _Segment:
         "witness_futs",
         "prepare_error",
         "prepare_stage",
+        "handed_ns",
+        "clocks",
     )
 
     def __init__(self, index, start, blocks, witnesses):
@@ -121,6 +154,23 @@ class _Segment:
         self.witness_futs = None  # None | list[Future] | ("local", ...)
         self.prepare_error = None
         self.prepare_stage = None
+        self.handed_ns = None  # when the pipeline took the segment up
+        self.clocks = {p: [0, 0] for p in PHASES}  # phase -> [wall ns, cpu ns]
+
+    def add(self, phase: str, wall_ns: int, cpu_ns: int) -> None:
+        got = self.clocks[phase]
+        got[0] += wall_ns
+        got[1] += cpu_ns
+
+    @contextlib.contextmanager
+    def clock(self, phase: str):
+        """Both clocks around one phase of this segment, on the thread
+        that runs it."""
+        t0, c0 = clock_ns(), cpu_clock_ns()
+        try:
+            yield
+        finally:
+            self.add(phase, clock_ns() - t0, cpu_clock_ns() - c0)
 
 
 class ReplayEngine:
@@ -188,12 +238,14 @@ class ReplayEngine:
         lanes entirely (a prior stage already recorded its death)."""
         from phant_tpu.serving.scheduler import SchedulerError
 
+        if seg.handed_ns is None:
+            seg.handed_ns = clock_ns()
         txs = [tx for b in seg.blocks for tx in b.transactions]
 
         # prefetch: the merged signing-hash pass for the whole segment —
         # one SigRows for K blocks (host keccak over RLP, off the
         # critical path at depth >= 2)
-        with metrics.phase("replay.prefetch"):
+        with metrics.phase("replay.prefetch"), seg.clock("prefetch"):
             seg.rows = signer.signature_rows(txs)
 
         sched = None if degraded else self._scheduler()
@@ -203,7 +255,7 @@ class ReplayEngine:
         # coalesce (mesh schedulers shard them over per-lane resident
         # intern tables)
         if seg.witnesses is not None:
-            with metrics.phase("replay.pack"):
+            with metrics.phase("replay.pack"), seg.clock("pack"):
                 futs = None
                 if sched is not None and sched.accepts_witness():
                     try:
@@ -226,7 +278,7 @@ class ReplayEngine:
         # dispatch: the merged ecrecover launch. Backlog pacing keeps a
         # deep replay pipeline from monopolizing the admission queue it
         # shares with live traffic (sig_backlog is rows, not jobs).
-        with metrics.phase("replay.dispatch"):
+        with metrics.phase("replay.dispatch"), seg.clock("dispatch"):
             if sched is not None and sched.accepts_sig() and seg.rows.n:
                 deadline = time.monotonic() + 0.25
                 while (
@@ -255,22 +307,24 @@ class ReplayEngine:
         pass."""
         from phant_tpu.serving.scheduler import SchedulerError
 
-        t0 = time.perf_counter()
         try:
-            if seg.sig_kind == "lane":
+            with seg.clock("sig_wait"):
+                if seg.sig_kind == "lane":
+                    try:
+                        senders, _meta = seg.sig_handle()
+                        return senders
+                    except SchedulerError as exc:
+                        self._record_crash(seg, STAGE_RESOLVE, exc)
+                        return signer.recover_rows_async(
+                            seg.rows, force_cpu=True
+                        )()
                 try:
-                    senders, _meta = seg.sig_handle()
-                    return senders
-                except SchedulerError as exc:
-                    self._record_crash(seg, STAGE_RESOLVE, exc)
+                    return seg.sig_handle()
+                except Exception:
+                    # a dead device surfaces here; pin this call to the CPU
                     return signer.recover_rows_async(seg.rows, force_cpu=True)()
-            try:
-                return seg.sig_handle()
-            except Exception:
-                # a dead device surfaces here; pin this call to the CPU
-                return signer.recover_rows_async(seg.rows, force_cpu=True)()
         finally:
-            metrics.observe("replay.sig_wait", time.perf_counter() - t0)
+            metrics.observe("replay.sig_wait", seg.clocks["sig_wait"][0] / 1e9)
 
     def _local_witness_verify(self, witnesses) -> List[bool]:
         """No-scheduler (or crashed-lane) fallback: the segment still
@@ -291,23 +345,25 @@ class ReplayEngine:
             return None
         from phant_tpu.serving.scheduler import SchedulerError
 
-        t0 = time.perf_counter()
         try:
-            if seg.witness_futs is not None:
-                verdicts: List[bool] = []
-                for k, fut in enumerate(seg.witness_futs):
-                    try:
-                        verdicts.append(bool(fut.result()))
-                    except SchedulerError as exc:
-                        self._record_crash(seg, STAGE_RESOLVE, exc)
-                        verdicts.extend(
-                            self._local_witness_verify(seg.witnesses[k:])
-                        )
-                        break
-            else:
-                verdicts = self._local_witness_verify(seg.witnesses)
+            with seg.clock("witness_wait"):
+                if seg.witness_futs is not None:
+                    verdicts: List[bool] = []
+                    for k, fut in enumerate(seg.witness_futs):
+                        try:
+                            verdicts.append(bool(fut.result()))
+                        except SchedulerError as exc:
+                            self._record_crash(seg, STAGE_RESOLVE, exc)
+                            verdicts.extend(
+                                self._local_witness_verify(seg.witnesses[k:])
+                            )
+                            break
+                else:
+                    verdicts = self._local_witness_verify(seg.witnesses)
         finally:
-            metrics.observe("replay.witness_wait", time.perf_counter() - t0)
+            metrics.observe(
+                "replay.witness_wait", seg.clocks["witness_wait"][0] / 1e9
+            )
         for k, ok in enumerate(verdicts):
             if not ok:
                 return k
@@ -319,7 +375,7 @@ class ReplayEngine:
         """Import `blocks` onto `chain` through the segment pipeline.
         `witnesses`: optional per-block (claimed_root, nodes) list
         (fixture.attach_witnesses) verified as segment megabatches."""
-        from phant_tpu.replay.lowering import device_roots_wanted
+        from phant_tpu.replay.lowering import auto_root_mode
 
         report = ReplayReport()
         if not blocks:
@@ -328,7 +384,7 @@ class ReplayEngine:
 
         root_mode = self.root_mode
         if root_mode is None:
-            root_mode = "defer" if device_roots_wanted() else "host"
+            root_mode, _why = auto_root_mode()
         verify_roots = chain.verify_state_root
         if root_mode == "defer" and verify_roots:
             # the engine owns root verification at segment granularity;
@@ -354,10 +410,12 @@ class ReplayEngine:
             "segments": 0,
             "lane_sig_segments": 0,
             "local_sig_segments": 0,
+            "lane_witness_segments": 0,
             "witness_blocks": 0,
             "device_root_groups": 0,
             "device_roots": 0,
             "host_roots": 0,
+            "root_mode": root_mode,
         }
 
         stop = threading.Event()
@@ -391,8 +449,10 @@ class ReplayEngine:
         try:
             for seg in segments:
                 if worker is not None:
+                    t0, c0 = clock_ns(), cpu_clock_ns()
                     got = ready.get()
                     assert got is seg  # strictly in order
+                    seg.add("ready_wait", clock_ns() - t0, cpu_clock_ns() - c0)
                 else:
                     try:
                         self._prepare(signer, seg)
@@ -430,6 +490,55 @@ class ReplayEngine:
         report.stats = stats
         return report
 
+    def _run_block(self, chain, seg: _Segment, block, senders) -> None:
+        """`chain.run_block`, with the post-state root it computes inside
+        itself (`Blockchain.root_clock`) on the segment's `root` clock and
+        the rest of it on `execute`'s."""
+        t0, c0 = clock_ns(), cpu_clock_ns()
+        r0, rc0 = chain.root_clock
+        try:
+            chain.run_block(block, senders=senders)
+        finally:
+            root, root_cpu = chain.root_clock[0] - r0, chain.root_clock[1] - rc0
+            seg.add("root", root, root_cpu)
+            seg.add("execute", clock_ns() - t0 - root, cpu_clock_ns() - c0 - root_cpu)
+
+    def _book(self, seg: _Segment, root_mode: str) -> None:
+        """One observation a phase of the segment's clocks, before
+        `replay.blocks` says the segment is done: a reader that watches
+        that counter finds the segment's seconds already there."""
+        wall = {p: seg.clocks[p][0] / 1e9 for p in PHASES}
+        metrics.observe_hist("replay.execute_seconds", wall["execute"])
+        metrics.observe_hist(
+            "replay.root_seconds",
+            wall["root"],
+            backend="device" if root_mode == "defer" else "host",
+        )
+        metrics.observe_hist("replay.ready_wait_seconds", wall["ready_wait"])
+        for phase in PHASES:
+            metrics.observe_split(
+                "replay.phase_cpu_seconds",
+                "replay.phase_offcpu_seconds",
+                wall[phase],
+                seg.clocks[phase][1] / 1e9,
+                phase=phase,
+            )
+
+    def _verdict(self, seg: _Segment, k: int, done_ns: int, error=None):
+        latency = (done_ns - seg.handed_ns) / 1e9
+        metrics.observe_hist(
+            "replay.block_latency_seconds",
+            latency,
+            buckets=REQUEST_SECONDS_BUCKETS,
+        )
+        return BlockVerdict(
+            index=seg.start + k,
+            block_number=seg.blocks[k].header.block_number,
+            ok=error is None,
+            error=error,
+            latency_s=latency,
+        )
+
     def _run_segment(
         self, chain, seg: _Segment, report, stats, root_mode, verify_roots
     ) -> bool:
@@ -443,37 +552,40 @@ class ReplayEngine:
               "local_sig_segments"] += 1
         if seg.witnesses is not None:
             stats["witness_blocks"] += len(seg.witnesses)
+            if seg.witness_futs is not None:
+                stats["lane_witness_segments"] += 1
 
         plans: List = []
         fallbacks: List = []
-        executed = 0  # blocks of THIS segment executed OK
+        done_ns: List[int] = []  # when each executed block's verdict stood
         failed: Optional[Tuple[int, str]] = None
         pos = 0
         for k, block in enumerate(seg.blocks):
-            idx = seg.start + k
             n = seg.counts[k]
             if bad_witness is not None and k >= bad_witness:
                 failed = (k, "witness verification failed")
                 break
             try:
-                chain.run_block(block, senders=senders[pos : pos + n])
+                self._run_block(chain, seg, block, senders[pos : pos + n])
             except BlockError as e:
                 failed = (k, str(e))
                 break
             pos += n
-            executed += 1
             report.txs += n
             if root_mode == "defer" and verify_roots:
                 from phant_tpu.ops.mpt_jax import build_hash_plan
 
-                trie = chain.state.flush_root_trie()
-                plan = build_hash_plan(trie)
-                plans.append(plan)
-                # unplannable block: capture the host root NOW (the trie
-                # mutates again next block)
-                fallbacks.append(
-                    (lambda r=trie.root_hash(): r) if plan is None else None
-                )
+                with seg.clock("root"):
+                    trie = chain.state.flush_root_trie()
+                    plan = build_hash_plan(trie)
+                    plans.append(plan)
+                    # unplannable block: capture the host root NOW (the
+                    # trie mutates again next block)
+                    fallbacks.append(
+                        (lambda r=trie.root_hash(): r) if plan is None else None
+                    )
+            done_ns.append(clock_ns())
+        failed_ns = clock_ns()
 
         # deferred segment roots: one vmapped device program per
         # structure-sharing run, host walk for the rest
@@ -484,8 +596,9 @@ class ReplayEngine:
             )
 
             t0 = time.perf_counter()
-            handles = lower_segment_plans(plans)
-            roots, rstats = resolve_segment_roots(handles, fallbacks)
+            with seg.clock("root"):
+                handles = lower_segment_plans(plans)
+                roots, rstats = resolve_segment_roots(handles, fallbacks)
             metrics.observe("replay.root_wait", time.perf_counter() - t0)
             if rstats["device_groups"]:
                 metrics.count(
@@ -499,7 +612,9 @@ class ReplayEngine:
             stats["device_root_groups"] += rstats["device_groups"]
             stats["device_roots"] += rstats["device_roots"]
             stats["host_roots"] += rstats["host_roots"]
-            for k in range(executed):
+            # a deferred root's verdict stands when the roots are read back
+            done_ns = [clock_ns()] * len(done_ns)
+            for k in range(len(done_ns)):
                 header = seg.blocks[k].header
                 if roots[k] != header.state_root:
                     failed = (
@@ -507,33 +622,23 @@ class ReplayEngine:
                         f"state root mismatch: {roots[k].hex()} != "
                         f"{header.state_root.hex()}",
                     )
-                    executed = k
+                    failed_ns = done_ns[k]
+                    del done_ns[k:]
                     break
 
+        executed = len(done_ns)  # blocks of THIS segment executed OK
         for k in range(executed):
-            report.verdicts.append(
-                BlockVerdict(
-                    index=seg.start + k,
-                    block_number=seg.blocks[k].header.block_number,
-                    ok=True,
-                )
-            )
-        metrics.count("replay.blocks", executed)
+            report.verdicts.append(self._verdict(seg, k, done_ns[k]))
+        self._book(seg, root_mode)
         metrics.count("replay.txs", sum(seg.counts[:executed]))
         metrics.count("replay.segments")
         metrics.observe("replay.segment_seconds", time.perf_counter() - t_seg)
+        metrics.count("replay.blocks", executed)  # last: see `_book`
 
         if failed is not None:
             k, err = failed
             block = seg.blocks[k]
-            report.verdicts.append(
-                BlockVerdict(
-                    index=seg.start + k,
-                    block_number=block.header.block_number,
-                    ok=False,
-                    error=err,
-                )
-            )
+            report.verdicts.append(self._verdict(seg, k, failed_ns, error=err))
             # stage-named record: the block failed at the segment's
             # resolve stage (join + execute + root check); earlier
             # blocks stand and the import stops here, exactly like a

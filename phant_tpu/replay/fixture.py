@@ -65,7 +65,15 @@ def build_synthetic_chain(
     touched_witnesses: bool = False,
 ) -> ReplayFixture:
     """A synthetic mainnet-shaped chain, made from `seed` and nothing on
-    disk: per block, `txs_per_block` value transfers PLUS half as many
+    disk. Its blocks are EXECUTED here, on the program's own `Blockchain`,
+    to fill their headers: a replay of a fixture made by this function
+    compares the program with itself (pipelined against serial, one lane
+    against another). The independent one is
+    `benchmarks/harness/fixture_of_chain.py`, which puts the chain of the
+    benchmark's plain reference into the same fixture without executing
+    anything (`tests/test_replay_reference.py`; ROADMAP D12).
+
+    Per block, `txs_per_block` value transfers PLUS half as many
     contract calls that SLOAD+SSTORE a counter (cold account + cold slot
     per tx under EIP-2929), so a replay exercises the EVM storage path,
     receipts with variable gas, and an evolving contract storage trie —

@@ -21,9 +21,10 @@ a single fused device program. This module owns that lowering:
     runs back after the EVM has moved on to the next segment.
 
 Env: `PHANT_REPLAY_ROOT` (`0`/`host` pins the host walk, `1`/`device`
-forces batched device dispatch — tests and the XLA-CPU proxy; `auto`
-engages it exactly when the device route exists, the same shape as
-PHANT_BATCHED_SIG/PHANT_BATCHED_ROOT).
+forces batched device dispatch — tests and the XLA-CPU proxy; under `auto`
+deferred roots go to the device exactly when the device route exists, the
+same shape as PHANT_BATCHED_SIG/PHANT_BATCHED_ROOT, and the engine's root
+MODE keeps the host walk: `auto_root_mode`).
 """
 
 from __future__ import annotations
@@ -54,6 +55,25 @@ def device_roots_wanted() -> bool:
     from phant_tpu.backend import crypto_backend, jax_device_ok
 
     return crypto_backend() == "tpu" and jax_device_ok()
+
+
+def auto_root_mode() -> Tuple[str, Optional[str]]:
+    """What root mode `auto` takes: (mode, a sentence where a live device
+    was passed over, else None). `PHANT_REPLAY_ROOT=1|device` is an explicit
+    request for deferred roots, as `--root defer` is. Otherwise the host
+    walk, on a live device too: a deferred plan is
+    `build_hash_plan(state.flush_root_trie())`, the WHOLE retained trie laid
+    out once a block (a million nodes at a genesis of 2^20 accounts), where
+    the walk re-encodes the dirty paths alone. A planner over what a block
+    dirtied is what will make `auto` defer; it brings its condition here."""
+    if os.environ.get("PHANT_REPLAY_ROOT", "auto") in ("1", "device"):
+        return "defer", None
+    if device_roots_wanted():
+        return "host", (
+            "host walk: the state retains its trie, and a deferred plan would "
+            "lay out the whole of it once a block (--root defer asks for that)"
+        )
+    return "host", None
 
 
 def group_segment_plans(

@@ -19,7 +19,9 @@ holds the process-global *active scheduler* slot:
 
 from __future__ import annotations
 
+import logging
 import threading
+import time
 from typing import Optional
 
 from phant_tpu.serving.qos import (
@@ -46,6 +48,7 @@ __all__ = [
     "DEFAULT_TENANT",
     "PRIORITY_BACKFILL",
     "PRIORITY_HEAD",
+    "LANE_PROGRAMS",
     "DeadlineExpired",
     "MeshExecutorPool",
     "QueueFull",
@@ -55,14 +58,18 @@ __all__ = [
     "VerificationScheduler",
     "active_scheduler",
     "affinity_device",
+    "boot_lanes",
     "current_priority",
     "current_tenant",
     "install",
+    "on_cpu",
     "parse_weights",
     "sanitize_tenant",
     "tenant_context",
     "uninstall",
 ]
+
+log = logging.getLogger(__name__)
 
 _active: Optional[VerificationScheduler] = None
 _active_lock = threading.Lock()
@@ -90,3 +97,42 @@ def active_scheduler() -> Optional[VerificationScheduler]:
     """The installed scheduler, or None (read is lock-free: a stale read
     just takes the direct-engine path for one call)."""
     return _active
+
+
+#: the served device programs whose shapes `lanes.program_shapes` counts
+#: (the root program's are `root.plan_shapes`)
+LANE_PROGRAMS = ("ecrecover", "verdict", "gather", "update")
+
+
+def on_cpu() -> bool:
+    import jax
+
+    return jax.default_backend() == "cpu"
+
+
+def boot_lanes(scheduler: VerificationScheduler) -> None:
+    """What every entry point that owns a scheduler runs before its first
+    job (the Engine API server's constructor, the replay CLI's builder):
+    `lanes.program_shapes{program=}` is on /metrics from the start; and
+    where the witness lane launches on an accelerator, the resident table's
+    update, verdict and gather programs are built here on every rung of
+    their ladders, on the table the scheduler will use, before the port
+    answers or the first segment is sent (`engine_api/server._boot_root_lane`'s
+    twin): which requests share a wave is up to a 5 ms window (20 ms in a
+    replay), so no warm-up of a client's could reach every rung (PERF.md
+    section 7, fault 0c). `ecrecover_kernel` is not built here: it has ONE
+    rung, which the first request builds whatever wave it comes in
+    (`secp256k1_jax.SIG_LADDER` says why). On the CPU, where tests and dry
+    runs force the lanes, a rung is built when first met."""
+    from phant_tpu.backend import crypto_backend, jax_device_ok
+    from phant_tpu.utils.rungs import export_shapes
+    from phant_tpu.utils.trace import metrics
+
+    export_shapes(LANE_PROGRAMS)
+    if crypto_backend() != "tpu" or not jax_device_ok() or on_cpu():
+        return
+    t0 = time.monotonic()
+    n = scheduler.prewarm_lanes()
+    dt = time.monotonic() - t0
+    metrics.gauge_set("lanes.prewarm_seconds", dt)
+    log.info("witness lane: %d programs built in %.1fs", n, dt)

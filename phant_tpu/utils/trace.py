@@ -348,6 +348,12 @@ METRIC_HELP: Dict[str, str] = {
     "replay.witness_wait": "Replay blocks joining a segment's witness verdicts at execute time",
     "replay.root_wait": "Deferred segment-root lowering + readback at segment end (the one root sync per segment)",
     "replay.segment_seconds": "Whole-segment resolve+execute wall clock (the blocks/s denominator at segment granularity)",
+    "replay.block_latency_seconds": "A block's seconds in the replay pipeline: from the moment its segment is handed to the pipeline (the lookahead worker, or the run loop at depth 1, begins the segment's prefetch) to the block's verdict (under the host walk when run_block returns or raises; under deferred roots when the segment's roots are read back). One observation a verdict; BlockVerdict.latency_s is the same reading",
+    "replay.execute_seconds": "Seconds a segment's blocks spent in Blockchain.run_block less the state root computed inside it: header checks, the transactions, the receipts root. One observation a segment",
+    "replay.root_seconds": "Seconds a segment spent on its blocks' post-state roots, by backend: host = StateDB.state_root inside run_block, the walk over the dirty paths of the retained trie (the first one hashes the whole trie); device = the deferred route, flush + build_hash_plan a block, then the lowering and the readback (replay.root_wait is that last part). One observation a segment",
+    "replay.ready_wait_seconds": "Seconds the run loop waited for the lookahead worker to hand over a prepared segment (0 at depth 1, where the segment is prepared inline): what of prefetch, pack and dispatch did NOT hide under the segment before. One observation a segment",
+    "replay.phase_cpu_seconds": "CPU seconds of the thread that ran each phase of a replayed segment (prefetch, pack, dispatch on the lookahead worker; ready_wait, sig_wait, witness_wait, execute, root on the run loop), by the thread's CPU clock read beside the span clock at the phase's ends; one observation a phase a segment, booked as engine_api.phase_cpu_seconds is (Metrics.observe_split)",
+    "replay.phase_offcpu_seconds": "A replayed segment's phase wall less replay.phase_cpu_seconds, observation by observation: what the phase's thread waited, for a lane (sig_wait, witness_wait), for the lookahead (ready_wait), or for its turn at the interpreter lock",
     "replay.segment_blocks": "Configured blocks per replay segment (--segment)",
     "replay.pipeline_depth": "Configured replay pipeline depth (1 = fully inline; >= 2 = segment N+1 prepared under segment N's execution)",
     # crypto backend dispatch

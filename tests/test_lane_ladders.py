@@ -346,7 +346,7 @@ def test_the_boot_builds_every_rung_and_a_wave_builds_none(tpu_backend, monkeypa
     ladders cut to test size: every rung of the table's programs is built
     on the engine's own table, which stays empty; the waves served after
     it build nothing. On the CPU the boot only exports the family."""
-    from phant_tpu.engine_api import server as srv
+    from phant_tpu import serving as srv
     from phant_tpu.ops import witness_resident as wr
     from phant_tpu.ops.witness_engine import WitnessEngine
     from phant_tpu.serving.scheduler import SchedulerConfig, VerificationScheduler
@@ -356,7 +356,7 @@ def test_the_boot_builds_every_rung_and_a_wave_builds_none(tpu_backend, monkeypa
     eng = WitnessEngine(resident=True, resident_cap=512)
     _root, wits = build_witnesses(n_blocks=6)
     with VerificationScheduler(engine=eng, config=SchedulerConfig()) as s:
-        srv._boot_lanes(s)  # this suite runs on the CPU: nothing is built
+        srv.boot_lanes(s)  # this suite runs on the CPU: nothing is built
         assert eng.resident_table() is None
         gauges = metrics.snapshot()["gauges"]
         assert all(f'lanes.program_shapes{{program="{p}"}}' in gauges for p in srv.LANE_PROGRAMS)
@@ -387,6 +387,7 @@ def test_the_boots_build_is_over_before_the_port_answers(tpu_backend, monkeypatc
     import threading
     import urllib.request
 
+    from phant_tpu import serving
     from phant_tpu.engine_api import server as srv
     from phant_tpu.serving.scheduler import VerificationScheduler
 
@@ -397,7 +398,7 @@ def test_the_boots_build_is_over_before_the_port_answers(tpu_backend, monkeypatc
         return 6
 
     monkeypatch.setattr(VerificationScheduler, "prewarm_lanes", build)
-    monkeypatch.setattr(srv, "_on_cpu", lambda: False)
+    monkeypatch.setattr(serving, "on_cpu", lambda: False)
     monkeypatch.setattr(srv, "_boot_root_lane", lambda: None)
     from phant_tpu.__main__ import build_parser, build_server
 
