@@ -927,14 +927,17 @@ PyObject* ext_hex_prefix(PyObject*, PyObject* const* args, Py_ssize_t nargs) {
 }
 
 // The host walk of a trie: every node below `node` that `cache` does not
-// hold is encoded, children first, and its (structure, encoding) kept in
-// `cache` under id(node), Trie._enc_cache's contract. A child enters its
-// parent as its structure where its encoding is shorter than
-// `embed_below` bytes, else as the keccak-256 of the encoding; a node of
-// none of the three kinds enters as its `.digest` (an unwitnessed
-// subtree), never encoded.
+// hold is encoded, children first, and its (structure, encoding,
+// reference) kept in `cache` under id(node), Trie._enc_cache's contract.
+// The reference is what the node's parent holds of it: its structure
+// where its encoding is shorter than `embed_below` bytes, else the
+// keccak-256 of the encoding, computed once, where the entry is built
+// (`hashes` tallies those); a clean child of a dirty parent is read from
+// its entry and never hashed again. A node of none of the three kinds
+// enters as its `.digest` (an unwitnessed subtree), never encoded.
 struct Walk {
   PyObject* cache;
+  Py_ssize_t hashes;  // digests this walk computed
   PyObject* path_enc;  // callable(path, is_leaf), or nullptr: hex-prefix
   Py_ssize_t embed_below;
   PyTypeObject* leaf;
@@ -969,21 +972,11 @@ PyObject* walk_ref(Walk* w, PyObject* child, int depth) {
     }
     return digest;
   }
-  PyObject* pair = walk_node(w, child, depth);
-  if (!pair) return nullptr;
-  PyObject* enc = PyTuple_GET_ITEM(pair, 1);
-  PyObject* ref;
-  if (PyBytes_GET_SIZE(enc) < w->embed_below) {
-    ref = PyTuple_GET_ITEM(pair, 0);
-    Py_INCREF(ref);
-  } else {
-    ref = PyBytes_FromStringAndSize(nullptr, 32);
-    if (ref)
-      phant_keccak256(reinterpret_cast<uint8_t*>(PyBytes_AS_STRING(enc)),
-                      static_cast<size_t>(PyBytes_GET_SIZE(enc)),
-                      reinterpret_cast<uint8_t*>(PyBytes_AS_STRING(ref)));
-  }
-  Py_DECREF(pair);
+  PyObject* entry = walk_node(w, child, depth);
+  if (!entry) return nullptr;
+  PyObject* ref = PyTuple_GET_ITEM(entry, 2);
+  Py_INCREF(ref);
+  Py_DECREF(entry);
   return ref;
 }
 
@@ -1046,8 +1039,24 @@ PyObject* walk_structure(Walk* w, PyObject* node, int depth) {
   return items;
 }
 
-// (structure, encoding) of a leaf, extension or branch: a new reference
-// to the tuple the cache holds.
+// What the parent of a node with this structure and encoding holds of
+// it (a new reference): the one place of the walk that hashes.
+PyObject* walk_reference(Walk* w, PyObject* structure, PyObject* enc) {
+  if (PyBytes_GET_SIZE(enc) < w->embed_below) {
+    Py_INCREF(structure);
+    return structure;
+  }
+  PyObject* ref = PyBytes_FromStringAndSize(nullptr, 32);
+  if (!ref) return nullptr;
+  phant_keccak256(reinterpret_cast<uint8_t*>(PyBytes_AS_STRING(enc)),
+                  static_cast<size_t>(PyBytes_GET_SIZE(enc)),
+                  reinterpret_cast<uint8_t*>(PyBytes_AS_STRING(ref)));
+  ++w->hashes;
+  return ref;
+}
+
+// (structure, encoding, reference) of a leaf, extension or branch: a new
+// reference to the tuple the cache holds.
 PyObject* walk_node(Walk* w, PyObject* node, int depth) {
   if (depth > kMaxTrieDepth) {
     PyErr_SetString(PyExc_RecursionError, "trie deeper than any key");
@@ -1055,24 +1064,32 @@ PyObject* walk_node(Walk* w, PyObject* node, int depth) {
   }
   PyObject* key = PyLong_FromVoidPtr(node);
   if (!key) return nullptr;
-  PyObject* pair = PyDict_GetItemWithError(w->cache, key);  // borrowed
-  if (pair) {
-    Py_INCREF(pair);
+  PyObject* entry = PyDict_GetItemWithError(w->cache, key);  // borrowed
+  if (entry) {
     Py_DECREF(key);
-    return pair;
+    if (!PyTuple_CheckExact(entry) || PyTuple_GET_SIZE(entry) != 3) {
+      PyErr_SetString(PyExc_TypeError,
+                      "a memo entry is (structure, encoding, reference)");
+      return nullptr;
+    }
+    Py_INCREF(entry);
+    return entry;
   }
   PyObject* structure = PyErr_Occurred() ? nullptr : walk_structure(w, node, depth + 1);
   PyObject* enc = structure ? ext_rlp_encode(nullptr, structure) : nullptr;
-  pair = enc ? PyTuple_Pack(2, structure, enc) : nullptr;
+  PyObject* ref = enc ? walk_reference(w, structure, enc) : nullptr;
+  entry = ref ? PyTuple_Pack(3, structure, enc, ref) : nullptr;
   Py_XDECREF(structure);
   Py_XDECREF(enc);
-  if (pair && PyDict_SetItem(w->cache, key, pair) < 0) Py_CLEAR(pair);
+  Py_XDECREF(ref);
+  if (entry && PyDict_SetItem(w->cache, key, entry) < 0) Py_CLEAR(entry);
   Py_DECREF(key);
-  return pair;
+  return entry;
 }
 
 // encode_subtree(node, cache, path_enc, embed_below, (Leaf, Extension,
-// Branch)) -> (structure, encoding) of `node`, the cache filled below it
+// Branch)) -> ((structure, encoding, reference) of `node`, digests
+// computed), the cache filled below it
 PyObject* ext_encode_subtree(PyObject*, PyObject* const* args, Py_ssize_t nargs) {
   if (nargs != 5 || !PyDict_Check(args[1]) || !PyTuple_Check(args[4]) ||
       PyTuple_GET_SIZE(args[4]) != 3) {
@@ -1083,6 +1100,7 @@ PyObject* ext_encode_subtree(PyObject*, PyObject* const* args, Py_ssize_t nargs)
   }
   Walk w;
   w.cache = args[1];
+  w.hashes = 0;
   w.path_enc = args[2] == Py_None ? nullptr : args[2];
   w.embed_below = PyLong_AsSsize_t(args[3]);
   if (w.embed_below == -1 && PyErr_Occurred()) return nullptr;
@@ -1097,9 +1115,9 @@ PyObject* ext_encode_subtree(PyObject*, PyObject* const* args, Py_ssize_t nargs)
   }
   w.empty = PyBytes_FromStringAndSize("", 0);
   if (!w.empty) return nullptr;
-  PyObject* pair = walk_node(&w, args[0], 0);
+  PyObject* entry = walk_node(&w, args[0], 0);
   Py_DECREF(w.empty);
-  return pair;
+  return entry ? Py_BuildValue("(Nn)", entry, w.hashes) : nullptr;
 }
 
 // --- the EVM's host binding --------------------------------------------------
@@ -1887,7 +1905,7 @@ PyMethodDef module_methods[] = {
     {"encode_subtree", reinterpret_cast<PyCFunction>(ext_encode_subtree),
      METH_FASTCALL,
      "encode_subtree(node, cache, path_enc, embed_below, node_types) -> "
-     "(structure, encoding)"},
+     "((structure, encoding, reference), digests computed)"},
     {nullptr, nullptr, 0, nullptr},
 };
 

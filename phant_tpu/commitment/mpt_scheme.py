@@ -63,7 +63,7 @@ class MptScheme(CommitmentScheme):
             return
 
         def walk(node):
-            _s, enc = trie.node_encoding(node)
+            enc = trie.node_encoding(node)[1]
             if len(enc) >= 32 or node is trie.root:
                 nodes[enc] = None
             if isinstance(node, ExtensionNode):

@@ -90,6 +90,10 @@ Node = Union[LeafNode, ExtensionNode, BranchNode]
 #: other type enters its parent as its `.digest`
 _NODE_KINDS = (LeafNode, ExtensionNode, BranchNode)
 
+#: an entry of a trie's memo: a node's structure, its encoding, and the
+#: reference its parent holds of it
+_Entry = Tuple[rlp.RLPItem, bytes, rlp.RLPItem]
+
 
 def _common_prefix_len(a: Sequence[int], b: Sequence[int]) -> int:
     n = min(len(a), len(b))
@@ -112,8 +116,11 @@ def _insert(
     `evict(node)` is called for every node whose cached encoding becomes
     stale — both MUTATED nodes (their encoding changes) and DISCARDED nodes
     (their id may be reused by a new object, so a live cache entry would be
-    a use-after-free-style stale hit). Untouched subtrees keep their cache
-    entries, making repeated root computation O(dirty-paths), not O(trie).
+    a use-after-free-style stale hit). The entry it drops holds the node's
+    encoding AND the reference its parent held of it, so neither outlives
+    the other. Untouched subtrees keep their entries, making repeated root
+    computation O(dirty-paths), not O(trie): a dirty branch reads its clean
+    children's references and hashes none of them again.
     """
     if node is None:
         return LeafNode(path, value)
@@ -295,7 +302,8 @@ class Trie:
     (native/pyext.cc `encode_subtree`: one call walks everything below a
     node that the memo does not hold) and in `_node_encoding_python`
     where it does not. Either reads nothing of a scheme but the three
-    hooks: a subclass varies those, never `_ref`."""
+    hooks: a subclass varies those, never `_ref`. Either hashes a node
+    once, where it builds the node's entry of the memo."""
 
     #: key -> path digits (hexary: nibbles; binary scheme: bits)
     _digits = staticmethod(bytes_to_nibbles)
@@ -309,11 +317,18 @@ class Trie:
         # upper bound on leaf count (overwrites double-count); used only as
         # the device-dispatch size heuristic in trie_root_hash
         self.approx_size = 0
-        # node-id -> (structure, encoding) memo with PER-PATH invalidation:
-        # put/delete evict exactly the mutated/discarded nodes (and any
-        # freed object is evicted before its id can be reused), so repeated
-        # roots after K updates re-encode only the K dirty paths.
-        self._enc_cache: Dict[int, Tuple[rlp.RLPItem, bytes]] = {}
+        # node-id -> (structure, encoding, reference) memo with PER-PATH
+        # invalidation: put/delete evict exactly the mutated/discarded
+        # nodes (and any freed object is evicted before its id can be
+        # reused), so repeated roots after K updates re-encode only the K
+        # dirty paths. The reference is what the node's parent holds of it
+        # (the structure where the encoding is shorter than `_embed_below`,
+        # else keccak256 of the encoding): ONE entry, so that whatever
+        # drops an encoding drops its reference in the same act.
+        self._enc_cache: Dict[int, _Entry] = {}
+        # digests the walks of this trie have computed; `root_hash` books
+        # the growth as `mpt.ref_hashes`
+        self._ref_hashes = 0
         # mutation epoch: bumped on every put/delete; the device HashPlan
         # cache (phant_tpu/ops/mpt_jax.py trie_root_device) is keyed on it
         self._epoch = 0
@@ -356,10 +371,11 @@ class Trie:
 
     # --- encoding ---------------------------------------------------------
 
-    def node_encoding(self, node: Node) -> Tuple[rlp.RLPItem, bytes]:
-        """(structure, rlp_encoding) of a node, memoized per build epoch —
-        proof generation and root hashing share subtree encodings instead of
-        re-walking them."""
+    def node_encoding(self, node: Node) -> _Entry:
+        """(structure, rlp_encoding, reference) of a node, memoized per
+        build epoch — proof generation and root hashing share subtree
+        encodings instead of re-walking them, and a parent reads its
+        child's reference instead of hashing the child again."""
         cached = self._enc_cache.get(id(node))
         if cached is not None:
             return cached
@@ -368,11 +384,13 @@ class Trie:
             return self._node_encoding_python(node)
         # the walk's own hex-prefix where the scheme's is the yellow paper's
         path_enc = None if self._path_enc is encode_hex_prefix else self._path_enc
-        return ext.encode_subtree(
+        entry, hashed = ext.encode_subtree(
             node, self._enc_cache, path_enc, self._embed_below, _NODE_KINDS
         )
+        self._ref_hashes += hashed
+        return entry
 
-    def _node_encoding_python(self, node: Node) -> Tuple[rlp.RLPItem, bytes]:
+    def _node_encoding_python(self, node: Node) -> _Entry:
         """`node_encoding` without the extension, and its oracle."""
         if isinstance(node, LeafNode):
             structure: rlp.RLPItem = [self._path_enc(node.path, True), node.value]
@@ -385,9 +403,14 @@ class Trie:
             slots.append(node.value if node.value is not None else b"")
             structure = slots
         encoded = rlp.encode_python(structure)
-        result = (structure, encoded)
-        self._enc_cache[id(node)] = result
-        return result
+        if len(encoded) < self._embed_below:
+            ref: rlp.RLPItem = structure
+        else:
+            ref = keccak256(encoded)
+            self._ref_hashes += 1
+        entry = (structure, encoded, ref)
+        self._enc_cache[id(node)] = entry
+        return entry
 
     def node_structure(self, node: Node) -> rlp.RLPItem:
         """The node's RLP structure (list), before the embed-or-hash rule."""
@@ -395,29 +418,32 @@ class Trie:
 
     def _ref(self, node: Node) -> rlp.RLPItem:
         """Reference to a child: embedded structure if rlp < 32B, else hash
-        (reference: src/mpt/mpt.zig:132-281 node encode paths)."""
-        structure, encoded = self.node_encoding(node)
-        if len(encoded) < self._embed_below:
-            return structure
-        return keccak256(encoded)
+        (reference: src/mpt/mpt.zig:132-281 node encode paths). Read from
+        the child's entry: hashed when the child was encoded, not again."""
+        return self.node_encoding(node)[2]
 
     def root_hash(self) -> bytes:
         if self.root is None:
             return EMPTY_TRIE_ROOT
-        held = len(self._enc_cache)
-        encoded = self.node_encoding(self.root)[1]
+        held, hashed = len(self._enc_cache), self._ref_hashes
+        _structure, encoded, ref = self.node_encoding(self.root)
         fresh = len(self._enc_cache) - held
         if fresh:
-            count_node_encodings(fresh)
-        return keccak256(encoded)
+            count_node_encodings(fresh, self._ref_hashes - hashed)
+        # a root is never embedded: one that encodes short is hashed here
+        return ref if len(encoded) >= self._embed_below else keccak256(encoded)
 
 
-def count_node_encodings(nodes: int) -> None:
+def count_node_encodings(nodes: int, ref_hashes: int = 0) -> None:
     """`mpt.node_encodings{impl=}`: once a root computation (a walk's
     `root_hash`, a plan's `finish`), the nodes it encoded, under the
-    encoder that serves this process now."""
+    encoder that serves this process now; and beside it
+    `mpt.ref_hashes{impl=}`, the digests a host walk computed for them (a
+    plan's are the device's: none)."""
     impl = "python" if load_engine_ext() is None else "native"
     metrics.count("mpt.node_encodings", nodes, impl=impl)
+    if ref_hashes:
+        metrics.count("mpt.ref_hashes", ref_hashes, impl=impl)
 
 
 # --- public API mirroring the reference ----------------------------------

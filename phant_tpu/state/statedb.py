@@ -414,10 +414,12 @@ class StateDB:
 
     def state_root(self) -> bytes:
         # host recursion on purpose, even on --crypto_backend=tpu: the
-        # retained trie re-encodes only dirty paths (per-path enc cache),
-        # which beats shipping a full plan rebuild to the device every
-        # block; the device state-root path serves FULL recomputes (the
-        # stateless witness pipeline and the replay engine's deferred
+        # retained trie re-encodes only dirty paths (the per-path enc cache
+        # keeps each clean node's encoding and the reference its parent
+        # holds of it, so a dirty branch hashes none of its clean children
+        # again), which beats shipping a full plan rebuild to the device
+        # every block; the device state-root path serves FULL recomputes
+        # (the stateless witness pipeline and the replay engine's deferred
         # segment roots), not incremental resident updates
         return self.flush_root_trie().root_hash()
 

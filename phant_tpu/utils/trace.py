@@ -234,6 +234,7 @@ METRIC_HELP: Dict[str, str] = {
     "root.plan_rows": "Rows hashed by served root programs, by kind: real = trie nodes of the merged plans, pad = the empty rows of their strips (what the ladder's fixed strip costs in keccak work)",
     "root.plan_rung": "Root batches by the ladder rung their merged plan was laid out on (over = above the top rung: hashed on the host)",
     "mpt.node_encodings": "Trie nodes encoded, by the encoder that did it: native = the extension's node encoder (native/pyext.cc), python = the fallback where the process runs without the extension; counted once a root computation (a host walk's root_hash, a hash plan's finish) with the nodes it encoded",
+    "mpt.ref_hashes": "keccak-256 digests a host walk computed for the trie nodes it encoded, by the encoder that did it (native | python): tallied in the walk and counted once a root computation (Trie.root_hash) beside mpt.node_encodings. A node is hashed once, where its entry of the trie's memo (structure, encoding, reference) is built, and a clean child's reference is read from there: at most one digest a node encoded, so a ratio to mpt.node_encodings above 1 means clean siblings are hashed again. A walk that raises part-way books neither counter, and the entries it had built stay in the memo, so the next root does not count them either: the counters then read low by the same nodes, the roots are right",
     "evm.native_frames": "Frames of bytecode the native VM (native/evm.cc) ran, nested ones too, by the host binding they went through: ext = the extension's EvmHost (native/pyext.cc), the only one there is; counted when a block's binding is closed (evm/native_vm.BlockHost.close). A plain transfer runs no frame; under the Python interpreter (no toolchain, --evm_backend=python) the family stands still",
     "evm.host_bindings": "Host bindings of the native VM built: one a block whose transactions reach code (Blockchain.run_block), one a message for an Evm outside a block",
     "root.prewarm_seconds": "Seconds the server took at start to build the root program on every rung of the ladder (only with the device root lane on and an accelerator under it)",
@@ -350,7 +351,7 @@ METRIC_HELP: Dict[str, str] = {
     "replay.segment_seconds": "Whole-segment resolve+execute wall clock (the blocks/s denominator at segment granularity)",
     "replay.block_latency_seconds": "A block's seconds in the replay pipeline: from the moment its segment is handed to the pipeline (the lookahead worker, or the run loop at depth 1, begins the segment's prefetch) to the block's verdict (under the host walk when run_block returns or raises; under deferred roots when the segment's roots are read back). One observation a verdict; BlockVerdict.latency_s is the same reading",
     "replay.execute_seconds": "Seconds a segment's blocks spent in Blockchain.run_block less the state root computed inside it: header checks, the transactions, the receipts root. One observation a segment",
-    "replay.root_seconds": "Seconds a segment spent on its blocks' post-state roots, by backend: host = StateDB.state_root inside run_block, the walk over the dirty paths of the retained trie (the first one hashes the whole trie); device = the deferred route, flush + build_hash_plan a block, then the lowering and the readback (replay.root_wait is that last part). One observation a segment",
+    "replay.root_seconds": "Seconds a segment spent on its blocks' post-state roots, by backend: host = StateDB.state_root inside run_block, the walk over the dirty paths of the retained trie (the first one hashes the whole trie; a later one encodes and hashes the dirty nodes alone, their clean siblings' references read from the trie's memo); device = the deferred route, flush + build_hash_plan a block, then the lowering and the readback (replay.root_wait is that last part). One observation a segment",
     "replay.ready_wait_seconds": "Seconds the run loop waited for the lookahead worker to hand over a prepared segment (0 at depth 1, where the segment is prepared inline): what of prefetch, pack and dispatch did NOT hide under the segment before. One observation a segment",
     "replay.phase_cpu_seconds": "CPU seconds of the thread that ran each phase of a replayed segment (prefetch, pack, dispatch on the lookahead worker; ready_wait, sig_wait, witness_wait, execute, root on the run loop), by the thread's CPU clock read beside the span clock at the phase's ends; one observation a phase a segment, booked as engine_api.phase_cpu_seconds is (Metrics.observe_split)",
     "replay.phase_offcpu_seconds": "A replayed segment's phase wall less replay.phase_cpu_seconds, observation by observation: what the phase's thread waited, for a lane (sig_wait, witness_wait), for the lookahead (ready_wait), or for its turn at the interpreter lock",

@@ -152,6 +152,22 @@ def node_encoder(request, monkeypatch):
     return request.param
 
 
+@pytest.fixture
+def walk_counts(node_encoder):
+    """`walk_counts()`: (`mpt.node_encodings`, `mpt.ref_hashes`) of the
+    encoder under test so far: what its host walks encoded, and hashed."""
+    from phant_tpu.utils.trace import metrics
+
+    def counts() -> tuple:
+        counters = metrics.snapshot()["counters"]
+        return tuple(
+            counters.get(f'mpt.{name}{{impl="{node_encoder}"}}', 0)
+            for name in ("node_encodings", "ref_hashes")
+        )
+
+    return counts
+
+
 @pytest.fixture(scope="module")
 def reference_block(tmp_path_factory):
     """Block 2 of a chain of the benchmark's own reference

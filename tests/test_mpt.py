@@ -310,6 +310,14 @@ def _oracle(node, path_enc=encode_hex_prefix, embed_below=32):
     return structure, rlp.encode_python(structure)
 
 
+def _oracle_entry(node, path_enc=encode_hex_prefix, embed_below=32):
+    """The memo's entry of a node: its oracle, and the reference its parent
+    holds of it."""
+    structure, encoded = _oracle(node, path_enc, embed_below)
+    ref = structure if len(encoded) < embed_below else keccak256(encoded)
+    return structure, encoded, ref
+
+
 def _leaf(path, size, fill=0xAB):
     from phant_tpu.mpt.mpt import LeafNode
 
@@ -370,21 +378,21 @@ _NODE_CASES = _node_cases()
 
 @pytest.mark.parametrize("case", sorted(_NODE_CASES))
 def test_node_encoding_matches_the_oracle(node_encoder, case):
-    """Structure AND encoding, of the node and of everything under it that
-    the walk left in the memo."""
+    """Structure, encoding AND reference, of the node and of everything
+    under it that the walk left in the memo."""
     from phant_tpu.mpt.mpt import BranchNode, ExtensionNode
 
     node = _NODE_CASES[case]
     trie = _witness_trie()
-    assert trie.node_encoding(node) == _oracle(node)
-    assert trie._enc_cache[id(node)] == _oracle(node)
+    assert trie.node_encoding(node) == _oracle_entry(node)
+    assert trie._enc_cache[id(node)] == _oracle_entry(node)
     stack = [node]
     while stack:
         at = stack.pop()
         if isinstance(at, _Edge):
             assert id(at) not in trie._enc_cache
             continue
-        assert trie._enc_cache[id(at)] == _oracle(at), type(at).__name__
+        assert trie._enc_cache[id(at)] == _oracle_entry(at), type(at).__name__
         if isinstance(at, ExtensionNode):
             stack.append(at.child)
         elif isinstance(at, BranchNode):
@@ -406,7 +414,7 @@ def test_random_tries_root_and_memo_match_the_oracle(node_encoder, seed):
         trie.delete(key)
     root = trie.root_hash()
     assert root == keccak256(_oracle(trie.root)[1])
-    assert trie._enc_cache[id(trie.root)] == _oracle(trie.root)
+    assert trie._enc_cache[id(trie.root)] == _oracle_entry(trie.root)
     # a put evicts its path alone; the walk fills in what is missing
     held = len(trie._enc_cache)
     trie.put(keys[1], b"\x05" * 40)
@@ -422,9 +430,214 @@ def test_binary_scheme_hashes_every_child(node_encoder):
     trie = BinaryTrie()
     for i in range(40):
         trie.put(keccak256(bytes([i])), bytes([i + 1]) * (1 + i % 5))
-    want = _oracle(trie.root, path_enc=encode_bit_prefix, embed_below=0)
+    want = _oracle_entry(trie.root, path_enc=encode_bit_prefix, embed_below=0)
     assert trie.node_encoding(trie.root) == want
-    assert trie.root_hash() == keccak256(want[1])
+    assert trie.root_hash() == keccak256(want[1]) == want[2]
+
+
+# --- the memo keeps each node's reference ------------------------------------
+#
+# An entry is (structure, encoding, reference): a dirty branch reads what it
+# holds of a clean child from the child's entry and hashes nothing twice.
+# One entry, so whatever drops an encoding drops the reference with it; the
+# cases below are the ways a reference could outlive its encoding.
+
+
+def _scheme(name):
+    """(trie class, the oracle's hooks) of a commitment scheme."""
+    if name == "hexary":
+        return Trie, {}
+    from phant_tpu.commitment.binary import BinaryTrie, encode_bit_prefix
+
+    return BinaryTrie, {"path_enc": encode_bit_prefix, "embed_below": 0}
+
+
+def _assert_memo_is_the_oracles(trie, hooks):
+    """Every node under the root has its entry, and each entry is what the
+    oracle computes from the structure as it stands NOW."""
+    from phant_tpu.mpt.mpt import BranchNode, ExtensionNode
+
+    stack, nodes = [trie.root], 0
+    while stack:
+        at = stack.pop()
+        nodes += 1
+        assert trie._enc_cache[id(at)] == _oracle_entry(at, **hooks), type(at).__name__
+        if isinstance(at, ExtensionNode):
+            stack.append(at.child)
+        elif isinstance(at, BranchNode):
+            stack.extend(c for c in at.children if c is not None)
+    # and nothing else: an entry of a node that left the trie is a
+    # reference waiting for its id to be used again
+    assert len(trie._enc_cache) == nodes
+
+
+@pytest.mark.parametrize("scheme", ["hexary", "binary"])
+@pytest.mark.parametrize("seed", range(3))
+def test_a_retained_trie_roots_like_a_fresh_build_after_every_round(
+    walk_counts, scheme, seed
+):
+    """Rounds of random puts, overwrites and deletes on ONE trie that keeps
+    its memo: after every round its root is a fresh build's, every entry of
+    the memo is the oracle's, and the round hashed no node twice."""
+    cls, hooks = _scheme(scheme)
+    rng = random.Random(4300 + seed)
+    held: dict = {}
+    trie = cls()
+
+    def value():
+        return rng.randbytes(rng.choice((1, 3, 33, 70)))
+
+    for round_no in range(8):
+        for _ in range(rng.randint(1, 40)):
+            roll = rng.random()
+            if held and roll < 0.3:
+                key = rng.choice(list(held))
+                trie.delete(key)
+                del held[key]
+            elif held and roll < 0.55:
+                key = rng.choice(list(held))
+                held[key] = value()
+                trie.put(key, held[key])
+            else:
+                key = rng.randbytes(rng.choice((1, 2, 32)))
+                held[key] = value()
+                trie.put(key, held[key])
+        before = walk_counts()
+        root = trie.root_hash()
+        encoded, hashed = (b - a for a, b in zip(before, walk_counts()))
+        fresh = cls()
+        for key, val in held.items():
+            fresh.put(key, val)
+        assert root == fresh.root_hash(), f"round {round_no}"
+        if trie.root is None:
+            continue
+        assert root == keccak256(_oracle(trie.root, **hooks)[1])
+        _assert_memo_is_the_oracles(trie, hooks)
+        assert 0 < encoded and hashed <= encoded, (round_no, encoded, hashed)
+    # a root with nothing dirty encodes nothing and hashes nothing
+    before = walk_counts()
+    assert trie.root_hash() == root
+    assert walk_counts() == before
+
+
+@pytest.mark.parametrize("scheme", ["hexary", "binary"])
+def test_a_root_after_k_updates_hashes_each_dirty_node_once(walk_counts, scheme):
+    """`mpt.ref_hashes` of a root is at most its `mpt.node_encodings`: the
+    dirty nodes are hashed, their clean siblings are read. (With the
+    reference left out of the memo's entry a root after 20 updates of 4,000
+    keys hashed seven to nine times the nodes it encoded.)"""
+    cls, _hooks = _scheme(scheme)
+    rng = random.Random(43)
+    keys = [keccak256(i.to_bytes(4, "big")) for i in range(4000)]
+    trie = cls()
+    for key in keys:
+        trie.put(key, rng.randbytes(70))
+    before = walk_counts()
+    trie.root_hash()
+    encoded, hashed = (b - a for a, b in zip(before, walk_counts()))
+    # the first root hashes every node once (all of them 32 bytes or more)
+    assert encoded == hashed == len(trie._enc_cache)
+    for key in rng.sample(keys, 20):
+        trie.put(key, rng.randbytes(70))
+    before = walk_counts()
+    trie.root_hash()
+    encoded, hashed = (b - a for a, b in zip(before, walk_counts()))
+    assert 20 < encoded < 400
+    assert 0 < hashed <= encoded
+
+
+def test_a_root_that_encodes_under_32_bytes_is_hashed_never_embedded(node_encoder):
+    """A short root's reference is its structure, as any short node's; the
+    trie's root is keccak256 of its encoding all the same, before the trie
+    grows past 32 bytes, after, and when it has shrunk again."""
+    trie = Trie()
+    trie.put(b"\x01", b"a")
+    structure, encoded, ref = trie.node_encoding(trie.root)
+    assert len(encoded) < 32 and ref is structure
+    assert trie.root_hash() == keccak256(encoded) == _rebuild_root({b"\x01": b"a"})
+    assert trie.root_hash() == keccak256(encoded)  # from the memo, the same
+    trie.put(b"\x02", b"b" * 40)
+    assert len(trie.node_encoding(trie.root)[1]) >= 32
+    assert trie.root_hash() == _rebuild_root({b"\x01": b"a", b"\x02": b"b" * 40})
+    assert trie.root_hash() == trie._enc_cache[id(trie.root)][2]
+    trie.delete(b"\x02")
+    assert trie.root_hash() == keccak256(encoded)
+    _assert_memo_is_the_oracles(trie, {})
+
+
+def test_an_embedded_child_beside_hashed_ones_stays_a_structure(walk_counts):
+    """A branch with a short child (held as its structure) among long ones
+    (held as digests): a write to one long sibling re-encodes the branch
+    from the others' entries, the embedded one's too; then the short child
+    grows past 32 bytes and shrinks back, and its reference follows."""
+    from phant_tpu.mpt.mpt import BranchNode
+
+    held = {bytes([0x10 * i, 0x11]): bytes([i]) * 40 for i in range(1, 6)}
+    held[b"\x00\x01"] = b"s"  # the short one: [hex-prefix, b"s"] in 5 bytes
+    trie = Trie()
+    for key, val in held.items():
+        trie.put(key, val)
+    assert trie.root_hash() == _rebuild_root(held)
+    assert isinstance(trie.root, BranchNode)
+    short = trie.root.children[0]
+    assert trie._enc_cache[id(short)][2] is trie._enc_cache[id(short)][0]
+    assert isinstance(trie._enc_cache[id(trie.root.children[1])][2], bytes)
+
+    before = walk_counts()
+    held[b"\x10\x11"] = b"\xee" * 50
+    trie.put(b"\x10\x11", held[b"\x10\x11"])
+    assert id(short) in trie._enc_cache  # off the dirty path
+    root = trie.root_hash()
+    encoded, hashed = (b - a for a, b in zip(before, walk_counts()))
+    assert (encoded, hashed) == (2, 2)  # the leaf and the root, no sibling
+    assert root == _rebuild_root(held)
+    _assert_memo_is_the_oracles(trie, {})
+
+    for val in (b"L" * 60, b"s"):
+        held[b"\x00\x01"] = val
+        trie.put(b"\x00\x01", val)
+        assert trie.root_hash() == _rebuild_root(held)
+        _assert_memo_is_the_oracles(trie, {})
+    now = trie._enc_cache[id(trie.root.children[0])]
+    assert now[2] is now[0] and len(now[1]) < 32
+
+
+def test_a_freed_nodes_id_never_answers_for_its_successor(node_encoder):
+    """Deleted subtrees are freed and CPython hands their addresses to the
+    next nodes made: rounds of delete-everything-under-a-prefix and put it
+    back with other values must never read a dead node's reference."""
+    import gc
+
+    rng = random.Random(7)
+    held = {rng.randbytes(4): rng.randbytes(40) for _ in range(300)}
+    trie = Trie()
+    for key, val in held.items():
+        trie.put(key, val)
+    trie.root_hash()
+    for round_no in range(6):
+        gone = [key for key in held if key[0] % 4 == round_no % 4]
+        for key in gone:
+            trie.delete(key)
+            del held[key]
+        trie.root_hash()  # entries of what is left, some beside freed nodes
+        gc.collect()
+        for key in gone:
+            held[key] = rng.randbytes(40)
+            trie.put(key, held[key])
+        assert trie.root_hash() == _rebuild_root(held), round_no
+        _assert_memo_is_the_oracles(trie, {})
+
+
+def test_a_memo_entry_of_another_form_is_refused(node_encoder):
+    """An entry without its reference (the form until PR 43) is an error,
+    never a read past the tuple's end."""
+    node = _leaf((1, 2), 40)
+    trie = Trie()
+    root = _branch({3: node})
+    trie._enc_cache[id(node)] = _oracle(node)
+    with pytest.raises((TypeError, IndexError)):
+        trie.node_encoding(root)
+    assert id(root) not in trie._enc_cache
 
 
 @pytest.mark.parametrize(

@@ -247,6 +247,100 @@ def test_incremental_root_survives_rollback_after_state_root():
     assert db.state_root() == good == rebuild_root(db.accounts)
 
 
+@pytest.mark.parametrize("seed", [43, 44])
+def test_a_rejected_blocks_root_leaves_no_reference_behind(walk_counts, seed):
+    """The rejected-block path on a trie that keeps each node's reference:
+    `state_root()` syncs the retained tries to the block's post-state (new
+    nodes made, old ones freed), `revert_to(0)` rejects it, and the next
+    `state_root()` must be the pre-block root and a full rebuild's: a
+    reference that outlived its node, or answered for a new node at a dead
+    one's address, is a wrong root here. Accepted blocks in between; and
+    every root hashes at most the nodes it encoded."""
+    import gc
+
+    import numpy as np
+
+    from phant_tpu.state.root import state_root as rebuild_root
+    from phant_tpu.state.statedb import StateDB
+    from phant_tpu.types.account import Account
+
+    rng = np.random.default_rng(seed)
+    db = StateDB(
+        {
+            rng.bytes(20): Account(
+                balance=int(rng.integers(1, 10**12)),
+                storage={s: s + 1 for s in range(int(rng.integers(0, 4)))},
+            )
+            for _ in range(600)
+        }
+    )
+    assert db.state_root() == rebuild_root(db.accounts)
+
+    for block in range(6):
+        addrs = list(db.accounts)
+        good = db.state_root()
+        db.begin_block()
+        for _ in range(40):
+            a = addrs[int(rng.integers(0, len(addrs)))]
+            db.add_balance(a, 3)
+            db.set_storage(a, int(rng.integers(0, 6)), int(rng.integers(0, 3)))
+        for _ in range(25):  # new leaves split old ones: new nodes, freed nodes
+            db.set_balance(rng.bytes(20), int(rng.integers(1, 10**9)))
+        for i in rng.choice(len(addrs), size=25, replace=False):
+            db.delete_account(addrs[int(i)])  # branches collapse
+        before = walk_counts()
+        post = db.state_root()
+        encoded, hashed = (b - a for a, b in zip(before, walk_counts()))
+        assert 0 < hashed <= encoded, (block, encoded, hashed)
+        assert post != good
+        if block % 2:
+            assert post == rebuild_root(db.accounts)
+            continue  # accepted
+        db.revert_to(0)
+        gc.collect()
+        before = walk_counts()
+        again = db.state_root()
+        encoded, hashed = (b - a for a, b in zip(before, walk_counts()))
+        assert 0 < hashed <= encoded, (block, encoded, hashed)
+        assert again == good == rebuild_root(db.accounts), block
+
+
+def test_delete_and_recreate_of_an_account_with_storage(node_encoder):
+    """An account with a retained storage trie is deleted and made again in
+    one block, with a root taken at every step and the block rejected at
+    the end: the storage trie's entries go with the trie, the account's
+    leaf is re-encoded with each storage root, and nothing of the dead
+    account is read afterwards."""
+    import numpy as np
+
+    from phant_tpu.state.root import state_root as rebuild_root
+    from phant_tpu.state.statedb import StateDB
+    from phant_tpu.types.account import Account
+
+    rng = np.random.default_rng(45)
+    accounts = {rng.bytes(20): Account(balance=int(rng.integers(1, 10**9))) for _ in range(80)}
+    big = rng.bytes(20)
+    accounts[big] = Account(code=b"\xfe", storage={i: i + 1 for i in range(150)})
+    db = StateDB(accounts)
+    good = db.state_root()
+    assert good == rebuild_root(db.accounts)
+
+    db.begin_block()
+    db.set_storage(big, 3, 99)
+    assert db.state_root() == rebuild_root(db.accounts)
+    db.delete_account(big)
+    assert db.state_root() == rebuild_root(db.accounts)
+    db.create_account(big)
+    db.set_balance(big, 5)
+    for slot in range(0, 40, 3):
+        db.set_storage(big, slot, 1000 + slot)
+    assert db.state_root() == rebuild_root(db.accounts)
+    db.set_storage(big, 3, 0)
+    assert db.state_root() == rebuild_root(db.accounts)
+    db.rollback_block()
+    assert db.state_root() == good == rebuild_root(db.accounts)
+
+
 def test_incremental_storage_root_heavy_account():
     """Per-account retained storage tries: repeated single-slot writes to a
     large contract must stay correct across roots, deletion, recreation."""
