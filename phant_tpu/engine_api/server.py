@@ -540,6 +540,8 @@ class EngineAPIServer:
                     log.debug("client stalled mid-body; connection dropped")
                     self.close_connection = True
                     return
+                # what `read` read and `json` and `decode` are about to parse
+                metrics.count("engine_api.request_body_bytes", len(body))
                 req = current_span()
                 req.mark("json")
                 try:

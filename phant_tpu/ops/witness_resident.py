@@ -91,13 +91,28 @@ __all__ = [
     "verdict_rung",
 ]
 
-#: THE shape set of `_verdict_impl`: (node rows, blocks) of one launch, as
-#: ONE index: one, two and four requests of mainnet's shape (a lone block
-#: under a 2^20 genesis is 1,400-1,580 nodes and sits on the first rung, as
-#: it did when the program was keyed on the wave's own powers of two). A
-#: wave above the top rung goes out as several launches, cut between
-#: blocks: a block's nodes only ever reference each other.
-VERDICT_LADDER: Tuple[Tuple[int, int], ...] = ((2048, 1), (4096, 2), (8192, 4))
+#: THE shape set of `_verdict_impl`: (node rows, blocks) of one launch. The
+#: first three rungs are a WAVE's, one index: one, two and four requests of
+#: mainnet's usual shape (a lone block of 225 transactions under a 2^20
+#: genesis is 1,400-1,580 nodes and sits on the first rung, as it did when
+#: the program was keyed on the wave's own powers of two). A wave above
+#: the widest of them (`_wave_rung`: the rung of the most blocks) goes out
+#: as several launches, cut between blocks: a block's nodes only ever
+#: reference each other. The last rung is ONE block's, and no wave is cut
+#: at it: a block's verdict is one reachability over all its rows and
+#: cannot be cut, and a block at the gas limit is wider than a wave of
+#: four usual ones (30M gas of plain transfers between distinct accounts
+#: touch 2,857 accounts: about 10,050 nodes under a 2^20 genesis, about
+#: 15,800 at mainnet's depth of 7-8). The boot builds it with the others
+#: (`ResidentTable.prewarm`), so such a block never waits for a build; a
+#: block above it (30M gas of cold SLOADs, 14,285 slots) keeps a shape of
+#: its own, counted in `lanes.oversize_launches`.
+VERDICT_LADDER: Tuple[Tuple[int, int], ...] = (
+    (2048, 1),
+    (4096, 2),
+    (8192, 4),
+    (16384, 1),
+)
 
 #: THE shape set of `_update_impl` and `_gather_impl`, in rows: ONE rung, a
 #: request's novel nodes (1,400-1,580 under a 2^20 genesis sat on 2,048
@@ -112,9 +127,16 @@ VERDICT_LADDER: Tuple[Tuple[int, int], ...] = ((2048, 1), (4096, 2), (8192, 4))
 ROW_LADDER: Tuple[int, ...] = (2048,)
 
 
+def _wave_rung() -> Tuple[int, int]:
+    """The rung a wave is cut at: the one that holds the most blocks."""
+    return max(VERDICT_LADDER, key=lambda rung: (rung[1], rung[0]))
+
+
 def verdict_rung(n_nodes: int, n_blocks: int) -> Optional[Tuple[int, int]]:
     """The first rung of VERDICT_LADDER that holds a launch of `n_nodes`
-    rows in `n_blocks` blocks, or None above the top rung."""
+    rows in `n_blocks` blocks, or None where none does: one block above
+    the lone block's rung (`_verdict_launches` never asks for a wave
+    above the wave's)."""
     for rows, blocks in VERDICT_LADDER:
         if rows >= n_nodes and blocks >= n_blocks:
             return rows, blocks
@@ -124,10 +146,11 @@ def verdict_rung(n_nodes: int, n_blocks: int) -> Optional[Tuple[int, int]]:
 def _verdict_launches(counts: Sequence[int]) -> List[Tuple[int, int, Tuple[int, int]]]:
     """Cut a wave of blocks of `counts` nodes into launches of whole
     blocks, in order: (first block, one past the last, the launch's shape),
-    each as many blocks as the top rung holds. One block above the top
-    rung's rows stands alone on its own power of two, the one shape
-    outside the ladder (`lanes.oversize_launches`)."""
-    top_rows, top_blocks = VERDICT_LADDER[-1]
+    each as many blocks as the wave's rung holds. One block above that
+    rung's rows stands alone: on the lone block's rung where it fits,
+    else on its own power of two, the one shape outside the ladder
+    (`lanes.oversize_launches`)."""
+    top_rows, top_blocks = _wave_rung()
     cuts = []
     first = rows = 0
     for b, n in enumerate(counts):

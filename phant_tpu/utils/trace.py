@@ -186,6 +186,7 @@ METRIC_HELP: Dict[str, str] = {
     "engine_api.inflight": "Engine API requests currently being handled",
     "engine_api.request_seconds": "Engine API request latency (decode + handle + reply)",
     "engine_api.phase_seconds": "The front end's own share of a POST, by phase, timed where the work happens (engine_api/server.py): read = headers parsed -> body read; json = json.loads; gate = the wait for a slot of the stateless gate; decode = payload and witness hex/RLP decode and the block-hash check, up to where verify_block opens; reply = result -> bytes -> written. With verify_block's wall clock they tile engine_api.request_seconds",
+    "engine_api.request_body_bytes": "Bytes of the POST bodies the front end read (Content-Length as received, counted in engine_api/server.py where the body is read): what the read, json and decode phases of engine_api.phase_seconds worked on; a block with its witness in hex JSON is 1.0 MB at 225 transactions and 5.9 MB at the gas limit",
     "engine_api.phase_cpu_seconds": "CPU seconds the handler thread ran inside each front-end phase of a POST (the thread's CPU clock read at the marks of engine_api.phase_seconds, one observation a phase a request, never above the phase's wall: what a reading of a clock that steps by ticks holds beyond it is booked against the series' next observations, Metrics.observe_split)",
     "engine_api.phase_offcpu_seconds": "engine_api.phase_seconds less engine_api.phase_cpu_seconds, phase by phase: what the handler thread waited inside the phase. In json, decode and reply it waits for nothing by design, so that is its wait for a turn at the interpreter lock; read waits for the socket, gate for a slot",
     "engine_api.decode_payload": "JSON -> ExecutionPayload decode phase",
@@ -255,7 +256,7 @@ METRIC_HELP: Dict[str, str] = {
     "lanes.program_shapes": "Distinct shapes each served device program has run on in this process, by program (ecrecover: device and signature rung; verdict, gather, update: device, table rows and rung): a server on an accelerator builds every rung of the table's ladders before its port answers and the first request ecrecover's one, so it stops there; exported from server start",
     "lanes.launches": "Launches of served device programs by program and rung (verdict: rows x blocks), the boot's own among them; a rung that first appears after server start was built inside a request",
     "lanes.split_launches": "Launches of waves above a ladder's top rung, which go out as several launches of the top rung, by program",
-    "lanes.oversize_launches": "Launches outside a ladder, by program: one block of more witness nodes than the verdict ladder's top rung holds keeps a shape of its own",
+    "lanes.oversize_launches": "Launches outside a ladder, by program: one block of more witness nodes than the verdict ladder's lone-block rung holds (16,384: a gas-limit block of plain transfers fits, one of 14,285 cold storage reads does not) keeps a shape of its own, built inside the request",
     "lanes.prewarm_seconds": "Seconds the server's constructor took to build the resident table's update, verdict and gather programs on every rung of their ladders (only with an accelerator under it)",
     "sig.rows": "Signature rows of the launches of the ecrecover kernel, by kind: real = signatures, pad = the filler rows up to the launch's rung of secp256k1_jax.SIG_LADDER",
     "witness_resident.dispatch": "Resident dispatch phase: prune + row assignment + update/verdict enqueue, no host sync",

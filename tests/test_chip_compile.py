@@ -153,6 +153,28 @@ def test_resident_verdict_compiles_for_v5e(shape, cap, rows):
     assert compiled.memory_analysis().temp_size_in_bytes < (1 << 30)
 
 
+def test_the_lone_blocks_verdict_rung_compiles_for_v5e_at_the_shipped_cap(shape):
+    """(16,384 rows, 1 block) against a table of 2^20 rows: the shape a
+    block at the gas limit is launched on (PR 44), the widest the boot
+    builds. Its temporaries (290 MiB here) grow with the rows' 17 references
+    each; held to 1 GiB beside a table of 0.7 GB on a chip of 16."""
+    import jax
+    import jax.numpy as jnp
+
+    from phant_tpu.ops.witness_resident import VERDICT_LADDER, _verdict_impl
+
+    rows, blocks = max(VERDICT_LADDER)
+    assert (rows, blocks) == (16384, 1)
+    compiled = jax.jit(_verdict_impl).lower(
+        *_table_shapes(shape, 1 << 20)[:3],
+        shape((rows,), jnp.int32),
+        shape((rows,), jnp.bool_),
+        shape((rows,), jnp.int32),
+        shape((blocks, 8), jnp.uint32),
+    ).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < (1 << 30)
+
+
 def test_root_plan_compiles_for_v5e(shape, pallas_is_the_keccak):
     import jax.numpy as jnp
 

@@ -65,8 +65,65 @@ def test_a_lone_request_lands_where_it_did():
         assert verdict_rung(nodes, 1) == (2048, 1)
         assert rungs.launches(ROW_LADDER, nodes) == [2048]
     assert SIG_LADDER == (256,)  # one rung: the first request builds the only shape
-    assert VERDICT_LADDER == ((2048, 1), (4096, 2), (8192, 4))
+    # a wave's three rungs, and ONE block's at the gas limit (PR 44)
+    assert VERDICT_LADDER == ((2048, 1), (4096, 2), (8192, 4), (16384, 1))
     assert ROW_LADDER == (2048,)  # one rung, as the signatures': the boot's seconds
+
+
+#: one block FULL of transfers between distinct accounts (1,428 senders,
+#: 1,428 recipients, the coinbase) under a 2^20 genesis: 9,900-10,050 nodes
+GAS_LIMIT_NODES = 10050
+
+
+@pytest.mark.parametrize("nodes", [8193, GAS_LIMIT_NODES, 16384])
+def test_one_block_at_the_gas_limit_sits_on_the_lone_blocks_rung(nodes):
+    """Above a wave's widest rung one block takes (16,384, 1), a member of
+    the ladder (what `lanes.oversize_launches` counts is a shape that is
+    not), alone or between the blocks of a wave, which are cut as before."""
+    from phant_tpu.ops.witness_resident import (
+        VERDICT_LADDER,
+        _verdict_launches,
+        verdict_rows,
+        verdict_rung,
+    )
+
+    assert verdict_rung(nodes, 1) == (16384, 1) and (16384, 1) in VERDICT_LADDER
+    assert verdict_rung(nodes, 2) is None  # no wave is cut that wide
+    assert _verdict_launches([nodes]) == [(0, 1, (16384, 1))]
+    assert verdict_rows([nodes]) == 16384
+    assert _verdict_launches([LONE_NODES, nodes, LONE_NODES, LONE_NODES]) == [
+        (0, 1, (2048, 1)),
+        (1, 2, (16384, 1)),
+        (2, 4, (4096, 2)),
+    ]
+
+
+def test_one_block_above_the_lone_blocks_rung_is_still_oversize():
+    from phant_tpu.ops.witness_resident import VERDICT_LADDER, _verdict_launches, verdict_rung
+
+    assert verdict_rung(16385, 1) is None
+    assert _verdict_launches([16385]) == [(0, 1, (32768, 1))]
+    assert (32768, 1) not in VERDICT_LADDER
+
+
+@pytest.mark.parametrize(
+    "wave,cuts",
+    [
+        (1, [(0, 1, (2048, 1))]),
+        (2, [(0, 2, (4096, 2))]),
+        (3, [(0, 3, (8192, 4))]),
+        (4, [(0, 4, (8192, 4))]),
+        (16, [(0, 4, (8192, 4)), (4, 8, (8192, 4)), (8, 12, (8192, 4)), (12, 16, (8192, 4))]),
+    ],
+)
+def test_a_wave_is_cut_where_it_was_before_the_lone_blocks_rung(wave, cuts):
+    """The cuts of e003592 (PR 43), whose ladder ended at (8,192, 4): a wave
+    of usual blocks is never put on the lone block's rung, five blocks of
+    1,500 nodes (7,500 rows) are still cut at four."""
+    from phant_tpu.ops.witness_resident import _verdict_launches, _wave_rung
+
+    assert _wave_rung() == (8192, 4)
+    assert _verdict_launches([LONE_NODES] * wave) == cuts
 
 
 @pytest.mark.parametrize("wave", range(1, 10))
@@ -92,19 +149,21 @@ def test_a_wave_of_whole_requests_stays_on_the_ladders(wave):
         assert shape in VERDICT_LADDER
         assert shape[0] >= (hi - lo) * LONE_NODES and shape[1] >= hi - lo
     if wave == 3:
-        assert cuts == [(0, 3, (8192, 4))]  # one index: three blocks take the top rung
+        assert cuts == [(0, 3, (8192, 4))]  # one index: three blocks take the wave's widest rung
 
 
 @pytest.mark.parametrize("seed", range(8))
 def test_random_counts_stay_on_the_ladders(seed):
     """Random signature counts, novel-node counts and waves of random
-    blocks up to 4 x the top rung: closed, whole blocks a launch, in
-    order; only one block above the top rung's rows leaves the ladder."""
+    blocks up to 4 x the wave's rung: closed, whole blocks a launch, in
+    order; one block above the wave's rung stands alone on the lone block's,
+    and only one above that leaves the ladder."""
     from phant_tpu.ops.secp256k1_jax import SIG_LADDER
     from phant_tpu.ops.witness_resident import (
         ROW_LADDER,
         VERDICT_LADDER,
         _verdict_launches,
+        _wave_rung,
         verdict_rows,
     )
 
@@ -114,7 +173,8 @@ def test_random_counts_stay_on_the_ladders(seed):
             got = rungs.launches(ladder, n)
             assert set(got) <= set(ladder) and n <= sum(got) < n + ladder[-1] + ladder[0]
             assert got[:-1] == [ladder[-1]] * (len(got) - 1)
-    top_rows, top_blocks = VERDICT_LADDER[-1]
+    top_rows, top_blocks = _wave_rung()
+    lone_rows = max(rows for rows, _blocks in VERDICT_LADDER)
     for _ in range(20):
         counts = rng.integers(1, 4000, int(rng.integers(1, 40))).tolist()
         if seed % 2:
@@ -126,7 +186,9 @@ def test_random_counts_stay_on_the_ladders(seed):
             n = sum(counts[lo:hi])
             assert rows >= n and blocks >= hi - lo and hi - lo <= top_blocks
             if (rows, blocks) not in VERDICT_LADDER:
-                assert hi - lo == 1 and n > top_rows and rows == rungs.pow2ceil(n)
+                assert hi - lo == 1 and n > lone_rows and rows == rungs.pow2ceil(n)
+            elif n > top_rows:
+                assert hi - lo == 1 and (rows, blocks) == (lone_rows, 1)
         assert verdict_rows(counts) == sum(c[2][0] for c in cuts)
 
 
@@ -269,6 +331,26 @@ def test_one_block_above_the_top_rung_keeps_a_shape_of_its_own(tpu_backend, monk
     assert _counter("lanes.oversize_launches", program="verdict") == over0 + 3
 
 
+def test_one_block_on_the_lone_blocks_rung_is_launched_alone_and_counted_nowhere(tpu_backend, monkeypatch):
+    """The same three blocks, each wider than the wave's rung, on a ladder
+    that has a rung for one such block: three launches of it, the verdicts
+    of the host route (the block with a tampered node refused alone), and
+    `lanes.oversize_launches` where it stood."""
+    from phant_tpu.ops import witness_resident as wr
+
+    monkeypatch.setattr(wr, "VERDICT_LADDER", ((4, 1), (8, 2), (64, 1)))
+    monkeypatch.setattr(wr, "ROW_LADDER", (32,))
+    _root, wits = build_witnesses(n_blocks=3)
+    assert all(8 < len(nodes) <= 64 for _r, nodes in wits)
+    over0 = _counter("lanes.oversize_launches", program="verdict")
+    on64 = _counter("lanes.launches", program="verdict", rung="64x1")
+    table = wr.ResidentTable(max_cap=1024, start_cap=1024)
+    verdicts, _d = table.dispatch(_tamper(wits, 1), []).resolve()
+    assert verdicts.tolist() == [True, False, True]
+    assert _counter("lanes.launches", program="verdict", rung="64x1") == on64 + 3
+    assert _counter("lanes.oversize_launches", program="verdict") == over0
+
+
 # -- (iii) the deployment against the plain reference ---------------------------
 
 
@@ -351,7 +433,7 @@ def test_the_boot_builds_every_rung_and_a_wave_builds_none(tpu_backend, monkeypa
     from phant_tpu.ops.witness_engine import WitnessEngine
     from phant_tpu.serving.scheduler import SchedulerConfig, VerificationScheduler
 
-    monkeypatch.setattr(wr, "VERDICT_LADDER", ((32, 1), (128, 4)))
+    monkeypatch.setattr(wr, "VERDICT_LADDER", ((32, 1), (128, 4), (256, 1)))
     monkeypatch.setattr(wr, "ROW_LADDER", (16, 128))
     eng = WitnessEngine(resident=True, resident_cap=512)
     _root, wits = build_witnesses(n_blocks=6)
@@ -361,11 +443,11 @@ def test_the_boot_builds_every_rung_and_a_wave_builds_none(tpu_backend, monkeypa
         gauges = metrics.snapshot()["gauges"]
         assert all(f'lanes.program_shapes{{program="{p}"}}' in gauges for p in srv.LANE_PROGRAMS)
         seen = {p: rungs.shapes_of(p) for p in srv.LANE_PROGRAMS}
-        assert s.prewarm_lanes() == 2 * 2 + 2
+        assert s.prewarm_lanes() == 2 * 2 + 3
         table = eng.resident_table()
         assert table is not None and table.rows() == 0
         built = {p: {sh[0] for sh in rungs.shapes_of(p) - seen[p]} for p in srv.LANE_PROGRAMS}
-        assert built["verdict"] == {(32, 1), (128, 4)}
+        assert built["verdict"] == {(32, 1), (128, 4), (256, 1)}  # the lone block's rung too
         assert built["update"] == built["gather"] == {16, 128}
         assert built["ecrecover"] == set()  # one rung, left to the first request
         sizes = [f._cache_size() for f in (table._update_fn, table._verdict_fn, table._gather_fn)]
